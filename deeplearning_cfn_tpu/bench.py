@@ -18,10 +18,9 @@ _T0 = time.monotonic()
 
 
 def stage(name: str, **info) -> None:
-    """Emit a stage-timestamped marker to stderr. The wrapper (root
-    bench.py) parses the LAST marker out of a timed-out child's stderr, so
-    a hang is localized to the exact phase (plugin import? device enum?
-    first compile?) instead of reading as a bare 'timeout'."""
+    """Emit a stage-timestamped marker to stderr, so the tail of a run that
+    was cut at its time limit says which phase it was in (backend init?
+    first compile? the timed block?)."""
     extra = "".join(f" {k}={v}" for k, v in info.items())
     print(f"[bench-stage] t=+{time.monotonic() - _T0:.1f}s {name}{extra}",
           file=sys.stderr, flush=True)
@@ -78,12 +77,18 @@ _PEAK_FLOPS_BF16 = (
 
 
 def peak_flops_per_chip(device) -> Optional[float]:
-    """Peak bf16 FLOPs/sec for ``device``, or None if unknown (e.g. CPU)."""
+    """Peak bf16 FLOPs/sec for ``device``. None on the CPU (which runs only
+    where it was asked for by name, and carries ``mfu: null``); an
+    accelerator kind that is not in the table is an error, not a default."""
+    if device.platform == "cpu":
+        return None
     kind = getattr(device, "device_kind", "").lower()
     for key, peak in _PEAK_FLOPS_BF16:
         if key in kind:
             return peak
-    return None
+    raise ValueError(
+        f"unknown accelerator kind {device.device_kind!r}: add its peak "
+        f"bf16 FLOP/s (with its source) to _PEAK_FLOPS_BF16")
 
 
 def _flops_of(compiled) -> Optional[float]:
@@ -190,14 +195,10 @@ def run_bench(
     stage("import_jax")
     import jax
 
-    # No-op when JAX_PLATFORMS is unset (real-chip runs); otherwise applies
-    # it in-process — the env var alone is too late on images that
-    # pre-register a TPU plugin (see runtime/platform.py).
-    from .runtime.platform import honor_env_platform
-
-    honor_env_platform()
+    from .runtime.platform import require_accelerator
 
     stage("backend_init")  # first jax.devices() triggers PJRT client init
+    require_accelerator()
     devices = jax.devices()
     stage("devices_ok", n=len(devices),
           kind=getattr(devices[0], "device_kind", "unknown"))
@@ -293,32 +294,27 @@ def run_bench(
             return compiled_step(st, dev_batch, step_rng)
     compile_s = time.perf_counter() - t_c
 
-    # Warmup (cache effects); sync via a scalar device→host read — some
-    # PJRT transports complete ready-events before execution finishes.
-    # Windowed metrics are stacked [k]; the last element is the freshest
-    # step's scalar either way.
     stage("warmup", n=max(warmup, 1))
     for _ in range(max(warmup, 1)):
         state, m = dispatch(state)
-    float(np.asarray(m["loss"]).reshape(-1)[-1])
+    jax.block_until_ready(m)
     n_windows = max(1, steps // k)
     stage("timed", steps=n_windows * k)
 
     # Timed block: dispatch every step back-to-back with NO per-step sync —
     # steady-state pipelined throughput, the number that matters at pod
-    # scale — then one trailing sync. The final scalar read is data-dependent
-    # on every step (state chains through the loop), so it cannot complete
-    # before all the work has, even on transports whose ready-events fire
-    # early.
+    # scale — then one trailing block_until_ready inside the timed region
+    # (the state chains through the loop, so the last step's metrics are
+    # ready only when every step has run).
     t0 = time.perf_counter()
     for _ in range(n_windows):
         state, m = dispatch(state)
-    float(np.asarray(m["loss"]).reshape(-1)[-1])
+    jax.block_until_ready(m)
     mean_step_s = (time.perf_counter() - t0) / (n_windows * k)
 
     # MFU: XLA-counted per-device FLOPs per step vs one chip's peak bf16
-    # rate. 0.0 when the peak is unknown (CPU runs) or cost analysis is
-    # unavailable. Scanned presets take their numerator from a dense-twin
+    # rate. null on the CPU (no peak) or when cost analysis is unavailable
+    # — never 0.0. Scanned presets take their numerator from a dense-twin
     # compile (cost analysis counts a scan body once — r03 Weak #3). That
     # same counts-the-body-once behavior makes the windowed program's
     # analysis a per-STEP number, which is exactly what mean_step_s pairs
@@ -337,8 +333,8 @@ def run_bench(
         if dense_flops:
             flops = dense_flops
             mfu_source = f"dense_equivalent:{_DENSE_FLOPS_EQUIV[preset]}"
-    peak = peak_flops_per_chip(jax.devices()[0])
-    mfu = flops / (mean_step_s * peak) if flops and peak else 0.0
+    peak = peak_flops_per_chip(devices[0])
+    mfu = round(flops / (mean_step_s * peak), 4) if flops and peak else None
 
     per_chip = gb / mean_step_s / n_chips
     unit = _UNITS.get(preset, "items/sec/chip")
@@ -350,7 +346,7 @@ def run_bench(
         # it is only meaningful for that preset.
         "vs_baseline": round(per_chip / HOROVOD_V100_IMG_PER_SEC_PER_GPU, 3)
         if preset == "imagenet_resnet50" else 0.0,
-        "mfu": round(mfu, 4),
+        "mfu": mfu,
         "steps": n_windows * k,
         "step_window": k,
         "steps_per_sec": round(1.0 / mean_step_s, 3),
@@ -358,7 +354,8 @@ def run_bench(
         "global_batch": gb,
         "n_chips": n_chips,
         "mean_step_s": round(mean_step_s, 5),
-        "device_kind": getattr(jax.devices()[0], "device_kind", "unknown"),
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
         # The mesh the step actually ran on. On one chip every preset
         # degenerates to {data: 1} — in particular bert_long then runs its
         # DENSE flash-attention fallback, not ring/Ulysses (those need a
@@ -439,9 +436,9 @@ def run_obs_overhead_smoke(
     stage("import_jax")
     import jax
 
-    from .runtime.platform import honor_env_platform
+    from .runtime.platform import require_accelerator
 
-    honor_env_platform()
+    require_accelerator()
     import numpy as np
 
     from .config import MeshConfig, apply_overrides
@@ -557,8 +554,8 @@ def run_obs_overhead_smoke(
 
 
 def main(argv=None) -> None:
-    """Child-process entry for the driver bench (see root ``bench.py``):
-    run one preset and print the contract JSON line."""
+    """``python -m deeplearning_cfn_tpu.bench``: run one preset and print
+    the contract JSON line (root ``bench.py`` runs the flagship case)."""
     import argparse
     import json
 

@@ -39,6 +39,18 @@ def _stack_cfg_from_args(args) -> StackConfig:
     )
 
 
+def _use_accelerator(args, cfg: ExperimentConfig) -> None:
+    """Apply ``--accelerator`` to ``cfg`` and select this process's
+    backend: the CPU only where it was asked for by name, a TPU otherwise
+    (runtime/platform.py). Raises AcceleratorError, which ``main`` turns
+    into an error message and a non-zero exit."""
+    from ..runtime.platform import require_accelerator
+
+    if getattr(args, "accelerator", ""):
+        cfg.stack.accelerator = args.accelerator
+    require_accelerator(cfg.stack.accelerator)
+
+
 def _cmd_stack_create(args) -> int:
     from ..provision import ProvisionError, create_stack
 
@@ -153,20 +165,13 @@ def _cmd_info(args) -> int:
 
 def _cmd_train(args) -> int:
     cfg = apply_overrides(get_preset(args.preset), args.overrides)
-    if args.accelerator:
-        cfg.stack.accelerator = args.accelerator
 
     if args.stack:
         return _train_on_stack(args, cfg)
 
     # Single-host path: run in-process, exactly like executing a reference
     # example script on one node.
-    if cfg.stack.accelerator == "cpu":
-        # Env var alone is too late on images that pre-register a TPU
-        # plugin — must also flip the platform in-process (platform.py).
-        from ..runtime.platform import force_cpu_platform
-
-        force_cpu_platform()
+    _use_accelerator(args, cfg)
     from ..train.run import run_experiment
 
     final = run_experiment(cfg, max_steps=args.max_steps)
@@ -177,12 +182,7 @@ def _cmd_train(args) -> int:
 
 def _cmd_eval(args) -> int:
     cfg = apply_overrides(get_preset(args.preset), args.overrides)
-    if args.accelerator:
-        cfg.stack.accelerator = args.accelerator
-    if cfg.stack.accelerator == "cpu":
-        from ..runtime.platform import force_cpu_platform
-
-        force_cpu_platform()
+    _use_accelerator(args, cfg)
     from ..train.run import run_eval
 
     try:
@@ -202,12 +202,7 @@ def _cmd_generate(args) -> int:
     (a vocab.json from data prepare-wikipedia/prepare-wmt) the prompt is
     BPE-encoded and the continuation BPE-decoded instead."""
     cfg = apply_overrides(get_preset(args.preset), args.overrides)
-    if args.accelerator:
-        cfg.stack.accelerator = args.accelerator
-    if cfg.stack.accelerator == "cpu":
-        from ..runtime.platform import force_cpu_platform
-
-        force_cpu_platform()
+    _use_accelerator(args, cfg)
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -336,6 +331,9 @@ def _train_on_stack(args, cfg: ExperimentConfig) -> int:
 
 
 def _cmd_bench(args) -> int:
+    from ..runtime.platform import require_accelerator
+
+    require_accelerator()
     if getattr(args, "smoke", False) and not (
             getattr(args, "serve", False) or getattr(args, "fleet", False)):
         print("[dlcfn-tpu] --smoke is a serving-scenario mode — pass it "
@@ -518,9 +516,6 @@ def _cmd_bench(args) -> int:
         # The nccl-tests role: psum/all-gather/ppermute/reduce-scatter bus
         # bandwidth over the mesh's links, one JSON line per op.
         from ..parallel.collectives_bench import run_collectives_bench
-        from ..runtime.platform import honor_env_platform
-
-        honor_env_platform()  # env var alone is too late on this image
 
         for rec in run_collectives_bench(size_mb=args.size_mb):
             print(json.dumps(rec))
@@ -567,12 +562,7 @@ def _cmd_serve(args) -> int:
     optional ``id``, ``max_new_tokens``, ``beam_size``, ``deadline_s``,
     ``tenant``, ``qos_class``."""
     cfg = apply_overrides(get_preset(args.preset), args.overrides)
-    if args.accelerator:
-        cfg.stack.accelerator = args.accelerator
-    if cfg.stack.accelerator == "cpu":
-        from ..runtime.platform import force_cpu_platform
-
-        force_cpu_platform()
+    _use_accelerator(args, cfg)
     import numpy as np
 
     from ..metrics.jsonl import MetricsWriter
@@ -746,13 +736,8 @@ def _fleet_build_replicas(args, n: int, specs=None, kv_block_size: int = 0):
     from ..fleet import EngineReplica
     from ..serve.loader import load_engine
 
-    cfg0 = apply_overrides(get_preset(args.preset), args.overrides)
-    if args.accelerator:
-        cfg0.stack.accelerator = args.accelerator
-    if cfg0.stack.accelerator == "cpu":
-        from ..runtime.platform import force_cpu_platform
-
-        force_cpu_platform()
+    _use_accelerator(
+        args, apply_overrides(get_preset(args.preset), args.overrides))
     replicas, at_step = [], None
     bpe = None
     radix = getattr(args, "radix_cache", False)
@@ -765,8 +750,6 @@ def _fleet_build_replicas(args, n: int, specs=None, kv_block_size: int = 0):
         else [(f"replica-{i}", "both") for i in range(n)]
     for name, phase in roles:
         cfg = apply_overrides(get_preset(args.preset), args.overrides)
-        if args.accelerator:
-            cfg.stack.accelerator = args.accelerator
         engine, bpe, at_step = load_engine(
             cfg, capacity=args.slots,
             default_max_new_tokens=args.max_new_tokens,
@@ -853,8 +836,6 @@ def _fleet_up_disagg(args) -> int:
               "table", file=sys.stderr)
         return 2
     cfg = apply_overrides(get_preset(args.preset), args.overrides)
-    if args.accelerator:
-        cfg.stack.accelerator = args.accelerator
     run_root = args.run_root or os.path.join(
         cfg.workdir, args.preset, "fleet")
     os.makedirs(run_root, exist_ok=True)
@@ -927,9 +908,13 @@ def _fleet_up_net(args) -> int:
     from ..net.client import RemoteReplica
     from ..net.router import NetRouter
     from ..net.server import TINY_VOCAB
+    from ..runtime.platform import refuse_shared_chip
     from ..serve import OverloadError
 
     cfg = apply_overrides(get_preset(args.preset), args.overrides)
+    refuse_shared_chip(args.replicas,
+                       args.accelerator or cfg.stack.accelerator,
+                       f"fleet up --net --replicas {args.replicas}")
     run_root = args.run_root or os.path.join(
         cfg.workdir, args.preset, "fleet")
     os.makedirs(run_root, exist_ok=True)
@@ -1036,6 +1021,7 @@ def _cmd_fleet_up(args) -> int:
     disaggregated in-process topology instead."""
     from ..fleet import ReplicaProcSpec, ReplicaSupervisor
     from ..obs.report import render_fleet_report, summarize_fleet
+    from ..runtime.platform import refuse_shared_chip
 
     if getattr(args, "net", False):
         if getattr(args, "prefill", 0) or getattr(args, "decode", 0):
@@ -1048,8 +1034,10 @@ def _cmd_fleet_up(args) -> int:
     if getattr(args, "prefill", 0) or getattr(args, "decode", 0):
         return _fleet_up_disagg(args)
     cfg = apply_overrides(get_preset(args.preset), args.overrides)
-    if args.accelerator:
-        cfg.stack.accelerator = args.accelerator
+    # This parent stays off jax, so that one serve child can have the chip.
+    refuse_shared_chip(args.replicas,
+                       args.accelerator or cfg.stack.accelerator,
+                       f"fleet up --replicas {args.replicas}")
     try:
         with open(args.requests) as fh:
             lines = [ln for ln in fh if ln.strip()]
@@ -1167,8 +1155,6 @@ def _cmd_fleet_rollout(args) -> int:
         replicas, bpe, at_step = _fleet_build_replicas(args, args.replicas)
         trace, bpe2 = _fleet_read_trace(args.requests, args.vocab)
         cfg = apply_overrides(get_preset(args.preset), args.overrides)
-        if args.accelerator:
-            cfg.stack.accelerator = args.accelerator
         variables, to_step = restore_swap_variables(cfg, step=args.to_step)
     except (FileNotFoundError, ValueError, OSError) as e:
         print(f"[dlcfn-tpu] ERROR: {e}", file=sys.stderr)
@@ -1217,9 +1203,8 @@ def _cmd_fleet_status(args) -> int:
 
 def _cmd_doctor(args) -> int:
     """Preflight: the reference-era 'verify drivers / EFA provider' role.
-    Every check prints one line with a wall-clock timestamp so a hang is
-    attributable to an exact stage (this image's TPU plugin is known to
-    hang in backend init — see bench.py)."""
+    Every check prints one line with a wall-clock timestamp, so a slow or
+    failing stage is attributable."""
     import time as _time
 
     t0 = _time.monotonic()
@@ -1244,54 +1229,43 @@ def _cmd_doctor(args) -> int:
     except Exception as e:
         report("presets", False, repr(e))
 
-    # 2. Native data loader builds (or degrades cleanly).
+    # 2. Native data loader: which loader is active, and why.
     try:
         from .. import dataio
 
-        if dataio.available():
-            report("native-loader", True, "dataio.so built and loadable")
-        else:
-            report("native-loader", True,
-                   "unavailable; Python fallback active (no g++?)")
+        report("native-loader", True, dataio.status())
     except Exception as e:
         report("native-loader", False, repr(e))
 
     # 3. Accelerator backend: import → init → devices, stage by stage.
-    if args.skip_backend:
-        report("backend", True, "skipped on request")
-    else:
-        try:
-            from ..runtime.platform import honor_env_platform
+    try:
+        from ..runtime.platform import require_accelerator
 
-            honor_env_platform()
-            import jax
+        import jax
 
-            report("jax-import", True, f"jax {jax.__version__}")
-            devices = jax.devices()  # the stage that hangs on bad images
-            kinds = sorted({getattr(d, "device_kind", "?")
-                            for d in devices})
-            report("backend-init", True,
-                   f"{len(devices)} device(s): {', '.join(kinds)}")
-            import jax.numpy as jnp
+        report("jax-import", True, f"jax {jax.__version__}")
+        platform = require_accelerator()
+        devices = jax.devices()
+        kinds = sorted({getattr(d, "device_kind", "?") for d in devices})
+        report("backend-init", True,
+               f"{len(devices)} {platform} device(s): {', '.join(kinds)}")
+        import jax.numpy as jnp
 
-            x = jnp.ones((128, 128))
-            val = float((x @ x).sum())  # executes + syncs one real program
-            report("device-exec", val == 128.0 * 128 * 128,
-                   f"matmul sum={val:.0f}")
-            try:
-                stats = devices[0].memory_stats() or {}
-            except Exception:
-                stats = {}  # some PJRT plugins raise instead of None
-            if "bytes_limit" in stats:
-                report("hbm", True,
-                       f"{stats.get('bytes_in_use', 0) / 2**30:.2f} / "
-                       f"{stats['bytes_limit'] / 2**30:.2f} GiB in use")
-            from ..config import MeshConfig
-            from ..parallel.mesh import build_mesh, describe
+        x = jnp.ones((128, 128))
+        val = float((x @ x).sum())  # executes + syncs one real program
+        report("device-exec", val == 128.0 * 128 * 128,
+               f"matmul sum={val:.0f}")
+        stats = devices[0].memory_stats() or {}  # None on the CPU
+        if "bytes_limit" in stats:
+            report("hbm", True,
+                   f"{stats.get('bytes_in_use', 0) / 2**30:.2f} / "
+                   f"{stats['bytes_limit'] / 2**30:.2f} GiB in use")
+        from ..config import MeshConfig
+        from ..parallel.mesh import build_mesh, describe
 
-            report("mesh", True, describe(build_mesh(MeshConfig(data=-1))))
-        except Exception as e:
-            report("backend", False, repr(e))
+        report("mesh", True, describe(build_mesh(MeshConfig(data=-1))))
+    except Exception as e:
+        report("backend", False, repr(e))
 
     print(f"[doctor] {'all checks passed' if ok else 'CHECKS FAILED'}")
     return 0 if ok else 1
@@ -2027,9 +2001,6 @@ def build_parser() -> argparse.ArgumentParser:
         "doctor",
         help="preflight checks: backend init (stage-timestamped), native "
              "loader build, preset integrity")
-    doc.add_argument("--skip-backend", action="store_true",
-                     help="skip accelerator init (for hosts where the "
-                          "backend is known-hung)")
     doc.set_defaults(fn=_cmd_doctor)
 
     be = sub.add_parser("bench", help="run the benchmark harness")
@@ -2420,8 +2391,14 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv: Optional[List[str]] = None) -> int:
+    from ..runtime.platform import AcceleratorError
+
     args = build_parser().parse_args(argv)
-    return args.fn(args)
+    try:
+        return args.fn(args)
+    except AcceleratorError as e:
+        print(f"[dlcfn-tpu] ERROR: {e}", file=sys.stderr)
+        return 1
 
 
 if __name__ == "__main__":

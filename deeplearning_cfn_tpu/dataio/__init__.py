@@ -1,93 +1,141 @@
 """Native data-loading bindings (ctypes over dataio.cpp).
 
-Builds the shared library on first use (g++ -O3, cached beside the source)
-and exposes the batch gather/augment entry points. Everything degrades to
-None when no compiler is available — pipeline.py falls back to the Python
-path, mirroring how the reference degraded when its native input pipelines
-were unavailable.
+Builds the shared library on first use (g++ -O3, cached beside the source
+under a name that carries a hash of the source) and exposes the batch
+gather/augment entry points. Without a compiler, or when the build fails,
+``get_lib`` returns None and pipeline.py takes the Python path — mirroring
+how the reference degraded when its native input pipelines were
+unavailable. The degradation is visible: :func:`status` says which loader
+is active and why, and a failed build prints the compiler's error once.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
+import shutil
 import subprocess
+import sys
 import threading
 from typing import Optional
 
 import numpy as np
 
 _SRC = os.path.join(os.path.dirname(__file__), "dataio.cpp")
-_LIB_PATH = os.path.join(os.path.dirname(__file__), "_dataio.so")
+_ABI_VERSION = 2
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+_status = "not loaded yet"
 
 
-def _build() -> bool:
+def _lib_path() -> str:
+    """Where the library built from the CURRENT source lives. Keyed on the
+    source's hash, not its mtime: a copied tree keeps no mtimes, and a
+    library git never saw must not be trusted for being newer."""
+    with open(_SRC, "rb") as fh:
+        digest = hashlib.sha256(fh.read()).hexdigest()[:16]
+    return os.path.join(os.path.dirname(_SRC), f"_dataio.{digest}.so")
+
+
+def _build(lib_path: str) -> str:
+    """Compile ``_SRC`` to ``lib_path``; returns "" or why it failed."""
+    if shutil.which("g++") is None:
+        return "no g++ on PATH"
     # Compile to a private temp path, then rename: concurrent processes
     # (multi-host launch, parallel pytest) must never dlopen a half-written
     # library.
-    tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
            "-o", tmp, _SRC]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True,
                               timeout=120)
         if proc.returncode != 0 or not os.path.exists(tmp):
-            return False
-        os.replace(tmp, _LIB_PATH)
-    except (OSError, subprocess.TimeoutExpired):
-        return False
+            print(f"[dataio] native loader build failed "
+                  f"(rc={proc.returncode}); using the Python loader:\n"
+                  f"{proc.stderr.strip()}", file=sys.stderr, flush=True)
+            return f"build failed (rc={proc.returncode}, error on stderr)"
+        os.replace(tmp, lib_path)
+    except (OSError, subprocess.TimeoutExpired) as e:
+        print(f"[dataio] native loader build failed: {e!r}; using the "
+              f"Python loader", file=sys.stderr, flush=True)
+        return f"build failed ({e!r})"
     finally:
         if os.path.exists(tmp):
             try:
                 os.unlink(tmp)
             except OSError:
                 pass
-    return os.path.exists(_LIB_PATH)
+    # Libraries built from an older source are dead weight now.
+    for old in glob.glob(os.path.join(os.path.dirname(lib_path),
+                                      "_dataio*.so")):
+        if old != lib_path:
+            try:
+                os.unlink(old)
+            except OSError:
+                pass
+    return ""
+
+
+def _load(lib_path: str) -> Optional[ctypes.CDLL]:
+    """dlopen + bind; None (with ``_status`` set) when it cannot."""
+    global _status
+    try:
+        lib = ctypes.CDLL(lib_path)
+    except OSError as e:
+        _status = f"python loader ({os.path.basename(lib_path)}: {e})"
+        return None
+    u64, i32, i64, f32p, i32p = (ctypes.c_uint64, ctypes.c_int,
+                                 ctypes.c_int64,
+                                 ctypes.POINTER(ctypes.c_float),
+                                 ctypes.POINTER(ctypes.c_int32))
+    lib.dlcfn_version.restype = ctypes.c_int
+    if lib.dlcfn_version() != _ABI_VERSION:
+        _status = (f"python loader (dataio.cpp is ABI "
+                   f"{lib.dlcfn_version()}, bindings expect {_ABI_VERSION})")
+        return None
+    for fn, argtypes in (
+            (lib.dlcfn_gather_augment,
+             [f32p, i32p, f32p, i32, i32, i32, i32, i32, u64, i32, i32]),
+            (lib.dlcfn_gather_rows_f32, [f32p, i32p, f32p, i32, i64, i32]),
+            (lib.dlcfn_gather_rows_i32, [i32p, i32p, i32p, i32, i64, i32]),
+            (lib.dlcfn_crop_resize_norm,
+             [ctypes.POINTER(u64), i32, i32, f32p, i32, i32, u64, i32,
+              f32p, f32p, i32])):
+        fn.argtypes = argtypes
+        fn.restype = None
+    return lib
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
     """The loaded library, building it if needed; None if unavailable."""
-    global _lib, _tried
+    global _lib, _tried, _status
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_LIB_PATH) or (
-            os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC)
-        ):
-            if not _build():
+        lib_path = _lib_path()
+        how = "cached"
+        if not os.path.exists(lib_path):
+            failure = _build(lib_path)
+            if failure:
+                _status = f"python loader ({failure})"
                 return None
-        try:
-            lib = ctypes.CDLL(_LIB_PATH)
-        except OSError:
-            return None
-        u64, i32, i64, f32p, i32p = (ctypes.c_uint64, ctypes.c_int,
-                                     ctypes.c_int64,
-                                     ctypes.POINTER(ctypes.c_float),
-                                     ctypes.POINTER(ctypes.c_int32))
-        # Version gate BEFORE symbol binding: a stale library that dodged
-        # the mtime check (same-second checkout, copied tree) must degrade
-        # to the Python path, not crash on a missing symbol.
-        try:
-            lib.dlcfn_version.restype = ctypes.c_int
-            if lib.dlcfn_version() != 2:
-                return None
-            lib.dlcfn_gather_augment.argtypes = [
-                f32p, i32p, f32p, i32, i32, i32, i32, i32, u64, i32, i32]
-            lib.dlcfn_gather_rows_f32.argtypes = [
-                f32p, i32p, f32p, i32, i64, i32]
-            lib.dlcfn_gather_rows_i32.argtypes = [
-                i32p, i32p, i32p, i32, i64, i32]
-            lib.dlcfn_crop_resize_norm.argtypes = [
-                ctypes.POINTER(u64), i32, i32, f32p, i32, i32, u64, i32,
-                f32p, f32p, i32]
-        except AttributeError:
-            return None
-        _lib = lib
+            how = "built now"
+        _lib = _load(lib_path)
+        if _lib is not None:
+            _status = f"native ({os.path.basename(lib_path)}, {how})"
         return _lib
+
+
+def status() -> str:
+    """One line: which loader is active and why (``doctor``,
+    ``chip_smoke.py``)."""
+    get_lib()
+    return _status
 
 
 def available() -> bool:

@@ -16,7 +16,7 @@ speaks JSONL everywhere (metrics, traces, ckpt manifests), the framed
 binary body covers the one payload JSON would butcher, and a
 reader can inspect a captured stream with nothing but stdlib.
 
-Failure taxonomy, decided at the frame boundary so every caller agrees:
+Failure classes, decided at the frame boundary so every caller agrees:
 
 - **truncation is not an error** — :meth:`FrameReader.next` returns
   ``None`` until the bytes arrive (a half-open TCP stream looks exactly

@@ -28,9 +28,8 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ..compat import shard_map
 
 PyTree = Any
 

@@ -23,9 +23,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ..compat import shard_map
 
 _NEG_INF = -1e30
 

@@ -29,9 +29,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map
 from .attention import fused_attention
 
 

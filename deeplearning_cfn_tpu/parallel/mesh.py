@@ -140,7 +140,11 @@ def build_mesh(
     try:
         dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
     except (ValueError, AssertionError, NotImplementedError):
-        # Fallback for host-simulated CPU meshes and odd device counts.
+        if getattr(devices[0], "platform", "") != "cpu":
+            # On real chips a plain reshape would silently give up the
+            # ICI-contiguous layout.
+            raise
+        # Host-simulated CPU meshes have no topology to honour.
         dev_array = np.asarray(devices).reshape(shape)
     return Mesh(dev_array, AXIS_ORDER)
 

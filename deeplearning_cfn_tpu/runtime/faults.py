@@ -22,7 +22,7 @@ makes them first-class and *deterministic*:
   attempt only, so the launcher's kill → restart → resume loop can be
   exercised end to end (launch/chaos.py drives it).
 
-Exception taxonomy mirrors the retry classification in ckpt/store.py:
+Exception classes mirror the retry classification in ckpt/store.py:
 :class:`InjectedTransientError` is an ``OSError`` (retriable),
 :class:`InjectedFatalError` is a ``ValueError`` (fatal, fail fast),
 :class:`InjectedHangError` is a ``TimeoutError`` (the hang class the

@@ -1,10 +1,9 @@
 """Hang watchdog: turn a stuck training process into a dead one.
 
 The launcher's failure detection (launch/launcher.py) watches for host
-*death* — but the failure mode this image actually exhibits is a *hang*:
-the accelerator backend stops completing work and the process blocks
-forever inside a device sync, alive but silent. The reference stack had
-the same blind spot (a wedged NCCL collective hung Horovod jobs until a
+*death* — but a job can also *hang*: a collective waits on a lost peer
+and the process blocks forever inside a device sync, alive but silent.
+The reference stack had the same blind spot (a wedged NCCL collective hung Horovod jobs until a
 human killed them). The fix is mechanical: a watchdog thread that
 hard-exits the process when the training loop stops making heartbeats,
 which converts the hang into exactly the failure the launcher already

@@ -32,11 +32,12 @@ def main(argv: Optional[List[str]] = None) -> int:
                         help="config overrides, e.g. train.global_batch=256")
     args = parser.parse_args(argv)
 
-    from ..runtime.platform import honor_env_platform
-
-    honor_env_platform()
+    from ..runtime.platform import require_accelerator
 
     spec = initialize()  # no-op single-host; rendezvous when contract present
+    # After the rendezvous: looking at the devices initialises the backend,
+    # which jax.distributed must precede.
+    require_accelerator()
     if args.profiler_port:
         start_profiler_server(args.profiler_port)
 

@@ -1,5 +1,6 @@
-"""Bench harness: wrapper parsing (stage diagnosis, record contract) and a
-tiny real run of the in-package measurement on the CPU backend."""
+"""Bench harness: the root wrapper's record contract (red is null and
+non-zero, no CPU fallback) and a tiny real run of the in-package
+measurement on the CPU backend."""
 
 import importlib.util
 import json
@@ -20,29 +21,31 @@ def _load_wrapper():
     return mod
 
 
-def test_wrapper_parses_contract_record():
+def test_wrapper_red_record_contract():
+    """The red record: the contract keys, null (never 0.0) where a number
+    would go, and the error's tail."""
     w = _load_wrapper()
-    out = "\n".join([
-        "noise",
-        json.dumps({"metric": "m", "value": 1.5, "unit": "u"}),
-        "[other] trailing line",
-    ])
-    rec = w._parse_record(out)
-    assert rec == {"metric": "m", "value": 1.5, "unit": "u"}
-    assert w._parse_record("no json here") is None
-    assert w._parse_record("{broken") is None
+    rec = w._red_record("x" * 5000 + " the end")
+    assert rec["metric"] == w.METRIC and rec["unit"] == w.UNIT
+    assert rec["measured"] is False
+    assert rec["value"] is None and rec["vs_baseline"] is None
+    assert rec["mfu"] is None
+    assert len(rec["error"]) == 2000 and rec["error"].endswith(" the end")
 
 
-def test_wrapper_extracts_last_stage():
-    w = _load_wrapper()
-    err = ("[bench-stage] t=+0.0s start preset=x\n"
-           "[bench-stage] t=+0.1s import_jax\n"
-           "some warning\n"
-           "[bench-stage] t=+0.2s backend_init\n")
-    assert w._last_stage(err) == "t=+0.2s backend_init"
-    assert w._last_stage(err.encode()) == "t=+0.2s backend_init"
-    assert "no stage marker" in w._last_stage("")
-    assert "no stage marker" in w._last_stage(None)
+def test_stage_markers_go_to_stderr(capsys):
+    """The in-package bench says on stderr which stage it is in, so the
+    tail of a run cut at its time limit localizes the stall."""
+    from deeplearning_cfn_tpu.bench import stage
+
+    stage("backend_init")
+    stage("build", preset="x", global_batch=8)
+    out, err = capsys.readouterr()
+    assert out == ""
+    lines = err.strip().splitlines()
+    assert lines[0].startswith("[bench-stage] t=+")
+    assert lines[0].endswith("s backend_init")
+    assert lines[1].endswith("s build preset=x global_batch=8")
 
 
 def test_annotate_record_labels():
@@ -118,54 +121,49 @@ def test_pipelined_mfu_uses_dense_twin_flops():
     assert dense > 1.5 * scanned, (dense, scanned)
 
 
-def test_wrapper_red_record_has_null_value(tmp_path):
+def test_wrapper_red_record_has_null_value():
     """A red (unmeasured) contract record must carry null value/vs_baseline/
-    mfu — never 0.0, which an aggregator would average in as a real zero
-    (r4 verdict weak #6). Drive the wrapper end-to-end with a preset the
-    child rejects so both attempts fail fast."""
+    mfu — never 0.0, which an aggregator would average in as a real zero —
+    and the wrapper must exit non-zero with it. Drive the wrapper
+    end-to-end with a preset the measurement rejects."""
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=1",
-               DLCFN_BENCH_PRESET="no_such_preset",
-               DLCFN_BENCH_TOTAL_BUDGET_S="240",
-               DLCFN_BENCH_ARTIFACT_DIR=str(tmp_path))
+               DLCFN_BENCH_PRESET="no_such_preset")
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO_ROOT, "bench.py")],
         capture_output=True, text=True, timeout=300, cwd=REPO_ROOT, env=env)
-    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.returncode == 1, proc.stderr[-2000:]
     rec = json.loads(proc.stdout.strip().splitlines()[-1])
     assert rec["measured"] is False
     assert rec["value"] is None
     assert rec["vs_baseline"] is None
     assert rec["mfu"] is None
-    assert "no_such_preset" in rec["error"] or "attempt" in rec["error"]
+    assert "no_such_preset" in rec["error"]
+    assert "Traceback" in proc.stderr
 
 
-def test_finalize_green_nulls_cpu_fallback(monkeypatch):
-    """A child that completed on the silent CPU fallback of a dead
-    accelerator plugin must come out measured=false with null value/
-    vs_baseline/mfu (raw number preserved as cpu_fallback_value) — a CPU
-    throughput against the TPU contract is worse than a fake zero."""
+def test_finalize_green_refuses_unrequested_cpu(monkeypatch):
+    """A record taken on the CPU without the CPU having been asked for by
+    name is a hard failure — there is no relabelling it, and no
+    cpu_fallback_value to carry it along."""
     w = _load_wrapper()
     monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    rec = w._finalize_green(
-        {"value": 12.3, "vs_baseline": 0.03, "mfu": 0.01,
-         "device_kind": "cpu"},
-        alive=False, probe_note="probe: accelerator plugin dead")
-    assert rec["measured"] is False
-    assert rec["value"] is None and rec["vs_baseline"] is None
-    assert rec["mfu"] is None
-    assert rec["cpu_fallback_value"] == 12.3
+    for rec in ({"value": 12.3, "mfu": None, "platform": "cpu",
+                 "device_kind": "cpu"},
+                {"value": 12.3, "device_kind": "cpu"}):
+        with pytest.raises(RuntimeError, match="without the CPU having"):
+            w._finalize_green(rec)
 
     # Explicitly-requested CPU (tests, operator smoke) stays green.
     monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    rec = w._finalize_green({"value": 12.3, "device_kind": "cpu"},
-                            alive=True, probe_note="probe: cpu alive")
+    rec = w._finalize_green({"value": 12.3, "platform": "cpu",
+                             "device_kind": "cpu"})
     assert rec["measured"] is True and rec["value"] == 12.3
 
-    # A real chip record with the probe alive is untouched.
+    # A real chip record is untouched.
     monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    rec = w._finalize_green({"value": 2413.7, "device_kind": "TPU v5e"},
-                            alive=True, probe_note="probe: tpu alive")
+    rec = w._finalize_green({"value": 2413.7, "platform": "tpu",
+                             "device_kind": "TPU v5 lite"})
     assert rec["measured"] is True and rec["value"] == 2413.7
 
 
@@ -178,15 +176,13 @@ def test_finalize_green_nulls_any_unmeasured_record(monkeypatch):
     monkeypatch.delenv("JAX_PLATFORMS", raising=False)
     rec = w._finalize_green(
         {"measured": False, "value": 99.9, "vs_baseline": 0.5, "mfu": 0.4,
-         "device_kind": "TPU v5e", "error": "child: warmup diverged"},
-        alive=True, probe_note="probe: tpu alive")
+         "device_kind": "TPU v5e", "error": "child: warmup diverged"})
     assert rec["measured"] is False
     assert rec["value"] is None
     assert rec["vs_baseline"] is None
     assert rec["mfu"] is None
-    # No fake fallback diagnosis was attached — the child's error stands.
+    # The measurement's own error stands.
     assert rec["error"] == "child: warmup diverged"
-    assert "cpu_fallback_value" not in rec
 
 
 def test_finalize_green_nulls_serving_perf_fields_when_unmeasured(
@@ -200,21 +196,21 @@ def test_finalize_green_nulls_serving_perf_fields_when_unmeasured(
         {"measured": False, "value": 99.9, "spec_gamma": 2,
          "spec_accept_rate": 0.9, "tokens_per_target_step": 2.5,
          "weight_bytes": 12345, "device_kind": "TPU v5e",
-         "error": "child: warmup diverged"},
-        alive=True, probe_note="probe: tpu alive")
+         "error": "child: warmup diverged"})
     for key in ("spec_gamma", "spec_accept_rate",
                 "tokens_per_target_step", "weight_bytes"):
         assert rec[key] is None
     rec = w._finalize_green(
         {"measured": False, "value": 1.0, "device_kind": "TPU v5e",
-         "error": "x"}, alive=True, probe_note="probe: tpu alive")
+         "error": "x"})
     assert "spec_gamma" not in rec  # key set untouched when absent
 
 
 def test_bench_child_measures_on_cpu():
-    """The child process measures a tiny preset on the forced-CPU backend,
-    prints the contract JSON with measured=true, and emits every stage
-    marker through 'done' on stderr."""
+    """``python -m deeplearning_cfn_tpu.bench`` measures a tiny preset on
+    the CPU it was given by name, prints the contract JSON with
+    measured=true, names the device, carries mfu null (the CPU has no
+    peak), and emits every stage marker through 'done' on stderr."""
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=1")
     proc = subprocess.run(
@@ -228,50 +224,67 @@ def test_bench_child_measures_on_cpu():
     assert rec["value"] > 0
     assert rec["unit"] == "images/sec/chip"
     assert rec["global_batch"] == 32
+    assert rec["platform"] == "cpu" and rec["device_kind"] == "cpu"
+    assert rec["mfu"] is None
     for name in ("start", "import_jax", "backend_init", "devices_ok",
                  "build", "first_compile", "warmup", "timed", "done"):
         assert f"s {name}" in proc.stderr, (name, proc.stderr[-2000:])
 
 
-def test_finalize_green_keeps_forced_cpu_measurement(monkeypatch):
-    """A run the wrapper itself forced to JAX_PLATFORMS=cpu (no accelerator
-    platform would initialize) is a real, labeled measurement: measured
-    stays true with the numeric value, and forced_platform marks that it
-    must not be read as a chip number."""
+def test_wrapper_goes_red_when_accelerator_dead(monkeypatch, capsys):
+    """No accelerator answers: the wrapper prints a red record (measured
+    false, null value) and returns non-zero. It does not measure on the
+    CPU instead."""
+    import deeplearning_cfn_tpu.bench as inner
+    from deeplearning_cfn_tpu.runtime.platform import AcceleratorError
+
+    def dead(**kwargs):
+        raise AcceleratorError("no TPU: jax's default backend is 'cpu'")
+
+    monkeypatch.setattr(inner, "run_bench", dead)
     w = _load_wrapper()
-    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    rec = w._finalize_green(
-        {"value": 12.3, "vs_baseline": 0.03, "mfu": 0.0,
-         "device_kind": "cpu"},
-        alive=False, probe_note="probe: backend_init hung >40s",
-        forced_cpu=True)
-    assert rec["measured"] is True
-    assert rec["value"] == 12.3
-    assert rec["forced_platform"] == "cpu"
-    assert "cpu_fallback_value" not in rec
+    assert w.main() == 1
+    out, err = capsys.readouterr()
+    rec = json.loads(out.strip().splitlines()[-1])
+    assert rec["measured"] is False
+    assert rec["value"] is None and rec["mfu"] is None
+    assert "forced_platform" not in rec
+    assert "AcceleratorError" in rec["error"] and "no TPU" in rec["error"]
+    assert "AcceleratorError" in err  # the traceback
 
 
-@pytest.mark.slow
-def test_wrapper_forces_cpu_when_accelerator_dead(tmp_path):
-    """End-to-end on a host with no accelerator: the probe reads jax's
-    silent CPU fallback as a dead plugin, the cpu probe comes up, and the
-    attempts run forced to JAX_PLATFORMS=cpu — a green, labeled CPU
-    measurement instead of five rounds of measured=false (r05)."""
+def test_wrapper_exits_nonzero_without_accelerator():
+    """End-to-end on a host with no accelerator and no request for the
+    CPU: jax quietly falls back to the CPU, and the wrapper must refuse —
+    rc 1, measured false, null value — where it used to ship a forced-CPU
+    number under the chip metric's name with rc 0."""
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=1",
                DLCFN_BENCH_PRESET="cifar10_resnet20",
                DLCFN_BENCH_STEPS="3", DLCFN_BENCH_WARMUP="1",
-               DLCFN_BENCH_GLOBAL_BATCH="32",
-               DLCFN_BENCH_TOTAL_BUDGET_S="400",
-               DLCFN_BENCH_ARTIFACT_DIR=str(tmp_path))
-    env.pop("JAX_PLATFORMS", None)  # accelerator-less: probe must go red
+               DLCFN_BENCH_GLOBAL_BATCH="32")
+    env.pop("JAX_PLATFORMS", None)
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO_ROOT, "bench.py")],
-        capture_output=True, text=True, timeout=500, cwd=REPO_ROOT, env=env)
-    assert proc.returncode == 0, proc.stderr[-2000:]
+        capture_output=True, text=True, timeout=300, cwd=REPO_ROOT, env=env)
+    assert proc.returncode == 1, proc.stderr[-2000:]
     rec = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert rec["measured"] is True, rec
-    assert rec["forced_platform"] == "cpu"
-    assert rec["value"] > 0
-    assert rec["device_kind"] == "cpu"
-    assert "forced JAX_PLATFORMS=cpu" in rec["probe"]
+    assert rec["measured"] is False and rec["value"] is None
+    assert "forced_platform" not in rec
+    assert "no TPU" in rec["error"] and "'cpu'" in rec["error"]
+
+
+def test_unknown_device_kind_is_an_error_cpu_has_no_peak():
+    from types import SimpleNamespace
+
+    from deeplearning_cfn_tpu.bench import peak_flops_per_chip
+
+    dev = lambda platform, kind: SimpleNamespace(platform=platform,
+                                                 device_kind=kind)
+    assert peak_flops_per_chip(dev("cpu", "cpu")) is None
+    assert peak_flops_per_chip(dev("tpu", "TPU v5 lite")) == 197e12
+    assert peak_flops_per_chip(dev("tpu", "TPU v5p")) == 459e12
+    with pytest.raises(ValueError, match="unknown accelerator kind"):
+        peak_flops_per_chip(dev("tpu", "TPU v9 ultra"))
+    with pytest.raises(ValueError, match="unknown accelerator kind"):
+        peak_flops_per_chip(dev("gpu", "NVIDIA H100"))

@@ -36,11 +36,19 @@ def test_doctor_passes_on_cpu(capsys, devices):
         assert check in out, out
 
 
-def test_doctor_skip_backend(capsys):
-    assert main(["doctor", "--skip-backend"]) == 0
+def test_doctor_says_which_loader_and_which_backend(capsys, monkeypatch):
+    """The loader's degradation is visible, and a backend that is not the
+    one asked for fails the preflight instead of being skipped."""
+    assert main(["doctor"]) == 0
     out = capsys.readouterr().out
-    assert "backend: ok — skipped on request" in out
-    assert "device-exec" not in out
+    assert "native-loader: ok — native (_dataio." in out \
+        or "native-loader: ok — python loader (" in out
+    assert "cpu device(s)" in out
+    monkeypatch.delenv("JAX_PLATFORMS")
+    assert main(["doctor"]) == 1
+    out = capsys.readouterr().out
+    assert "backend: FAIL" in out and "no TPU" in out
+    assert "device-exec" not in out and "CHECKS FAILED" in out
 
 
 def test_config_rejects_unknown_override():
@@ -246,12 +254,6 @@ def test_train_on_dryrun_stack_fans_out_worker(tmp_path, capsys):
     assert any("attempt0-host0.log" == p.name for p in logs)
 
 
-@pytest.mark.skipif(
-    tuple(map(int, __import__("jax").__version__.split(".")[:2])) < (0, 5),
-    reason="jaxlib 0.4.x CPU backend rejects multi-process SPMD: workers die "
-           "with 'INVALID_ARGUMENT: Multiprocess computations aren't "
-           "implemented on the CPU backend' once both ranks join the mesh. "
-           "Environmental, not a repo bug — see PARITY.md (tier-1 triage).")
 def test_train_on_multihost_dryrun_stack(tmp_path, capsys):
     """The keystone cluster simulation: a 2-host dry-run stack (v5p-8),
     `train --stack` fans TWO worker processes that rendezvous over loopback

@@ -47,12 +47,6 @@ def test_comm_volume_rejects_unknown_dtype():
         comm_volume("  %q = f8e4m3fn[8]{0} all-reduce(%x)\n")
 
 
-@pytest.mark.skipif(
-    tuple(map(int, __import__("jax").__version__.split(".")[:2])) < (0, 5),
-    reason="jaxlib 0.4.x XLA SPMD partitioner lowers the ring strategy's "
-           "shard_map ppermute with extra all-to-all ops (observed: 7 where "
-           "the contract demands 0), so the signature assertions cannot hold "
-           "on this toolchain. Environmental — see PARITY.md (tier-1 triage).")
 def test_seq_parallel_comm_structure(devices):
     """The strategies' collective SIGNATURES: ring moves K/V by ppermute
     (no all-to-all), Ulysses by all-to-all (no ppermute), byte-identical

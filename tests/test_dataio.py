@@ -76,3 +76,61 @@ def test_pipeline_uses_native_path():
                            process_index=0, process_count=1, native=False)
     np.testing.assert_array_equal(batches[0]["label"],
                                   next(iter(pipe_py.one_epoch(0)))["label"])
+
+
+@pytest.fixture()
+def fresh_loader(monkeypatch, tmp_path):
+    """The loader pointed at a private copy of the source, unloaded."""
+    import shutil
+
+    src = tmp_path / "dataio.cpp"
+    shutil.copy(dataio._SRC, src)
+    monkeypatch.setattr(dataio, "_SRC", str(src))
+    monkeypatch.setattr(dataio, "_lib", None)
+    monkeypatch.setattr(dataio, "_tried", False)
+    monkeypatch.setattr(dataio, "_status", "not loaded yet")
+    return src
+
+
+def test_stale_library_with_newer_mtime_is_rebuilt_not_loaded(fresh_loader):
+    """The cached library is keyed on a hash of the source, not on mtime
+    (which a copied tree does not keep): a library built from an older
+    source is not loaded however new it looks."""
+    import os
+    import time
+
+    src = fresh_loader
+    assert dataio.get_lib() is not None
+    first = dataio._lib_path()
+    assert os.path.exists(first) and "built now" in dataio.status()
+
+    # The source changes; the old library gets the newest mtime around.
+    with open(src, "a") as fh:
+        fh.write("\n// edited\n")
+    future = time.time() + 3600
+    os.utime(first, (future, future))
+    assert os.path.getmtime(first) > os.path.getmtime(src)
+    dataio._lib, dataio._tried = None, False
+    assert dataio.get_lib() is not None
+    second = dataio._lib_path()
+    assert second != first and os.path.exists(second)
+    assert "built now" in dataio.status()
+    assert not os.path.exists(first)  # dead weight, removed by the build
+
+    # Unchanged source: the cached library is found again, not rebuilt.
+    dataio._lib, dataio._tried = None, False
+    assert dataio.get_lib() is not None
+    assert "cached" in dataio.status() and dataio._lib_path() == second
+
+
+def test_failed_build_is_visible_and_degrades(fresh_loader, capfd):
+    """A build that was attempted and failed prints the compiler's error
+    once and says so in status(); the Python loader takes over."""
+    with open(fresh_loader, "w") as fh:
+        fh.write("this is not C++\n")
+    assert dataio.get_lib() is None
+    assert not dataio.available()
+    assert "python loader (build failed" in dataio.status()
+    err = capfd.readouterr().err
+    assert err.count("native loader build failed") == 1
+    assert "error" in err  # the compiler's own words

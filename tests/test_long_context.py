@@ -80,16 +80,8 @@ def test_seq_parallel_matches_data_parallel(impl, devices):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-3)
 
 
-_RING_PPERMUTE_OLD_JAXLIB = pytest.mark.skipif(
-    tuple(map(int, jax.__version__.split(".")[:2])) < (0, 5),
-    reason="jaxlib 0.4.x SPMD partitioner fails on the ring op's jaxpr with "
-           "'UNIMPLEMENTED: PartitionId instruction is not supported for "
-           "SPMD partitioning'. Environmental — see PARITY.md (tier-1 "
-           "triage); the ulysses case still runs.")
-
-
 @pytest.mark.parametrize("impl,collective", [
-    pytest.param("ring", "ppermute", marks=_RING_PPERMUTE_OLD_JAXLIB),
+    ("ring", "ppermute"),
     ("ulysses", "all_to_all"),
 ])
 def test_seq_attention_actually_parallel(impl, collective, devices):

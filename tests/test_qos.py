@@ -554,13 +554,12 @@ def test_finalize_green_nulls_qos_fields_when_unmeasured(monkeypatch):
          "error": "x", "qos_p95_by_class": {"latency": 0.1},
          "preemptions": 3, "preempted_tokens_replayed": 12,
          "fair_share_violation_max": 0.2,
-         "qos_decode_p95_no_adversary": 0.05},
-        alive=True, probe_note="probe: tpu alive")
+         "qos_decode_p95_no_adversary": 0.05})
     for key in ("qos_p95_by_class", "preemptions",
                 "preempted_tokens_replayed", "fair_share_violation_max",
                 "qos_decode_p95_no_adversary"):
         assert rec[key] is None
     rec2 = w._finalize_green(
         {"measured": False, "value": 1.0, "device_kind": "TPU v5e",
-         "error": "x"}, alive=True, probe_note="probe: tpu alive")
+         "error": "x"})
     assert "preemptions" not in rec2   # key set untouched when absent

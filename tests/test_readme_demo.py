@@ -65,7 +65,7 @@ def test_readme_five_minute_demo(tmp_path, capsys, monkeypatch):
     ran = 0
     for cmd in demo_cmds:
         if cmd[0] == "doctor":
-            assert main(["doctor", "--skip-backend"]) == 0
+            assert main(["doctor"]) == 0
             ran += 1
         elif cmd[0] == "train":
             assert main(relocate(cmd)) == 0, cmd
