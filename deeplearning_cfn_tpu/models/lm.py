@@ -106,7 +106,10 @@ class TransformerCausalLm(nn.Module):
             else:
                 x = lyr(x, causal=True, deterministic=not train)
         x = self.final_norm(x)
-        logits = self.token.attend(x.astype(jnp.float32))
+        # A scope of the program's own (docs/OBSERVABILITY.md): flax names
+        # the head and the embedding lookup alike, after the module `token`.
+        with jax.named_scope("lm_head"):
+            logits = self.token.attend(x.astype(jnp.float32))
         if self.num_experts > 0:
             return logits, acc.mean()
         return logits

@@ -23,6 +23,13 @@ Design constraints, in order:
   read, no allocation beyond the call itself. The train hot loop pays
   one truthiness check.
 
+- **One clock with the device.** A live span is also a
+  ``jax.profiler.TraceAnnotation``: while a profiler session runs it is an
+  event of the same ``.xplane.pb`` as the device operations, on the
+  profiler's clock; outside a session the annotation costs a flag check.
+  Only where ``jax`` is already loaded: this module never imports it, so
+  the ``obs`` tools go on starting without it.
+
 Span durations also feed a per-name :class:`~.metrics.Histogram`
 (``span_dur_s{name=...}``) in the tracer's registry, so ``obs summarize``
 and the Prometheus snapshot see latency distributions without re-parsing
@@ -32,6 +39,7 @@ the JSONL stream.
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 from typing import Dict, List, Optional
@@ -78,9 +86,16 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+def _profiler_annotation(name: str):
+    """The span as an event of a running profiler session, or None in a
+    process that has not loaded jax (no session can run there)."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    return None if profiler is None else profiler.TraceAnnotation(name)
+
+
 class _Span:
     __slots__ = ("_tracer", "name", "span_id", "parent_id", "_t0",
-                 "attrs")
+                 "attrs", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, span_id: int,
                  parent_id: Optional[int], attrs: Dict):
@@ -97,9 +112,14 @@ class _Span:
 
     def __enter__(self):
         self._tracer._push(self)
+        self._annotation = _profiler_annotation(self.name)
+        if self._annotation is not None:
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         dur = time.monotonic() - self._t0
         self._tracer._pop(self, dur, ok=exc_type is None)
         return False

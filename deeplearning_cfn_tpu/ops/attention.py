@@ -304,6 +304,7 @@ def _flash_forward(q, k, v, bias, causal, sm_scale, interpret=False,
                                  "arbitrary"),
         ) if not interpret else None,
         interpret=interpret,
+        name="flash_fwd",
     )(*args)
     if return_stats:
         out, lse = result
@@ -499,6 +500,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, interpret):
                         pltpu.VMEM((block_k, d), jnp.float32)],
         compiler_params=semantics,
         interpret=interpret,
+        name="flash_bwd_dkdv",
     )(qp, kp, vp, dop, lse_p, delta_p)
 
     # dQ: grid over q blocks, kv blocks innermost (accumulated).
@@ -518,6 +520,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, interpret):
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=semantics,
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qp, kp, vp, dop, lse_p, delta_p)
 
     return dq[:, :, :sq, :], dk[:, :, :sk, :], dv[:, :, :sk, :]

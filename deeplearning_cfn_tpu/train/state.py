@@ -35,10 +35,11 @@ class TrainState(flax.struct.PyTreeNode):
         new_params = optax.apply_updates(self.params, updates)
         new_ema = self.ema_params
         if new_ema is not None and ema_decay > 0:
-            new_ema = jax.tree_util.tree_map(
-                lambda e, p: e * ema_decay + p * (1.0 - ema_decay),
-                new_ema, new_params,
-            )
+            with jax.named_scope("ema"):
+                new_ema = jax.tree_util.tree_map(
+                    lambda e, p: e * ema_decay + p * (1.0 - ema_decay),
+                    new_ema, new_params,
+                )
         return self.replace(
             step=self.step + 1, params=new_params, opt_state=new_opt_state,
             ema_params=new_ema,

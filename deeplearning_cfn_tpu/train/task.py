@@ -414,41 +414,47 @@ class CausalLmTask:
         if train and self.remat:
             apply = jax.checkpoint(apply)
         inputs = batch["tokens"][:, :-1]
-        targets = batch["tokens"][:, 1:]
         out = apply(params, inputs)
         logits, moe_aux = out if isinstance(out, tuple) else (out, None)
-        mask = example_mask(batch, inputs.shape[0])
-        weights = batch["loss_mask"] * mask[:, None]
-        ce = cross_entropy(logits, targets)
-        denom = jnp.maximum(jnp.sum(weights), 1e-6)
-        # CE kept separate from the optimization objective: perplexity is
-        # defined on cross-entropy alone, and MoE aux terms below must not
-        # contaminate it.
-        ce_loss = jnp.sum(ce * weights) / denom
-        loss = ce_loss
-        hits = (jnp.argmax(logits, -1) == targets).astype(jnp.float32)
-        aux = {"token_accuracy": jnp.sum(hits * weights) / denom}
-        if moe_aux is not None:
-            # ST-MoE aux-loss weights, as in MlmTask.
-            loss = loss + MOE_LOAD_BALANCE_WEIGHT * moe_aux["load_balance"] \
-                + MOE_ROUTER_Z_WEIGHT * moe_aux["router_z"]
-            aux["moe_load_balance"] = moe_aux["load_balance"]
-            aux["moe_router_z"] = moe_aux["router_z"]
-        if train:
-            # Per-step perplexity for the train log only: exp of THIS
-            # step's token-mean CE (clipped against random-init overflow).
-            # Eval perplexity is derived post-aggregation instead — a
-            # weighted mean of per-batch exp(CE) is not perplexity
-            # (Jensen); see eval_derived below.
-            aux["perplexity"] = jnp.exp(jnp.minimum(ce_loss, 20.0))
-            aux["batch_stats"] = batch_stats
-        else:
-            # Every eval metric here (incl. the losses) is token-weighted:
-            # the default normalizer is the batch's real token count, so
-            # cross-batch aggregation yields the exact full-set token-mean
-            # even with ragged loss_masks or padded eval tails.
-            aux["ce_loss"] = ce_loss
-            aux["eval_weight"] = jnp.sum(weights)
+        # The program's own scope (docs/OBSERVABILITY.md): nothing below is
+        # inside a flax module, so without it a trace cannot tell the loss
+        # and its backward pass from the optimizer.
+        with jax.named_scope("lm_loss"):
+            targets = batch["tokens"][:, 1:]
+            mask = example_mask(batch, inputs.shape[0])
+            weights = batch["loss_mask"] * mask[:, None]
+            ce = cross_entropy(logits, targets)
+            denom = jnp.maximum(jnp.sum(weights), 1e-6)
+            # CE kept separate from the optimization objective: perplexity
+            # is defined on cross-entropy alone, and MoE aux terms below
+            # must not contaminate it.
+            ce_loss = jnp.sum(ce * weights) / denom
+            loss = ce_loss
+            hits = (jnp.argmax(logits, -1) == targets).astype(jnp.float32)
+            aux = {"token_accuracy": jnp.sum(hits * weights) / denom}
+            if moe_aux is not None:
+                # ST-MoE aux-loss weights, as in MlmTask.
+                loss = loss \
+                    + MOE_LOAD_BALANCE_WEIGHT * moe_aux["load_balance"] \
+                    + MOE_ROUTER_Z_WEIGHT * moe_aux["router_z"]
+                aux["moe_load_balance"] = moe_aux["load_balance"]
+                aux["moe_router_z"] = moe_aux["router_z"]
+            if train:
+                # Per-step perplexity for the train log only: exp of THIS
+                # step's token-mean CE (clipped against random-init
+                # overflow). Eval perplexity is derived post-aggregation
+                # instead — a weighted mean of per-batch exp(CE) is not
+                # perplexity (Jensen); see eval_derived below.
+                aux["perplexity"] = jnp.exp(jnp.minimum(ce_loss, 20.0))
+                aux["batch_stats"] = batch_stats
+            else:
+                # Every eval metric here (incl. the losses) is
+                # token-weighted: the default normalizer is the batch's real
+                # token count, so cross-batch aggregation yields the exact
+                # full-set token-mean even with ragged loss_masks or padded
+                # eval tails.
+                aux["ce_loss"] = ce_loss
+                aux["eval_weight"] = jnp.sum(weights)
         return loss, aux
 
     # Derived post-aggregation (Trainer.evaluate): exact perplexity from

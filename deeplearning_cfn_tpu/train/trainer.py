@@ -285,18 +285,23 @@ class Trainer:
             return grads, new_stats, metrics
 
         def train_step(state: TrainState, batch: Batch, rng: jax.Array):
-            step_rng = jax.random.fold_in(rng, state.step)
+            # The scopes name what no flax module names, so that a trace
+            # can give every device operation of the step to a section of
+            # the program (docs/OBSERVABILITY.md lists them).
+            with jax.named_scope("step_rng"):
+                step_rng = jax.random.fold_in(rng, state.step)
             if accum > 1:
                 grads, new_stats, metrics = accum_grads_and_metrics(
                     state, batch, step_rng)
             else:
                 grads, new_stats, metrics = grads_and_metrics(
                     state, batch, step_rng)
-            new_state = state.apply_gradients(grads, tx, ema_decay)
-            new_state = new_state.replace(batch_stats=new_stats)
-            # Same implementation clip_by_global_norm uses, so the logged
-            # norm matches the clipping decision.
-            metrics["grad_norm"] = optax.global_norm(grads)
+            with jax.named_scope("optimizer"):
+                new_state = state.apply_gradients(grads, tx, ema_decay)
+                new_state = new_state.replace(batch_stats=new_stats)
+                # Same implementation clip_by_global_norm uses, so the
+                # logged norm matches the clipping decision.
+                metrics["grad_norm"] = optax.global_norm(grads)
             return new_state, metrics
 
         return train_step
@@ -475,19 +480,23 @@ class Trainer:
                 k = 1 if K == 1 else _plan_window(
                     step, num_steps, K, cadences,
                     (trace_start, trace_stop))
-                # The span brackets DISPATCH (async — not device time;
-                # honest step time is the boundary-derived step_time_s
-                # key below). DLCFN_OBS_OFF=1 makes this a shared no-op.
+                # What the loop waited for its input, prefetcher and all.
+                with span("train.next_batch", step=step, k=k):
+                    batches = tuple(next_batch() for _ in range(k))
+                # The span brackets DISPATCH of the compiled step alone
+                # (async — not device time; honest step time is the
+                # boundary-derived step_time_s key below).
+                # DLCFN_OBS_OFF=1 makes this a shared no-op.
                 with span("train.dispatch", step=step, k=k):
                     if k == 1:
                         # Per-step program — also the remainder path when
                         # a window clamps to one step.
                         state, metrics = self.train_step(
-                            state, next_batch(), rng)
+                            state, batches[0], rng)
                     else:
-                        batches = tuple(next_batch() for _ in range(k))
                         state, metrics = self.window_step(
                             state, batches, rng)
+                del batches  # the device keeps what the step still reads
                 prev, last = last, (step + k - 1, metrics)
                 window_examples += gb * k
                 step += k
@@ -572,8 +581,9 @@ class Trainer:
                 # not couple to log cadence); metrics arg is the last
                 # realized window, if any.
                 t_hooks = time.perf_counter()
-                for hook in hooks:
-                    hook(step, state, last_realized)
+                with span("train.hooks", step=step):
+                    for hook in hooks:
+                        hook(step, state, last_realized)
                 if watchdog is not None and \
                         time.perf_counter() - t_hooks > 1.0:
                     # A hook that blocked for real host work (a slow

@@ -243,7 +243,9 @@ def test_loopback_submit_stream_and_result(loopback):
     req = loopback.submit([5, 9, 13, 2], max_new_tokens=4,
                           request_id="loop-1")
     assert req.id == "loop-1"
-    deadline = 100
+    # Polls of 20 ms each: a minute, so that the server's first compile
+    # fits on a machine that six test workers share (100 polls did not).
+    deadline = 3000
     while req.state.value not in ("done", "cancelled", "expired") \
             and deadline:
         loopback.step()

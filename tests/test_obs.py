@@ -298,6 +298,105 @@ def test_set_enabled_overrides_env(fresh_tracer, monkeypatch):
     assert len(sink.records) == 1
 
 
+def _profiled_events(tmp_path, body):
+    """Run ``body`` under a jax.profiler session and return the host-plane
+    events of the written ``.xplane.pb`` as ``{name: [(start, end)]}``."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                events.setdefault(ev.name, []).append(
+                    (ev.start_ns, ev.start_ns + ev.duration_ns))
+    return events
+
+
+def test_live_span_is_an_event_of_the_profiler_trace(fresh_tracer, tmp_path):
+    sink = MemorySink()
+    fresh_tracer.add_sink(sink)
+
+    def body():
+        with span("obs_test.outer", step=1):
+            with span("obs_test.inner"):
+                pass
+            with span("obs_test.inner"):
+                pass
+
+    events = _profiled_events(tmp_path, body)
+    (outer,) = events["obs_test.outer"]
+    inners = sorted(events["obs_test.inner"])
+    assert len(inners) == 2
+    # Nested spans overlap as nested, siblings do not overlap at all.
+    assert all(outer[0] <= s and e <= outer[1] for s, e in inners)
+    assert inners[0][1] <= inners[1][0]
+    # The JSONL records are what they were: one per span, on the tracer's
+    # own clock.
+    assert [r["span"] for r in sink.records] == [
+        "obs_test.inner", "obs_test.inner", "obs_test.outer"]
+
+
+@pytest.mark.parametrize("how", ["env", "set_enabled"])
+def test_span_switched_off_writes_no_profiler_event(fresh_tracer, tmp_path,
+                                                    monkeypatch, how):
+    if how == "env":
+        monkeypatch.setenv("DLCFN_OBS_OFF", "1")
+    else:
+        set_enabled(False)
+
+    def body():
+        with span("obs_test.off"):
+            pass
+
+    assert "obs_test.off" not in _profiled_events(tmp_path, body)
+
+
+def test_span_outside_a_profiler_session_still_records(fresh_tracer):
+    sink = MemorySink()
+    fresh_tracer.add_sink(sink)
+    with pytest.raises(RuntimeError):
+        with span("obs_test.alone"):
+            raise RuntimeError("x")
+    (rec,) = sink.records
+    assert rec["span"] == "obs_test.alone" and rec["ok"] is False
+
+
+def test_obs_starts_without_jax_and_spans_work_there():
+    """``obs tail/summarize/diff/check/export`` run in processes that never
+    load jax: no module of ``obs/`` imports it, and a span there is a span
+    without an annotation."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "import deeplearning_cfn_tpu.obs as obs\n"
+        "from deeplearning_cfn_tpu.obs import trace\n"
+        "sink = obs.MemorySink()\n"
+        "obs.get_tracer().add_sink(sink)\n"
+        "with obs.span('a'):\n"
+        "    pass\n"
+        "assert [r['span'] for r in sink.records] == ['a']\n"
+        "assert trace._profiler_annotation('a') is None\n"
+        "assert 'jax' not in sys.modules, 'obs imported jax'\n")
+    p = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120,
+                       cwd=os.path.dirname(os.path.dirname(__file__)))
+    assert p.returncode == 0, p.stderr[-2000:]
+
+
 def test_get_tracer_returns_configured_default():
     t = Tracer()
     configured(t)

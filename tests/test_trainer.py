@@ -501,3 +501,148 @@ def test_step_window_preserves_cadences(tmp_workdir, devices):
     eps = [r["examples_per_sec"] for r in train_recs
            if "examples_per_sec" in r]
     assert all(v > 0 for v in eps)
+
+
+# -- program scopes and spans (PR 24) ----------------------------------------
+
+# Scopes the program owns (docs/OBSERVABILITY.md), and the model's own
+# module name, which flax puts on everything inside the model.
+_PROGRAM_SCOPE = r"\b(lm_head|lm_loss|optimizer|step_rng)\b"
+_MODULE_SCOPE = r"TransformerCausalLm"
+
+
+def _tiny_gpt(devices, extra=()):
+    cfg = get_preset("gpt_small_lm")
+    apply_overrides(cfg, [
+        "model.name=gpt_tiny", "data.vocab_size=512", "data.seq_len=32",
+        "model.kwargs.max_len=32", "train.dtype=float32",
+        "train.global_batch=4", "mesh.data=1", "train.ema_decay=0.99",
+        *extra])
+    mesh = build_mesh(cfg.mesh, devices=devices[:1])
+    task = build_task(cfg, mesh=mesh)
+    tx = build_optimizer(cfg.optimizer, build_schedule(
+        cfg.schedule, 100, cfg.train.global_batch, None))
+    state = create_train_state(jax.random.PRNGKey(0), task.init, tx, mesh,
+                               param_rules=task.param_rules, ema=True)
+    return cfg, Trainer(cfg, task.loss_fn, tx, mesh=mesh), state
+
+
+def _lm_batches(n, batch=4, seq=32):
+    rs = np.random.RandomState(0)
+    return [{"tokens": rs.randint(0, 512, (batch, seq + 1)).astype(np.int32),
+             "loss_mask": np.ones((batch, seq), np.float32)}
+            for _ in range(n)]
+
+
+def _op_names(hlo_text, entry_only=False):
+    """``op_name`` of every instruction of a compiled program's text that
+    carries one (the compiler's own copies carry none)."""
+    import re
+
+    out, in_entry = [], False
+    for line in hlo_text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            in_entry = line.startswith("ENTRY")
+        m = re.search(r'op_name="([^"]*)"', line)
+        if m and " = " in line and (in_entry or not entry_only) \
+                and not re.search(r" (parameter|constant)\(", line):
+            out.append(m.group(1))
+    return out
+
+
+@pytest.mark.parametrize("program", ["train_step", "window_step"])
+def test_every_operation_of_the_step_belongs_to_a_scope(devices, program):
+    import re
+
+    _, trainer, state = _tiny_gpt(devices)
+    b = [trainer.device_batch(x) for x in _lm_batches(2)]
+    rng = jax.random.PRNGKey(7)
+    lowered = trainer.train_step.lower(state, b[0], rng) \
+        if program == "train_step" \
+        else trainer.window_step.lower(state, tuple(b), rng)
+    text = lowered.compile().as_text()
+    names = _op_names(text)
+    # What the parent left bare: the clip, the schedule, Adam's moments and
+    # the EMA now say ``optimizer``; the cross-entropy's gather and its
+    # scatter-add backward say ``lm_loss``; the head is told from the
+    # embedding lookup that shares the module ``token``.
+    marks = {r"jit\(clip\)|/cos$|/sqrt$": r"/optimizer/",
+             r"take_along_axis": r"\blm_loss\b",
+             r"token\.attend": r"/lm_head/",
+             r"_train_step_fn": r"/step_rng/"}
+    for mark, scope in marks.items():
+        marked = [n for n in names if re.search(mark, n)]
+        assert marked, f"no instruction matches {mark}"
+        bare = [n for n in marked if not re.search(scope, n)]
+        assert not bare, f"{mark} outside {scope}: {bare[:5]}"
+    assert any("/optimizer/ema/" in n for n in names)
+    if program == "window_step":
+        return  # the same body inside a loop: the marks above are the check
+    # The sections are whole: of the entry computation's instructions that
+    # carry a name at all, fewer than 5 % have neither a program nor a
+    # module scope.
+    named = _op_names(text, entry_only=True)
+    loose = [n for n in named if not re.search(
+        _PROGRAM_SCOPE + "|" + _MODULE_SCOPE, n)]
+    assert len(named) > 300
+    assert len(loose) < 0.05 * len(named), (len(loose), len(named),
+                                            sorted(set(loose))[:20])
+
+
+def test_scopes_change_no_numerics(devices, monkeypatch):
+    """``jax.named_scope`` writes ``op_name`` metadata and nothing else:
+    the step with every scope of the program taken out gives the same bits."""
+    import contextlib
+
+    def losses():
+        _, trainer, state = _tiny_gpt(devices)
+        rng, out = jax.random.PRNGKey(7), []
+        for batch in _lm_batches(3):
+            state, m = trainer.train_step(state, trainer.device_batch(batch),
+                                          rng)
+            out.append((float(m["loss"]).hex(), float(m["grad_norm"]).hex()))
+        w = sum(float(jnp.sum(jnp.abs(x)))
+                for x in jax.tree_util.tree_leaves(state.ema_params))
+        return out, w.hex()
+
+    scoped = losses()
+    seen = []
+    monkeypatch.setattr(jax, "named_scope", lambda name: (
+        seen.append(name), contextlib.nullcontext())[1])
+    bare = losses()
+    assert {"lm_head", "lm_loss", "optimizer", "step_rng", "ema"} <= set(seen)
+    assert scoped == bare
+
+
+@pytest.mark.parametrize("step_window,prefetch", [(1, 0), (1, 2), (2, 0)])
+def test_fit_spans_are_siblings(devices, step_window, prefetch):
+    """``train.next_batch``, ``train.dispatch`` and ``train.hooks`` are
+    siblings: the wait for input is no longer inside the dispatch span, and
+    a fused window draws its k batches under one span."""
+    from deeplearning_cfn_tpu.obs import MemorySink, Tracer, configured
+
+    _, trainer, state = _tiny_gpt(devices, [
+        f"train.step_window={step_window}",
+        f"train.device_prefetch={prefetch}"])
+    tracer, sink = Tracer(), MemorySink()
+    tracer.add_sink(sink)
+    configured(tracer)
+    calls = []
+    try:
+        trainer.fit(state, iter(_lm_batches(4)), num_steps=4,
+                    rng=jax.random.PRNGKey(7), log_every=2,
+                    hooks=(lambda step, st, last: calls.append(step),),
+                    hook_every=step_window)
+    finally:
+        configured(None)
+    windows = 4 // step_window
+    assert len(calls) == windows
+    names = [r["span"] for r in sink.records if r["span"] != "train.realize"]
+    assert names == ["train.next_batch", "train.dispatch",
+                     "train.hooks"] * windows
+    assert all(r["parent_id"] is None for r in sink.records)
+    assert all(r["ok"] for r in sink.records)
+    for r in sink.records:
+        if r["span"] in ("train.next_batch", "train.dispatch"):
+            assert r["k"] == step_window
+    assert len(sink.by_span("train.realize")) == 2
