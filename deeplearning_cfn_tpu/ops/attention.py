@@ -30,21 +30,36 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from ..obs.trace import get_tracer
+
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps masked softmax NaN-free
 
-# Flash kernel tiling. Swept on a real v5e chip (2026-07-31, BERT-shaped
-# d=64 cases at S in {512, 1024, 2048, 8192}): 1024x1024 beat the initial
-# 256x128 by 1.3-4.7x fwd+bwd — bigger tiles amortize the d=64 contraction
-# (half the MXU's 128 depth) over more rows/columns and cut grid overhead.
-# The f32 score tile (BQ x BK = 4 MB) plus operand blocks stays inside the
-# 16 MB scoped-VMEM budget; short sequences clamp to ceil8(S) anyway.
+# Flash kernel tiling: the grid tile (block) one grid step holds in VMEM, and
+# the sub-tile in whose units a causal call's backward kernels leave out,
+# inside the grid step, what lies above the diagonal. Swept on a v5e chip on
+# 2026-09-28 (tools/flash_tile_sweep.py; the table is in PERF.md, PR 25),
+# causal bf16 [16,12,1024,64], [1,8,2048,128], [1,12,8192,64], two sq < sk:
+# - The 1024 x 1024 block stands (2026-07-31, BERT-shaped d=64 cases at S in
+#   {512, 1024, 2048, 8192}: it beat 256 x 128 grid tiles by 1.3-4.7x fwd+bwd;
+#   a grid step costs more than the tiles it would skip).
+# - Everything about a sub-tile has to be static. Loops over sub-tiles with
+#   bounds from program_id ran 2-3.5x slower than one whole tile at S = 8192;
+#   the schedule below is decided from the static lengths instead.
+# - The backward kernels follow the live share: 2.13 ms a call as one piece,
+#   1.48 at 256-square, 1.54 at 128-square, 1.66 at 512 ([16,12,1024,64]).
+# - The forward kernel does not: 0.81 ms as one piece, 0.95 at 512, 1.04 at
+#   256, 0.74 at 128, 1.17 at 64. And every piece is traced and lowered again
+#   for every layer: 128-square in all three kernels added 4 s to a training
+#   step's first call, 256-square in the backward pair alone under 1 s.
 _BLOCK_Q = 1024
 _BLOCK_K = 1024
+_SUB_Q = 256
+_SUB_K = 256
 # Row statistics (logsumexp, delta) are stored lane-replicated with a
 # trailing dim of 8: Mosaic requires a block's last two dims to be
 # (divisible by 8, divisible by 128) or equal to the array's — a bare
@@ -55,6 +70,192 @@ _STAT_LANES = 8
 
 def _ceil8(n: int) -> int:
     return max(8, -(-n // 8) * 8)
+
+
+def _tile_plan(sq: int, sk: int, d: int, causal: bool,
+               backward: bool = False) -> Tuple[int, int, int, int]:
+    """``(block_q, block_k, sub_q, sub_k)`` for one call's forward kernel or
+    its two backward kernels, from what the call can observe (pure,
+    unit-tested). The backward kernels of a causal call compute their grid
+    tile in ``sub``-sized pieces, so that what lies above the diagonal is
+    left out inside the grid step; their blocks are whole numbers of
+    sub-tiles. Everything else keeps one piece per grid tile (``sub ==
+    block``), the kernel it has had since the first sweep: a non-causal call
+    has nothing to leave out, a call with a bias has no backward kernel, and
+    the forward kernel gains only at 128-square, where tracing its 16 pieces
+    a layer costs a training step's set-up more than it saves (the sweep
+    above)."""
+    del d  # one rule held for d = 64 and d = 128
+
+    def one(s, block, sub):
+        if not (causal and backward) or s <= sub:
+            b = min(block, _ceil8(s))
+            return b, b
+        return min(block, -(-s // sub) * sub), sub
+
+    block_q, sub_q = one(sq, _BLOCK_Q, _SUB_Q)
+    block_k, sub_k = one(sk, _BLOCK_K, _SUB_K)
+    return block_q, block_k, sub_q, sub_k
+
+
+# The three cases of a sub-tile, decided from positions alone (a row's
+# position is its index plus ``seq_k - seq_q``: the diagonal aligns the ends):
+# above the diagonal (its first column lies past its last row) it is not
+# computed; crossed by the diagonal it is computed under the mask; wholly
+# below, it is computed with no mask at all. (Padded columns lie above the
+# diagonal of every real row, so the causal cases cover them.) Sequence
+# lengths are static, so all of it is decided while the kernel is traced:
+# ``_schedule`` gives, for each offset ``rel`` between a grid tile's first row
+# and its first column that the call's grid has, the pieces of the tile to
+# compute. A kernel's grid step looks its ``rel`` up and runs those pieces;
+# no loop bound and no slice depends on a ``program_id``.
+
+
+def _row_band_spans(q0: int, sub_q: int, sub_k: int, n_c: int):
+    """For the rows at positions ``[q0, q0 + sub_q)`` against ``n_c`` column
+    sub-tiles starting at position 0: ``(full_end, live_end)``. Sub-tiles
+    ``[0, full_end)`` need no mask, ``[full_end, live_end)`` the mask, the
+    rest lie above the diagonal."""
+    live_end = max(0, min((q0 + sub_q - 1) // sub_k + 1, n_c))
+    return max(0, min((q0 + 1) // sub_k, live_end)), live_end
+
+
+def _col_band_spans(k0: int, q0: int, sub_k: int, sub_q: int, n_r: int):
+    """For the columns at ``[k0, k0 + sub_k)`` against ``n_r`` row sub-tiles
+    whose positions start at ``q0``: ``(live_start, full_start)``. Sub-tiles
+    ``[live_start, full_start)`` need the mask, ``[full_start, n_r)`` none,
+    those before lie above the diagonal."""
+    live_start = max(0, min((k0 - q0) // sub_q, n_r))
+    full_start = -((q0 + 1 - k0 - sub_k) // sub_q)
+    return live_start, max(live_start, min(full_start, n_r))
+
+
+def _staircase(rel: int, plan, by_columns: bool):
+    """The pieces of a grid tile whose first row stands at position ``rel``
+    of its own columns, band by band: ``[((o0, o1), [(i0, i1, masked),
+    ...]), ...]``. A band is ``sub_q`` rows with pieces of columns (forward,
+    dQ) or, ``by_columns``, ``sub_k`` columns with pieces of rows (dK/dV).
+    The sub-tiles a band computes without a mask are one piece."""
+    block_q, block_k, sub_q, sub_k = plan
+    n_r, n_c = block_q // sub_q, block_k // sub_k
+    bands = []
+    for o in range(n_c if by_columns else n_r):
+        if by_columns:
+            start, mid = _col_band_spans(o * sub_k, rel, sub_k, sub_q, n_r)
+            band = (o * sub_k, (o + 1) * sub_k)
+            pieces = [(start * sub_q, mid * sub_q, True),
+                      (mid * sub_q, block_q, False)]
+        else:
+            mid, end = _row_band_spans(rel + o * sub_q, sub_q, sub_k, n_c)
+            band = (o * sub_q, (o + 1) * sub_q)
+            pieces = [(0, mid * sub_k, False),
+                      (mid * sub_k, end * sub_k, True)]
+        pieces = [piece for piece in pieces if piece[0] < piece[1]]
+        if pieces:
+            bands.append((band, pieces))
+    return bands
+
+
+def _grid_rels(sq: int, sk: int, block_q: int, block_k: int):
+    """``rel`` of every grid tile of a call: its first row's position less
+    its first column's."""
+    return [iq * block_q - kb * block_k + sk - sq
+            for iq in range(-(-sq // block_q))
+            for kb in range(-(-sk // block_k))]
+
+
+def _schedule(sq: int, sk: int, plan, causal: bool, by_columns: bool = False,
+              mask_whole: bool = False):
+    """What a grid step computes, as cases ``(lo, hi, bands)``: the bands
+    (as ``_staircase`` gives them) for a tile whose ``rel`` is ``lo == hi``,
+    or at least ``lo`` where ``hi`` is None, or anything where both are. A
+    tile no case takes lies above the diagonal.
+
+    With one sub-tile per block every live tile is one piece, masked as it
+    has always been (``mask_whole`` for a non-causal call: the backward masks
+    padded columns itself, the forward takes them as a bias): the kernel of
+    before the sub-tiles. Otherwise a tile the diagonal crosses gets its
+    staircase, and a tile below it is one unmasked piece."""
+    block_q, block_k, sub_q, sub_k = plan
+    outer, inner = (block_k, block_q) if by_columns else (block_q, block_k)
+
+    def whole(masked):
+        return [((0, outer), [(0, inner, masked)])]
+
+    if not causal:
+        return [(None, None, whole(mask_whole))]
+    if (sub_q, sub_k) == (block_q, block_k):
+        return [(1 - block_q, None, whole(True))]
+    crossed = sorted({rel for rel in _grid_rels(sq, sk, block_q, block_k)
+                      if -block_q < rel < block_k - 1})
+    return [(rel, rel, _staircase(rel, plan, by_columns))
+            for rel in crossed] + [(block_k - 1, None, whole(False))]
+
+
+def _subtile_counts(sq: int, sk: int, plan, cases) -> Tuple[int, int, int]:
+    """``(all, computed, masked)`` sub-tiles of one head of a call, counted
+    from the ``cases`` (``_schedule``) its kernel runs."""
+    block_q, block_k, sub_q, sub_k = plan
+    tiles = _grid_rels(sq, sk, block_q, block_k)
+    live = masked = 0
+    for rel in tiles:
+        for lo, hi, bands in cases:
+            if (lo is None or lo <= rel) and (hi is None or rel <= hi):
+                for (o0, o1), pieces in bands:
+                    for i0, i1, under_mask in pieces:
+                        area = (o1 - o0) * (i1 - i0) // (sub_q * sub_k)
+                        live += area
+                        masked += area * under_mask
+    return len(tiles) * (block_q // sub_q) * (block_k // sub_k), live, masked
+
+
+def _record_subtiles(kernel: str, counts: Tuple[int, int, int]) -> None:
+    """The mechanism's engagement is static, so it is two gauges set when a
+    kernel is traced (docs/OBSERVABILITY.md)."""
+    total, live, masked = counts
+    registry = get_tracer().registry
+    registry.gauge(
+        "attention.flash.live_subtile_share",
+        "sub-tiles of the score matrix a flash kernel computes, of all",
+    ).set(live / total, kernel=kernel)
+    registry.gauge(
+        "attention.flash.masked_subtile_share",
+        "sub-tiles a flash kernel computes under the mask, of all",
+    ).set(masked / total, kernel=kernel)
+
+
+def _when(cond):
+    """``pl.when``, decided in Python where the condition is static."""
+    if isinstance(cond, bool):
+        return lambda f: f() if cond else None
+    from jax.experimental import pallas as pl
+    return pl.when(cond)
+
+
+def _run_schedule(cases, rel, band) -> None:
+    """Inside a kernel: ``band(o0, o1, pieces)`` for every band of the case
+    that takes this grid step's ``rel`` (a Python int where the grid is one
+    tile)."""
+    def run(bands):
+        for (o0, o1), pieces in bands:
+            band(o0, o1, pieces)
+
+    for lo, hi, bands in cases:
+        if lo is None:
+            cond = True
+        elif hi is None:
+            cond = rel >= lo
+        else:  # a staircase: lo == hi
+            cond = rel == lo
+        _when(cond)(functools.partial(run, bands))
+
+
+def _below_diagonal(shape, q0, k0):
+    """``k_pos <= q_pos`` on a score tile whose first row is at position
+    ``q0`` and whose first column is at ``k0``."""
+    rel = jax.lax.broadcasted_iota(jnp.int32, shape, 1) \
+        - jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    return rel <= q0 - k0
 
 
 # ---------------------------------------------------------------------------
@@ -95,7 +296,8 @@ def attention_reference(
 
 def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                   m_scr, l_scr, acc_scr, *,
-                  causal: bool, sm_scale: float, seq_k: int, seq_q: int):
+                  causal: bool, sm_scale: float, seq_k: int, seq_q: int,
+                  cases, one_tile: bool):
     """One (batch, head, q-block, kv-block) grid step of the online softmax.
 
     The kv-block axis is the innermost ("arbitrary") grid dimension: the
@@ -107,6 +309,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
     (An earlier design held K/V whole in VMEM and looped inside the
     kernel; it hit Mosaic's scoped-vmem limit at long S.)
 
+    Of its block the step computes the pieces that ``cases`` (``_schedule``)
+    gives for the block's place against the diagonal: a band of rows takes
+    one softmax step over its pieces together, then the next band. A whole
+    kv block above the diagonal matches no case: the DMA still happens, the
+    FLOPs not. ``one_tile`` says the grid has one q and one kv block, so
+    that place is known while tracing.
+
     ``seq_q``/``seq_k`` are the TRUE (unpadded) lengths — the causal
     diagonal aligns their ends; the refs hold block-padded arrays. The
     [S,S] score matrix never exists in HBM.
@@ -115,60 +324,57 @@ def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
 
     block_q = q_ref.shape[-2]
     block_k = k_ref.shape[-2]
-    iq = pl.program_id(2)
-    kb = pl.program_id(3)
-    num_kb = pl.num_programs(3)
+    iq = 0 if one_tile else pl.program_id(2)
+    kb = 0 if one_tile else pl.program_id(3)
+    last_kb = 0 if one_tile else pl.num_programs(3) - 1
+    rel = iq * block_q - kb * block_k + (seq_k - seq_q)
 
-    @pl.when(kb == 0)
+    @_when(kb == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def _accumulate():
-        q = q_ref[0, 0, :, :].astype(jnp.float32) * sm_scale
-        k_blk = k_ref[0, 0, :, :]
-        v_blk = v_ref[0, 0, :, :]
-        s = jax.lax.dot_general(
-            q, k_blk.astype(jnp.float32),
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [block_q, block_k]
-        if bias_ref is not None:
-            s = s + bias_ref[0, 0, :, :].astype(jnp.float32)
-        if causal:
-            q_pos = jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0) + iq * block_q \
-                + (seq_k - seq_q)
-            k_pos = jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1) + kb * block_k
-            s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
-        m_prev = m_scr[:, :1]
-        l_prev = l_scr[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)  # [block_q, 1]
+    def _band(r0, r1, pieces):
+        q = q_ref[0, 0, r0:r1, :].astype(jnp.float32) * sm_scale
+        scores = []
+        for c0, c1, masked in pieces:
+            s = jax.lax.dot_general(
+                q, k_ref[0, 0, c0:c1, :].astype(jnp.float32),
+                dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [r1 - r0, c1 - c0]
+            if bias_ref is not None:  # the plan gives a bias one piece
+                s = s + bias_ref[0, 0, :, :].astype(jnp.float32)
+            if masked:
+                s = jnp.where(_below_diagonal(s.shape, rel + r0, c0),
+                              s, _NEG_INF)
+            scores.append(s)
+        m_prev = m_scr[r0:r1, :1]
+        l_prev = l_scr[r0:r1, :1]
+        m_cur = functools.reduce(jnp.maximum, [
+            jnp.max(s, axis=-1, keepdims=True) for s in scores])
         m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
+        # In this order (every p before alpha and the products) one piece
+        # is the kernel as it was before the sub-tiles, operation for
+        # operation; scaling acc first cost it 9 % (PERF.md, PR 25).
+        ps = [jnp.exp(s - m_new) for s in scores]
         alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_new = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
-        acc_scr[...] = acc_new
+        l_new = l_prev * alpha + functools.reduce(jnp.add, [
+            jnp.sum(p, axis=-1, keepdims=True) for p in ps])
+        acc_new = acc_scr[r0:r1, :] * alpha + functools.reduce(jnp.add, [
+            jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[0, 0, c0:c1, :],
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            for p, (c0, c1, _) in zip(ps, pieces)])
+        m_scr[r0:r1, :] = jnp.broadcast_to(m_new, (r1 - r0, _STAT_LANES))
+        l_scr[r0:r1, :] = jnp.broadcast_to(l_new, (r1 - r0, _STAT_LANES))
+        acc_scr[r0:r1, :] = acc_new
 
-    if causal:
-        # Whole kv block above the diagonal for every row of this q block
-        # (true positions; padded k columns lie above it by construction):
-        # skip the matmuls entirely — the DMA still happens, the FLOPs not.
-        q_end = (iq + 1) * block_q + (seq_k - seq_q)
-        pl.when(kb * block_k < q_end)(_accumulate)
-    else:
-        _accumulate()
+    _run_schedule(cases, rel, _band)
 
-    @pl.when(kb == num_kb - 1)
+    @_when(kb == last_kb)
     def _finalize():
         m = m_scr[:, :1]
         l = l_scr[:, :1]
@@ -201,20 +407,12 @@ def _pad_to(x: jnp.ndarray, axis: int, multiple: int) -> jnp.ndarray:
 
 
 def _flash_forward(q, k, v, bias, causal, sm_scale, interpret=False,
-                   return_stats=False):
+                   return_stats=False, plan=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, sq, d = q.shape
     sk = k.shape[-2]
-    # Multiples of 8 (the f32 sublane count) — Mosaic's block-shape rule.
-    block_q = min(_BLOCK_Q, _ceil8(sq))
-    block_k = min(_BLOCK_K, _ceil8(sk))
-
-    qp = _pad_to(q, 2, block_q)
-    kp = _pad_to(k, 2, block_k)
-    vp = _pad_to(v, 2, block_k)
-    sq_p, sk_p = qp.shape[2], kp.shape[2]
     if bias is not None and bias.shape[-1] == 1:
         # The contract is "broadcastable to [B,H,Sq,Sk]"; a bias constant
         # across the K (softmax) axis shifts every logit in a row equally,
@@ -223,6 +421,17 @@ def _flash_forward(q, k, v, bias, causal, sm_scale, interpret=False,
         # exactly zero, still flows via the custom VJP's reference
         # recompute, which sees the original bias).
         bias = None
+    # Blocks are multiples of 8 (the f32 sublane count) — Mosaic's
+    # block-shape rule. ``plan`` is for tests, which force small sub-tiles.
+    plan = plan or _tile_plan(sq, sk, d, causal)
+    block_q, block_k = plan[:2]
+    cases = _schedule(sq, sk, plan, causal)
+    _record_subtiles("flash_fwd", _subtile_counts(sq, sk, plan, cases))
+
+    qp = _pad_to(q, 2, block_q)
+    kp = _pad_to(k, 2, block_k)
+    vp = _pad_to(v, 2, block_k)
+    sq_p, sk_p = qp.shape[2], kp.shape[2]
     if bias is not None:
         # Align the user bias's K axis with the padded KV (zeros are fine:
         # the pad_bias below kills padded columns).
@@ -251,7 +460,9 @@ def _flash_forward(q, k, v, bias, causal, sm_scale, interpret=False,
     # The causal diagonal is defined by the TRUE lengths (ends aligned, as
     # in attention_reference); padded q rows are sliced off at the end and
     # padded k columns sit above the diagonal, so neither corrupts it.
-    kernel_kw = dict(causal=causal, sm_scale=sm_scale, seq_k=sk, seq_q=sq)
+    kernel_kw = dict(causal=causal, sm_scale=sm_scale, seq_k=sk, seq_q=sq,
+                     cases=cases,
+                     one_tile=(sq_p, sk_p) == (block_q, block_k))
     if bias is not None:
         # Keep broadcast dims at size 1 (indexed with block 0) instead of
         # materializing [B,H,Sq,Sk] in HBM.
@@ -329,72 +540,93 @@ def _flash_forward(q, k, v, bias, causal, sm_scale, interpret=False,
 # ---------------------------------------------------------------------------
 
 
-def _bwd_mask(s, iq_block, ik_block, block_q, block_k, causal, seq_q, seq_k):
+def _bwd_mask(s, q0, k0, causal, cols):
     """Recreate the forward's masking (true-length causal diagonal + padded
-    KV columns) on one [block_q, block_k] score tile."""
-    k_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) \
-        + ik_block * block_k
-    live = k_pos < seq_k
+    KV columns) on one score tile whose first row is at position ``q0`` of
+    the block's columns, whose first column is the block's ``k0``-th, in a
+    block with ``cols`` true columns."""
+    live = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) < cols - k0
     if causal:
-        q_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) \
-            + iq_block * block_q + (seq_k - seq_q)
-        live = live & (k_pos <= q_pos)
+        live = live & _below_diagonal(s.shape, q0, k0)
     return jnp.where(live, s, _NEG_INF)
+
+
+def _bwd_rows(q_ref, do_ref, lse_ref, delta_ref, r0, r1):
+    """Rows ``[r0, r1)`` of the q-side refs as the backward kernels use
+    them: ``(q, do, lse, delta)``, float32."""
+    return (q_ref[0, 0, r0:r1, :].astype(jnp.float32),
+            do_ref[0, 0, r0:r1, :].astype(jnp.float32),
+            # Stats are lane-replicated [rows, _STAT_LANES]; one column
+            # suffices.
+            lse_ref[0, 0, r0:r1, :][:, :1],
+            delta_ref[0, 0, r0:r1, :][:, :1])
+
+
+def _bwd_piece(rows, k_blk, v_blk, *, sm_scale, mask):
+    """What both backward kernels recompute on one piece: ``(p, ds)`` from
+    ``_bwd_rows`` and a float32 k/v piece. ``mask`` masks the scores, or is
+    None below the diagonal."""
+    q_blk, do_blk, lse, delta = rows
+    s = jax.lax.dot_general(
+        q_blk, k_blk, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * sm_scale
+    if mask is not None:
+        s = mask(s)
+    p = jnp.exp(s - lse)  # 0 for masked/padded rows
+    dp = jax.lax.dot_general(
+        do_blk, v_blk, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return p, p * (dp - delta)
 
 
 def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                            dk_ref, dv_ref, dk_scr, dv_scr, *, causal,
-                           sm_scale, seq_q, seq_k):
+                           sm_scale, seq_q, seq_k, cases, one_tile):
     """One (batch, head, kv-block, q-block) grid step: accumulate this q
     block's contribution to dK/dV of one kv block in VMEM scratch; write on
-    the last q step. Same block-mapped structure as the forward kernel."""
+    the last q step. Same block-mapped structure as the forward kernel, and
+    the same schedule inside the step, here band of columns by band of
+    columns with pieces of rows."""
     from jax.experimental import pallas as pl
 
-    ik = pl.program_id(2)
-    qi = pl.program_id(3)
-    num_qb = pl.num_programs(3)
     block_q = q_ref.shape[-2]
     block_k = k_ref.shape[-2]
+    ik = 0 if one_tile else pl.program_id(2)
+    qi = 0 if one_tile else pl.program_id(3)
+    last_qi = 0 if one_tile else pl.num_programs(3) - 1
+    rel = qi * block_q - ik * block_k + (seq_k - seq_q)
+    cols = seq_k - ik * block_k
 
-    @pl.when(qi == 0)
+    @_when(qi == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    def _accumulate():
-        k_blk = k_ref[0, 0, :, :].astype(jnp.float32)
-        v_blk = v_ref[0, 0, :, :].astype(jnp.float32)
-        q_blk = q_ref[0, 0, :, :].astype(jnp.float32)
-        do_blk = do_ref[0, 0, :, :].astype(jnp.float32)
-        # Stats are lane-replicated [rows, _STAT_LANES]; one column
-        # suffices.
-        lse = lse_ref[0, 0, :, :][:, :1]
-        delta = delta_ref[0, 0, :, :][:, :1]
-        s = jax.lax.dot_general(
-            q_blk, k_blk, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        s = _bwd_mask(s, qi, ik, block_q, block_k, causal, seq_q, seq_k)
-        p = jnp.exp(s - lse)  # [bq, bk]; 0 for masked/padded rows
-        dv_scr[...] = dv_scr[...] + jax.lax.dot_general(
-            p, do_blk, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do_blk, v_blk, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dk_scr[...] = dk_scr[...] + sm_scale * jax.lax.dot_general(
-            ds, q_blk, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def _band(c0, c1, pieces):
+        k_blk = k_ref[0, 0, c0:c1, :].astype(jnp.float32)
+        v_blk = v_ref[0, 0, c0:c1, :].astype(jnp.float32)
+        dk = dk_scr[c0:c1, :]
+        dv = dv_scr[c0:c1, :]
+        for r0, r1, masked in pieces:
+            mask = functools.partial(
+                _bwd_mask, q0=rel + r0, k0=c0, causal=causal,
+                cols=cols) if masked else None
+            rows = _bwd_rows(q_ref, do_ref, lse_ref, delta_ref, r0, r1)
+            q_blk, do_blk = rows[:2]
+            p, ds = _bwd_piece(rows, k_blk, v_blk, sm_scale=sm_scale,
+                               mask=mask)
+            dv = dv + jax.lax.dot_general(
+                p, do_blk, dimension_numbers=(((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dk = dk + sm_scale * jax.lax.dot_general(
+                ds, q_blk, dimension_numbers=(((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        dk_scr[c0:c1, :] = dk
+        dv_scr[c0:c1, :] = dv
 
-    if causal:
-        # Live iff this q block's last row reaches this kv block's first
-        # column (ends-aligned true positions) — else skip the matmuls.
-        pl.when((qi + 1) * block_q + (seq_k - seq_q) > ik * block_k)(
-            _accumulate)
-    else:
-        _accumulate()
+    _run_schedule(cases, rel, _band)
 
-    @pl.when(qi == num_qb - 1)
+    @_when(qi == last_qi)
     def _finalize():
         dk_ref[0, 0, :, :] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0, 0, :, :] = dv_scr[...].astype(dv_ref.dtype)
@@ -402,61 +634,63 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, dq_scr, *, causal, sm_scale, seq_q,
-                         seq_k):
+                         seq_k, cases, one_tile):
     """One (batch, head, q-block, kv-block) grid step: accumulate one kv
-    block's contribution to dQ of one q block; write on the last kv step."""
+    block's contribution to dQ of one q block; write on the last kv step.
+    Band of rows by band of rows, as the forward is."""
     from jax.experimental import pallas as pl
 
-    iq = pl.program_id(2)
-    kb = pl.program_id(3)
-    num_kb = pl.num_programs(3)
     block_q = q_ref.shape[-2]
     block_k = k_ref.shape[-2]
+    iq = 0 if one_tile else pl.program_id(2)
+    kb = 0 if one_tile else pl.program_id(3)
+    last_kb = 0 if one_tile else pl.num_programs(3) - 1
+    rel = iq * block_q - kb * block_k + (seq_k - seq_q)
+    cols = seq_k - kb * block_k
 
-    @pl.when(kb == 0)
+    @_when(kb == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    def _accumulate():
-        q_blk = q_ref[0, 0, :, :].astype(jnp.float32)
-        do_blk = do_ref[0, 0, :, :].astype(jnp.float32)
-        lse = lse_ref[0, 0, :, :][:, :1]
-        delta = delta_ref[0, 0, :, :][:, :1]
-        k_blk = k_ref[0, 0, :, :].astype(jnp.float32)
-        v_blk = v_ref[0, 0, :, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q_blk, k_blk, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        s = _bwd_mask(s, iq, kb, block_q, block_k, causal, seq_q, seq_k)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(
-            do_blk, v_blk, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dq_scr[...] = dq_scr[...] + sm_scale * jax.lax.dot_general(
-            ds, k_blk, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def _band(r0, r1, pieces):
+        rows = _bwd_rows(q_ref, do_ref, lse_ref, delta_ref, r0, r1)
+        dq = dq_scr[r0:r1, :]
+        for c0, c1, masked in pieces:
+            k_blk = k_ref[0, 0, c0:c1, :].astype(jnp.float32)
+            v_blk = v_ref[0, 0, c0:c1, :].astype(jnp.float32)
+            mask = functools.partial(
+                _bwd_mask, q0=rel + r0, k0=c0, causal=causal,
+                cols=cols) if masked else None
+            _, ds = _bwd_piece(rows, k_blk, v_blk, sm_scale=sm_scale,
+                               mask=mask)
+            dq = dq + sm_scale * jax.lax.dot_general(
+                ds, k_blk, dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        dq_scr[r0:r1, :] = dq
 
-    if causal:
-        pl.when(kb * block_k < (iq + 1) * block_q + (seq_k - seq_q))(
-            _accumulate)
-    else:
-        _accumulate()
+    _run_schedule(cases, rel, _band)
 
-    @pl.when(kb == num_kb - 1)
+    @_when(kb == last_kb)
     def _finalize():
         dq_ref[0, 0, :, :] = dq_scr[...].astype(dq_ref.dtype)
 
 
-def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, interpret):
+def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, interpret,
+                    plan=None):
     """dq, dk, dv via the blocked kernels (bias-free path)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, sq, d = q.shape
     sk = k.shape[-2]
-    block_q = min(_BLOCK_Q, _ceil8(sq))
-    block_k = min(_BLOCK_K, _ceil8(sk))
+    plan = plan or _tile_plan(sq, sk, d, causal, backward=True)
+    block_q, block_k = plan[:2]
+    cases = {"flash_bwd_dkdv": _schedule(sq, sk, plan, causal,
+                                         by_columns=True, mask_whole=True),
+             "flash_bwd_dq": _schedule(sq, sk, plan, causal,
+                                       mask_whole=True)}
+    for name, kernel_cases in cases.items():
+        _record_subtiles(name, _subtile_counts(sq, sk, plan, kernel_cases))
 
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
 
@@ -476,7 +710,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, interpret):
     lse_p = jnp.broadcast_to(lse_p[..., None], (b, h, sq_p, _STAT_LANES))
     delta_p = jnp.broadcast_to(delta_p[..., None], (b, h, sq_p, _STAT_LANES))
 
-    common = dict(causal=causal, sm_scale=sm_scale, seq_q=sq, seq_k=sk)
+    common = dict(causal=causal, sm_scale=sm_scale, seq_q=sq, seq_k=sk,
+                  one_tile=(sq_p, sk_p) == (block_q, block_k))
     semantics = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel",
                              "arbitrary")) if not interpret else None
@@ -489,7 +724,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, interpret):
     kv_by_outer = pl.BlockSpec((1, 1, block_k, d),
                                lambda ib, ih, ik, iq: (ib, ih, ik, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkdv_kernel, **common),
+        functools.partial(_flash_bwd_dkdv_kernel, **common,
+                          cases=cases["flash_bwd_dkdv"]),
         grid=(b, h, sk_p // block_k, sq_p // block_q),
         in_specs=[q_by_inner, kv_by_outer, kv_by_outer, q_by_inner,
                   row_by_inner, row_by_inner],
@@ -511,7 +747,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, interpret):
     kv_by_inner = pl.BlockSpec((1, 1, block_k, d),
                                lambda ib, ih, iq, ik: (ib, ih, ik, 0))
     dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, **common),
+        functools.partial(_flash_bwd_dq_kernel, **common,
+                          cases=cases["flash_bwd_dq"]),
         grid=(b, h, sq_p // block_q, sk_p // block_k),
         in_specs=[q_by_outer, kv_by_inner, kv_by_inner, q_by_outer,
                   row_by_outer, row_by_outer],

@@ -36,16 +36,24 @@ def v5e_chip():
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-@pytest.mark.parametrize("name,shape,causal", [
-    ("gpt_small_lm", (16, 12, 1024, 64), True),
-    ("bert_long_wikipedia", (8, 12, 4096, 64), False),
-    ("head_dim_128", (1, 8, 2048, 128), True),
+@pytest.mark.parametrize("name,shape,sk,causal", [
+    ("gpt_small_lm", (16, 12, 1024, 64), 1024, True),
+    ("bert_long_wikipedia", (8, 12, 4096, 64), 4096, False),
+    ("head_dim_128", (1, 8, 2048, 128), 2048, True),
+    # Several grid tiles, so the sub-tiles' bounds come from program_id: a
+    # slice the chip's tiling refuses, or a body that outgrows VMEM, fails
+    # here and not on the chip.
+    ("gpt_long_lm", (1, 12, 8192, 64), 8192, True),
+    ("causal_sq_lt_sk", (2, 12, 1024, 64), 2048, True),
 ])
 @pytest.mark.parametrize("what", ["forward", "grad"])
-def test_flash_kernel_compiles_for_v5e(v5e_chip, name, shape, causal, what):
+def test_flash_kernel_compiles_for_v5e(v5e_chip, name, shape, sk, causal,
+                                       what):
     assert v5e_chip.device_kind == "TPU v5 lite"
-    arg = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
-                               sharding=SingleDeviceSharding(v5e_chip))
+    one_chip = SingleDeviceSharding(v5e_chip)
+    arg = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct(shape[:2] + (sk,) + shape[3:], jnp.bfloat16,
+                              sharding=one_chip)
 
     def attn(q, k, v):
         return fused_attention(q, k, v, causal=causal,
@@ -54,5 +62,5 @@ def test_flash_kernel_compiles_for_v5e(v5e_chip, name, shape, causal, what):
     fn = attn if what == "forward" else jax.grad(
         lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum(),
         argnums=(0, 1, 2))
-    compiled = jax.jit(fn).lower(arg, arg, arg).compile()
+    compiled = jax.jit(fn).lower(arg, kv, kv).compile()
     assert "tpu_custom_call" in compiled.as_text()

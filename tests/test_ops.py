@@ -210,11 +210,18 @@ def test_flash_backward_bf16():
                                    atol=5e-2, rtol=5e-2)
 
 
-def test_flash_backward_no_full_score_matrix():
+def test_flash_backward_no_full_score_matrix(monkeypatch):
     """The point of the flash backward: no [Sq,Sk] intermediate anywhere in
     the grad computation (walk the jaxpr, including pallas kernel bodies —
-    block tiles are fine, full S×S is not)."""
-    sq = sk = 512  # well above both block sizes
+    block tiles and sub-tiles are fine, full S×S is not)."""
+    from deeplearning_cfn_tpu.ops import attention
+
+    # Blocks and sub-tiles under S, as a long call has them: 512 would fit
+    # the shipped 1024-square block whole.
+    for name, size in (("_BLOCK_Q", 256), ("_BLOCK_K", 256),
+                       ("_SUB_Q", 128), ("_SUB_K", 128)):
+        monkeypatch.setattr(attention, name, size)
+    sq = sk = 512
     q, k, v = _qkv(b=1, h=1, sq=sq, sk=sk, d=16, seed=11)
 
     def loss(q, k, v):
@@ -223,9 +230,9 @@ def test_flash_backward_no_full_score_matrix():
 
     jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
 
-    offenders = []
+    offenders, tiles = [], set()
 
-    def walk(jx):
+    def walk(jx, kernel=None):
         for eqn in jx.eqns:
             for var in list(eqn.invars) + list(eqn.outvars):
                 aval = getattr(var, "aval", None)
@@ -233,13 +240,187 @@ def test_flash_backward_no_full_score_matrix():
                 if len(shape) >= 2 and shape[-1] == sk and \
                         shape[-2] == sq:
                     offenders.append((eqn.primitive.name, shape))
+                if kernel and len(shape) == 2 and shape[0] == shape[1]:
+                    tiles.add((kernel, shape[0]))
+            within = eqn.params["name"] \
+                if eqn.primitive.name == "pallas_call" else kernel
             for param in eqn.params.values():
-                inner = getattr(param, "jaxpr", param)
-                if hasattr(inner, "eqns"):
-                    walk(inner)
+                for one in param if isinstance(param, (tuple, list)) \
+                        else (param,):
+                    inner = getattr(one, "jaxpr", one)
+                    if hasattr(inner, "eqns"):
+                        walk(inner, within)
 
     walk(jaxpr.jaxpr)
     assert not offenders, f"full score-matrix tensors found: {offenders}"
+    # The walk did reach the three kernels' bodies: the forward forms its
+    # block's score tile, the backward kernels their sub-tiles.
+    assert tiles >= {("flash_fwd", 256), ("flash_bwd_dkdv", 128),
+                     ("flash_bwd_dq", 128)}, tiles
+
+
+# -- the tile plan and the sub-tiles inside a grid step ------------------------
+
+
+def _counts(sq, sk, plan, causal, **schedule_kw):
+    """(all, computed, masked) sub-tiles by the schedule a kernel runs."""
+    from deeplearning_cfn_tpu.ops.attention import (
+        _schedule, _subtile_counts)
+
+    return _subtile_counts(sq, sk, plan,
+                           _schedule(sq, sk, plan, causal, **schedule_kw))
+
+
+def _brute_force_counts(sq, sk, plan):
+    """(all, computed, masked) sub-tiles of a causal call, from positions."""
+    block_q, block_k, sub_q, sub_k = plan
+    sq_p = -(-sq // block_q) * block_q
+    sk_p = -(-sk // block_k) * block_k
+    q_pos = np.arange(sq_p)[:, None] + (sk - sq)
+    k_pos = np.arange(sk_p)[None, :]
+    below = k_pos <= q_pos
+    total = live = masked = 0
+    for r0 in range(0, sq_p, sub_q):
+        for c0 in range(0, sk_p, sub_k):
+            total += 1
+            tile = (slice(r0, r0 + sub_q), slice(c0, c0 + sub_k))
+            if not below[tile].any():
+                continue  # above the diagonal
+            live += 1
+            masked += not below[tile].all()
+    return total, live, masked
+
+
+@pytest.mark.parametrize("sq,sk,d,causal,backward,plan,counts", [
+    # The cell's shape. Backward: 256-square sub-tiles, 10 of 16 computed, 4
+    # of them masked. Forward: one piece.
+    (1024, 1024, 64, True, True, (1024, 1024, 256, 256), (16, 10, 4)),
+    (1024, 1024, 64, True, False, (1024, 1024, 1024, 1024), (1, 1, 1)),
+    (8192, 8192, 64, True, True, (1024, 1024, 256, 256), None),
+    (8192, 8192, 64, True, False, (1024, 1024, 1024, 1024), (64, 36, 36)),
+    (2048, 2048, 128, True, True, (1024, 1024, 256, 256), None),
+    (1000, 1000, 64, True, True, (1024, 1024, 256, 256), None),
+    (1024, 2048, 64, True, True, (1024, 1024, 256, 256), None),
+    (1000, 3000, 64, True, True, (1024, 1024, 256, 256), None),
+    (300, 300, 64, True, True, (512, 512, 256, 256), (4, 3, 2)),
+    # Not causal: one sub-tile per block, all of it computed. (A call with a
+    # bias has no backward kernel, and its forward is one piece like any.)
+    (4096, 4096, 64, False, False, (1024, 1024, 1024, 1024), (16, 16, 0)),
+    (4096, 4096, 64, False, True, (1024, 1024, 1024, 1024), (16, 16, 16)),
+    # Short calls fit one sub-tile: ends aligned, and ragged.
+    (64, 128, 64, True, True, (64, 128, 64, 128), (1, 1, 1)),
+    (100, 100, 64, True, True, (104, 104, 104, 104), (1, 1, 1)),
+    (40, 72, 64, True, True, (40, 72, 40, 72), (1, 1, 1)),
+])
+def test_tile_plan(sq, sk, d, causal, backward, plan, counts):
+    """ops/attention._tile_plan, and what the kernels make of it
+    (_subtile_counts, from the very schedule the kernels run)."""
+    from deeplearning_cfn_tpu.ops.attention import _tile_plan
+
+    assert _tile_plan(sq, sk, d, causal, backward) == plan
+    got = _counts(sq, sk, plan, causal, mask_whole=backward)
+    if counts is not None:
+        assert got == counts
+    if not causal:
+        assert got[1] == got[0]  # a live share of 1.0
+    elif plan[:2] == plan[2:]:
+        # One piece per grid tile: every tile the grid does not skip, masked.
+        assert got[1] == got[2] == _brute_force_counts(sq, sk, plan)[1]
+    else:
+        assert got == _brute_force_counts(sq, sk, plan)
+        # The dK/dV kernel walks bands of columns: the same sub-tiles.
+        assert got == _counts(sq, sk, plan, causal, by_columns=True)
+
+
+@pytest.mark.parametrize("sq,sk,plan", [
+    (64, 128, (64, 128, 32, 32)),      # sq < sk, ends aligned
+    (100, 100, (128, 128, 32, 64)),    # ragged: padded rows and columns
+    (100, 100, (64, 64, 32, 32)),      # the same over a 2 x 2 grid
+    (40, 72, (48, 96, 16, 32)),
+    (300, 300, (128, 128, 64, 64)),    # 3 x 3 grid, 84 padded
+])
+def test_subtile_counts_forced_plans(sq, sk, plan):
+    want = _brute_force_counts(sq, sk, plan)
+    assert _counts(sq, sk, plan, True) == want
+    assert _counts(sq, sk, plan, True, by_columns=True) == want
+
+
+@pytest.mark.parametrize("sq,sk,plan,causal,dtype", [
+    # One grid tile (static loops): square and oblong sub-tiles.
+    (256, 256, (256, 256, 64, 64), True, jnp.float32),
+    (256, 256, (256, 256, 64, 128), True, jnp.float32),
+    (256, 256, (256, 256, 64, 64), False, jnp.float32),
+    # Several grid tiles (bounds from program_id).
+    (256, 256, (128, 128, 64, 64), True, jnp.float32),
+    (256, 256, (128, 128, 32, 64), False, jnp.float32),
+    # sq < sk (ends aligned), one tile and several.
+    (128, 256, (128, 256, 64, 64), True, jnp.float32),
+    (128, 256, (64, 128, 32, 64), True, jnp.float32),
+    # Padded lengths.
+    (200, 200, (256, 256, 64, 64), True, jnp.float32),
+    (200, 200, (128, 128, 64, 64), True, jnp.float32),
+    (40, 72, (48, 96, 16, 32), True, jnp.float32),
+    # bfloat16, at test_flash_backward_bf16's tolerances.
+    (256, 256, (256, 256, 64, 64), True, jnp.bfloat16),
+    (256, 256, (128, 128, 64, 128), True, jnp.bfloat16),
+])
+def test_flash_subtiles_match_reference(sq, sk, plan, causal, dtype):
+    """Forward and gradients with sub-tiles forced smaller than the block,
+    so that all three cases of a sub-tile (skipped, masked, unmasked) and
+    the boundaries between them run on the CPU."""
+    from deeplearning_cfn_tpu.ops.attention import (
+        _flash_backward, _flash_forward)
+
+    if causal:  # the plan does exercise every case
+        total, live, masked = _counts(sq, sk, plan, True)
+        assert 0 < masked < live < total
+    d = 16
+    q, k, v = _qkv(b=1, h=2, sq=sq, sk=sk, d=d, seed=12, dtype=dtype)
+    g = jnp.asarray(np.random.RandomState(13).normal(0, 1, q.shape), dtype)
+    scale = d ** -0.5
+    out, lse = _flash_forward(q, k, v, None, causal, scale, interpret=True,
+                              return_stats=True, plan=plan)
+    grads = _flash_backward(q, k, v, out, lse, g, causal, scale, True,
+                            plan=plan)
+    ref, vjp = jax.vjp(
+        lambda q, k, v: attention_reference(q, k, v, causal=causal,
+                                            sm_scale=scale), q, k, v)
+    tol = dict(atol=5e-4, rtol=5e-4) if dtype == jnp.float32 \
+        else dict(atol=5e-2, rtol=5e-2)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), **tol)
+    for name, a, b in zip("qkv", vjp(g), grads):
+        np.testing.assert_allclose(
+            np.asarray(b, np.float32), np.asarray(a, np.float32), **tol,
+            err_msg=f"d{name} mismatch")
+
+
+def test_flash_subtile_gauges():
+    """The two gauges (docs/OBSERVABILITY.md), set when a kernel is traced:
+    the plan's shares at the benchmark cell's shape, 1.0 for a non-causal
+    call."""
+    from deeplearning_cfn_tpu.obs.trace import get_tracer
+
+    def shares(shape, causal):
+        arg = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        jax.eval_shape(jax.grad(
+            lambda q, k, v: fused_attention(
+                q, k, v, causal=causal, implementation="interpret"
+            ).astype(jnp.float32).sum(), argnums=(0, 1, 2)), arg, arg, arg)
+        registry = get_tracer().registry
+        live = registry.gauge("attention.flash.live_subtile_share")
+        masked = registry.gauge("attention.flash.masked_subtile_share")
+        return {kernel: (live.value(kernel=kernel),
+                         masked.value(kernel=kernel))
+                for kernel in ("flash_fwd", "flash_bwd_dkdv",
+                               "flash_bwd_dq")}
+
+    assert shares((16, 12, 1024, 64), True) == {
+        "flash_fwd": (1.0, 1.0), "flash_bwd_dkdv": (0.625, 0.25),
+        "flash_bwd_dq": (0.625, 0.25)}
+    assert shares((2, 12, 4096, 64), False) == {
+        "flash_fwd": (1.0, 0.0), "flash_bwd_dkdv": (1.0, 1.0),
+        "flash_bwd_dq": (1.0, 1.0)}
 
 
 # -- ring attention ---------------------------------------------------------
