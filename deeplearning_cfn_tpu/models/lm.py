@@ -13,7 +13,7 @@ logits matmul reads the same HBM the embedding lookup warmed).
 
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, Tuple
 
 import flax.linen as nn
 import jax
@@ -22,7 +22,10 @@ import jax.numpy as jnp
 from . import register_model
 from .moe import MOE_PARAM_RULES
 from .transformer import (
+    BlockStyle,
     MoeAuxAccumulator,
+    RMSNorm,
+    Rope,
     TRANSFORMER_PARAM_RULES,
     TransformerLayer,
     is_moe_layer,
@@ -42,7 +45,17 @@ class TransformerCausalLm(nn.Module):
     :meth:`decode_step` — single-position, against the blocks' KV caches
     (flax "cache" collection, NMT's decode_step contract: create the
     cache with ``model.init(..., method=TransformerCausalLm.decode_step)``
-    and thread it through the loop)."""
+    and thread it through the loop).
+
+    ``blocks`` makes it a current decoder instead: one ``(layer index, query
+    heads, MLP width, BlockStyle)`` for each layer this chip holds, named
+    ``layer_<index>`` (the other layers of the model lie on further chips as
+    pipeline stages and are not here). Then there are no learned positions,
+    no embedding norm and no dropout, the final norm is an RMSNorm, and
+    ``num_layers``, ``num_heads`` and ``mlp_dim`` are not read. With an
+    expert layer among them ``__call__`` returns ``(logits, aux)``, ``aux``
+    what the expert layers counted. ``tie_embeddings=False`` gives the output
+    head a matrix of its own (``lm_head/kernel``)."""
 
     vocab_size: int
     hidden_size: int = 768
@@ -60,6 +73,8 @@ class TransformerCausalLm(nn.Module):
     moe_every: int = 2
     moe_capacity_factor: float = 1.25
     moe_top_k: int = 2
+    blocks: Tuple[Tuple[int, int, int, BlockStyle], ...] = ()
+    tie_embeddings: bool = True
 
     def _is_moe(self, i: int) -> bool:
         return is_moe_layer(i, self.num_experts, self.moe_every)
@@ -68,6 +83,19 @@ class TransformerCausalLm(nn.Module):
         self.token = nn.Embed(self.vocab_size, self.hidden_size,
                               param_dtype=jnp.float32,
                               embedding_init=nn.initializers.normal(0.02))
+        if not self.tie_embeddings:
+            self.lm_head = nn.Dense(
+                self.vocab_size, use_bias=False, dtype=jnp.float32,
+                param_dtype=jnp.float32,
+                kernel_init=nn.initializers.xavier_uniform())
+        if self.blocks:
+            self.layers = [
+                TransformerLayer(heads, mlp_dim, dtype=self.dtype,
+                                 attention_impl=self.attention_impl,
+                                 style=style, name=f"layer_{index}")
+                for index, heads, mlp_dim, style in self.blocks]
+            self.final_norm = RMSNorm(self.blocks[-1][3].rms_eps, self.dtype)
+            return
         self.position = self.param(
             "position", nn.initializers.normal(0.02),
             (self.max_len, self.hidden_size), jnp.float32)
@@ -88,14 +116,43 @@ class TransformerCausalLm(nn.Module):
         self.final_norm = nn.LayerNorm(dtype=self.dtype,
                                        param_dtype=jnp.float32)
 
+    def _logits(self, x):
+        # A scope of the program's own (docs/OBSERVABILITY.md): flax names
+        # the tied head and the embedding lookup alike, after `token`.
+        with jax.named_scope("lm_head"):
+            if self.tie_embeddings:
+                return self.token.attend(x.astype(jnp.float32))
+            return self.lm_head(x.astype(jnp.float32))
+
     def _embed(self, tokens, pos_emb, train: bool):
+        if self.blocks:
+            return self.token(tokens).astype(self.dtype)
         x = self.token(tokens) + pos_emb
         x = self.embed_norm(x.astype(self.dtype))
         if self.dropout_rate > 0:
             x = self.dropout(x, deterministic=not train)
         return x
 
+    def _styled(self, tokens):
+        x = self._embed(tokens, None, False)
+        counted = []
+        for (_, _, _, style), lyr in zip(self.blocks, self.layers):
+            if style.mlp == "experts":
+                x, aux = lyr(x, causal=True)
+                counted.append(aux)
+            else:
+                x = lyr(x, causal=True)
+        logits = self._logits(self.final_norm(x))
+        if not counted:
+            return logits
+        return logits, {
+            "rows_held": sum(a["rows_held"] for a in counted),
+            "load_max_over_mean": jnp.max(jnp.stack(
+                [a["load_max_over_mean"] for a in counted]))}
+
     def __call__(self, tokens, train: bool = False):
+        if self.blocks:
+            return self._styled(tokens)
         x = self._embed(tokens,
                         self.position[None, :tokens.shape[1], :], train)
         acc = MoeAuxAccumulator()
@@ -105,11 +162,7 @@ class TransformerCausalLm(nn.Module):
                 acc.add(aux)
             else:
                 x = lyr(x, causal=True, deterministic=not train)
-        x = self.final_norm(x)
-        # A scope of the program's own (docs/OBSERVABILITY.md): flax names
-        # the head and the embedding lookup alike, after the module `token`.
-        with jax.named_scope("lm_head"):
-            logits = self.token.attend(x.astype(jnp.float32))
+        logits = self._logits(self.final_norm(x))
         if self.num_experts > 0:
             return logits, acc.mean()
         return logits
@@ -119,6 +172,9 @@ class TransformerCausalLm(nn.Module):
         position ``pos + 1``, appending this position's K/V to the
         cache. MoE aux losses are a training concern; decode discards
         them."""
+        if self.blocks:
+            raise NotImplementedError(
+                "a decoder of styled blocks has no decode step yet")
         pos_emb = jax.lax.dynamic_slice(
             self.position, (pos, 0), (1, self.hidden_size))[None, :, :]
         x = self._embed(token, pos_emb, train=False)
@@ -229,3 +285,95 @@ def gpt_tiny(num_classes: int = 0, dtype=jnp.float32, *,
     return TransformerCausalLm(
         vocab_size=vocab_size, hidden_size=64, num_layers=2,
         num_heads=4, mlp_dim=128, max_len=max_len, dtype=dtype, **kw)
+
+
+# Laguna-XS.2 as poolside published it (config.json, `model_type: laguna`,
+# 33.4B-A3B): 40 layers of hidden size 2048, head size 128, 8 K/V heads under
+# 48 query heads in the full-attention layers (every fourth, from layer 0)
+# and 64 in the sliding ones (window 512); layer 0 a dense gated MLP of 8192,
+# layers 1-39 256 routed experts of width 512, 8 a token, one shared expert
+# of 512, routed scale 2.5; a sigmoid gate a head on the attention output;
+# RMSNorm 1e-6; an untied head over 100,352 tokens. Full layers turn the
+# first half of each head with YaRN's frequencies, sliding ones the whole
+# head. benchmark/configs/laguna_xs2.json lists what the source leaves
+# unsaid and how it was read.
+_LAGUNA_XS2 = dict(
+    hidden_size=2048, num_layers=40, period=4, head_dim=128, kv_heads=8,
+    full_heads=48, sliding_heads=64, window=512, dense_width=8192,
+    experts=256, top_k=8, expert_width=512, shared_width=512,
+    routed_scale=2.5,
+    full_rope=Rope(theta=500_000.0, rotary_dim=64, yarn_factor=64.0,
+                   original_len=4096, beta_fast=64.0, beta_slow=1.0,
+                   attention_factor=1.4158883083359672),
+    sliding_rope=Rope(theta=10_000.0))
+# The same block at sizes a CPU test holds: dense, sliding, full.
+_LAGUNA_TINY = dict(
+    hidden_size=64, num_layers=3, period=2, head_dim=16, kv_heads=2,
+    full_heads=4, sliding_heads=6, window=8, dense_width=128,
+    experts=16, top_k=4, expert_width=32, shared_width=32,
+    routed_scale=2.5,
+    full_rope=Rope(theta=500_000.0, rotary_dim=8, yarn_factor=4.0,
+                   original_len=16, beta_fast=2.0, beta_slow=1.0,
+                   attention_factor=1.1386294361119891),
+    sliding_rope=Rope(theta=10_000.0))
+
+
+def _laguna(sizes, dtype, vocab_size, layers_held, experts_held,
+            attention_impl):
+    """The ``laguna`` decoder at ``sizes``, or one chip's share of it:
+    ``layers_held`` are the layers of this pipeline stage (None: all),
+    ``experts_held`` the ``(first, count)`` of each layer's routed experts on
+    this expert-parallel rank (None: all), ``vocab_size`` the rows of the
+    embedding and of the head held here. A layer is full-attention where its
+    index divides by ``period``, else sliding; layer 0 has the dense MLP."""
+    z = sizes
+    layers = range(z["num_layers"]) if layers_held is None \
+        else tuple(layers_held)
+    first, count = experts_held or (0, z["experts"])
+    experts = (("num_experts", z["experts"]), ("top_k", z["top_k"]),
+               ("held", (int(first), int(count))),
+               ("routed_scale", z["routed_scale"]),
+               ("shared_dim", z["shared_width"]),
+               # The model's one kernel switch: where the flash kernels are
+               # forced (a compile for a chip that is not attached), so is
+               # the grouped matmul; where they are ruled out, so is it.
+               ("implementation", {"auto": "auto", "pallas": "megablox"}.get(
+                   attention_impl, "ragged_dot")))
+
+    def block(i):
+        full = i % z["period"] == 0
+        style = BlockStyle(
+            num_kv_heads=z["kv_heads"], head_dim=z["head_dim"],
+            out_gate=True, rms_eps=1e-6,
+            rope=z["full_rope"] if full else z["sliding_rope"],
+            window=0 if full else z["window"],
+            mlp="swiglu" if i == 0 else "experts",
+            experts=() if i == 0 else experts)
+        return (i, z["full_heads"] if full else z["sliding_heads"],
+                z["dense_width"] if i == 0 else z["expert_width"], style)
+
+    return TransformerCausalLm(
+        vocab_size=vocab_size, hidden_size=z["hidden_size"], dtype=dtype,
+        attention_impl=attention_impl, tie_embeddings=False,
+        blocks=tuple(block(i) for i in layers))
+
+
+@register_model("gpt_laguna_xs2")
+def gpt_laguna_xs2(num_classes: int = 0, dtype=jnp.bfloat16, *,
+                   vocab_size: int = 100_352, max_len: int = 4096,
+                   layers_held=None, experts_held=None,
+                   attention_impl: str = "auto"):
+    # Every width is the published one. num_classes and max_len are not
+    # read (rotary positions have no table to size); they are accepted for
+    # the registry's and CausalLmTask's sake.
+    return _laguna(_LAGUNA_XS2, dtype, vocab_size, layers_held, experts_held,
+                   attention_impl)
+
+
+@register_model("gpt_laguna_tiny")
+def gpt_laguna_tiny(num_classes: int = 0, dtype=jnp.float32, *,
+                    vocab_size: int = 96, max_len: int = 32,
+                    layers_held=None, experts_held=None,
+                    attention_impl: str = "auto"):
+    return _laguna(_LAGUNA_TINY, dtype, vocab_size, layers_held, experts_held,
+                   attention_impl)

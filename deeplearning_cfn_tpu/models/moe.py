@@ -32,6 +32,7 @@ Design notes:
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, Tuple
 
 import flax.linen as nn
@@ -160,3 +161,191 @@ class MoeMlp(nn.Module):
         load_balance = e * jnp.sum(f_e * p_e)
         router_z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
         return y, {"load_balance": load_balance, "router_z": router_z}
+
+
+# ---------------------------------------------------------------------------
+# An expert layer that is told which experts it holds
+# ---------------------------------------------------------------------------
+
+
+# Row, contraction and column tile of the grouped matmul on the chip: of
+# seven tilings at 16,384 rows, 32 groups, 2048 x 1024 and 512 x 2048 this one
+# and (256, 1024, 1024) read within 5 % of each other, 128- and 512-row tiles
+# 20-40 % slower (my chip run, PR 26, call 1).
+_GMM_TILE = (256, 1024, 512)
+# The usual row buffer, in rows a uniform router would send to the experts
+# held: twice them.
+_BUFFER_SHARE = 2.0
+
+
+def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray,
+                   group_sizes: jnp.ndarray,
+                   implementation: str = "auto") -> jnp.ndarray:
+    """``lhs[rows of group g] @ rhs[g]`` for rows sorted by group: ``lhs``
+    ``[rows, k]``, ``rhs`` ``[groups, k, n]``, ``group_sizes`` ``[groups]``
+    int32 whose sum may be less than ``rows``. Rows past the last group cost
+    next to nothing and come back undefined: the caller masks them.
+
+    On a TPU the Pallas grouped matmul of ``jax.experimental.pallas.ops.tpu
+    .megablox`` (its grid is the row tiles that hold a group's rows, found
+    from ``group_sizes`` at run time; backward through its own VJP, the
+    weights' gradient by the transposed kernel); elsewhere
+    ``jax.lax.ragged_dot``."""
+    if implementation == "auto":
+        implementation = "megablox" if jax.default_backend() == "tpu" \
+            else "ragged_dot"
+    if implementation == "ragged_dot":
+        return jax.lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32),
+                                  preferred_element_type=lhs.dtype)
+    if implementation != "megablox":
+        raise ValueError(f"unknown implementation {implementation!r}")
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    rows, k = lhs.shape
+    n = rhs.shape[-1]
+    tiling = (min(_GMM_TILE[0], rows), min(_GMM_TILE[1], k),
+              min(_GMM_TILE[2], n))
+    return gmm(lhs, rhs, group_sizes.astype(jnp.int32),
+               preferred_element_type=lhs.dtype, tiling=tiling)
+
+
+def _whole_tiles(rows: int) -> int:
+    """``rows`` rounded up to what the grouped matmul's row tile divides."""
+    tile = _GMM_TILE[0] if rows > _GMM_TILE[0] else 8
+    return -(-rows // tile) * tile
+
+
+class ExpertStack(nn.Module):
+    """The weights of the experts held, one 2-D ``kernel``
+    ``[experts * d_in, d_out]`` (a matrix like any other to initialisers,
+    weight decay and checkpoints), seen as ``[experts, d_in, d_out]``."""
+
+    experts: int
+    d_in: int
+    d_out: int
+    dtype: Dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self):
+        kernel = self.param("kernel", nn.initializers.xavier_uniform(),
+                            (self.experts * self.d_in, self.d_out),
+                            jnp.float32)
+        return kernel.astype(self.dtype).reshape(
+            self.experts, self.d_in, self.d_out)
+
+
+def _held_rows(m, pair_weight, order, sizes, n_held, w_in, w_out, *,
+               rows: int, top_k: int, implementation: str):
+    """The held experts' part of the layer's result from a buffer of ``rows``
+    rows: the first ``rows`` (token, choice) pairs in ``order`` (sorted by
+    expert, those of held experts first, ``n_held`` of them)."""
+    tokens, dtype = m.shape[0], m.dtype
+    pair = order[:rows]
+    token = pair // top_k
+    valid = (jnp.arange(rows) < n_held)[:, None]
+    with jax.named_scope("moe_dispatch"):
+        xs = jnp.where(valid, m[token], 0)
+    with jax.named_scope("moe_experts"):
+        h = grouped_matmul(xs, w_in, sizes, implementation)
+        gate, up = jnp.split(jnp.where(valid, h, 0), 2, axis=-1)
+        y = grouped_matmul(nn.silu(gate) * up, w_out, sizes, implementation)
+    with jax.named_scope("moe_combine"):
+        y = jnp.where(valid, y, 0).astype(jnp.float32) \
+            * pair_weight[pair][:, None]
+        return jax.ops.segment_sum(y, token, num_segments=tokens) \
+            .astype(dtype)
+
+
+class HeldExpertsMlp(nn.Module):
+    """The expert layer of one expert-parallel rank: it routes every token
+    over all ``num_experts`` experts, is told which of them it ``held``
+    (``(first, count)``; ``count`` 0 is all of them), and returns their part
+    of the layer's result, plus the shared expert's where ``shared_dim`` > 0.
+    Summed over the ranks (the shared expert counted once) the parts are the
+    whole layer; the exchange between ranks is not here.
+
+    ``s = sigmoid(x W_r)`` in float32; the ``top_k`` largest are chosen;
+    ``w_e = routed_scale * s_e / sum over the chosen``; the result is ``sum
+    over chosen and held e of w_e E_e(x)``, each ``E`` a gated MLP
+    ``(silu(x W1) * x W3) W2`` of width ``mlp_dim``.
+
+    **Dropless over the experts held.** The (token, choice) pairs are sorted
+    by expert with the held experts first, their rows gathered, multiplied
+    expert by expert in one grouped matmul over the sorted rows, and summed
+    back into their tokens. The row buffer is static: twice what a uniform
+    router would send (``_BUFFER_SHARE``), and where a step's routing sends more
+    (``lax.cond`` on the count) a second buffer of every pair,
+    ``tokens * top_k`` rows, takes the step. Either is recomputed in the
+    backward pass, so that no buffer is kept. No row is dropped whatever the
+    routing.
+
+    Returns ``(y, aux)``: ``aux["rows_held"]`` the rows routed to held
+    experts, ``aux["load_max_over_mean"]`` the fullest held expert's rows
+    over the mean."""
+
+    num_experts: int
+    mlp_dim: int
+    top_k: int
+    held: Tuple[int, int] = (0, 0)
+    routed_scale: float = 1.0
+    shared_dim: int = 0
+    dtype: Dtype = jnp.bfloat16
+    implementation: str = "auto"
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray
+                 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+        from .transformer import GatedMlp
+
+        b, s, f = x.shape
+        e, k = self.num_experts, self.top_k
+        first, count = self.held if self.held[1] else (0, e)
+        if k > e or not 0 < count <= e:
+            raise ValueError(f"top_k {k}, held {self.held}, experts {e}")
+        m = x.reshape(b * s, f).astype(self.dtype)
+        pairs = b * s * k
+
+        with jax.named_scope("moe_router"):
+            logits = nn.Dense(
+                e, use_bias=False, dtype=jnp.float32,
+                param_dtype=jnp.float32, precision=jax.lax.Precision.HIGHEST,
+                kernel_init=nn.initializers.xavier_uniform(),
+                name="router")(m.astype(jnp.float32))
+            top, chosen = jax.lax.top_k(jax.nn.sigmoid(logits), k)
+            pair_weight = (self.routed_scale * top
+                           / jnp.sum(top, axis=-1, keepdims=True)).reshape(-1)
+        with jax.named_scope("moe_dispatch"):
+            # Held experts become groups 0 .. count - 1, every other expert
+            # the group ``count``, which sorts last and is never computed.
+            group = jnp.minimum((chosen.reshape(-1) - first) % e, count)
+            order = jnp.argsort(group, stable=True).astype(jnp.int32)
+            sizes = jnp.sum(group[:, None] == jnp.arange(count)[None, :],
+                            axis=0, dtype=jnp.int32)
+            n_held = jnp.sum(sizes)
+
+        w_in = ExpertStack(count, f, 2 * self.mlp_dim, self.dtype,
+                           name="experts_in")()
+        w_out = ExpertStack(count, self.mlp_dim, f, self.dtype,
+                            name="experts_out")()
+        # Recomputed in the backward pass: little arithmetic, and the row
+        # buffers (0.3 GB a layer at the usual size, four times that at the
+        # other) are then never kept.
+        part = lambda rows: jax.checkpoint(functools.partial(
+            _held_rows, rows=rows, top_k=k,
+            implementation=self.implementation))
+        usual = _whole_tiles(int(_BUFFER_SHARE * pairs * count / e))
+        operands = (m, pair_weight, order, sizes, n_held, w_in, w_out)
+        if usual >= pairs:
+            y = part(pairs)(*operands)
+        else:
+            y = jax.lax.cond(n_held <= usual, part(usual), part(pairs),
+                             *operands)
+        if self.shared_dim:
+            with jax.named_scope("moe_shared"):
+                y = y + GatedMlp(self.shared_dim, self.dtype,
+                                 name="shared")(m)
+        load = sizes.astype(jnp.float32)
+        aux = {"rows_held": n_held.astype(jnp.float32),
+               "load_max_over_mean": jnp.max(load)
+               / jnp.maximum(jnp.mean(load), 1e-9)}
+        return y.reshape(b, s, f), aux

@@ -15,11 +15,14 @@ TPU-first choices:
 
 from __future__ import annotations
 
-from typing import Any, Callable
+import dataclasses
+import math
+from typing import Any, Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..ops import fused_attention
@@ -35,6 +38,104 @@ TRANSFORMER_PARAM_RULES = (
     (r"mlp_in/kernel", P(None, "model")),
     (r"mlp_out/kernel", P("model", None)),
 )
+
+
+class RMSNorm(nn.Module):
+    """``x / rms(x) * scale``: statistics in float32, no mean, no bias."""
+
+    epsilon: float = 1e-6
+    dtype: Dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        x32 = x.astype(jnp.float32)
+        var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+        return (x32 * jax.lax.rsqrt(var + self.epsilon) * scale) \
+            .astype(self.dtype)
+
+
+@dataclasses.dataclass(frozen=True)
+class Rope:
+    """Rotary positions of one attention layer. The first ``rotary_dim``
+    dimensions of a head turn (0: all of them), in the two-halves layout
+    (dimension ``i`` pairs with ``i + rotary_dim / 2``). ``yarn_factor`` > 0
+    takes YaRN's frequencies (Peng et al. 2023): the slow ones divided by the
+    factor, the fast ones kept, a linear ramp between the dimensions that
+    turn ``beta_fast`` and ``beta_slow`` times over ``original_len``; cos and
+    sin are multiplied by ``attention_factor``."""
+
+    theta: float = 10000.0
+    rotary_dim: int = 0
+    yarn_factor: float = 0.0
+    original_len: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def inv_freq(self, head_dim: int) -> np.ndarray:
+        dim = self.rotary_dim or head_dim
+        pos_freqs = self.theta ** (np.arange(0, dim, 2, dtype=np.float64)
+                                   / dim)
+        if not self.yarn_factor:
+            return 1.0 / pos_freqs
+
+        def turns_dim(turns):  # the dimension that turns so often
+            return dim * math.log(self.original_len / (turns * 2 * math.pi)) \
+                / (2 * math.log(self.theta))
+
+        low = max(math.floor(turns_dim(self.beta_fast)), 0)
+        high = min(math.ceil(turns_dim(self.beta_slow)), dim - 1)
+        ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                       / max(high - low, 1e-3), 0.0, 1.0)
+        return (1.0 / (self.yarn_factor * pos_freqs)) * ramp \
+            + (1.0 / pos_freqs) * (1.0 - ramp)
+
+    def tables(self, seq_len: int, head_dim: int):
+        """``(cos, sin)``, each float32 ``[seq_len, rotary_dim / 2]``."""
+        angles = np.arange(seq_len, dtype=np.float64)[:, None] \
+            * self.inv_freq(head_dim)[None, :]
+        return tuple((f(angles) * self.attention_factor).astype(np.float32)
+                     for f in (np.cos, np.sin))
+
+
+def apply_rope(x: jnp.ndarray, rope: Rope) -> jnp.ndarray:
+    """Turn ``x [B, S, H, D]`` by its positions ``0 .. S - 1``. The two
+    turning halves are computed apart and joined: written as one
+    multiply-add over the whole head (``x * cos + swap(x) * sin``) the step
+    of the Laguna cell ran 2.4 % slower on the chip (PERF.md, PR 26)."""
+    head_dim = x.shape[-1]
+    rot = rope.rotary_dim or head_dim
+    cos, sin = (jnp.asarray(t)[None, :, None, :]
+                for t in rope.tables(x.shape[1], head_dim))
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., :rot // 2], x32[..., rot // 2:rot]
+    turned = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    if rot < head_dim:
+        turned.append(x32[..., rot:])
+    return jnp.concatenate(turned, axis=-1).astype(x.dtype)
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockStyle:
+    """What a block of a current open model has that the 2018 block
+    (LayerNorm, biases, as many K/V heads as query heads, learned positions
+    outside the block, GELU MLP) has not. A :class:`TransformerLayer` with
+    ``style=None`` is the 2018 block, unchanged.
+
+    ``mlp``: ``"swiglu"`` (``(silu(x W1) * x W3) W2``, width ``mlp_dim``) or
+    ``"experts"`` (``models/moe.py:HeldExpertsMlp`` built from ``experts``, a
+    tuple of its keyword pairs)."""
+
+    num_kv_heads: int = 0          # 0: as many as query heads
+    head_dim: int = 0              # 0: hidden size / heads
+    rope: Optional[Rope] = None
+    window: int = 0                # > 0: row i sees i - window < j <= i
+    out_gate: bool = False         # sigmoid gate a head, from the normed input
+    rms_eps: float = 1e-6
+    mlp: str = "swiglu"
+    experts: Tuple[Tuple[str, Any], ...] = ()
 
 
 class QuantDense(nn.Module):
@@ -144,13 +245,20 @@ class MultiHeadAttention(nn.Module):
     attention_impl: str = "auto"
     quantized: bool = False
     kv_quant: str = ""
+    # A current block's attention reads its shape from the block's style:
+    # fewer K/V heads than query heads, a head size of its own, no biases,
+    # rotary positions, a sliding window, a sigmoid gate a head on the
+    # output. Training and evaluation only: the decode paths below keep the
+    # 2018 layout.
+    style: Optional[BlockStyle] = None
 
     def core_attention(self, q, k, v, bias, causal):
         """The [B,H,S,D] attention op. Subclasses swap this for a
         distributed strategy (SeqParallelAttention) while inheriting the
         projections/KV-cache/dropout plumbing unchanged."""
         return fused_attention(q, k, v, bias=bias, causal=causal,
-                               implementation=self.attention_impl)
+                               implementation=self.attention_impl,
+                               window=self.style.window if self.style else 0)
 
     @nn.compact
     def __call__(self, x, kv=None, bias=None, causal=False,
@@ -161,27 +269,39 @@ class MultiHeadAttention(nn.Module):
         self_attention = kv is None
         kv = x if kv is None else kv
         features = x.shape[-1]
-        if features % self.num_heads:
+        st = self.style or BlockStyle()
+        if not st.head_dim and features % self.num_heads:
             raise ValueError(
                 f"hidden size {features} not divisible by "
                 f"{self.num_heads} heads")
-        head_dim = features // self.num_heads
+        head_dim = st.head_dim or features // self.num_heads
+        kv_heads = st.num_kv_heads or self.num_heads
+        if self.style is not None and (
+                decode or not self_attention or self.quantized):
+            raise NotImplementedError(
+                "rotary positions, a window, an output gate and grouped K/V "
+                "heads are for self-attention in training and evaluation; "
+                "the decode paths keep the 2018 layout")
         if self.quantized:
-            dense = lambda name: QuantDense(features, dtype=self.dtype,
-                                            name=name)
+            dense = lambda name, feats=features: QuantDense(
+                feats, dtype=self.dtype, name=name)
         else:
-            dense = lambda name: nn.Dense(
-                features, dtype=self.dtype, param_dtype=jnp.float32,
-                name=name, kernel_init=nn.initializers.xavier_uniform())
+            dense = lambda name, feats=features: nn.Dense(
+                feats, dtype=self.dtype, param_dtype=jnp.float32,
+                name=name, use_bias=self.style is None,
+                kernel_init=nn.initializers.xavier_uniform())
 
-        def split(t):  # [B,S,F] -> [B,H,S,D]
-            b, s, _ = t.shape
-            return t.reshape(b, s, self.num_heads, head_dim) \
-                .transpose(0, 2, 1, 3)
+        def heads(t, n):  # [B,S,n*D] -> [B,S,n,D]
+            return t.reshape(*t.shape[:2], n, head_dim)
 
-        q = split(dense("query")(x))
-        k = split(dense("key")(kv))
-        v = split(dense("value")(kv))
+        q = heads(dense("query", self.num_heads * head_dim)(x),
+                  self.num_heads)
+        k = heads(dense("key", kv_heads * head_dim)(kv), kv_heads)
+        v = heads(dense("value", kv_heads * head_dim)(kv), kv_heads)
+        if st.rope is not None:
+            with jax.named_scope("rope"):
+                q, k = apply_rope(q, st.rope), apply_rope(k, st.rope)
+        q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # [B,H,S,D]
         if decode and self_attention and block_tables is not None:
             if kv_num_blocks <= 0 or kv_block_size <= 0:
                 raise ValueError(
@@ -419,8 +539,11 @@ class MultiHeadAttention(nn.Module):
         else:
             out = self.core_attention(q, k, v, bias, causal)
         b, h, s, d = out.shape
-        out = out.transpose(0, 2, 1, 3).reshape(b, s, h * d)
-        out = dense("attn_out")(out)
+        out = out.transpose(0, 2, 1, 3)
+        if st.out_gate:
+            with jax.named_scope("attn_gate"):
+                out = out * jax.nn.sigmoid(dense("gate", h)(x))[..., None]
+        out = dense("attn_out")(out.reshape(b, s, h * d))
         if self.dropout_rate > 0:
             out = nn.Dropout(self.dropout_rate)(
                 out, deterministic=deterministic)
@@ -452,6 +575,22 @@ class Mlp(nn.Module):
         return y
 
 
+class GatedMlp(nn.Module):
+    """``(silu(x W1) * x W3) W2``, no biases: ``W1`` and ``W3`` side by side
+    in ``mlp_in``, one product for both."""
+
+    mlp_dim: int
+    dtype: Dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        dense = lambda feats, name: nn.Dense(
+            feats, dtype=self.dtype, param_dtype=jnp.float32, name=name,
+            use_bias=False, kernel_init=nn.initializers.xavier_uniform())
+        gate, up = jnp.split(dense(2 * self.mlp_dim, "mlp_in")(x), 2, axis=-1)
+        return dense(x.shape[-1], "mlp_out")(nn.silu(gate) * up)
+
+
 class TransformerLayer(nn.Module):
     """One block: self-attn (+ optional cross-attn) + FFN.
 
@@ -462,6 +601,11 @@ class TransformerLayer(nn.Module):
     (models/moe.py) and changes the return type to ``(x, moe_aux)`` where
     moe_aux is the MoE layer's aux-loss dict — callers that enable MoE own
     threading those losses into the objective.
+
+    ``style`` makes it a current block (:class:`BlockStyle`): pre-norm with
+    RMSNorm, no biases, no dropout, the style's attention and MLP. With
+    ``style.mlp == "experts"`` it returns ``(x, aux)`` too, where ``aux``
+    holds what the expert layer counted.
     """
 
     num_heads: int
@@ -476,6 +620,25 @@ class TransformerLayer(nn.Module):
     moe_top_k: int = 2
     quantized: bool = False
     kv_quant: str = ""
+    style: Optional[BlockStyle] = None
+
+    def _styled(self, x, causal):
+        st = self.style
+        norm = lambda name: RMSNorm(st.rms_eps, self.dtype, name=name)
+        x = x + MultiHeadAttention(
+            self.num_heads, self.dtype, 0.0, self.attention_impl,
+            style=st, name="self_attn")(
+                norm("self_attn_norm")(x), causal=causal)
+        y = norm("mlp_norm")(x)
+        if st.mlp == "experts":
+            from .moe import HeldExpertsMlp
+
+            out, aux = HeldExpertsMlp(mlp_dim=self.mlp_dim, dtype=self.dtype,
+                                      name="mlp", **dict(st.experts))(y)
+            return x + out, aux
+        if st.mlp != "swiglu":
+            raise ValueError(f"unknown BlockStyle.mlp {st.mlp!r}")
+        return x + GatedMlp(self.mlp_dim, self.dtype, name="mlp")(y)
 
     @nn.compact
     def __call__(self, x, enc=None, self_bias=None, cross_bias=None,
@@ -483,6 +646,12 @@ class TransformerLayer(nn.Module):
                  max_decode_len: int = 0, decode_pos=None,
                  block_tables=None, kv_num_blocks: int = 0,
                  kv_block_size: int = 0):
+        if self.style is not None:
+            if decode or enc is not None or self_bias is not None:
+                raise NotImplementedError(
+                    "a styled block runs self-attention over whole "
+                    "sequences: no decode step, encoder or bias yet")
+            return self._styled(x, causal)
         ln = lambda name: nn.LayerNorm(
             dtype=self.dtype, param_dtype=jnp.float32, name=name)
         attn = lambda name: MultiHeadAttention(

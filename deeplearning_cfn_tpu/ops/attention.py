@@ -73,7 +73,8 @@ def _ceil8(n: int) -> int:
 
 
 def _tile_plan(sq: int, sk: int, d: int, causal: bool,
-               backward: bool = False) -> Tuple[int, int, int, int]:
+               backward: bool = False, window: int = 0
+               ) -> Tuple[int, int, int, int]:
     """``(block_q, block_k, sub_q, sub_k)`` for one call's forward kernel or
     its two backward kernels, from what the call can observe (pure,
     unit-tested). The backward kernels of a causal call compute their grid
@@ -84,11 +85,13 @@ def _tile_plan(sq: int, sk: int, d: int, causal: bool,
     has nothing to leave out, a call with a bias has no backward kernel, and
     the forward kernel gains only at 128-square, where tracing its 16 pieces
     a layer costs a training step's set-up more than it saves (the sweep
-    above)."""
+    above). A call with a ``window`` has sub-tiles in all three kernels: a
+    band of rows needs ``window + sub`` columns whatever the length, so the
+    forward leaves out more than the pieces cost it."""
     del d  # one rule held for d = 64 and d = 128
 
     def one(s, block, sub):
-        if not (causal and backward) or s <= sub:
+        if not (causal and (backward or window)) or s <= sub:
             b = min(block, _ceil8(s))
             return b, b
         return min(block, -(-s // sub) * sub), sub
@@ -103,7 +106,9 @@ def _tile_plan(sq: int, sk: int, d: int, causal: bool,
 # above the diagonal (its first column lies past its last row) it is not
 # computed; crossed by the diagonal it is computed under the mask; wholly
 # below, it is computed with no mask at all. (Padded columns lie above the
-# diagonal of every real row, so the causal cases cover them.) Sequence
+# diagonal of every real row, so the causal cases cover them.) A sliding
+# window (row ``i`` sees columns ``i - window < j <= i``) adds its far edge,
+# with the same three cases on the other side. Sequence
 # lengths are static, so all of it is decided while the kernel is traced:
 # ``_schedule`` gives, for each offset ``rel`` between a grid tile's first row
 # and its first column that the call's grid has, the pieces of the tile to
@@ -111,48 +116,49 @@ def _tile_plan(sq: int, sk: int, d: int, causal: bool,
 # no loop bound and no slice depends on a ``program_id``.
 
 
-def _row_band_spans(q0: int, sub_q: int, sub_k: int, n_c: int):
-    """For the rows at positions ``[q0, q0 + sub_q)`` against ``n_c`` column
-    sub-tiles starting at position 0: ``(full_end, live_end)``. Sub-tiles
-    ``[0, full_end)`` need no mask, ``[full_end, live_end)`` the mask, the
-    rest lie above the diagonal."""
-    live_end = max(0, min((q0 + sub_q - 1) // sub_k + 1, n_c))
-    return max(0, min((q0 + 1) // sub_k, live_end)), live_end
+_CAUSAL, _WINDOW = 1, 2
 
 
-def _col_band_spans(k0: int, q0: int, sub_k: int, sub_q: int, n_r: int):
-    """For the columns at ``[k0, k0 + sub_k)`` against ``n_r`` row sub-tiles
-    whose positions start at ``q0``: ``(live_start, full_start)``. Sub-tiles
-    ``[live_start, full_start)`` need the mask, ``[full_start, n_r)`` none,
-    those before lie above the diagonal."""
-    live_start = max(0, min((k0 - q0) // sub_q, n_r))
-    full_start = -((q0 + 1 - k0 - sub_k) // sub_q)
-    return live_start, max(live_start, min(full_start, n_r))
+def _subtile_kind(q0: int, q1: int, k0: int, k1: int, window: int):
+    """A sub-tile of rows at positions ``[q0, q1)`` and columns ``[k0, k1)``:
+    None where nothing of it is needed (its first column lies past its last
+    row, or its last column ``window`` or more before its first row), else
+    the masks it needs: ``_CAUSAL`` where the diagonal crosses it,
+    ``_WINDOW`` where the window's far edge does, 0 where neither."""
+    if k0 > q1 - 1 or (window and q0 - (k1 - 1) >= window):
+        return None
+    kind = _CAUSAL if k1 - 1 > q0 else 0
+    if window and (q1 - 1) - k0 >= window:
+        kind |= _WINDOW
+    return kind
 
 
-def _staircase(rel: int, plan, by_columns: bool):
+def _staircase(rel: int, plan, by_columns: bool, window: int = 0):
     """The pieces of a grid tile whose first row stands at position ``rel``
-    of its own columns, band by band: ``[((o0, o1), [(i0, i1, masked),
+    of its own columns, band by band: ``[((o0, o1), [(i0, i1, kind),
     ...]), ...]``. A band is ``sub_q`` rows with pieces of columns (forward,
     dQ) or, ``by_columns``, ``sub_k`` columns with pieces of rows (dK/dV).
-    The sub-tiles a band computes without a mask are one piece."""
+    Neighbouring sub-tiles of a band that need the same masks
+    (``_subtile_kind``) are one piece."""
     block_q, block_k, sub_q, sub_k = plan
     n_r, n_c = block_q // sub_q, block_k // sub_k
+    inner = sub_q if by_columns else sub_k
     bands = []
     for o in range(n_c if by_columns else n_r):
-        if by_columns:
-            start, mid = _col_band_spans(o * sub_k, rel, sub_k, sub_q, n_r)
-            band = (o * sub_k, (o + 1) * sub_k)
-            pieces = [(start * sub_q, mid * sub_q, True),
-                      (mid * sub_q, block_q, False)]
-        else:
-            mid, end = _row_band_spans(rel + o * sub_q, sub_q, sub_k, n_c)
-            band = (o * sub_q, (o + 1) * sub_q)
-            pieces = [(0, mid * sub_k, False),
-                      (mid * sub_k, end * sub_k, True)]
-        pieces = [piece for piece in pieces if piece[0] < piece[1]]
+        pieces = []
+        for i in range(n_r if by_columns else n_c):
+            r, c = (i, o) if by_columns else (o, i)
+            kind = _subtile_kind(rel + r * sub_q, rel + (r + 1) * sub_q,
+                                 c * sub_k, (c + 1) * sub_k, window)
+            if kind is None:
+                continue
+            if pieces and pieces[-1][1:] == (i * inner, kind):
+                pieces[-1] = (pieces[-1][0], (i + 1) * inner, kind)
+            else:
+                pieces.append((i * inner, (i + 1) * inner, kind))
         if pieces:
-            bands.append((band, pieces))
+            outer = sub_k if by_columns else sub_q
+            bands.append(((o * outer, (o + 1) * outer), pieces))
     return bands
 
 
@@ -165,7 +171,7 @@ def _grid_rels(sq: int, sk: int, block_q: int, block_k: int):
 
 
 def _schedule(sq: int, sk: int, plan, causal: bool, by_columns: bool = False,
-              mask_whole: bool = False):
+              mask_whole: bool = False, window: int = 0):
     """What a grid step computes, as cases ``(lo, hi, bands)``: the bands
     (as ``_staircase`` gives them) for a tile whose ``rel`` is ``lo == hi``,
     or at least ``lo`` where ``hi`` is None, or anything where both are. A
@@ -175,9 +181,15 @@ def _schedule(sq: int, sk: int, plan, causal: bool, by_columns: bool = False,
     has always been (``mask_whole`` for a non-causal call: the backward masks
     padded columns itself, the forward takes them as a bias): the kernel of
     before the sub-tiles. Otherwise a tile the diagonal crosses gets its
-    staircase, and a tile below it is one unmasked piece."""
+    staircase, and a tile below it is one unmasked piece. With a ``window``
+    a tile far below the diagonal is not computed either, so every offset
+    the grid has gets its own case, or none."""
     block_q, block_k, sub_q, sub_k = plan
     outer, inner = (block_k, block_q) if by_columns else (block_q, block_k)
+    if window:
+        cases = [(rel, rel, _staircase(rel, plan, by_columns, window))
+                 for rel in sorted(set(_grid_rels(sq, sk, block_q, block_k)))]
+        return [case for case in cases if case[2]]
 
     def whole(masked):
         return [((0, outer), [(0, inner, masked)])]
@@ -205,23 +217,27 @@ def _subtile_counts(sq: int, sk: int, plan, cases) -> Tuple[int, int, int]:
                     for i0, i1, under_mask in pieces:
                         area = (o1 - o0) * (i1 - i0) // (sub_q * sub_k)
                         live += area
-                        masked += area * under_mask
+                        masked += area * bool(under_mask)
     return len(tiles) * (block_q // sub_q) * (block_k // sub_k), live, masked
 
 
-def _record_subtiles(kernel: str, counts: Tuple[int, int, int]) -> None:
+def _record_subtiles(kernel: str, counts: Tuple[int, int, int],
+                     window: int = 0) -> None:
     """The mechanism's engagement is static, so it is two gauges set when a
-    kernel is traced (docs/OBSERVABILITY.md)."""
+    kernel is traced (docs/OBSERVABILITY.md); a windowed call's are labelled
+    ``mask="window"`` beside the kernel."""
     total, live, masked = counts
+    labels = dict(kernel=kernel, mask="window") if window \
+        else dict(kernel=kernel)
     registry = get_tracer().registry
     registry.gauge(
         "attention.flash.live_subtile_share",
         "sub-tiles of the score matrix a flash kernel computes, of all",
-    ).set(live / total, kernel=kernel)
+    ).set(live / total, **labels)
     registry.gauge(
         "attention.flash.masked_subtile_share",
         "sub-tiles a flash kernel computes under the mask, of all",
-    ).set(masked / total, kernel=kernel)
+    ).set(masked / total, **labels)
 
 
 def _when(cond):
@@ -258,6 +274,13 @@ def _below_diagonal(shape, q0, k0):
     return rel <= q0 - k0
 
 
+def _inside_window(shape, q0, k0, window):
+    """``q_pos - k_pos < window`` on the same tile."""
+    rel = jax.lax.broadcasted_iota(jnp.int32, shape, 1) \
+        - jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    return rel > q0 - k0 - window
+
+
 # ---------------------------------------------------------------------------
 # Reference implementation (oracle + fallback + backward)
 # ---------------------------------------------------------------------------
@@ -270,12 +293,19 @@ def attention_reference(
     bias: Optional[jnp.ndarray] = None,
     causal: bool = False,
     sm_scale: Optional[float] = None,
+    window: int = 0,
 ) -> jnp.ndarray:
     """Plain jnp attention; computes in f32 regardless of input dtype (the
-    softmax accumulator precision the kernel also uses)."""
+    softmax accumulator precision the kernel also uses). Fewer K/V heads
+    than query heads are repeated to them here (the kernels index them
+    instead); ``window`` keeps, of a causal row ``i``, columns ``j`` with
+    ``i - j < window``."""
     *_, sq, d = q.shape
     sk = k.shape[-2]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
+    group = q.shape[1] // k.shape[1]
+    if group > 1:
+        k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
     if bias is not None:
@@ -284,6 +314,8 @@ def attention_reference(
         q_pos = jnp.arange(sq)[:, None] + (sk - sq)  # align ends
         k_pos = jnp.arange(sk)[None, :]
         logits = jnp.where(k_pos <= q_pos, logits, _NEG_INF)
+        if window:
+            logits = jnp.where(q_pos - k_pos < window, logits, _NEG_INF)
     weights = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", weights.astype(v.dtype), v,
                       preferred_element_type=jnp.float32).astype(q.dtype)
@@ -297,7 +329,7 @@ def attention_reference(
 def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                   m_scr, l_scr, acc_scr, *,
                   causal: bool, sm_scale: float, seq_k: int, seq_q: int,
-                  cases, one_tile: bool):
+                  cases, one_tile: bool, window: int = 0):
     """One (batch, head, q-block, kv-block) grid step of the online softmax.
 
     The kv-block axis is the innermost ("arbitrary") grid dimension: the
@@ -346,8 +378,14 @@ def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
             )  # [r1 - r0, c1 - c0]
             if bias_ref is not None:  # the plan gives a bias one piece
                 s = s + bias_ref[0, 0, :, :].astype(jnp.float32)
-            if masked:
+            if masked & _CAUSAL:
                 s = jnp.where(_below_diagonal(s.shape, rel + r0, c0),
+                              s, _NEG_INF)
+            if masked & _WINDOW:
+                # A row may see nothing of its first piece; what it then
+                # sums (p = 1 at m = -1e30) is wiped by alpha = 0 when its
+                # own diagonal comes.
+                s = jnp.where(_inside_window(s.shape, rel + r0, c0, window),
                               s, _NEG_INF)
             scores.append(s)
         m_prev = m_scr[r0:r1, :1]
@@ -407,12 +445,13 @@ def _pad_to(x: jnp.ndarray, axis: int, multiple: int) -> jnp.ndarray:
 
 
 def _flash_forward(q, k, v, bias, causal, sm_scale, interpret=False,
-                   return_stats=False, plan=None):
+                   return_stats=False, plan=None, window=0):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, sq, d = q.shape
     sk = k.shape[-2]
+    group = h // k.shape[1]  # query heads to one K/V head
     if bias is not None and bias.shape[-1] == 1:
         # The contract is "broadcastable to [B,H,Sq,Sk]"; a bias constant
         # across the K (softmax) axis shifts every logit in a row equally,
@@ -423,10 +462,11 @@ def _flash_forward(q, k, v, bias, causal, sm_scale, interpret=False,
         bias = None
     # Blocks are multiples of 8 (the f32 sublane count) — Mosaic's
     # block-shape rule. ``plan`` is for tests, which force small sub-tiles.
-    plan = plan or _tile_plan(sq, sk, d, causal)
+    plan = plan or _tile_plan(sq, sk, d, causal, window=window)
     block_q, block_k = plan[:2]
-    cases = _schedule(sq, sk, plan, causal)
-    _record_subtiles("flash_fwd", _subtile_counts(sq, sk, plan, cases))
+    cases = _schedule(sq, sk, plan, causal, window=window)
+    _record_subtiles("flash_fwd", _subtile_counts(sq, sk, plan, cases),
+                     window)
 
     qp = _pad_to(q, 2, block_q)
     kp = _pad_to(k, 2, block_k)
@@ -448,20 +488,23 @@ def _flash_forward(q, k, v, bias, causal, sm_scale, interpret=False,
             jnp.arange(sk_p) < sk, 0.0, _NEG_INF)[None, None, None, :]
         bias = pad_bias if bias is None else bias + pad_bias
 
+    # A group of query heads reads its one K/V head through the index map:
+    # nothing is repeated in HBM.
+    kv_spec = pl.BlockSpec(
+        (1, 1, block_k, d),
+        (lambda ib, ih, iq, ik: (ib, ih, ik, 0)) if group == 1
+        else (lambda ib, ih, iq, ik: (ib, ih // group, ik, 0)))
     in_specs = [
         pl.BlockSpec((1, 1, block_q, d),
                      lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-        pl.BlockSpec((1, 1, block_k, d),
-                     lambda ib, ih, iq, ik: (ib, ih, ik, 0)),
-        pl.BlockSpec((1, 1, block_k, d),
-                     lambda ib, ih, iq, ik: (ib, ih, ik, 0)),
+        kv_spec, kv_spec,
     ]
     args = [qp, kp, vp]
     # The causal diagonal is defined by the TRUE lengths (ends aligned, as
     # in attention_reference); padded q rows are sliced off at the end and
     # padded k columns sit above the diagonal, so neither corrupts it.
     kernel_kw = dict(causal=causal, sm_scale=sm_scale, seq_k=sk, seq_q=sq,
-                     cases=cases,
+                     cases=cases, window=window,
                      one_tile=(sq_p, sk_p) == (block_q, block_k))
     if bias is not None:
         # Keep broadcast dims at size 1 (indexed with block 0) instead of
@@ -551,6 +594,25 @@ def _bwd_mask(s, q0, k0, causal, cols):
     return jnp.where(live, s, _NEG_INF)
 
 
+def _piece_mask(kind, q0, k0, causal, cols, window):
+    """The mask of one piece of a backward kernel that needs one: the call's
+    own mask as ever or, in a windowed call, the edges that cross the
+    piece."""
+    if not window:
+        return functools.partial(_bwd_mask, q0=q0, k0=k0, causal=causal,
+                                 cols=cols)
+
+    def mask(s):
+        if kind & _CAUSAL:
+            s = _bwd_mask(s, q0, k0, True, cols)
+        if kind & _WINDOW:
+            s = jnp.where(_inside_window(s.shape, q0, k0, window), s,
+                          _NEG_INF)
+        return s
+
+    return mask
+
+
 def _bwd_rows(q_ref, do_ref, lse_ref, delta_ref, r0, r1):
     """Rows ``[r0, r1)`` of the q-side refs as the backward kernels use
     them: ``(q, do, lse, delta)``, float32."""
@@ -581,23 +643,30 @@ def _bwd_piece(rows, k_blk, v_blk, *, sm_scale, mask):
 
 def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                            dk_ref, dv_ref, dk_scr, dv_scr, *, causal,
-                           sm_scale, seq_q, seq_k, cases, one_tile):
+                           sm_scale, seq_q, seq_k, cases, one_tile,
+                           window=0, group=1, n_q=1):
     """One (batch, head, kv-block, q-block) grid step: accumulate this q
     block's contribution to dK/dV of one kv block in VMEM scratch; write on
     the last q step. Same block-mapped structure as the forward kernel, and
     the same schedule inside the step, here band of columns by band of
-    columns with pieces of rows."""
+    columns with pieces of rows. With ``group`` query heads to one K/V head
+    the innermost axis walks the ``n_q`` q blocks of each of them in turn,
+    so dK/dV are summed over the group where they are accumulated."""
     from jax.experimental import pallas as pl
 
     block_q = q_ref.shape[-2]
     block_k = k_ref.shape[-2]
     ik = 0 if one_tile else pl.program_id(2)
-    qi = 0 if one_tile else pl.program_id(3)
-    last_qi = 0 if one_tile else pl.num_programs(3) - 1
+    step = 0 if one_tile and group == 1 else pl.program_id(3)
+    last_step = 0 if one_tile and group == 1 else pl.num_programs(3) - 1
+    if group == 1:
+        qi = step
+    else:
+        qi = 0 if n_q == 1 else jax.lax.rem(step, n_q)
     rel = qi * block_q - ik * block_k + (seq_k - seq_q)
     cols = seq_k - ik * block_k
 
-    @_when(qi == 0)
+    @_when(step == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
@@ -608,9 +677,8 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk = dk_scr[c0:c1, :]
         dv = dv_scr[c0:c1, :]
         for r0, r1, masked in pieces:
-            mask = functools.partial(
-                _bwd_mask, q0=rel + r0, k0=c0, causal=causal,
-                cols=cols) if masked else None
+            mask = _piece_mask(masked, rel + r0, c0, causal, cols,
+                               window) if masked else None
             rows = _bwd_rows(q_ref, do_ref, lse_ref, delta_ref, r0, r1)
             q_blk, do_blk = rows[:2]
             p, ds = _bwd_piece(rows, k_blk, v_blk, sm_scale=sm_scale,
@@ -626,7 +694,7 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     _run_schedule(cases, rel, _band)
 
-    @_when(qi == last_qi)
+    @_when(step == last_step)
     def _finalize():
         dk_ref[0, 0, :, :] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0, 0, :, :] = dv_scr[...].astype(dv_ref.dtype)
@@ -634,7 +702,7 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, dq_scr, *, causal, sm_scale, seq_q,
-                         seq_k, cases, one_tile):
+                         seq_k, cases, one_tile, window=0):
     """One (batch, head, q-block, kv-block) grid step: accumulate one kv
     block's contribution to dQ of one q block; write on the last kv step.
     Band of rows by band of rows, as the forward is."""
@@ -658,9 +726,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         for c0, c1, masked in pieces:
             k_blk = k_ref[0, 0, c0:c1, :].astype(jnp.float32)
             v_blk = v_ref[0, 0, c0:c1, :].astype(jnp.float32)
-            mask = functools.partial(
-                _bwd_mask, q0=rel + r0, k0=c0, causal=causal,
-                cols=cols) if masked else None
+            mask = _piece_mask(masked, rel + r0, c0, causal, cols,
+                               window) if masked else None
             _, ds = _bwd_piece(rows, k_blk, v_blk, sm_scale=sm_scale,
                                mask=mask)
             dq = dq + sm_scale * jax.lax.dot_general(
@@ -676,21 +743,25 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, interpret,
-                    plan=None):
+                    plan=None, window=0):
     """dq, dk, dv via the blocked kernels (bias-free path)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, sq, d = q.shape
-    sk = k.shape[-2]
-    plan = plan or _tile_plan(sq, sk, d, causal, backward=True)
+    hk, sk = k.shape[1], k.shape[-2]
+    group = h // hk
+    plan = plan or _tile_plan(sq, sk, d, causal, backward=True,
+                              window=window)
     block_q, block_k = plan[:2]
     cases = {"flash_bwd_dkdv": _schedule(sq, sk, plan, causal,
-                                         by_columns=True, mask_whole=True),
+                                         by_columns=True, mask_whole=True,
+                                         window=window),
              "flash_bwd_dq": _schedule(sq, sk, plan, causal,
-                                       mask_whole=True)}
+                                       mask_whole=True, window=window)}
     for name, kernel_cases in cases.items():
-        _record_subtiles(name, _subtile_counts(sq, sk, plan, kernel_cases))
+        _record_subtiles(name, _subtile_counts(sq, sk, plan, kernel_cases),
+                         window)
 
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
 
@@ -711,27 +782,34 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, interpret,
     delta_p = jnp.broadcast_to(delta_p[..., None], (b, h, sq_p, _STAT_LANES))
 
     common = dict(causal=causal, sm_scale=sm_scale, seq_q=sq, seq_k=sk,
+                  window=window,
                   one_tile=(sq_p, sk_p) == (block_q, block_k))
+    n_q = sq_p // block_q
     semantics = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel",
                              "arbitrary")) if not interpret else None
 
     # dK/dV: grid over kv blocks, q blocks innermost (accumulated).
-    q_by_inner = pl.BlockSpec((1, 1, block_q, d),
-                              lambda ib, ih, ik, iq: (ib, ih, iq, 0))
-    row_by_inner = pl.BlockSpec((1, 1, block_q, _STAT_LANES),
-                                lambda ib, ih, ik, iq: (ib, ih, iq, 0))
+    # With grouped heads the grid runs over K/V heads, and the innermost
+    # axis over the q blocks of every query head of the group.
+    if group == 1:
+        q_rows = lambda ib, ih, ik, iq: (ib, ih, iq, 0)
+    else:
+        q_rows = lambda ib, ih, ik, step: (
+            ib, ih * group + step // n_q, step % n_q, 0)
+    q_by_inner = pl.BlockSpec((1, 1, block_q, d), q_rows)
+    row_by_inner = pl.BlockSpec((1, 1, block_q, _STAT_LANES), q_rows)
     kv_by_outer = pl.BlockSpec((1, 1, block_k, d),
                                lambda ib, ih, ik, iq: (ib, ih, ik, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkdv_kernel, **common,
-                          cases=cases["flash_bwd_dkdv"]),
-        grid=(b, h, sk_p // block_k, sq_p // block_q),
+        functools.partial(_flash_bwd_dkdv_kernel, **common, group=group,
+                          n_q=n_q, cases=cases["flash_bwd_dkdv"]),
+        grid=(b, hk, sk_p // block_k, group * n_q),
         in_specs=[q_by_inner, kv_by_outer, kv_by_outer, q_by_inner,
                   row_by_inner, row_by_inner],
         out_specs=[kv_by_outer, kv_by_outer],
-        out_shape=[jax.ShapeDtypeStruct((b, h, sk_p, d), k.dtype),
-                   jax.ShapeDtypeStruct((b, h, sk_p, d), v.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((b, hk, sk_p, d), k.dtype),
+                   jax.ShapeDtypeStruct((b, hk, sk_p, d), v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         compiler_params=semantics,
@@ -744,8 +822,10 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, interpret,
                               lambda ib, ih, iq, ik: (ib, ih, iq, 0))
     row_by_outer = pl.BlockSpec((1, 1, block_q, _STAT_LANES),
                                 lambda ib, ih, iq, ik: (ib, ih, iq, 0))
-    kv_by_inner = pl.BlockSpec((1, 1, block_k, d),
-                               lambda ib, ih, iq, ik: (ib, ih, ik, 0))
+    kv_by_inner = pl.BlockSpec(
+        (1, 1, block_k, d),
+        (lambda ib, ih, iq, ik: (ib, ih, ik, 0)) if group == 1
+        else (lambda ib, ih, iq, ik: (ib, ih // group, ik, 0)))
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, **common,
                           cases=cases["flash_bwd_dq"]),
@@ -768,38 +848,40 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, interpret,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _fused_attention(q, k, v, bias, causal, sm_scale, use_pallas, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _fused_attention(q, k, v, bias, causal, sm_scale, use_pallas, interpret,
+                     window):
     if use_pallas:
         return _flash_forward(q, k, v, bias, causal, sm_scale,
-                              interpret=interpret)
-    return attention_reference(q, k, v, bias, causal, sm_scale)
+                              interpret=interpret, window=window)
+    return attention_reference(q, k, v, bias, causal, sm_scale, window)
 
 
-def _fwd(q, k, v, bias, causal, sm_scale, use_pallas, interpret):
+def _fwd(q, k, v, bias, causal, sm_scale, use_pallas, interpret, window):
     if use_pallas and bias is None:
         # Full flash path: keep O + logsumexp so the backward kernels can
         # rebuild P per block — O(S) residual memory in training too.
         out, lse = _flash_forward(q, k, v, None, causal, sm_scale,
-                                  interpret=interpret, return_stats=True)
+                                  interpret=interpret, return_stats=True,
+                                  window=window)
         return out, (q, k, v, None, out, lse)
     out = _fused_attention(q, k, v, bias, causal, sm_scale, use_pallas,
-                           interpret)
+                           interpret, window)
     return out, (q, k, v, bias, None, None)
 
 
-def _bwd(causal, sm_scale, use_pallas, interpret, res, g):
+def _bwd(causal, sm_scale, use_pallas, interpret, window, res, g):
     q, k, v, bias, out, lse = res
     if use_pallas and bias is None:
         dq, dk, dv = _flash_backward(q, k, v, out, lse, g, causal,
-                                     sm_scale, interpret)
+                                     sm_scale, interpret, window=window)
         return dq, dk, dv, None
     # Bias path (trainable biases must receive a cotangent, and dS would be
     # a full [Sq,Sk] output anyway): recompute through the reference
     # formulation — XLA fuses it. Costs O(S²) backward memory; bias-free
     # training (the long-context path) never lands here.
     def f(q, k, v, bias):
-        return attention_reference(q, k, v, bias, causal, sm_scale)
+        return attention_reference(q, k, v, bias, causal, sm_scale, window)
     _, vjp = jax.vjp(f, q, k, v, bias)
     dq, dk, dv, dbias = vjp(g)
     return dq, dk, dv, None if bias is None else dbias
@@ -839,8 +921,16 @@ def fused_attention(
     causal: bool = False,
     sm_scale: Optional[float] = None,
     implementation: str = "auto",
+    window: int = 0,
 ) -> jnp.ndarray:
     """Multi-head attention, fused on TPU.
+
+    ``k`` and ``v`` may have fewer heads than ``q``, a whole number of query
+    heads to each (query head ``i`` reads K/V head ``i // group``): the
+    kernels index them, nothing is repeated in HBM, and dK/dV come back
+    summed over the group. ``window`` > 0 (causal calls without a bias)
+    keeps, of row ``i``, the columns ``j`` with ``i - j < window``; tiles
+    and sub-tiles wholly outside it are not computed.
 
     implementation: 'auto' (on TPU: flash kernel, except the measured
     short-sequence window — Sk < 1024 with the quadratic backward
@@ -851,6 +941,14 @@ def fused_attention(
     """
     if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
         raise ValueError(f"expected [B,H,S,D] inputs, got {q.shape}")
+    if k.shape[1] != v.shape[1] or q.shape[1] % k.shape[1]:
+        raise ValueError(
+            f"{q.shape[1]} query heads cannot share {k.shape[1]} key and "
+            f"{v.shape[1]} value heads")
+    if window and (not causal or bias is not None or window < 0):
+        raise ValueError("a window needs a causal call without a bias, and "
+                         f"a positive size; got window={window}, "
+                         f"causal={causal}, bias given: {bias is not None}")
     if causal and q.shape[-2] > k.shape[-2]:
         # Ill-defined: ends are aligned, so the leading queries would
         # precede every key (and the kernel/reference paths would disagree
@@ -873,4 +971,4 @@ def fused_attention(
     else:
         raise ValueError(f"unknown implementation {implementation!r}")
     return _fused_attention(q, k, v, bias, causal, scale, use_pallas,
-                            interpret)
+                            interpret, window)

@@ -308,6 +308,35 @@ def _gpt_small() -> ExperimentConfig:
     )
 
 
+@register_preset("laguna_xs2_lm")
+def _laguna_xs2() -> ExperimentConfig:
+    """Laguna-XS.2 (poolside, 33.4B-A3B: window and full attention mixed
+    over grouped K/V heads, 256 routed experts 8 a token and a shared one)
+    pre-trained on one chip's share of a pod: the chip is one of 8 that share
+    each layer, experts 0-31 of 256 and 12,544 of the 100,352 vocabulary rows
+    here, attention and the dense MLP whole (data-parallel attention beside
+    expert-parallel experts), and it holds layers 0-4 of 40 as one pipeline
+    stage: the dense layer, then one whole period (sliding x 3, full). The
+    exchange with the other chips is not here (models/moe.py). Sequences of
+    4096, the source's pre-training context. Recipe: gpt_small_lm's (the
+    source publishes none), no auxiliary loss."""
+    return ExperimentConfig(
+        model=ModelConfig(
+            name="gpt_laguna_xs2",
+            kwargs=dict(layers_held=(0, 1, 2, 3, 4), experts_held=(0, 32)),
+        ),
+        data=DataConfig(name="lm_text", seq_len=4096, vocab_size=12_544),
+        train=TrainConfig(global_batch=2, steps=100_000, dtype="bfloat16",
+                          shard_opt_state=False),
+        optimizer=OptimizerConfig(name="adamw", b1=0.9, b2=0.95,
+                                  weight_decay=0.1, grad_clip_norm=1.0),
+        schedule=ScheduleConfig(name="cosine", base_lr=6e-4,
+                                warmup_steps=2000),
+        mesh=MeshConfig(data=-1),
+        stack=StackConfig(slice_type="v5e-8"),
+    )
+
+
 @register_preset("transformer_nmt_wmt")
 def _nmt() -> ExperimentConfig:
     """Transformer NMT WMT En-De (reference: Sockeye + MXNet

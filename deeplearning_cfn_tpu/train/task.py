@@ -433,12 +433,17 @@ class CausalLmTask:
             hits = (jnp.argmax(logits, -1) == targets).astype(jnp.float32)
             aux = {"token_accuracy": jnp.sum(hits * weights) / denom}
             if moe_aux is not None:
-                # ST-MoE aux-loss weights, as in MlmTask.
-                loss = loss \
-                    + MOE_LOAD_BALANCE_WEIGHT * moe_aux["load_balance"] \
-                    + MOE_ROUTER_Z_WEIGHT * moe_aux["router_z"]
-                aux["moe_load_balance"] = moe_aux["load_balance"]
-                aux["moe_router_z"] = moe_aux["router_z"]
+                # What the model's expert layers report goes to the step's
+                # metrics as moe_<name>; of it the capacity layer's two
+                # losses join the objective (ST-MoE's weights, as in
+                # MlmTask). A layer that reports none trains on
+                # cross-entropy alone.
+                for name, weight in (
+                        ("load_balance", MOE_LOAD_BALANCE_WEIGHT),
+                        ("router_z", MOE_ROUTER_Z_WEIGHT)):
+                    if name in moe_aux:
+                        loss = loss + weight * moe_aux[name]
+                aux.update({f"moe_{k}": v for k, v in moe_aux.items()})
             if train:
                 # Per-step perplexity for the train log only: exp of THIS
                 # step's token-mean CE (clipped against random-init

@@ -25,7 +25,7 @@ import optax
 from jax.sharding import Mesh
 
 from ..config import ExperimentConfig
-from ..obs.trace import span
+from ..obs.trace import get_tracer, span
 from ..parallel.mesh import build_mesh, validate_batch
 from ..parallel.sharding import batch_sharding, replicated
 from .state import TrainState
@@ -541,6 +541,17 @@ class Trainer:
                                 for k_, v in
                                 jax.device_get(w_metrics).items()
                             }
+                            # What the expert layers counted in this step,
+                            # in the registry too: the gauge holds this
+                            # step, the histogram every realized step
+                            # (docs/OBSERVABILITY.md).
+                            registry = get_tracer().registry
+                            for k_, v in realized.items():
+                                if k_.startswith("moe_"):
+                                    name = "moe." + k_[4:]
+                                    registry.gauge(name).set(v)
+                                    registry.histogram(
+                                        name + ".steps").observe(v)
                         if first_write:
                             # Throughput covers everything dispatched
                             # since the last written boundary; the final

@@ -64,3 +64,61 @@ def test_flash_kernel_compiles_for_v5e(v5e_chip, name, shape, sk, causal,
         argnums=(0, 1, 2))
     compiled = jax.jit(fn).lower(arg, kv, kv).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name,heads,window", [
+    ("laguna_full", 48, 0), ("laguna_sliding", 64, 512)])
+@pytest.mark.parametrize("what", ["forward", "grad"])
+def test_grouped_windowed_kernels_compile_for_v5e(v5e_chip, name, heads,
+                                                  window, what):
+    """The Laguna cell's two attention shapes: 8 K/V heads under 48 and 64
+    query heads at 4096 positions and head size 128, the sliding one under
+    its window of 512 (sub-tiles in all three kernels)."""
+    one_chip = SingleDeviceSharding(v5e_chip)
+    q = jax.ShapeDtypeStruct((2, heads, 4096, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 8, 4096, 128), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def attn(q, k, v):
+        return fused_attention(q, k, v, causal=True, window=window,
+                               implementation="pallas")
+
+    fn = attn if what == "forward" else jax.grad(
+        lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))
+    compiled = jax.jit(fn).lower(q, kv, kv).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == (1 if what == "forward" else 3)
+    # K/V are not repeated to the query heads: every kernel takes them as
+    # they are, 8 heads.
+    for line in text.splitlines():
+        if "tpu_custom_call" in line and " custom-call(" in line:
+            assert "bf16[2,8,4096,128]" in line, line[:300]
+
+
+def test_laguna_step_compiles_and_fits_a_v5e(v5e_chip):
+    """The whole train step of ``laguna_xs2_train_4k`` at the cell's shapes
+    (``benchmark/rehearse_compile.py``, the builder's rehearsal): the chip's
+    compiler takes it, the flash kernels and the grouped matmuls are in it,
+    and arguments plus temporaries fit the chip's 16 GB. PERF.md section 4
+    has the number."""
+    import os
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path.insert(0, bench)
+    try:
+        from harness import manifest
+        import rehearse_compile
+    finally:
+        sys.path.remove(bench)
+    cell = manifest.Cell(manifest.load_manifest(), "laguna_xs2_train_4k")
+    _, compiled, _ = rehearse_compile.compile_step(cell)
+    mem = compiled.memory_analysis()
+    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    assert 4e9 < total < 16e9, total
+    # 5 forward and 10 backward flash kernels, and the grouped matmuls.
+    assert compiled.as_text().count("tpu_custom_call") > 15
