@@ -25,7 +25,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from ..obs.trace import get_tracer
 from ..ops import fused_attention
+from ..ops.rope import kernel_engages, rotate_to_heads
 
 Dtype = Any
 
@@ -101,10 +103,13 @@ class Rope:
 
 
 def apply_rope(x: jnp.ndarray, rope: Rope) -> jnp.ndarray:
-    """Turn ``x [B, S, H, D]`` by its positions ``0 .. S - 1``. The two
-    turning halves are computed apart and joined: written as one
-    multiply-add over the whole head (``x * cos + swap(x) * sin``) the step
-    of the Laguna cell ran 2.4 % slower on the chip (PERF.md, PR 26)."""
+    """Turn ``x [B, S, H, D]`` by its positions ``0 .. S - 1``: the plain
+    form, the two turning halves computed apart and joined. At a 128-lane
+    head XLA does each slice and the join through HBM in float32 (30 ms of
+    the Laguna cell's 281 ms step, and 2.4 % more written as one multiply-add
+    over the whole head: PERF.md, PR 26), so there ``rope_to_heads`` takes
+    the kernel of ``ops/rope.py``; this stays for the heads it cannot tile,
+    and is what the kernel is tested against."""
     head_dim = x.shape[-1]
     rot = rope.rotary_dim or head_dim
     cos, sin = (jnp.asarray(t)[None, :, None, :]
@@ -115,6 +120,28 @@ def apply_rope(x: jnp.ndarray, rope: Rope) -> jnp.ndarray:
     if rot < head_dim:
         turned.append(x32[..., rot:])
     return jnp.concatenate(turned, axis=-1).astype(x.dtype)
+
+
+def rope_to_heads(x: jnp.ndarray, rope: Rope,
+                  implementation: str = "auto") -> jnp.ndarray:
+    """``x [B, S, H, D]`` turned by its positions, as ``[B, H, S, D]``: the
+    kernel where ``ops/rope.py:kernel_engages`` says so (a head of whole lane
+    tiles on a TPU), else :func:`apply_rope` and the transpose. Which one is
+    static, so it is counted when the call is traced: ``attention.rope.calls``
+    labelled ``path=kernel|xla`` (docs/OBSERVABILITY.md)."""
+    b, seq_len, h, head_dim = x.shape
+    use_kernel, interpret = kernel_engages(implementation, seq_len, head_dim)
+    get_tracer().registry.counter(
+        "attention.rope.calls",
+        "rotary-position calls traced, by the path they took",
+    ).inc(path="kernel" if use_kernel else "xla")
+    if use_kernel:
+        # The kernel reads the projection's output as it lies: this undoes
+        # the caller's split of the last dimension, and XLA drops both.
+        return rotate_to_heads(x.reshape(b, seq_len, h * head_dim),
+                               *rope.tables(seq_len, head_dim), head_dim,
+                               interpret=interpret)
+    return apply_rope(x, rope).transpose(0, 2, 1, 3)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -300,8 +327,11 @@ class MultiHeadAttention(nn.Module):
         v = heads(dense("value", kv_heads * head_dim)(kv), kv_heads)
         if st.rope is not None:
             with jax.named_scope("rope"):
-                q, k = apply_rope(q, st.rope), apply_rope(k, st.rope)
-        q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # [B,H,S,D]
+                q, k = (rope_to_heads(t, st.rope, self.attention_impl)
+                        for t in (q, k))
+            v = v.transpose(0, 2, 1, 3)
+        else:
+            q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # [B,H,S,D]
         if decode and self_attention and block_tables is not None:
             if kv_num_blocks <= 0 or kv_block_size <= 0:
                 raise ValueError(

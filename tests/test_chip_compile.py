@@ -1,6 +1,6 @@
-"""The flash-attention kernels, compiled by the TPU's own compiler for a chip
-that is described and not attached (a v5e 2x2), at the shapes the shipped
-presets produce. Interpret mode cannot see what this does: a slice that is
+"""The flash-attention kernels and the rotary-position kernel, compiled by the
+TPU's own compiler for a chip that is described and not attached (a v5e 2x2),
+at the shapes the shipped presets produce. Interpret mode cannot see what this does: a slice that is
 not aligned to the tiling, or more fast memory than a kernel may use. A
 compile that passes is not a chip run — chip_smoke.py is."""
 
@@ -97,12 +97,43 @@ def test_grouped_windowed_kernels_compile_for_v5e(v5e_chip, name, heads,
             assert "bf16[2,8,4096,128]" in line, line[:300]
 
 
+@pytest.mark.parametrize("name,heads,rope", [
+    ("q_sliding", 64, "sliding_rope"), ("q_full", 48, "full_rope"),
+    ("k_sliding", 8, "sliding_rope"), ("k_full", 8, "full_rope")])
+@pytest.mark.parametrize("what", ["forward", "grad"])
+def test_rope_kernel_compiles_for_v5e(v5e_chip, name, heads, rope, what):
+    """The Laguna cell's four rotary shapes: q ``[2,4096,64*128]`` turning
+    whole heads (one lane rotate), q ``[2,4096,48*128]`` turning 64 of 128
+    lanes (YaRN: two rotates and a select), k ``[2,4096,8*128]`` at both.
+    Forward reads the projection's layout and writes the flash kernels';
+    the gradient is the same kernel the other way round."""
+    from deeplearning_cfn_tpu.models.lm import _LAGUNA_XS2
+    from deeplearning_cfn_tpu.models.transformer import rope_to_heads
+
+    one_chip = SingleDeviceSharding(v5e_chip)
+    x = jax.ShapeDtypeStruct((2, 4096, heads, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    g = jax.ShapeDtypeStruct((2, heads, 4096, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def turn(x):
+        return rope_to_heads(x, _LAGUNA_XS2[rope], "pallas")
+
+    # The turn is linear: its gradient alone depends on no x, and a jit
+    # without the described chip among its arguments compiles for the CPU.
+    compiled = jax.jit(turn).lower(x).compile() if what == "forward" else \
+        jax.jit(lambda x, g: jax.vjp(turn, x)[1](g)[0]).lower(x, g).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert ("rope_fwd" if what == "forward" else "rope_bwd") in text
+
+
 def test_laguna_step_compiles_and_fits_a_v5e(v5e_chip):
     """The whole train step of ``laguna_xs2_train_4k`` at the cell's shapes
     (``benchmark/rehearse_compile.py``, the builder's rehearsal): the chip's
-    compiler takes it, the flash kernels and the grouped matmuls are in it,
-    and arguments plus temporaries fit the chip's 16 GB. PERF.md section 4
-    has the number."""
+    compiler takes it, the flash kernels, the grouped matmuls and the rotary
+    kernels are in it, and arguments plus temporaries fit the chip's 16 GB.
+    PERF.md section 4 has the number."""
     import os
     import sys
 
@@ -114,11 +145,28 @@ def test_laguna_step_compiles_and_fits_a_v5e(v5e_chip):
         import rehearse_compile
     finally:
         sys.path.remove(bench)
+    from deeplearning_cfn_tpu.obs.trace import get_tracer
+
+    calls = get_tracer().registry.counter("attention.rope.calls")
+    before = {path: calls.value(path=path) for path in ("kernel", "xla")}
     cell = manifest.Cell(manifest.load_manifest(), "laguna_xs2_train_4k")
     _, compiled, _ = rehearse_compile.compile_step(cell)
+    # ``compile_step`` traces the model twice, once for the parameters'
+    # shapes and once in the step: each trace turns q and k of five layers.
+    assert {path: calls.value(path=path) - n
+            for path, n in before.items()} == {"kernel": 20, "xla": 0}
     mem = compiled.memory_analysis()
     total = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         + mem.output_size_in_bytes - mem.alias_size_in_bytes
     assert 4e9 < total < 16e9, total
     # 5 forward and 10 backward flash kernels, and the grouped matmuls.
-    assert compiled.as_text().count("tpu_custom_call") > 15
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") > 15
+    # q and k of five layers, turned forward and back by the kernel, which
+    # keeps the scope that ``blocks_ms`` counts it under.
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               and "/rope/" in line]
+    assert sum("rope_fwd" in line for line in kernels) == 10
+    assert sum("rope_bwd" in line for line in kernels) == 10
+    assert all("/self_attn/rope/" in line for line in kernels)
