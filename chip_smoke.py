@@ -47,7 +47,7 @@ import time
 
 REPO_ROOT = os.path.dirname(os.path.abspath(__file__))
 
-# One chip: the per-chip batches are deeplearning_cfn_tpu/bench.py's.
+# One chip: a per-chip batch for each preset.
 RESNET_OVERRIDES = (
     "data.synthetic=true", "train.global_batch=512",
     # A few batches, not the default 8192-image (5 GB) synthetic set.

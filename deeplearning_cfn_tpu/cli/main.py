@@ -377,12 +377,9 @@ def _cmd_bench(args) -> int:
               "with --fleet", file=sys.stderr)
         return 2
     if getattr(args, "fleet", False):
-        if getattr(args, "ops", None) or args.collectives or \
-                getattr(args, "sweep_batches", None) or \
-                getattr(args, "serve", False):
+        if args.collectives or getattr(args, "serve", False):
             print("[dlcfn-tpu] --fleet is its own scenario — don't combine "
-                  "with --serve/--ops/--collectives/--sweep-batches",
-                  file=sys.stderr)
+                  "with --serve/--collectives", file=sys.stderr)
             return 2
         if getattr(args, "net", False):
             # Real child processes over unix sockets — the wall-clock
@@ -447,20 +444,10 @@ def _cmd_bench(args) -> int:
                                degrade=args.degrade)
         print(json.dumps(line))
         return 0
-    if getattr(args, "obs_smoke", False):
-        from ..bench import run_obs_overhead_smoke
-
-        record = run_obs_overhead_smoke(
-            preset=args.preset, steps=args.steps,
-            global_batch=args.global_batch)
-        print(json.dumps(record))
-        return 0
     if getattr(args, "serve", False):
-        if getattr(args, "ops", None) or args.collectives or \
-                getattr(args, "sweep_batches", None):
+        if args.collectives:
             print("[dlcfn-tpu] --serve is its own scenario — don't combine "
-                  "with --ops/--collectives/--sweep-batches",
-                  file=sys.stderr)
+                  "with --collectives", file=sys.stderr)
             return 2
         from ..serve.bench import run_serve_bench
 
@@ -494,24 +481,6 @@ def _cmd_bench(args) -> int:
                   "the bound", file=sys.stderr)
             return 1
         return 0
-    if getattr(args, "sweep_batches", None):
-        if getattr(args, "ops", None) or args.collectives:
-            print("[dlcfn-tpu] --sweep-batches only applies to the "
-                  "training-step bench (not --ops/--collectives)",
-                  file=sys.stderr)
-            return 2
-        if args.global_batch:
-            print("[dlcfn-tpu] pass either --sweep-batches or "
-                  "--global-batch, not both", file=sys.stderr)
-            return 2
-    if getattr(args, "ops", None):
-        from ..opsbench import main as opsbench_main
-
-        ops_argv = ["--suite", args.ops, "--steps", str(args.steps)]
-        if args.global_batch:
-            ops_argv += ["--batch", str(args.global_batch)]
-        opsbench_main(ops_argv)
-        return 0
     if args.collectives:
         # The nccl-tests role: psum/all-gather/ppermute/reduce-scatter bus
         # bandwidth over the mesh's links, one JSON line per op.
@@ -520,36 +489,10 @@ def _cmd_bench(args) -> int:
         for rec in run_collectives_bench(size_mb=args.size_mb):
             print(json.dumps(rec))
         return 0
-    from ..bench import run_bench
-
-    if getattr(args, "sweep_batches", None):
-        # Batch-size tuning table (how BASELINE.md's 512-vs-1024 row was
-        # found): one JSON line per global batch, same process so later
-        # sizes reuse the warm backend.
-        try:
-            batches = [int(b) for b in args.sweep_batches.split(",") if b]
-        except ValueError:
-            print(f"[dlcfn-tpu] bad --sweep-batches {args.sweep_batches!r}: "
-                  "expected comma-separated integers, e.g. 256,512,768",
-                  file=sys.stderr)
-            return 2
-        if not batches or any(b <= 0 for b in batches):
-            print("[dlcfn-tpu] --sweep-batches values must be positive "
-                  "integers", file=sys.stderr)
-            return 2
-        for gb in batches:
-            line = run_bench(preset=args.preset, steps=args.steps,
-                             global_batch=gb,
-                             include_input=args.with_input,
-                             step_window=args.step_window)
-            print(json.dumps(line), flush=True)
-        return 0
-    line = run_bench(preset=args.preset, steps=args.steps,
-                     global_batch=args.global_batch,
-                     include_input=args.with_input,
-                     step_window=args.step_window)
-    print(json.dumps(line))
-    return 0
+    print("[dlcfn-tpu] bench needs a mode (--collectives, --serve, --fleet); "
+          "training is measured by `python3 benchmark/run.py --workload "
+          "<cell>` (BENCHMARK.json lists the cells)", file=sys.stderr)
+    return 2
 
 
 def _cmd_serve(args) -> int:
@@ -2004,30 +1947,13 @@ def build_parser() -> argparse.ArgumentParser:
     doc.set_defaults(fn=_cmd_doctor)
 
     be = sub.add_parser("bench", help="run the benchmark harness")
-    be.add_argument("--preset", default="cifar10_resnet20")
-    be.add_argument("--steps", type=int, default=30)
-    be.add_argument("--global-batch", type=int, default=0)
-    be.add_argument("--with-input", action="store_true",
-                    help="also report value_with_input (host pipeline + "
-                         "transfer in the timed loop)")
-    be.add_argument("--step-window", type=int, default=1,
-                    help="fuse K train steps per device dispatch (bench "
-                         "the fast path's scan program; 1 = per-step)")
     be.add_argument("--collectives", action="store_true",
-                    help="run the collectives microbench (nccl-tests role) "
-                         "instead of a training-step bench")
+                    help="run the collectives microbench (nccl-tests role)")
     be.add_argument("--size-mb", type=float, default=64.0,
                     help="collectives payload size in MB")
-    be.add_argument("--ops", choices=["detection", "resnet", "all"],
-                    help="run the op-level microbench suite (opsbench) "
-                         "instead of a training-step bench")
-    be.add_argument("--sweep-batches",
-                    help="comma-separated global batch sizes to bench in "
-                         "sequence (one JSON line each), e.g. 256,512,768")
     be.add_argument("--serve", action="store_true",
                     help="run the serving scenario (fixed request trace "
-                         "through the continuous-batching engine) instead "
-                         "of a training-step bench")
+                         "through the continuous-batching engine)")
     be.add_argument("--requests-count", type=int, default=16,
                     help="serving scenario: trace length")
     be.add_argument("--slots", type=int, default=4,
@@ -2180,10 +2106,6 @@ def build_parser() -> argparse.ArgumentParser:
                          "router fleet.request spans and the signal "
                          "snapshot under DIR (merge with "
                          "'obs export --fleet DIR')")
-    be.add_argument("--obs-smoke", action="store_true",
-                    help="obs overhead smoke: step time instrumented vs "
-                         "spans disabled (the <=5%% gate; use "
-                         "--preset transformer_nmt_wmt on CPU)")
     be.set_defaults(fn=_cmd_bench)
 
     met = sub.add_parser(

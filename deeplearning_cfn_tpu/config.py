@@ -131,18 +131,6 @@ class TrainConfig:
     # (unroll on CPU, where XLA executes convs inside loop bodies ~10x
     # slower than straight-line — measured r04; scan elsewhere).
     grad_accum_unroll: str = "auto"
-    # Device-resident fast path: fuse this many consecutive train steps
-    # into ONE jitted lax.scan per device call (a *train window*), paying
-    # Python dispatch + input staging once per window instead of per step.
-    # Per-step RNG folds in the global step inside the scan body, so the
-    # loss trajectory is bit-identical to the per-step loop for any K (the
-    # parity contract — mirrors serve's decode windows). Windows clamp to
-    # the next log/eval/trace/hook-cadence boundary so every existing
-    # cadence lands exactly where it does today. 1 (the default) is the
-    # per-step loop, unchanged; keep 1 on CPU for conv presets — XLA:CPU
-    # runs convs inside scan bodies ~10x slower than straight-line (the
-    # r04 scan-vs-unroll finding).
-    step_window: int = 1
     # Host→device input staging depth: batches are device_put with their
     # target shardings on a background thread (double-buffered at the
     # default 2) so transfer overlaps device compute and the step loop

@@ -817,8 +817,7 @@ def run_fleet_bench(replicas: int = 2, num_requests: int = 16,
         fleet_tokens = [r["tokens"] for r in results]
         token_identical = fleet_tokens == baseline
 
-    # Loadgen / autoscale derived fields (null when the feature is off —
-    # root bench.py _finalize_green nulls them for unmeasured records).
+    # Loadgen / autoscale derived fields (null when the feature is off).
     p95_during_burst = None
     time_to_scale_s = None
     scale_ups = scale_downs = 0

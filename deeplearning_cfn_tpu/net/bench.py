@@ -37,8 +37,7 @@ from ..serve.queue import OverloadError
 METRIC = "net_fleet_tiny_nmt_tokens_per_sec"
 UNIT = "tokens/sec"
 
-#: Record fields that must be null (never 0) when unmeasured — root
-#: bench.py's ``_finalize_green`` nulls these on red/unmeasured runs.
+#: Record fields that must be null (never 0) when unmeasured.
 NULLABLE_FIELDS = ("net_decode_p95_disagg", "net_decode_p95_colocated",
                    "autoscale_time_to_scale_s", "net_stream_ttfb_p50",
                    "net_stream_ttfb_p95")

@@ -8,16 +8,10 @@ relative tolerance. Direction is metric-aware: throughputs
 anything else is reported informationally and never gates. Comparing a
 run against itself yields zero deltas and no regressions by construction
 — the tier-1 self-diff smoke pins that.
-
-The same comparator gates bench records: root ``bench.py`` calls
-:func:`diff_bench_records` when ``DLCFN_BENCH_DIFF_AGAINST`` points at a
-prior contract JSON, attaching the verdict to the new record.
 """
 
 from __future__ import annotations
 
-import json
-import os
 from typing import Any, Dict, List, Optional
 
 from .metrics import percentile
@@ -174,62 +168,3 @@ def render_diff(report: Dict[str, Any]) -> str:
                 if report["regressions"] else ""))
     return "\n".join(L)
 
-
-# -- bench record gating -----------------------------------------------------
-
-_BENCH_KEYS = ("value", "mean_step_s", "mfu", "value_with_input",
-               "mean_step_s_with_input")
-
-
-def diff_bench_records(prior: Dict[str, Any], current: Dict[str, Any],
-                       tolerance: float = DEFAULT_TOLERANCE
-                       ) -> Dict[str, Any]:
-    """Compare two bench contract records key-by-key; same direction
-    rules as the run diff. Unmeasured records never gate."""
-    out: Dict[str, Any] = {"tolerance": tolerance, "regressions": [],
-                           "metrics": {}}
-    if not prior.get("measured", True) or not current.get("measured",
-                                                          True):
-        out["skipped"] = "one of the records is measured=false"
-        out["ok"] = True
-        return out
-    for key in _BENCH_KEYS:
-        a, b = prior.get(key), current.get(key)
-        if not isinstance(a, (int, float)) or isinstance(a, bool) \
-                or not isinstance(b, (int, float)) or isinstance(b, bool):
-            continue
-        rel = _rel(float(a), float(b))
-        d = direction(key)
-        regressed = (rel is not None
-                     and ((d == "lower" and rel > tolerance)
-                          or (d == "higher" and rel < -tolerance)))
-        out["metrics"][key] = {"prior": a, "current": b, "rel": rel,
-                               "direction": d, "regressed": regressed}
-        if regressed:
-            out["regressions"].append(key)
-    out["ok"] = not out["regressions"]
-    return out
-
-
-def load_bench_record(path: str) -> Optional[Dict[str, Any]]:
-    """Read a prior bench contract record: a JSON file holding one
-    record, or a JSONL file whose last parseable line with a "metric"
-    key wins."""
-    if not os.path.exists(path):
-        return None
-    with open(path) as fh:
-        text = fh.read()
-    try:
-        doc = json.loads(text)
-        if isinstance(doc, dict):
-            return doc
-    except json.JSONDecodeError:
-        pass
-    for line in reversed(text.strip().splitlines()):
-        try:
-            doc = json.loads(line)
-        except json.JSONDecodeError:
-            continue
-        if isinstance(doc, dict) and "metric" in doc:
-            return doc
-    return None

@@ -890,8 +890,8 @@ def _bwd(causal, sm_scale, use_pallas, interpret, window, res, g):
 _fused_attention.defvjp(_fwd, _bwd)
 
 
-# Auto-dispatch crossover, measured on hardware in r03 (BASELINE.md kernel
-# table, v5e): XLA's own fused attention beat the flash kernel at S=512
+# Auto-dispatch crossover, measured on a v5e in r03 (that log is lost and
+# no cell measures it: PERF.md section 7): XLA's own fused attention beat the flash kernel at S=512
 # (9.0 ms vs 6.7 ms, 0.74×) while flash won 1.4× at S=2048 and 35× at
 # S=8192 (where XLA spills the [S,S] matrix to HBM). Between the measured
 # points the switch sits at 1024. The XLA path's backward holds 2-3

@@ -60,8 +60,7 @@ class StepTimer:
     """Wall-clock step timing with explicit device sync.
 
     Async dispatch makes naive timing lie (the Python loop runs ahead of the
-    device); this timer syncs on a result before reading the clock, which is
-    how every number in BASELINE.md must be measured.
+    device); this timer syncs on a result before reading the clock.
 
     Timings land in an ``obs`` :class:`Histogram` (``step_time_s``) — raw
     samples retained, exponential buckets for the Prometheus export — in a

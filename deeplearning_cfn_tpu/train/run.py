@@ -209,9 +209,6 @@ def run_experiment(
             eval_iter_fn=lambda: eval_pipe.one_epoch(),
             eval_every=eval_every,
             hooks=tuple(hooks),
-            # Step windows must land exactly on the save cadence — the
-            # manager's own should_save(step) check only fires on multiples.
-            hook_every=ckpt_every,
             log_every=cfg.train.log_every_steps,
             metrics_writer=writer,
             trace_dir=os.path.join(workdir, "profile")

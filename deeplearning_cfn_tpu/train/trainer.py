@@ -67,22 +67,6 @@ class _LazyShardedJit:
         return self._ensure(state).lower(state, batch, rng)
 
 
-def _plan_window(step: int, num_steps: int, window: int,
-                 cadences, boundaries=()) -> int:
-    """Largest k <= ``window`` such that the half-open step range
-    [step, step+k) crosses no cadence multiple and no explicit boundary
-    except at its end — so log/eval/hook cadences and trace start/stop
-    always land exactly on a window edge, never inside a fused scan."""
-    k = min(window, num_steps - step)
-    for c in cadences:
-        if c and c > 0:
-            k = min(k, c - step % c)
-    for b in boundaries:
-        if b > step:
-            k = min(k, b - step)
-    return max(k, 1)
-
-
 class Trainer:
     """Owns the compiled train/eval steps and the step loop.
 
@@ -127,10 +111,6 @@ class Trainer:
             raise ValueError(
                 f"train.grad_accum_unroll must be auto|scan|unroll, got "
                 f"{cfg.train.grad_accum_unroll!r}")
-        if cfg.train.step_window < 1:
-            raise ValueError(
-                f"train.step_window must be >= 1, got "
-                f"{cfg.train.step_window}")
         if cfg.train.device_prefetch < 0:
             raise ValueError(
                 f"train.device_prefetch must be >= 0, got "
@@ -141,7 +121,6 @@ class Trainer:
         # targets are also 4-D but their dim 1 is a box count, not height.
         self.spatial_keys = spatial_keys
         self._train_step = None
-        self._window_step = None
         self._eval_step = None
         self._donate = donate
         # Post-aggregation metric transforms (task.eval_derived): computed
@@ -186,14 +165,10 @@ class Trainer:
     # -- compiled steps -----------------------------------------------------
 
     def _train_step_fn(self):
-        """The raw (unjitted) per-step function. Shared by the per-step
-        jit and the fused step-window scan so the two paths trace the
-        SAME per-step jaxpr — that sharing, plus ``fold_in(rng,
-        state.step)`` keyed off the in-carry step counter, pins the
-        window path to the per-step loop's exact math and RNG streams.
-        (XLA may still fuse a while-loop body differently than the
-        straight-line program, so trajectories agree to float precision
-        — ~1 ulp/step — not necessarily bit-for-bit.)"""
+        """The raw (unjitted) per-step function. ``fold_in(rng,
+        state.step)`` keys the step's randomness off the state's own
+        counter, so a run resumed from a checkpoint, or ``fit`` called
+        twice, draws the streams one uninterrupted run draws."""
         tx = self.tx
         loss_fn = self.loss_fn
         ema_decay = self.cfg.train.ema_decay
@@ -310,31 +285,6 @@ class Trainer:
         donate = (0,) if self._donate else ()
         return _LazyShardedJit(self._train_step_fn(), donate)
 
-    def _build_window_step(self):
-        step_fn = self._train_step_fn()
-
-        def window_step(state: TrainState, batches: Tuple[Batch, ...],
-                        rng: jax.Array):
-            # Stack the k device-staged batches inside the jitted program
-            # (device-side concat — each batch was already put with its
-            # target sharding, so the stack inherits it on dims 1+), then
-            # scan the SAME per-step body the per-step jit runs. The body
-            # folds rng with the in-carry step counter, so every step of
-            # the window draws its canonical RNG stream and the loss
-            # trajectory matches k per-step calls step for step (to float
-            # precision — XLA's loop-body codegen can differ from the
-            # straight-line program by ~1 ulp).
-            stacked = jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs), *batches)
-
-            def body(st, b):
-                return step_fn(st, b, rng)
-
-            return jax.lax.scan(body, state, stacked)
-
-        donate = (0,) if self._donate else ()
-        return _LazyShardedJit(window_step, donate)
-
     def _build_eval_step(self):
         loss_fn = self.loss_fn
 
@@ -352,16 +302,6 @@ class Trainer:
         if self._train_step is None:
             self._train_step = self._build_train_step()
         return self._train_step
-
-    @property
-    def window_step(self):
-        """Fused multi-step program: ``(state, (batch,)*k, rng) ->
-        (state, stacked metrics [k])``. jit re-specializes per distinct k
-        (the tuple length is part of the pytree structure), so a clamped
-        remainder window compiles its own program once."""
-        if self._window_step is None:
-            self._window_step = self._build_window_step()
-        return self._window_step
 
     @property
     def eval_step(self):
@@ -386,29 +326,18 @@ class Trainer:
         start_step: Optional[int] = None,
         trace_dir: Optional[str] = None,
         trace_steps: int = 0,
-        hook_every: int = 1,
     ) -> TrainState:
         """The step loop. Dispatches async; only syncs on metrics at
         ``log_every`` boundaries so device compute and host input prep overlap
         (the reference achieved this with MXNet/TF's async engines; here it is
-        jax dispatch + explicit sync points).
-
-        With ``train.step_window`` K > 1, K consecutive steps run as ONE
-        fused ``window_step`` program (a lax.scan over K device-staged
-        batches) — K fewer dispatches and zero host round-trips between
-        the fused steps, with the per-step loop's exact math and RNG
-        streams (trajectories agree to float precision; see
-        ``_train_step_fn``). Windows are clamped so log/eval/hook cadences and
-        trace start/stop always land on a window edge; hooks fire at
-        every window boundary, and ``hook_every`` names the cadence (in
-        steps) hooks must land on exactly — run.py passes the checkpoint
-        cadence. K = 1 (the default) is the per-step loop, unchanged.
+        jax dispatch + explicit sync points). Each turn: next batch,
+        dispatch, first-step sync, log boundary, hooks, eval.
 
         With ``train.device_prefetch`` d > 0, host batches are staged to
         device (``device_batch``) on a background thread, d deep, so
-        host→device transfer overlaps the previous window's compute.
+        host→device transfer overlaps the previous step's compute.
 
-        The first dispatched program carries trace+compile cost; the loop
+        The first dispatched step carries trace+compile cost; the loop
         syncs on it, reports the wall time as ``compile_s`` on the first
         logged record, and restarts the throughput window — so the first
         ``examples_per_sec`` measures post-compile steps only (a boundary
@@ -438,20 +367,8 @@ class Trainer:
         tracing = False
         window_start = time.perf_counter()
         window_examples = 0
-        last: Optional[tuple] = None
-        prev: Optional[tuple] = None
-        realized_thru = step - 1  # last step index already logged
         last_realized: Optional[Dict[str, float]] = None
         gb = self.cfg.train.global_batch
-        K = self.cfg.train.step_window
-        # Cadences a fused window must not straddle. hook_every only
-        # binds when there are hooks to land; log_every=0 still logs
-        # every step (the boundary test uses max(log_every, 1)).
-        cadences = [max(log_every, 1)]
-        if eval_iter_fn is not None and eval_every > 0:
-            cadences.append(eval_every)
-        if hooks and hook_every > 0:
-            cadences.append(hook_every)
         compile_s: Optional[float] = None
         first_sync_done = False
 
@@ -477,29 +394,18 @@ class Trainer:
                 if step == trace_start:
                     trace_stack.enter_context(profiler_trace(trace_dir))
                     tracing = True
-                k = 1 if K == 1 else _plan_window(
-                    step, num_steps, K, cadences,
-                    (trace_start, trace_stop))
                 # What the loop waited for its input, prefetcher and all.
-                with span("train.next_batch", step=step, k=k):
-                    batches = tuple(next_batch() for _ in range(k))
+                with span("train.next_batch", step=step):
+                    batch = next_batch()
                 # The span brackets DISPATCH of the compiled step alone
                 # (async — not device time; honest step time is the
                 # boundary-derived step_time_s key below).
                 # DLCFN_OBS_OFF=1 makes this a shared no-op.
-                with span("train.dispatch", step=step, k=k):
-                    if k == 1:
-                        # Per-step program — also the remainder path when
-                        # a window clamps to one step.
-                        state, metrics = self.train_step(
-                            state, batches[0], rng)
-                    else:
-                        state, metrics = self.window_step(
-                            state, batches, rng)
-                del batches  # the device keeps what the step still reads
-                prev, last = last, (step + k - 1, metrics)
-                window_examples += gb * k
-                step += k
+                with span("train.dispatch", step=step):
+                    state, metrics = self.train_step(state, batch, rng)
+                del batch  # the device keeps what the step still reads
+                window_examples += gb
+                step += 1
                 if tracing and step >= trace_stop:
                     jax.block_until_ready(metrics)
                     trace_stack.close()
@@ -518,79 +424,55 @@ class Trainer:
                         watchdog.beat()
 
                 if step % max(log_every, 1) == 0 or step >= num_steps:
-                    # Sync point. The per-step path realizes the latest
-                    # step. Windowed runs realize the PREVIOUS window —
-                    # it has certainly finished on device (its successor
-                    # was dispatched after it), so the host never stalls
-                    # on in-flight compute; records lag one boundary, and
-                    # the final boundary flushes both pending windows.
-                    at_end = step >= num_steps
-                    to_realize = []
-                    if K == 1:
-                        to_realize.append(last)
-                    else:
-                        if prev is not None and prev[0] > realized_thru:
-                            to_realize.append(prev)
-                        if at_end and last[0] > realized_thru:
-                            to_realize.append(last)
-                    first_write = True
-                    for w_end, w_metrics in to_realize:
-                        with span("train.realize", step=w_end + 1):
-                            realized = {
-                                k_: float(np.asarray(v).reshape(-1)[-1])
-                                for k_, v in
-                                jax.device_get(w_metrics).items()
-                            }
-                            # What the expert layers counted in this step,
-                            # in the registry too: the gauge holds this
-                            # step, the histogram every realized step
-                            # (docs/OBSERVABILITY.md).
-                            registry = get_tracer().registry
-                            for k_, v in realized.items():
-                                if k_.startswith("moe_"):
-                                    name = "moe." + k_[4:]
-                                    registry.gauge(name).set(v)
-                                    registry.histogram(
-                                        name + ".steps").observe(v)
-                        if first_write:
-                            # Throughput covers everything dispatched
-                            # since the last written boundary; the final
-                            # flush's second record carries step metrics
-                            # only.
-                            elapsed = time.perf_counter() - window_start
-                            if window_examples > 0:
-                                realized["examples_per_sec"] = \
-                                    window_examples / max(elapsed, 1e-9)
-                                realized["examples_per_sec_per_device"] = (
-                                    realized["examples_per_sec"]
-                                    / self.mesh.devices.size
-                                )
-                                # Additive key (obs report feed): honest
-                                # synced per-step wall time over the same
-                                # post-compile window as examples_per_sec.
-                                realized["step_time_s"] = (
-                                    elapsed / max(window_examples // gb, 1)
-                                )
-                            window_start = time.perf_counter()
-                            window_examples = 0
-                            first_write = False
-                        realized["step"] = w_end + 1
-                        if compile_s is not None:
-                            realized["compile_s"] = compile_s
-                            compile_s = None
-                        if metrics_writer is not None:
-                            metrics_writer.write(realized)
-                        realized_thru = w_end
-                        last_realized = realized
-                    if to_realize and watchdog is not None:
+                    # Sync point: realize the step just dispatched.
+                    with span("train.realize", step=step):
+                        realized = {
+                            k: float(v) for k, v in
+                            jax.device_get(metrics).items()
+                        }
+                        # What the expert layers counted in this step, in
+                        # the registry too: the gauge holds this step, the
+                        # histogram every realized step
+                        # (docs/OBSERVABILITY.md).
+                        registry = get_tracer().registry
+                        for k, v in realized.items():
+                            if k.startswith("moe_"):
+                                name = "moe." + k[4:]
+                                registry.gauge(name).set(v)
+                                registry.histogram(
+                                    name + ".steps").observe(v)
+                    # Throughput covers everything dispatched since the
+                    # last boundary.
+                    elapsed = time.perf_counter() - window_start
+                    if window_examples > 0:
+                        realized["examples_per_sec"] = \
+                            window_examples / max(elapsed, 1e-9)
+                        realized["examples_per_sec_per_device"] = (
+                            realized["examples_per_sec"]
+                            / self.mesh.devices.size
+                        )
+                        # Additive key (obs report feed): honest synced
+                        # per-step wall time over the same post-compile
+                        # window as examples_per_sec.
+                        realized["step_time_s"] = (
+                            elapsed / max(window_examples // gb, 1)
+                        )
+                    window_start = time.perf_counter()
+                    window_examples = 0
+                    realized["step"] = step
+                    if compile_s is not None:
+                        realized["compile_s"] = compile_s
+                        compile_s = None
+                    if metrics_writer is not None:
+                        metrics_writer.write(realized)
+                    last_realized = realized
+                    if watchdog is not None:
                         # device_get above proved device-side progress.
                         watchdog.beat()
 
-                # Hooks run at every window boundary — every step when
-                # K = 1, and window planning lands them exactly on
-                # hook_every multiples otherwise (checkpoint cadence must
-                # not couple to log cadence); metrics arg is the last
-                # realized window, if any.
+                # Hooks run every step (checkpoint cadence must not couple
+                # to log cadence); the metrics argument is the last
+                # realized record, if any.
                 t_hooks = time.perf_counter()
                 with span("train.hooks", step=step):
                     for hook in hooks:

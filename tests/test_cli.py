@@ -69,6 +69,14 @@ def test_bench_collectives_verb(capsys, devices):
         assert r["busbw_gbps"] > 0
 
 
+def test_bench_without_a_mode_points_to_the_benchmark(capsys):
+    """Training is timed by one program; `bench` alone says which."""
+    assert main(["bench"]) == 2
+    captured = capsys.readouterr()
+    assert "benchmark/run.py --workload" in captured.err
+    assert captured.out == ""
+
+
 def test_stack_lifecycle(tmp_path, capsys):
     state_dir = str(tmp_path)
     assert main(["stack", "create", "--name", "clitest",

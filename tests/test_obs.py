@@ -1106,36 +1106,6 @@ def test_diff_direction_awareness():
     assert direction("accuracy") is None
 
 
-def test_diff_bench_records_gate():
-    from deeplearning_cfn_tpu.obs.diff import diff_bench_records
-
-    prior = {"metric": "examples_per_sec", "value": 100.0,
-             "mean_step_s": 0.1, "measured": True}
-    worse = {"metric": "examples_per_sec", "value": 50.0,
-             "mean_step_s": 0.2, "measured": True}
-    verdict = diff_bench_records(prior, worse)
-    assert not verdict["ok"]
-    assert set(verdict["regressions"]) == {"value", "mean_step_s"}
-    assert diff_bench_records(prior, prior)["ok"]
-    # Unmeasured (fallback) records never gate.
-    unmeasured = dict(worse, measured=False)
-    v = diff_bench_records(prior, unmeasured)
-    assert v["ok"] and "skipped" in v
-
-
-def test_load_bench_record(tmp_path):
-    from deeplearning_cfn_tpu.obs.diff import load_bench_record
-
-    assert load_bench_record("/nonexistent.json") is None
-    p = tmp_path / "r.json"
-    p.write_text(json.dumps({"metric": "examples_per_sec", "value": 9.0}))
-    assert load_bench_record(str(p))["value"] == 9.0
-    jl = tmp_path / "r.jsonl"
-    jl.write_text('{"other": 1}\n{"metric": "m", "value": 1.0}\n'
-                  '{"metric": "m", "value": 2.0}\n')
-    assert load_bench_record(str(jl))["value"] == 2.0  # last wins
-
-
 def test_cli_obs_diff_self_and_regression(tmp_path, capsys):
     from deeplearning_cfn_tpu.cli.main import main
 

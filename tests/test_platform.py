@@ -90,7 +90,7 @@ def test_require_accelerator_refuses_a_silent_cpu(monkeypatch, cache_config):
     ["eval", "--preset", "cifar10_resnet20", "workdir={}"],
     ["serve", "--preset", "transformer_nmt_wmt", "--requests", "none.jsonl",
      "workdir={}"],
-    ["bench", "--preset", "cifar10_resnet20", "--steps", "1"],
+    ["bench", "--collectives"],
     ["fleet", "route", "--preset", "transformer_nmt_wmt",
      "--requests", "none.jsonl", "workdir={}"],
 ])

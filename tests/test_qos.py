@@ -12,9 +12,7 @@ must behave — and serialize — byte-identically to the pre-QoS engine
 """
 
 import dataclasses
-import importlib.util
 import json
-import os
 
 import numpy as np
 import pytest
@@ -28,8 +26,6 @@ from deeplearning_cfn_tpu.serve.queue import (
     RequestState,
     default_qos_classes,
 )
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 class FakeClock:
@@ -538,28 +534,3 @@ def test_tail_status_line_shows_preemptions():
     fplain = FleetTailState(["replica-0"])
     fplain.update("replica-0", {"serve_submitted": 2})
     assert "preempt" not in fplain.status_line()
-
-
-# -- root bench wrapper: null-over-zero for qos fields -----------------------
-
-
-def test_finalize_green_nulls_qos_fields_when_unmeasured(monkeypatch):
-    spec = importlib.util.spec_from_file_location(
-        "root_bench_qos", os.path.join(REPO_ROOT, "bench.py"))
-    w = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(w)
-    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    rec = w._finalize_green(
-        {"measured": False, "value": 9.9, "device_kind": "TPU v5e",
-         "error": "x", "qos_p95_by_class": {"latency": 0.1},
-         "preemptions": 3, "preempted_tokens_replayed": 12,
-         "fair_share_violation_max": 0.2,
-         "qos_decode_p95_no_adversary": 0.05})
-    for key in ("qos_p95_by_class", "preemptions",
-                "preempted_tokens_replayed", "fair_share_violation_max",
-                "qos_decode_p95_no_adversary"):
-        assert rec[key] is None
-    rec2 = w._finalize_green(
-        {"measured": False, "value": 1.0, "device_kind": "TPU v5e",
-         "error": "x"})
-    assert "preemptions" not in rec2   # key set untouched when absent

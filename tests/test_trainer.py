@@ -393,88 +393,16 @@ def test_grad_accum_divisibility_validated(devices):
         Trainer(cfg, lambda *a: None, None, mesh=mesh)
 
 
-def test_plan_window_respects_cadences():
-    """Pure window planning: a fused window never straddles a cadence
-    multiple or an explicit boundary — they land exactly on window edges."""
-    from deeplearning_cfn_tpu.train.trainer import _plan_window
-
-    # Clamp to the next log (3) / hook (4) multiple, whichever is nearer.
-    assert _plan_window(0, 100, 8, [3, 4]) == 3
-    assert _plan_window(3, 100, 8, [3, 4]) == 1
-    assert _plan_window(4, 100, 8, [3, 4]) == 2
-    # Tail clamp: never run past num_steps.
-    assert _plan_window(98, 100, 8, [100]) == 2
-    # Explicit boundaries (trace start/stop) clamp too; past ones don't.
-    assert _plan_window(4, 100, 8, [100], boundaries=(6, 10)) == 2
-    assert _plan_window(8, 100, 8, [100], boundaries=(6, 10)) == 2
-    # Zero/negative cadences are ignored; the floor is one step.
-    assert _plan_window(0, 100, 8, [0, -1, 8]) == 8
-    assert _plan_window(99, 100, 8, [1]) == 1
-
-
-@pytest.mark.parametrize("window", [1, 4])
-def test_step_window_matches_per_step_loop(tmp_workdir, devices, window):
-    """The fused K-step scan (window_step) reproduces the per-step loop's
-    loss trajectory and final weights: the scan body is the SAME per-step
-    fn, and fold_in(rng, state.step) keyed off the in-carry step counter
-    gives every fused step its canonical RNG stream. Tolerance is float-
-    level (XLA's loop-body codegen can differ from the straight-line
-    program by ~1 ulp), which still catches any RNG- or order-level bug."""
-    cfg = _tiny_cfg(tmp_workdir)
-    mesh = build_mesh(cfg.mesh)
-    sched = build_schedule(cfg.schedule, 16, cfg.train.global_batch, 8)
-    tx = build_optimizer(cfg.optimizer, sched)
-
-    def init_fn(rng):
-        return {"params": {"w": jnp.zeros((8, 4), jnp.float32)}}
-
-    def loss_fn(params, stats, batch, rng, train):
-        logits = batch["x"] @ params["w"]
-        if train:
-            # RNG inside the loss: parity must hold for stochastic steps.
-            logits = logits + 0.01 * jax.random.normal(rng, logits.shape)
-        return jnp.mean((logits - batch["y"]) ** 2), {}
-
-    rs = np.random.RandomState(0)
-    batches = [{"x": rs.randn(32, 8).astype(np.float32),
-                "y": rs.randn(32, 4).astype(np.float32)} for _ in range(8)]
-    rng = jax.random.PRNGKey(7)
-
-    def weights(st):
-        return np.asarray(jax.tree_util.tree_leaves(st.params)[0])
-
-    state = create_train_state(jax.random.PRNGKey(0), init_fn, tx, mesh)
-    trainer = Trainer(cfg, loss_fn, tx, mesh=mesh)
-    ref_losses = []
-    for b in batches:
-        state, m = trainer.train_step(state, trainer.device_batch(b), rng)
-        ref_losses.append(float(m["loss"]))
-    ref_w = weights(state)
-
-    state = create_train_state(jax.random.PRNGKey(0), init_fn, tx, mesh)
-    trainer = Trainer(cfg, loss_fn, tx, mesh=mesh)
-    win_losses = []
-    for i in range(0, len(batches), window):
-        devb = tuple(trainer.device_batch(b)
-                     for b in batches[i:i + window])
-        state, m = trainer.window_step(state, devb, rng)
-        win_losses.extend(np.asarray(m["loss"]).reshape(-1).tolist())
-    assert int(state.step) == len(batches)
-    np.testing.assert_allclose(win_losses, ref_losses, rtol=1e-5,
-                               atol=1e-7)
-    np.testing.assert_allclose(weights(state), ref_w, rtol=1e-5,
-                               atol=1e-7)
-
-
-def test_step_window_preserves_cadences(tmp_workdir, devices):
-    """Windowed fit keeps every cadence contract: periodic checkpoints
-    COMMIT on their exact steps, eval fires on eval_every multiples, the
-    watchdog stays beaten (run survives), and the metrics log carries
-    compile_s once plus honest post-compile examples_per_sec."""
-    cfg = _tiny_cfg(tmp_workdir, steps=8)
+def test_fit_preserves_cadences(tmp_workdir, devices):
+    """``fit`` keeps every cadence contract: periodic checkpoints COMMIT on
+    their exact steps (log every 4, eval and checkpoint every 8), eval
+    fires on eval_every multiples, the watchdog stays beaten (run
+    survives), and the metrics log carries compile_s once plus honest
+    post-compile examples_per_sec."""
+    cfg = _tiny_cfg(tmp_workdir, steps=16)
     apply_overrides(cfg, [
-        "train.step_window=4", "train.log_every_steps=4",
-        "checkpoint.every_steps=4", "train.eval_every_steps=4",
+        "train.log_every_steps=4",
+        "checkpoint.every_steps=8", "train.eval_every_steps=8",
         "train.hang_timeout_s=600",
     ])
     final = run_experiment(cfg)
@@ -484,22 +412,18 @@ def test_step_window_preserves_cadences(tmp_workdir, devices):
         os.path.basename(os.path.dirname(p)) for p in
         glob.glob(os.path.join(tmp_workdir, "cifar10_resnet20", "ckpt",
                                "step_*", "COMMIT")))
-    assert "step_00000004" in ckpts and "step_00000008" in ckpts, ckpts
+    assert ckpts == ["step_00000008", "step_00000016"], ckpts
 
     records = read_metrics(
         os.path.join(tmp_workdir, "cifar10_resnet20", "metrics.jsonl"))
     eval_steps = [r["step"] for r in records
                   if any(k.startswith("eval_") for k in r)]
-    assert 4 in eval_steps and 8 in eval_steps, records
+    assert eval_steps == [8, 16], records
     train_recs = [r for r in records if "loss" in r]
-    # Async realization: windows are logged exactly once each (no
-    # duplicate steps), and the final boundary flushes the latest window.
-    steps_logged = [r["step"] for r in train_recs]
-    assert len(steps_logged) == len(set(steps_logged)), steps_logged
-    assert steps_logged[-1] == 8
+    assert [r["step"] for r in train_recs] == [4, 8, 12, 16]
     assert sum(1 for r in records if "compile_s" in r) == 1
-    eps = [r["examples_per_sec"] for r in train_recs
-           if "examples_per_sec" in r]
+    assert "compile_s" in train_recs[0]
+    eps = [r["examples_per_sec"] for r in train_recs]
     assert all(v > 0 for v in eps)
 
 
@@ -550,17 +474,13 @@ def _op_names(hlo_text, entry_only=False):
     return out
 
 
-@pytest.mark.parametrize("program", ["train_step", "window_step"])
-def test_every_operation_of_the_step_belongs_to_a_scope(devices, program):
+def test_every_operation_of_the_step_belongs_to_a_scope(devices):
     import re
 
     _, trainer, state = _tiny_gpt(devices)
-    b = [trainer.device_batch(x) for x in _lm_batches(2)]
-    rng = jax.random.PRNGKey(7)
-    lowered = trainer.train_step.lower(state, b[0], rng) \
-        if program == "train_step" \
-        else trainer.window_step.lower(state, tuple(b), rng)
-    text = lowered.compile().as_text()
+    batch = trainer.device_batch(_lm_batches(1)[0])
+    text = trainer.train_step.lower(
+        state, batch, jax.random.PRNGKey(7)).compile().as_text()
     names = _op_names(text)
     # What the parent left bare: the clip, the schedule, Adam's moments and
     # the EMA now say ``optimizer``; the cross-entropy's gather and its
@@ -576,8 +496,6 @@ def test_every_operation_of_the_step_belongs_to_a_scope(devices, program):
         bare = [n for n in marked if not re.search(scope, n)]
         assert not bare, f"{mark} outside {scope}: {bare[:5]}"
     assert any("/optimizer/ema/" in n for n in names)
-    if program == "window_step":
-        return  # the same body inside a loop: the marks above are the check
     # The sections are whole: of the entry computation's instructions that
     # carry a name at all, fewer than 5 % have neither a program nor a
     # module scope.
@@ -614,15 +532,13 @@ def test_scopes_change_no_numerics(devices, monkeypatch):
     assert scoped == bare
 
 
-@pytest.mark.parametrize("step_window,prefetch", [(1, 0), (1, 2), (2, 0)])
-def test_fit_spans_are_siblings(devices, step_window, prefetch):
+@pytest.mark.parametrize("prefetch", [0, 2])
+def test_fit_spans_are_siblings(devices, prefetch):
     """``train.next_batch``, ``train.dispatch`` and ``train.hooks`` are
-    siblings: the wait for input is no longer inside the dispatch span, and
-    a fused window draws its k batches under one span."""
+    siblings: the wait for input is no longer inside the dispatch span."""
     from deeplearning_cfn_tpu.obs import MemorySink, Tracer, configured
 
     _, trainer, state = _tiny_gpt(devices, [
-        f"train.step_window={step_window}",
         f"train.device_prefetch={prefetch}"])
     tracer, sink = Tracer(), MemorySink()
     tracer.add_sink(sink)
@@ -631,18 +547,112 @@ def test_fit_spans_are_siblings(devices, step_window, prefetch):
     try:
         trainer.fit(state, iter(_lm_batches(4)), num_steps=4,
                     rng=jax.random.PRNGKey(7), log_every=2,
-                    hooks=(lambda step, st, last: calls.append(step),),
-                    hook_every=step_window)
+                    hooks=(lambda step, st, last: calls.append(step),))
     finally:
         configured(None)
-    windows = 4 // step_window
-    assert len(calls) == windows
+    assert calls == [1, 2, 3, 4]
     names = [r["span"] for r in sink.records if r["span"] != "train.realize"]
     assert names == ["train.next_batch", "train.dispatch",
-                     "train.hooks"] * windows
+                     "train.hooks"] * 4
     assert all(r["parent_id"] is None for r in sink.records)
     assert all(r["ok"] for r in sink.records)
-    for r in sink.records:
-        if r["span"] in ("train.next_batch", "train.dispatch"):
-            assert r["k"] == step_window
-    assert len(sink.by_span("train.realize")) == 2
+    assert [r["step"] for r in sink.by_span("train.dispatch")] == [0, 1, 2, 3]
+    assert [r["step"] for r in sink.by_span("train.realize")] == [2, 4]
+
+
+# -- the step loop's record contract (PR 28) ----------------------------------
+
+class _Recorder:
+    def __init__(self):
+        self.records = []
+
+    def write(self, record):
+        self.records.append(dict(record))
+
+
+class _ClosableBatches:
+    def __init__(self, batches):
+        self._it = iter(batches)
+        self.closed = False
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        return next(self._it)
+
+    def close(self):
+        self.closed = True
+
+
+_THROUGHPUT_KEYS = {"examples_per_sec", "examples_per_sec_per_device",
+                    "step_time_s"}
+
+
+@pytest.mark.parametrize("prefetch", [0, 2])
+@pytest.mark.parametrize("num_steps,log_every",
+                         [(6, 1), (6, 4), (7, 3), (5, 0)])
+def test_fit_record_contract(devices, num_steps, log_every, prefetch):
+    """What ``fit`` writes and when: a record at every multiple of
+    ``max(log_every, 1)`` and at the last step and nowhere else,
+    ``compile_s`` on the first record only, no throughput key on a boundary
+    that no step after the compile precedes, every hook once a step with the
+    last realized record, and the iterator closed at the end."""
+    _, trainer, state = _tiny_gpt(
+        devices, [f"train.device_prefetch={prefetch}"])
+    writer, calls = _Recorder(), []
+    feed = _ClosableBatches(_lm_batches(num_steps))
+    out = trainer.fit(
+        state, feed, num_steps=num_steps, rng=jax.random.PRNGKey(7),
+        log_every=log_every, metrics_writer=writer,
+        hooks=(lambda step, st, last: calls.append(
+            (step, int(st.step), None if last is None else last["step"])),))
+    assert int(out.step) == num_steps
+    assert feed.closed
+
+    every = max(log_every, 1)
+    boundaries = [s for s in range(1, num_steps + 1)
+                  if s % every == 0 or s == num_steps]
+    assert [r["step"] for r in writer.records] == boundaries
+    assert ["compile_s" in r for r in writer.records] == \
+        [True] + [False] * (len(boundaries) - 1)
+    assert writer.records[0]["compile_s"] > 0
+    for r in writer.records:
+        assert np.isfinite(r["loss"]) and np.isfinite(r["grad_norm"])
+        # The throughput window restarts after the first step's sync, so
+        # only a boundary at step 1 has nothing to report.
+        if r["step"] == 1:
+            assert not _THROUGHPUT_KEYS & set(r)
+        else:
+            assert _THROUGHPUT_KEYS <= set(r)
+            assert r["examples_per_sec"] > 0 and r["step_time_s"] > 0
+
+    assert [c[0] for c in calls] == list(range(1, num_steps + 1))
+    assert all(step == at for step, at, _ in calls)
+    realized = [max([b for b in boundaries if b <= s], default=None)
+                for s in range(1, num_steps + 1)]
+    assert [c[2] for c in calls] == realized
+
+
+def test_fit_in_two_calls_equals_one(devices):
+    """The step's key folds in ``state.step``: 3 + 3 steps, the second call
+    from the state the first returned, end bit-equal in losses and
+    parameters to one call of 6, dropout on."""
+    def run(splits):
+        cfg, trainer, state = _tiny_gpt(devices)
+        assert cfg.model.kwargs["dropout_rate"] > 0
+        writer, batches = _Recorder(), _lm_batches(6)
+        for lo, hi in splits:
+            state = trainer.fit(state, iter(batches[lo:hi]), num_steps=hi,
+                                rng=jax.random.PRNGKey(7), log_every=1,
+                                metrics_writer=writer)
+        losses = [float(r["loss"]).hex() for r in writer.records]
+        leaves = [np.asarray(x) for x in
+                  jax.tree_util.tree_leaves(state.params)]
+        return losses, leaves
+
+    one_losses, one_leaves = run([(0, 6)])
+    two_losses, two_leaves = run([(0, 3), (3, 6)])
+    assert len(one_losses) == 6 and one_losses == two_losses
+    for a, b in zip(one_leaves, two_leaves):
+        np.testing.assert_array_equal(a, b)
