@@ -28,6 +28,7 @@ broadcastable to [B, H, Sq, Sk] (use -inf for padding); returns [B, H, Sq, D].
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 from typing import Optional, Tuple
@@ -56,10 +57,23 @@ _NEG_INF = -1e30  # large-negative instead of -inf: keeps masked softmax NaN-fre
 #   256, 0.74 at 128, 1.17 at 64. And every piece is traced and lowered again
 #   for every layer: 128-square in all three kernels added 4 s to a training
 #   step's first call, 256-square in the backward pair alone under 1 s.
+# A windowed call (2026-09-28, bf16 [2,64,4096,128] over 8 K/V heads, window
+# 512, on a grid of the band, ms a call forward / dK/dV / dQ; PERF.md, PR 29):
+# - 1024-square blocks of 256-square sub-tiles 4.18 / 3.33 / 2.23; of
+#   128-square ones 3.06 / 3.07 / 2.44: the forward follows the masked area
+#   (150 sub-tiles of 1024 computed for 45 of 256), the backward pair does not
+#   (5.51 together against 5.56) and keeps the fewer pieces.
+# - Smaller blocks lose: 512-square 5.38 / 3.24 / 2.71 at 256, 3.71 / 3.51 /
+#   2.86 at 128 (twice the grid steps for the same sub-tiles), 1024 x 512
+#   5.03 / 4.79 / 3.11 (three K/V blocks a q block, five of dK/dV's 16 steps
+#   dead).
+# - 2048-square blocks of 128-square sub-tiles win the forward by a further
+#   0.33 ms (2.72) and no more fit VMEM at d = 256; not taken.
 _BLOCK_Q = 1024
 _BLOCK_K = 1024
 _SUB_Q = 256
 _SUB_K = 256
+_WINDOW_FWD_SUB = 128  # both ways, in a windowed call's forward kernel
 # Row statistics (logsumexp, delta) are stored lane-replicated with a
 # trailing dim of 8: Mosaic requires a block's last two dims to be
 # (divisible by 8, divisible by 128) or equal to the array's — a bare
@@ -87,8 +101,10 @@ def _tile_plan(sq: int, sk: int, d: int, causal: bool,
     a layer costs a training step's set-up more than it saves (the sweep
     above). A call with a ``window`` has sub-tiles in all three kernels: a
     band of rows needs ``window + sub`` columns whatever the length, so the
-    forward leaves out more than the pieces cost it."""
+    forward leaves out more than the pieces cost it, and most at 128-square,
+    where the band has few pieces to trace."""
     del d  # one rule held for d = 64 and d = 128
+    fwd_sub = _WINDOW_FWD_SUB if window and not backward else None
 
     def one(s, block, sub):
         if not (causal and (backward or window)) or s <= sub:
@@ -96,8 +112,8 @@ def _tile_plan(sq: int, sk: int, d: int, causal: bool,
             return b, b
         return min(block, -(-s // sub) * sub), sub
 
-    block_q, sub_q = one(sq, _BLOCK_Q, _SUB_Q)
-    block_k, sub_k = one(sk, _BLOCK_K, _SUB_K)
+    block_q, sub_q = one(sq, _BLOCK_Q, fwd_sub or _SUB_Q)
+    block_k, sub_k = one(sk, _BLOCK_K, fwd_sub or _SUB_K)
     return block_q, block_k, sub_q, sub_k
 
 
@@ -204,6 +220,15 @@ def _schedule(sq: int, sk: int, plan, causal: bool, by_columns: bool = False,
             for rel in crossed] + [(block_k - 1, None, whole(False))]
 
 
+def _bands_at(cases, rel: int):
+    """The bands of the case that takes a tile at ``rel`` (``_run_schedule``
+    decides the same inside a kernel), or none."""
+    for lo, hi, bands in cases:
+        if (lo is None or lo <= rel) and (hi is None or rel <= hi):
+            return bands
+    return []
+
+
 def _subtile_counts(sq: int, sk: int, plan, cases) -> Tuple[int, int, int]:
     """``(all, computed, masked)`` sub-tiles of one head of a call, counted
     from the ``cases`` (``_schedule``) its kernel runs."""
@@ -211,14 +236,168 @@ def _subtile_counts(sq: int, sk: int, plan, cases) -> Tuple[int, int, int]:
     tiles = _grid_rels(sq, sk, block_q, block_k)
     live = masked = 0
     for rel in tiles:
-        for lo, hi, bands in cases:
-            if (lo is None or lo <= rel) and (hi is None or rel <= hi):
-                for (o0, o1), pieces in bands:
-                    for i0, i1, under_mask in pieces:
-                        area = (o1 - o0) * (i1 - i0) // (sub_q * sub_k)
-                        live += area
-                        masked += area * bool(under_mask)
+        for (o0, o1), pieces in _bands_at(cases, rel):
+            for i0, i1, under_mask in pieces:
+                area = (o1 - o0) * (i1 - i0) // (sub_q * sub_k)
+                live += area
+                masked += area * bool(under_mask)
     return len(tiles) * (block_q // sub_q) * (block_k // sub_k), live, masked
+
+
+def _clip(x, lo, hi):
+    """``max(min(x, hi), lo)`` of Python ints or of traced scalars: ``lo``
+    where ``hi`` lies under it."""
+    if all(isinstance(t, int) for t in (x, lo, hi)):
+        return max(min(x, hi), lo)
+    return jnp.maximum(jnp.minimum(x, hi), lo)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Walk:
+    """The innermost grid axis of one flash kernel: which inner block step
+    ``j`` of outer block ``o`` stands for, and which block its index maps
+    name. Outer blocks are q blocks and inner ones K/V blocks (forward, dQ)
+    or, ``by_columns``, the other way round (dK/dV). All of it follows from
+    the static lengths, as ``_schedule`` does, and works on Python ints (the
+    gauges, the tests) as on ``program_id``s.
+
+    ``span(o)`` is the first and last inner block with anything to compute:
+    down to the window's far edge and up to the diagonal for a block of
+    rows, from the diagonal to the window's far edge for a block of columns
+    (``last < first`` where no row sees them). A windowed call's axis has
+    as many ``steps`` as its widest span and starts at the span's first
+    block; a call without a window keeps the whole sequence (a triangle has
+    no fixed width). A step stands for one ``block`` (``rel`` is computed
+    from it) and its index maps name that block clamped into the span
+    (``named``), so a step outside the span names what its neighbour holds
+    and nothing is copied for it. Where every step computes (a call that is
+    not causal, a grid of one tile) nothing ``clamps``: step ``j`` is block
+    ``j`` and names it, the index maps and kernel text of ever."""
+    outer: int         # block sizes
+    inner: int
+    outer_blocks: int  # and how many the padded lengths hold
+    inner_blocks: int
+    shift: int         # sk - sq: a row's position is its index plus this
+    window: int
+    by_columns: bool
+    clamps: bool
+
+    def span(self, o):
+        # Inner positions that block ``o``'s own reach: a row at position p
+        # sees columns (p - window, p], a column c is seen by the rows at
+        # positions [c, c + window).
+        lo = hi = None
+        if self.by_columns:
+            lo = o * self.outer - self.shift
+            if self.window:
+                hi = lo + self.outer - 1 + self.window - 1
+        else:
+            hi = o * self.outer + self.outer - 1 + self.shift
+            if self.window:
+                lo = hi - (self.outer - 1) - (self.window - 1)
+        last = self.inner_blocks - 1
+        return (0 if lo is None else _clip(lo // self.inner, 0, last),
+                last if hi is None else _clip(hi // self.inner, -1, last))
+
+    @functools.cached_property
+    def steps(self) -> int:
+        if not (self.clamps and self.window):
+            return self.inner_blocks
+        spans = [self.span(o) for o in range(self.outer_blocks)]
+        return max(1, max(last - first + 1 for first, last in spans))
+
+    def block(self, o, j):
+        """``(block, live)``: the inner block step ``j`` of outer block
+        ``o`` stands for, and whether it lies in the span. A step past its
+        span is not live whatever its ``rel`` (which can equal a live
+        tile's); without a window ``rel`` alone says so, as ever."""
+        if not (self.clamps and self.window):
+            return j, True
+        first, last = self.span(o)
+        return first + j, first + j <= last
+
+    def named(self, o, j):
+        """The inner block that step's index maps name."""
+        if not self.clamps:
+            return j
+        first, last = self.span(o)
+        return _clip(self.block(o, j)[0], first, last)
+
+    def rel(self, o, block):
+        """A tile's first row's position less its first column's."""
+        return (block * self.inner - o * self.outer if self.by_columns
+                else o * self.outer - block * self.inner) + self.shift
+
+
+def _walk(sq: int, sk: int, plan, causal: bool, window: int,
+          by_columns: bool) -> _Walk:
+    """The ``_Walk`` of one kernel of a call."""
+    block_q, block_k = plan[:2]
+    n_q, n_k = -(-sq // block_q), -(-sk // block_k)
+    clamps = causal and (n_q, n_k) != (1, 1)
+    if by_columns:
+        return _Walk(block_k, block_q, n_k, n_q, sk - sq, window, True,
+                     clamps)
+    return _Walk(block_q, block_k, n_q, n_k, sk - sq, window, False, clamps)
+
+
+_GRID_GAUGES = ("grid_steps", "dead_grid_steps", "dead_step_copies")
+
+
+def _labels(kernel: str, window: int) -> dict:
+    """A flash gauge's labels: a windowed call's say so beside the kernel."""
+    return dict(kernel=kernel, mask="window") if window \
+        else dict(kernel=kernel)
+
+
+def _grid_steps(walk: _Walk, cases, group: int = 1):
+    """A kernel's grid steps for one head (one K/V head and its ``group``
+    for dK/dV), in the order the grid runs them, through the index maps the
+    ``pallas_call`` is given: ``[(outer, block, named, bands), ...]`` with
+    the block the step stands for, the block index its innermost operands'
+    map names, and the bands it computes (none: a dead step)."""
+    named = _q_by_inner(walk, group) if walk.by_columns \
+        else _kv_by_inner(walk, group)
+    steps = []
+    for o in range(walk.outer_blocks):
+        for step in range(walk.steps * (group if walk.by_columns else 1)):
+            block, live = walk.block(o, step % walk.steps)
+            steps.append((o, block, named(0, 0, o, step), _bands_at(
+                cases, walk.rel(o, block)) if live else []))
+    return steps
+
+
+def _record_grid(kernel: str, walk: _Walk, cases, group: int) -> None:
+    """Three more gauges beside ``_record_subtiles``', each a count for one
+    query head (the dK/dV kernel's innermost axis covers a group, so its
+    counts are divided by ``group``): the grid's steps, the dead ones (no
+    case runs), and the dead ones that start a copy no live step reads (a
+    block other than the resident one, gone before a live step names it)."""
+    steps = _grid_steps(walk, cases, group)
+    dead = copies = 0
+    read = False  # is the block a step names read before another is named?
+    for at in reversed(range(len(steps))):
+        _, _, named, bands = steps[at]
+        held_on = at + 1 < len(steps) and steps[at + 1][2] == named
+        read = bool(bands) or (held_on and read)
+        dead += not bands
+        copies += not bands and not read and not (
+            at and steps[at - 1][2] == named)
+    heads = group if walk.by_columns else 1
+    registry = get_tracer().registry
+    for name, count, what in zip(_GRID_GAUGES, (len(steps), dead, copies), (
+            "grid steps of a flash kernel", "those in which no case runs",
+            "dead steps that copy a block no live step reads")):
+        registry.gauge(f"attention.flash.{name}", f"{what}, a query head"
+                       ).set(count / heads, **_labels(kernel, walk.window))
+
+
+def _grid_gauges(kernel: str, window: int = 0) -> Tuple[float, ...]:
+    """What ``_record_grid`` last set for a kernel: ``(grid_steps,
+    dead_grid_steps, dead_step_copies)`` a query head."""
+    registry = get_tracer().registry
+    return tuple(registry.gauge(f"attention.flash.{name}").value(
+        **_labels(kernel, window)) for name in _GRID_GAUGES)
 
 
 def _record_subtiles(kernel: str, counts: Tuple[int, int, int],
@@ -227,8 +406,7 @@ def _record_subtiles(kernel: str, counts: Tuple[int, int, int],
     kernel is traced (docs/OBSERVABILITY.md); a windowed call's are labelled
     ``mask="window"`` beside the kernel."""
     total, live, masked = counts
-    labels = dict(kernel=kernel, mask="window") if window \
-        else dict(kernel=kernel)
+    labels = _labels(kernel, window)
     registry = get_tracer().registry
     registry.gauge(
         "attention.flash.live_subtile_share",
@@ -248,10 +426,10 @@ def _when(cond):
     return pl.when(cond)
 
 
-def _run_schedule(cases, rel, band) -> None:
+def _run_schedule(cases, rel, band, live=True) -> None:
     """Inside a kernel: ``band(o0, o1, pieces)`` for every band of the case
     that takes this grid step's ``rel`` (a Python int where the grid is one
-    tile)."""
+    tile), if the step is ``live`` (``_Walk.block``)."""
     def run(bands):
         for (o0, o1), pieces in bands:
             band(o0, o1, pieces)
@@ -263,6 +441,8 @@ def _run_schedule(cases, rel, band) -> None:
             cond = rel >= lo
         else:  # a staircase: lo == hi
             cond = rel == lo
+        if live is not True:
+            cond = jnp.logical_and(live, cond)
         _when(cond)(functools.partial(run, bands))
 
 
@@ -328,8 +508,8 @@ def attention_reference(
 
 def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                   m_scr, l_scr, acc_scr, *,
-                  causal: bool, sm_scale: float, seq_k: int, seq_q: int,
-                  cases, one_tile: bool, window: int = 0):
+                  causal: bool, sm_scale: float, cases, one_tile: bool,
+                  walk: _Walk, window: int = 0):
     """One (batch, head, q-block, kv-block) grid step of the online softmax.
 
     The kv-block axis is the innermost ("arbitrary") grid dimension: the
@@ -344,24 +524,25 @@ def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
     Of its block the step computes the pieces that ``cases`` (``_schedule``)
     gives for the block's place against the diagonal: a band of rows takes
     one softmax step over its pieces together, then the next band. A whole
-    kv block above the diagonal matches no case: the DMA still happens, the
-    FLOPs not. ``one_tile`` says the grid has one q and one kv block, so
+    kv block above the diagonal matches no case, and the index maps name the
+    diagonal's block for it again, so neither FLOPs nor a DMA are spent on
+    it; a windowed call's kv axis walks the q block's band alone (``walk``,
+    a ``_Walk``). ``one_tile`` says the grid has one q and one kv block, so
     that place is known while tracing.
 
-    ``seq_q``/``seq_k`` are the TRUE (unpadded) lengths — the causal
+    The walk's ``rel`` is by the TRUE (unpadded) lengths — the causal
     diagonal aligns their ends; the refs hold block-padded arrays. The
     [S,S] score matrix never exists in HBM.
     """
     from jax.experimental import pallas as pl  # deferred: TPU-only path
 
-    block_q = q_ref.shape[-2]
-    block_k = k_ref.shape[-2]
     iq = 0 if one_tile else pl.program_id(2)
-    kb = 0 if one_tile else pl.program_id(3)
-    last_kb = 0 if one_tile else pl.num_programs(3) - 1
-    rel = iq * block_q - kb * block_k + (seq_k - seq_q)
+    step = 0 if one_tile else pl.program_id(3)
+    last_step = 0 if one_tile else pl.num_programs(3) - 1
+    kb, live = walk.block(iq, step)
+    rel = walk.rel(iq, kb)
 
-    @_when(kb == 0)
+    @_when(step == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -410,9 +591,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         l_scr[r0:r1, :] = jnp.broadcast_to(l_new, (r1 - r0, _STAT_LANES))
         acc_scr[r0:r1, :] = acc_new
 
-    _run_schedule(cases, rel, _band)
+    _run_schedule(cases, rel, _band, live)
 
-    @_when(kb == last_kb)
+    @_when(step == last_step)
     def _finalize():
         m = m_scr[:, :1]
         l = l_scr[:, :1]
@@ -432,6 +613,26 @@ def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                             -_NEG_INF)  # [block_q, 1]
             lse_ref[0, 0, :, :] = jnp.broadcast_to(
                 lse, lse_ref.shape[2:]).astype(jnp.float32)
+
+
+def _kv_by_inner(walk: _Walk, group: int):
+    """Index map of a K/V block where the kv axis is innermost (forward,
+    dQ). A group of query heads reads its one K/V head through it: nothing
+    is repeated in HBM."""
+    if group == 1:
+        return lambda ib, ih, iq, step: (ib, ih, walk.named(iq, step), 0)
+    return lambda ib, ih, iq, step: (
+        ib, ih // group, walk.named(iq, step), 0)
+
+
+def _q_by_inner(walk: _Walk, group: int):
+    """Index map of a q-side block where the q axis is innermost (dK/dV):
+    the walk's steps for each query head of the group in turn."""
+    if group == 1:
+        return lambda ib, ih, ik, step: (ib, ih, walk.named(ik, step), 0)
+    n_q = walk.steps
+    return lambda ib, ih, ik, step: (
+        ib, ih * group + step // n_q, walk.named(ik, step % n_q), 0)
 
 
 def _pad_to(x: jnp.ndarray, axis: int, multiple: int) -> jnp.ndarray:
@@ -488,12 +689,9 @@ def _flash_forward(q, k, v, bias, causal, sm_scale, interpret=False,
             jnp.arange(sk_p) < sk, 0.0, _NEG_INF)[None, None, None, :]
         bias = pad_bias if bias is None else bias + pad_bias
 
-    # A group of query heads reads its one K/V head through the index map:
-    # nothing is repeated in HBM.
-    kv_spec = pl.BlockSpec(
-        (1, 1, block_k, d),
-        (lambda ib, ih, iq, ik: (ib, ih, ik, 0)) if group == 1
-        else (lambda ib, ih, iq, ik: (ib, ih // group, ik, 0)))
+    walk = _walk(sq, sk, plan, causal, window, by_columns=False)
+    _record_grid("flash_fwd", walk, cases, group)
+    kv_spec = pl.BlockSpec((1, 1, block_k, d), _kv_by_inner(walk, group))
     in_specs = [
         pl.BlockSpec((1, 1, block_q, d),
                      lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
@@ -503,8 +701,8 @@ def _flash_forward(q, k, v, bias, causal, sm_scale, interpret=False,
     # The causal diagonal is defined by the TRUE lengths (ends aligned, as
     # in attention_reference); padded q rows are sliced off at the end and
     # padded k columns sit above the diagonal, so neither corrupts it.
-    kernel_kw = dict(causal=causal, sm_scale=sm_scale, seq_k=sk, seq_q=sq,
-                     cases=cases, window=window,
+    kernel_kw = dict(causal=causal, sm_scale=sm_scale, cases=cases,
+                     window=window, walk=walk,
                      one_tile=(sq_p, sk_p) == (block_q, block_k))
     if bias is not None:
         # Keep broadcast dims at size 1 (indexed with block 0) instead of
@@ -517,7 +715,8 @@ def _flash_forward(q, k, v, bias, causal, sm_scale, interpret=False,
             (1, 1, block_bq, block_k),
             lambda ib, ih, iq, ik: (ib if bb > 1 else 0,
                                     ih if bh > 1 else 0,
-                                    iq if bq > 1 else 0, ik)))
+                                    iq if bq > 1 else 0,
+                                    walk.named(iq, ik))))
         args.append(bias)
 
         def kernel(q_ref, k_ref, v_ref, b_ref, o_ref, *rest):
@@ -544,7 +743,7 @@ def _flash_forward(q, k, v, bias, causal, sm_scale, interpret=False,
 
     result = pl.pallas_call(
         kernel,
-        grid=(b, h, sq_p // block_q, sk_p // block_k),
+        grid=(b, h, sq_p // block_q, walk.steps),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
@@ -643,18 +842,19 @@ def _bwd_piece(rows, k_blk, v_blk, *, sm_scale, mask):
 
 def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                            dk_ref, dv_ref, dk_scr, dv_scr, *, causal,
-                           sm_scale, seq_q, seq_k, cases, one_tile,
-                           window=0, group=1, n_q=1):
+                           sm_scale, seq_k, cases, one_tile, walk,
+                           window=0, group=1):
     """One (batch, head, kv-block, q-block) grid step: accumulate this q
     block's contribution to dK/dV of one kv block in VMEM scratch; write on
     the last q step. Same block-mapped structure as the forward kernel, and
     the same schedule inside the step, here band of columns by band of
     columns with pieces of rows. With ``group`` query heads to one K/V head
-    the innermost axis walks the ``n_q`` q blocks of each of them in turn,
-    so dK/dV are summed over the group where they are accumulated."""
+    the innermost axis takes the ``walk``'s steps (a ``_Walk``: the q
+    blocks, in a windowed call those of this kv block's band) for each of
+    them in turn, so dK/dV are summed over the group where they are
+    accumulated."""
     from jax.experimental import pallas as pl
 
-    block_q = q_ref.shape[-2]
     block_k = k_ref.shape[-2]
     ik = 0 if one_tile else pl.program_id(2)
     step = 0 if one_tile and group == 1 else pl.program_id(3)
@@ -662,8 +862,9 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     if group == 1:
         qi = step
     else:
-        qi = 0 if n_q == 1 else jax.lax.rem(step, n_q)
-    rel = qi * block_q - ik * block_k + (seq_k - seq_q)
+        qi = 0 if walk.steps == 1 else jax.lax.rem(step, walk.steps)
+    qi, live = walk.block(ik, qi)
+    rel = walk.rel(ik, qi)
     cols = seq_k - ik * block_k
 
     @_when(step == 0)
@@ -692,7 +893,7 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[c0:c1, :] = dk
         dv_scr[c0:c1, :] = dv
 
-    _run_schedule(cases, rel, _band)
+    _run_schedule(cases, rel, _band, live)
 
     @_when(step == last_step)
     def _finalize():
@@ -701,22 +902,22 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, dq_scr, *, causal, sm_scale, seq_q,
-                         seq_k, cases, one_tile, window=0):
+                         dq_ref, dq_scr, *, causal, sm_scale, seq_k, cases,
+                         one_tile, walk, window=0):
     """One (batch, head, q-block, kv-block) grid step: accumulate one kv
     block's contribution to dQ of one q block; write on the last kv step.
     Band of rows by band of rows, as the forward is."""
     from jax.experimental import pallas as pl
 
-    block_q = q_ref.shape[-2]
     block_k = k_ref.shape[-2]
     iq = 0 if one_tile else pl.program_id(2)
-    kb = 0 if one_tile else pl.program_id(3)
-    last_kb = 0 if one_tile else pl.num_programs(3) - 1
-    rel = iq * block_q - kb * block_k + (seq_k - seq_q)
+    step = 0 if one_tile else pl.program_id(3)
+    last_step = 0 if one_tile else pl.num_programs(3) - 1
+    kb, live = walk.block(iq, step)
+    rel = walk.rel(iq, kb)
     cols = seq_k - kb * block_k
 
-    @_when(kb == 0)
+    @_when(step == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
@@ -735,9 +936,9 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 preferred_element_type=jnp.float32)
         dq_scr[r0:r1, :] = dq
 
-    _run_schedule(cases, rel, _band)
+    _run_schedule(cases, rel, _band, live)
 
-    @_when(kb == last_kb)
+    @_when(step == last_step)
     def _finalize():
         dq_ref[0, 0, :, :] = dq_scr[...].astype(dq_ref.dtype)
 
@@ -781,10 +982,13 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, interpret,
     lse_p = jnp.broadcast_to(lse_p[..., None], (b, h, sq_p, _STAT_LANES))
     delta_p = jnp.broadcast_to(delta_p[..., None], (b, h, sq_p, _STAT_LANES))
 
-    common = dict(causal=causal, sm_scale=sm_scale, seq_q=sq, seq_k=sk,
-                  window=window,
+    common = dict(causal=causal, sm_scale=sm_scale, seq_k=sk, window=window,
                   one_tile=(sq_p, sk_p) == (block_q, block_k))
-    n_q = sq_p // block_q
+    walks = {name: _walk(sq, sk, plan, causal, window,
+                         by_columns=name == "flash_bwd_dkdv")
+             for name in cases}
+    for name, walk in walks.items():
+        _record_grid(name, walk, cases[name], group)
     semantics = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel",
                              "arbitrary")) if not interpret else None
@@ -792,19 +996,16 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, interpret,
     # dK/dV: grid over kv blocks, q blocks innermost (accumulated).
     # With grouped heads the grid runs over K/V heads, and the innermost
     # axis over the q blocks of every query head of the group.
-    if group == 1:
-        q_rows = lambda ib, ih, ik, iq: (ib, ih, iq, 0)
-    else:
-        q_rows = lambda ib, ih, ik, step: (
-            ib, ih * group + step // n_q, step % n_q, 0)
+    walk = walks["flash_bwd_dkdv"]
+    q_rows = _q_by_inner(walk, group)
     q_by_inner = pl.BlockSpec((1, 1, block_q, d), q_rows)
     row_by_inner = pl.BlockSpec((1, 1, block_q, _STAT_LANES), q_rows)
     kv_by_outer = pl.BlockSpec((1, 1, block_k, d),
                                lambda ib, ih, ik, iq: (ib, ih, ik, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkdv_kernel, **common, group=group,
-                          n_q=n_q, cases=cases["flash_bwd_dkdv"]),
-        grid=(b, hk, sk_p // block_k, group * n_q),
+                          cases=cases["flash_bwd_dkdv"], walk=walk),
+        grid=(b, hk, sk_p // block_k, group * walk.steps),
         in_specs=[q_by_inner, kv_by_outer, kv_by_outer, q_by_inner,
                   row_by_inner, row_by_inner],
         out_specs=[kv_by_outer, kv_by_outer],
@@ -822,14 +1023,12 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, interpret,
                               lambda ib, ih, iq, ik: (ib, ih, iq, 0))
     row_by_outer = pl.BlockSpec((1, 1, block_q, _STAT_LANES),
                                 lambda ib, ih, iq, ik: (ib, ih, iq, 0))
-    kv_by_inner = pl.BlockSpec(
-        (1, 1, block_k, d),
-        (lambda ib, ih, iq, ik: (ib, ih, ik, 0)) if group == 1
-        else (lambda ib, ih, iq, ik: (ib, ih // group, ik, 0)))
+    walk = walks["flash_bwd_dq"]
+    kv_by_inner = pl.BlockSpec((1, 1, block_k, d), _kv_by_inner(walk, group))
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, **common,
-                          cases=cases["flash_bwd_dq"]),
-        grid=(b, h, sq_p // block_q, sk_p // block_k),
+                          cases=cases["flash_bwd_dq"], walk=walk),
+        grid=(b, h, sq_p // block_q, walk.steps),
         in_specs=[q_by_outer, kv_by_inner, kv_by_inner, q_by_outer,
                   row_by_outer, row_by_outer],
         out_specs=q_by_outer,

@@ -73,7 +73,12 @@ def test_grouped_windowed_kernels_compile_for_v5e(v5e_chip, name, heads,
                                                   window, what):
     """The Laguna cell's two attention shapes: 8 K/V heads under 48 and 64
     query heads at 4096 positions and head size 128, the sliding one under
-    its window of 512 (sub-tiles in all three kernels)."""
+    its window of 512 (sub-tiles in all three kernels, on a grid of the
+    band: ``_tile_plan``'s plan for it, and index maps that clamp), the
+    full one on its whole grid with the dead steps' index maps clamped. No
+    dead step of either copies a block for nothing."""
+    from deeplearning_cfn_tpu.ops.attention import _grid_gauges
+
     one_chip = SingleDeviceSharding(v5e_chip)
     q = jax.ShapeDtypeStruct((2, heads, 4096, 128), jnp.bfloat16,
                              sharding=one_chip)
@@ -95,6 +100,10 @@ def test_grouped_windowed_kernels_compile_for_v5e(v5e_chip, name, heads,
     for line in text.splitlines():
         if "tpu_custom_call" in line and " custom-call(" in line:
             assert "bf16[2,8,4096,128]" in line, line[:300]
+    for kernel in ("flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq")[
+            :1 if what == "forward" else 3]:
+        assert _grid_gauges(kernel, window) == (
+            (8, 1, 0) if window else (16, 6, 0)), kernel
 
 
 @pytest.mark.parametrize("name,heads,rope", [

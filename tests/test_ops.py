@@ -433,13 +433,19 @@ def _grouped_qkv(h, hk, sq, sk, d=16, seed=21, dtype=jnp.float32):
     return mk(h, sq), mk(hk, sk), mk(hk, sk)
 
 
+def _seen(sq, sk, plan, window):
+    """Which column each row of the block-padded score matrix sees."""
+    block_q, block_k = plan[:2]
+    q_pos = np.arange(-(-sq // block_q) * block_q)[:, None] + (sk - sq)
+    k_pos = np.arange(-(-sk // block_k) * block_k)[None, :]
+    seen = k_pos <= q_pos
+    return seen & (q_pos - k_pos < window) if window else seen
+
+
 def _brute_force_window_counts(sq, sk, plan, window):
-    block_q, block_k, sub_q, sub_k = plan
-    sq_p = -(-sq // block_q) * block_q
-    sk_p = -(-sk // block_k) * block_k
-    q_pos = np.arange(sq_p)[:, None] + (sk - sq)
-    k_pos = np.arange(sk_p)[None, :]
-    seen = (k_pos <= q_pos) & (q_pos - k_pos < window)
+    sub_q, sub_k = plan[2:]
+    seen = _seen(sq, sk, plan, window)
+    sq_p, sk_p = seen.shape
     total = live = masked = 0
     for r0 in range(0, sq_p, sub_q):
         for c0 in range(0, sk_p, sub_k):
@@ -467,9 +473,136 @@ def test_window_schedule_counts(sq, sk, window, plan):
     assert _counts(sq, sk, plan, True, window=window) == want
     assert _counts(sq, sk, plan, True, by_columns=True, window=window) == want
     if plan == (1024, 1024, 256, 256):
-        for backward in (False, True):
-            assert _tile_plan(sq, sk, 128, True, backward, window) == plan
+        # the backward pair's plan; the forward's sub-tiles are 128-square
+        assert _tile_plan(sq, sk, 128, True, True, window) == plan
+        assert _tile_plan(sq, sk, 128, True, False, window) == (
+            1024, 1024, 128, 128)
     assert want[1] < want[0] or want[0] == 1
+
+
+_GRID_WALKS = [
+    # (sq, sk, window, plan), grid steps / dead / wasted copies a query head
+    # in each of the three kernels: test_window_schedule_counts' cases ...
+    ((4096, 4096, 512, (1024, 1024, 256, 256)), (8, 1, 0)),  # parent: 16/9/9
+    ((4096, 4096, 512, (1024, 1024, 128, 128)), (8, 1, 0)),  # its forward
+    ((1024, 2048, 512, (1024, 1024, 256, 256)), None),
+    ((256, 256, 40, (128, 128, 32, 32)), None),
+    ((100, 100, 24, (64, 64, 32, 16)), None),
+    ((128, 256, 300, (64, 128, 32, 64)), None),
+    ((32, 32, 8, (32, 32, 32, 32)), (1, 0, 0)),
+    # ... the plans of the PR 29 sweep at the Laguna cell's sliding layers ...
+    ((4096, 4096, 512, (512, 512, 256, 256)), (16, 1, 0)),
+    ((4096, 4096, 512, (1024, 512, 256, 128)), None),  # 12/1/0, dK/dV 16/5/0
+    ((4096, 4096, 512, (512, 512, 128, 128)), (16, 1, 0)),
+    # ... blocks that do not align, a window wider than the rows' block,
+    # sq < sk under a window that leaves the first K/V blocks to no row ...
+    ((96, 96, 21, (32, 16, 16, 16)), None),
+    ((96, 96, 40, (16, 32, 16, 16)), None),
+    ((48, 160, 24, (16, 32, 16, 16)), None),   # dK/dV: two blocks unseen
+    # ... and no window: the grid stays, its dead steps name no new block.
+    ((4096, 4096, 0, (1024, 1024, 256, 256)), (16, 6, 0)),   # parent: 16/6/6
+    ((8192, 8192, 0, (1024, 1024, 1024, 1024)), (64, 28, 0)),
+    ((1024, 2048, 0, (1024, 1024, 256, 256)), (2, 0, 0)),
+    ((300, 300, 0, (128, 128, 64, 64)), (9, 3, 0)),
+    ((128, 256, 0, (64, 128, 32, 64)), (4, 0, 0)),
+    ((1024, 1024, 0, (1024, 1024, 256, 256)), (1, 0, 0)),    # gpt2_small_train
+]
+
+
+@pytest.mark.parametrize("group", [1, 4])
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dkdv",
+                                    "flash_bwd_dq"])
+@pytest.mark.parametrize("call,counts", _GRID_WALKS)
+def test_flash_grid_walk(call, counts, kernel, group):
+    """Every kernel's grid, walked in Python through the index maps its
+    pallas_call is given (_grid_steps): (a) the live steps compute exactly
+    the sub-tiles a brute-force look at positions finds, each once, from the
+    block the step's operands were named; (b) a dead step names the block a
+    neighbouring step holds, so nothing is copied for it alone; (c) the three
+    gauges read what the walk counts."""
+    from collections import Counter
+
+    from deeplearning_cfn_tpu.ops.attention import (
+        _grid_gauges, _grid_steps, _record_grid, _schedule, _walk)
+
+    sq, sk, window, plan = call
+    block_q, block_k, sub_q, sub_k = plan
+    by_columns = kernel == "flash_bwd_dkdv"
+    cases = _schedule(sq, sk, plan, True, by_columns=by_columns,
+                      window=window)
+    walk = _walk(sq, sk, plan, True, window, by_columns)
+    steps = _grid_steps(walk, cases, group)
+
+    # (a) what is computed, in sub-tiles of the padded score matrix.
+    seen = _seen(sq, sk, plan, window)
+    want = Counter()
+    for r0 in range(0, seen.shape[0], sub_q):
+        for c0 in range(0, seen.shape[1], sub_k):
+            if seen[r0:r0 + sub_q, c0:c0 + sub_k].any():
+                want[r0, c0] = group if by_columns else 1
+    got = Counter()
+    heads = Counter()
+    for at, (outer, block, named, bands) in enumerate(steps):
+        iq, kb = (block, outer) if by_columns else (outer, block)
+        if bands:
+            # the operands in VMEM are the block the step computes on, of
+            # one of the group's heads (dK/dV) or of the one K/V head
+            assert named[::2] == (0, block) and named[3] == 0, (at, named)
+            heads[named[1]] += 1
+        for (o0, o1), pieces in bands:
+            for i0, i1, _ in pieces:
+                (r0, r1), (c0, c1) = ((i0, i1), (o0, o1)) if by_columns \
+                    else ((o0, o1), (i0, i1))
+                for r in range(iq * block_q + r0, iq * block_q + r1, sub_q):
+                    for c in range(kb * block_k + c0, kb * block_k + c1,
+                                   sub_k):
+                        got[r, c] += 1
+    assert got == want
+    assert sorted(heads) == list(range(group if by_columns else 1))
+    assert len(set(heads.values())) == 1
+
+    # (b) dead steps, and the copies they start for nothing.
+    dead = [at for at, step in enumerate(steps) if not step[3]]
+    for at in dead:
+        neighbours = [steps[n][2] for n in (at - 1, at + 1)
+                      if 0 <= n < len(steps)]
+        assert steps[at][2] in neighbours, (at, steps[at], neighbours)
+    # K/V blocks that lie before every row's window (sq < sk): their dK/dV
+    # are zeros, their steps all dead, and each head's q block is copied.
+    unseen = [o for o in range(walk.outer_blocks)
+              if walk.span(o)[0] > walk.span(o)[1]]
+    if window:
+        # a windowed call's other dead steps are those clamped at an edge
+        assert all(steps[at][1] != steps[at][2][2] for at in dead
+                   if steps[at][0] not in unseen)
+
+    # (c) the gauges, a query head.
+    _record_grid(kernel, walk, cases, group)
+    read = _grid_gauges(kernel, window)
+    per_head = group if by_columns else 1
+    assert read[:2] == (len(steps) / per_head, len(dead) / per_head)
+    # no dead step copies for nothing, but a head's at an unseen K/V block
+    assert read[2] <= len(unseen)
+    if counts is not None:
+        assert read == counts
+
+
+def test_flash_dead_step_not_taken_by_its_rel():
+    """The step a windowed dK/dV walk takes past the last q block stands at
+    a ``rel`` that a live tile has too (1024 at the Laguna cell's sliding
+    layers: K/V block 3 against a q block 4 that is not there, and K/V
+    block 0 against q block 1): it is dead by its place, not by its rel."""
+    from deeplearning_cfn_tpu.ops.attention import (
+        _bands_at, _schedule, _walk)
+
+    plan = (1024, 1024, 256, 256)
+    walk = _walk(4096, 4096, plan, True, 512, True)
+    cases = _schedule(4096, 4096, plan, True, by_columns=True, window=512)
+    assert walk.steps == 2 and walk.span(3) == (3, 3)
+    block, live = walk.block(3, 1)
+    assert (block, live, walk.named(3, 1)) == (4, False, 3)
+    assert _bands_at(cases, block * 1024 - 3 * 1024)  # rel 1024 has a case
+    assert walk.block(0, 1) == (1, True)              # and a live tile
 
 
 @pytest.mark.parametrize("sq,sk", [(64, 64), (48, 96)])
@@ -478,6 +611,11 @@ def test_window_schedule_counts(sq, sk, window, plan):
     (4, 2, 0, (32, 32, 16, 16)), (6, 2, 0, (32, 32, 16, 16)),
     (6, 2, 24, (32, 32, 16, 16)),
     (4, 4, 24, None), (4, 4, 24, (32, 32, 16, 16)),   # a window alone
+    # The band's grid (PR 29): a window wider than a block, one that is no
+    # multiple of the sub-tile, blocks that do not align, one block of rows.
+    (4, 2, 40, (32, 32, 16, 16)), (4, 2, 21, (32, 32, 16, 16)),
+    (6, 2, 21, (32, 16, 16, 16)), (4, 2, 40, (16, 32, 16, 16)),
+    (4, 4, 7, (16, 16, 16, 16)), (4, 2, 24, (64, 32, 16, 16)),
 ])
 def test_flash_grouped_window_matches_reference(h, hk, window, sq, sk, plan):
     """Grouped K/V heads x {causal, causal + window} x {sq = sk, sq < sk}:
@@ -557,7 +695,10 @@ def test_ungrouped_unwindowed_jaxpr_unchanged(shape, sk, causal, digest):
 
 def test_window_subtile_gauges():
     """A windowed call's gauges are labelled mask="window" and say what the
-    kernels leave out at the Laguna cell's sliding layers: 22 of 256."""
+    kernels leave out at the Laguna cell's sliding layers, of all sub-tiles
+    of the score matrix: the backward pair computes 45 of 256 (256-square
+    sub-tiles), the forward 150 of 1024 (128-square since PR 29: 37.5 of
+    those 256)."""
     from deeplearning_cfn_tpu.obs.trace import get_tracer
 
     q = jax.ShapeDtypeStruct((1, 8, 4096, 128), jnp.bfloat16)
@@ -567,7 +708,8 @@ def test_window_subtile_gauges():
             q, k, v, causal=True, window=512, implementation="interpret"
         ).astype(jnp.float32).sum(), argnums=(0, 1, 2)), q, kv, kv)
     live = get_tracer().registry.gauge("attention.flash.live_subtile_share")
-    for kernel in ("flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq"):
+    assert live.value(kernel="flash_fwd", mask="window") == 150 / 1024
+    for kernel in ("flash_bwd_dkdv", "flash_bwd_dq"):
         assert live.value(kernel=kernel, mask="window") == 45 / 256
 
 

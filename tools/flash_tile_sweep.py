@@ -8,8 +8,9 @@ kernels run ``--steps`` times under the profiler, and a kernel's time is the
 mean device duration of the events that carry its name (``flash_fwd``,
 ``flash_bwd_dkdv``, ``flash_bwd_dq``). One JSON line per plan; the last
 lines time the XLA path against the kernels at S = 512 by the host's clock.
-``ops/attention.py:_tile_plan`` is fixed from this table (PERF.md, PR 25).
-A chip run only: it stops where jax finds no TPU.
+``ops/attention.py:_tile_plan`` is fixed from this table (PERF.md, PR 25; its
+windowed branch from the two shapes of ``laguna_xs2_train_4k``, ``--sq
+4096``, PR 29). A chip run only: it stops where jax finds no TPU.
 """
 
 from __future__ import annotations
@@ -40,8 +41,10 @@ def _sq(block, subs):
     return [(block, block, s, s) for s in subs]
 
 
-# (b, h, sq, sk, d) -> plans; the first plan is the single sub-tile the
-# kernels had before PR 25, and every other output is compared with its.
+# (b, h, sq, sk, d), with K/V heads and a window where the call has them ->
+# plans; every other output is compared with the first plan's (the single
+# sub-tile the kernels had before PR 25; in the windowed call the plan of
+# before PR 29).
 SWEEP = [
     ((16, 12, 1024, 1024, 64), _sq(1024, (1024, 512, 256, 128)) + [
         (1024, 1024, 256, 512), (1024, 1024, 512, 256),
@@ -53,14 +56,34 @@ SWEEP = [
         (1024, 1024, 256, 512)]),
     ((2, 12, 1024, 2048, 64), _sq(1024, (1024, 512, 256, 128))),
     ((2, 12, 1000, 3000, 64), _sq(1024, (1024, 256, 128))),
+    # laguna_xs2_train_4k's sliding layers (64 query heads over 8 K/V heads,
+    # window 512) and its full ones (48 over 8).
+    ((2, 64, 4096, 4096, 128, 8, 512), _sq(1024, (256, 128)) + [
+        (1024, 1024, 256, 128), (1024, 512, 256, 256), (1024, 512, 256, 128),
+        (512, 512, 512, 512), (512, 512, 256, 256), (512, 512, 256, 128),
+        (512, 512, 128, 256), (512, 512, 128, 128), (2048, 1024, 256, 256),
+        (1024, 1024, 128, 256), (1024, 1024, 128, 512), (1024, 1024, 64, 128),
+        (1024, 1024, 128, 64), (2048, 1024, 128, 128),
+        (2048, 2048, 128, 128)]),
+    ((2, 48, 4096, 4096, 128, 8), _sq(1024, (1024, 256, 128))),
 ]
 
 
+def _call(shape):
+    """``(b, h, sq, sk, d, hk, window)`` of a SWEEP shape: as many K/V heads
+    as query heads and no window where it names none."""
+    b, h, sq, sk, d, *rest = shape
+    return (b, h, sq, sk, d, rest[0] if rest else h,
+            rest[1] if len(rest) > 1 else 0)
+
+
 def _inputs(shape, seed=0):
-    b, h, sq, sk, d = shape
+    b, h, sq, sk, d, hk, _ = _call(shape)
     keys = jax.random.split(jax.random.PRNGKey(seed), 4)
-    mk = lambda key, s: jax.random.normal(key, (b, h, s, d), jnp.bfloat16)
-    return mk(keys[0], sq), mk(keys[1], sk), mk(keys[2], sk), mk(keys[3], sq)
+    mk = lambda key, heads, s: jax.random.normal(key, (b, heads, s, d),
+                                                 jnp.bfloat16)
+    return (mk(keys[0], h, sq), mk(keys[1], hk, sk), mk(keys[2], hk, sk),
+            mk(keys[3], h, sq))
 
 
 def _kernel_ms(trace_dir, steps):
@@ -79,15 +102,16 @@ def _kernel_ms(trace_dir, steps):
 
 def measure(shape, plan, steps, baseline):
     q, k, v, g = _inputs(shape)
-    scale = shape[-1] ** -0.5
+    *_, sq, sk, d, _, window = _call(shape)
+    scale = d ** -0.5
 
     def fwd(q, k, v):
         return A._flash_forward(q, k, v, None, True, scale,
-                                return_stats=True, plan=plan)
+                                return_stats=True, plan=plan, window=window)
 
     def bwd(q, k, v, out, lse, g):
         return A._flash_backward(q, k, v, out, lse, g, True, scale, False,
-                                 plan=plan)
+                                 plan=plan, window=window)
 
     t0 = time.perf_counter()
     fwd_c = jax.jit(fwd).lower(q, k, v).compile()
@@ -97,9 +121,12 @@ def measure(shape, plan, steps, baseline):
     compile_s = time.perf_counter() - t0
     results = (out, *grads)
     total, live, masked = A._subtile_counts(
-        shape[2], shape[3], plan, A._schedule(shape[2], shape[3], plan, True))
+        sq, sk, plan, A._schedule(sq, sk, plan, True, window=window))
     line = {"shape": list(shape), "plan": list(plan),
             "live": live / total, "masked": masked / total,
+            # grid steps a query head, the dead ones, their wasted copies
+            "grid": {kernel: A._grid_gauges(kernel, window)
+                     for kernel in KERNELS},
             "compile_s": round(compile_s, 2)}
     # By the host's clock too (the whole jitted call: for the backward that
     # is both kernels and the delta beside them), should the trace fail.
