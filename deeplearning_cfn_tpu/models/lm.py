@@ -19,6 +19,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..obs.trace import get_tracer
 from . import register_model
 from .moe import MOE_PARAM_RULES
 from .transformer import (
@@ -54,7 +55,8 @@ class TransformerCausalLm(nn.Module):
     no embedding norm and no dropout, the final norm is an RMSNorm, and
     ``num_layers``, ``num_heads`` and ``mlp_dim`` are not read. With an
     expert layer among them ``__call__`` returns ``(logits, aux)``, ``aux``
-    what the expert layers counted. ``tie_embeddings=False`` gives the output
+    what the expert layers counted; a router's state goes from each expert
+    layer to the next one here. ``tie_embeddings=False`` gives the output
     head a matrix of its own (``lm_head/kernel``)."""
 
     vocab_size: int
@@ -135,13 +137,24 @@ class TransformerCausalLm(nn.Module):
 
     def _styled(self, tokens):
         x = self._embed(tokens, None, False)
-        counted = []
+        # Carried from block to block beside x: the state of a router that
+        # keeps one (None before the first such block, and for good where
+        # no router does).
+        state, received, counted = None, 0, []
         for (_, _, _, style), lyr in zip(self.blocks, self.layers):
             if style.mlp == "experts":
-                x, aux = lyr(x, causal=True)
+                received += state is not None
+                x, aux = lyr(x, causal=True, router_state=state)
+                state = aux.pop("router_state", None)
                 counted.append(aux)
             else:
                 x = lyr(x, causal=True)
+        if state is not None:
+            get_tracer().registry.gauge(
+                "moe.router.state_layers",
+                "expert layers of the traced model whose router was given "
+                "the state of the layer before",
+            ).set(received)
         logits = self._logits(self.final_norm(x))
         if not counted:
             return logits
@@ -318,6 +331,14 @@ _LAGUNA_TINY = dict(
     sliding_rope=Rope(theta=10_000.0))
 
 
+def _grouped_matmul_for(attention_impl: str) -> str:
+    """A model's one kernel switch: where the flash kernels are forced (a
+    compile for a chip that is not attached), so is the grouped matmul;
+    where they are ruled out, so is it."""
+    return {"auto": "auto", "pallas": "megablox"}.get(attention_impl,
+                                                      "ragged_dot")
+
+
 def _laguna(sizes, dtype, vocab_size, layers_held, experts_held,
             attention_impl):
     """The ``laguna`` decoder at ``sizes``, or one chip's share of it:
@@ -334,11 +355,7 @@ def _laguna(sizes, dtype, vocab_size, layers_held, experts_held,
                ("held", (int(first), int(count))),
                ("routed_scale", z["routed_scale"]),
                ("shared_dim", z["shared_width"]),
-               # The model's one kernel switch: where the flash kernels are
-               # forced (a compile for a chip that is not attached), so is
-               # the grouped matmul; where they are ruled out, so is it.
-               ("implementation", {"auto": "auto", "pallas": "megablox"}.get(
-                   attention_impl, "ragged_dot")))
+               ("implementation", _grouped_matmul_for(attention_impl)))
 
     def block(i):
         full = i % z["period"] == 0
@@ -377,3 +394,73 @@ def gpt_laguna_tiny(num_classes: int = 0, dtype=jnp.float32, *,
                     attention_impl: str = "auto"):
     return _laguna(_LAGUNA_TINY, dtype, vocab_size, layers_held, experts_held,
                    attention_impl)
+
+
+# ZAYA1-8B as Zyphra published it (config.json, `model_type: zaya`, 8.4 B
+# parameters, 0.76 B active): 40 layers of hidden size 2048, every one an
+# attention sublayer and an expert sublayer and no dense MLP; attention
+# inside a compressed, convolved latent (CCA) of 8 query heads over 2 K/V
+# heads of 128, rotary positions on half of each head at theta 5e6; 16
+# experts of width 2048, one a token, chosen by an MLP router of width 256
+# that hands its state to the next layer's; a learned scale and bias on the
+# residual stream and on each sublayer's result; RMSNorm 1e-5; a tied head
+# over 262,272 tokens. benchmark/configs/zaya1_8b.json lists what the source
+# leaves unsaid and how it was read.
+_ZAYA1_8B = dict(
+    hidden_size=2048, num_layers=40, head_dim=128, heads=8, kv_heads=2,
+    latent_mix=(2, 2), experts=16, expert_width=2048, router_hidden=256,
+    rope=Rope(theta=5_000_000.0, rotary_dim=64), rms_eps=1e-5)
+# The same block at sizes a CPU test holds.
+_ZAYA1_TINY = dict(
+    hidden_size=64, num_layers=3, head_dim=16, heads=4, kv_heads=2,
+    latent_mix=(2, 2), experts=8, expert_width=64, router_hidden=16,
+    rope=Rope(theta=5_000_000.0, rotary_dim=8), rms_eps=1e-5)
+
+
+def _zaya1(sizes, dtype, vocab_size, layers_held, experts_held,
+           attention_impl):
+    """The ``zaya`` decoder at ``sizes``, or one chip's share of it, told as
+    :func:`_laguna` is: the layers of this pipeline stage, the ``(first,
+    count)`` of each layer's experts on this rank, the vocabulary rows of
+    the embedding, which is the head too."""
+    z = sizes
+    layers = range(z["num_layers"]) if layers_held is None \
+        else tuple(layers_held)
+    first, count = experts_held or (0, z["experts"])
+    experts = (("num_experts", z["experts"]),
+               ("held", (int(first), int(count))),
+               ("implementation", _grouped_matmul_for(attention_impl)))
+    router = (("hidden", z["router_hidden"]), ("rms_eps", z["rms_eps"]))
+
+    def block(i):
+        return (i, z["heads"], z["expert_width"], BlockStyle(
+            num_kv_heads=z["kv_heads"], head_dim=z["head_dim"],
+            rope=z["rope"], rms_eps=z["rms_eps"],
+            latent_mix=z["latent_mix"], residual_scale=True,
+            from_embedding=i == 0, mlp="experts", experts=experts,
+            router=router))
+
+    return TransformerCausalLm(
+        vocab_size=vocab_size, hidden_size=z["hidden_size"], dtype=dtype,
+        attention_impl=attention_impl, tie_embeddings=True,
+        blocks=tuple(block(i) for i in layers))
+
+
+@register_model("gpt_zaya1_8b")
+def gpt_zaya1_8b(num_classes: int = 0, dtype=jnp.bfloat16, *,
+                 vocab_size: int = 262_272, max_len: int = 4096,
+                 layers_held=None, experts_held=None,
+                 attention_impl: str = "auto"):
+    # Every width is the published one; num_classes and max_len are not read,
+    # as in gpt_laguna_xs2.
+    return _zaya1(_ZAYA1_8B, dtype, vocab_size, layers_held, experts_held,
+                  attention_impl)
+
+
+@register_model("gpt_zaya1_tiny")
+def gpt_zaya1_tiny(num_classes: int = 0, dtype=jnp.float32, *,
+                   vocab_size: int = 96, max_len: int = 32,
+                   layers_held=None, experts_held=None,
+                   attention_impl: str = "auto"):
+    return _zaya1(_ZAYA1_TINY, dtype, vocab_size, layers_held, experts_held,
+                  attention_impl)

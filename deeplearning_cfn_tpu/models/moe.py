@@ -33,7 +33,7 @@ Design notes:
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, Dict, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -176,6 +176,12 @@ _GMM_TILE = (256, 1024, 512)
 # The usual row buffer, in rows a uniform router would send to the experts
 # held: twice them.
 _BUFFER_SHARE = 2.0
+# The rate of the controller that moves an MLP router's balancing bias: the
+# largest step a training step makes. ZAYA1-8B's router from seeded weights
+# starts collapsed onto two or three of its 16 experts (fullest over mean
+# 2.7-4.9) and is balanced (1.2-1.3) after three steps at this rate; 0.03 is
+# a step faster and noisier afterwards, 0.01 takes about ten (PERF.md).
+BALANCE_RATE = 0.02
 
 
 def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray,
@@ -256,18 +262,93 @@ def _held_rows(m, pair_weight, order, sizes, n_held, w_in, w_out, *,
             .astype(dtype)
 
 
+class SigmoidTopKRouter(nn.Module):
+    """One matrix, a sigmoid, the ``top_k`` largest: ``s = sigmoid(x W_r)`` in
+    float32; ``w_e = routed_scale * s_e / sum over the chosen``. It keeps no
+    state: what it is given it drops, and it hands on none."""
+
+    num_experts: int
+    top_k: int
+    routed_scale: float = 1.0
+
+    @nn.compact
+    def __call__(self, m, state=None):
+        del state
+        kernel = self.param("kernel", nn.initializers.xavier_uniform(),
+                            (m.shape[-1], self.num_experts), jnp.float32)
+        logits = jnp.dot(m.astype(jnp.float32), kernel,
+                         precision=jax.lax.Precision.HIGHEST)
+        top, chosen = jax.lax.top_k(jax.nn.sigmoid(logits), self.top_k)
+        return chosen, self.routed_scale * top \
+            / jnp.sum(top, axis=-1, keepdims=True), None
+
+
+class MlpStateRouter(nn.Module):
+    """A router that is an MLP with memory (Zyphra's ZAYA1): ``z = x W_d +
+    b_d`` ``[T, hidden]``, plus ``gamma * r`` where the layer before handed
+    its own ``z`` on as ``r`` (exponential depth averaging; the first layer
+    is given none and has no ``gamma``); ``r' = z`` goes to the next layer;
+    ``p = softmax(W_3 gelu(W_2 gelu(W_1 RMSNorm(z) + b_1) + b_2))`` in
+    float32. **One expert a token**: ``e = argmax(p + beta)``; the combine
+    weight is ``p[e]`` itself. Normalised over the one chosen it would be 1,
+    and the router cut off from the loss.
+
+    ``beta`` is the balancing bias (the parameter ``bias``, 0 from the
+    seed). It enters the choice alone, so no gradient reaches it; what moves
+    it is a controller on the load: after a training step ``beta_e <-
+    beta_e - BALANCE_RATE * min(n_e / mean(n) - 1, 1)``, ``n_e`` the tokens
+    of the step that chose expert ``e``. The step is sown as
+    ``nudges/.../bias`` for the trainer to add once the optimizer has run
+    (``train/state.py``); nothing moves where that collection is not
+    asked for."""
+
+    num_experts: int
+    hidden: int
+    rms_eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, m, state=None):
+        from .transformer import RMSNorm
+
+        dense = lambda feats, name, bias=True: nn.Dense(
+            feats, use_bias=bias, dtype=jnp.float32, param_dtype=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
+            kernel_init=nn.initializers.xavier_uniform(), name=name)
+        z = dense(self.hidden, "down")(m.astype(jnp.float32))
+        if state is not None:
+            gamma = self.param("scale", nn.initializers.ones,
+                               (self.hidden,), jnp.float32)
+            z = z + gamma * state
+        y = RMSNorm(self.rms_eps, jnp.float32, name="norm")(z)
+        for name in ("hidden_0", "hidden_1"):
+            y = nn.gelu(dense(self.hidden, name)(y), approximate=False)
+        probs = jax.nn.softmax(dense(self.num_experts, "out", False)(y))
+        beta = self.param("bias", nn.initializers.zeros,
+                          (self.num_experts,), jnp.float32)
+        chosen = jnp.argmax(probs + jax.lax.stop_gradient(beta), axis=-1,
+                            keepdims=True)
+        if not self.is_initializing():
+            load = jnp.sum(chosen == jnp.arange(self.num_experts)[None, :],
+                           axis=0, dtype=jnp.float32)
+            self.sow("nudges", "bias", -BALANCE_RATE * jnp.minimum(
+                load / jnp.mean(load) - 1.0, 1.0),
+                reduce_fn=lambda _, step: step, init_fn=lambda: None)
+        return chosen, jnp.take_along_axis(probs, chosen, axis=-1), z
+
+
 class HeldExpertsMlp(nn.Module):
-    """The expert layer of one expert-parallel rank: it routes every token
-    over all ``num_experts`` experts, is told which of them it ``held``
-    (``(first, count)``; ``count`` 0 is all of them), and returns their part
-    of the layer's result, plus the shared expert's where ``shared_dim`` > 0.
+    """The expert layer of one expert-parallel rank. It is told the
+    ``num_experts`` experts of the whole layer, which of them it ``held``
+    (``(first, count)``; ``count`` 0 is all of them) and its ``router``, a
+    module ``(tokens [T, F], state) -> (chosen [T, k], weight [T, k], state')``
+    that scores every token over all the experts, held or not. Either a
+    router or ``top_k`` (with ``routed_scale``), never both: told ``top_k``
+    the layer makes the :class:`SigmoidTopKRouter` of them itself. It returns
+    the held experts' part of the layer's result, ``sum over chosen and held
+    e of w_e E_e(x)``, each ``E`` a gated MLP ``(silu(x W1) * x W3) W2`` of
+    width ``mlp_dim``, plus the shared expert's where ``shared_dim`` > 0.
     Summed over the ranks (the shared expert counted once) the parts are the
     whole layer; the exchange between ranks is not here.
-
-    ``s = sigmoid(x W_r)`` in float32; the ``top_k`` largest are chosen;
-    ``w_e = routed_scale * s_e / sum over the chosen``; the result is ``sum
-    over chosen and held e of w_e E_e(x)``, each ``E`` a gated MLP
-    ``(silu(x W1) * x W3) W2`` of width ``mlp_dim``.
 
     **Dropless over the experts held.** The (token, choice) pairs are sorted
     by expert with the held experts first, their rows gathered, multiplied
@@ -275,45 +356,53 @@ class HeldExpertsMlp(nn.Module):
     back into their tokens. The row buffer is static: twice what a uniform
     router would send (``_BUFFER_SHARE``), and where a step's routing sends more
     (``lax.cond`` on the count) a second buffer of every pair,
-    ``tokens * top_k`` rows, takes the step. Either is recomputed in the
-    backward pass, so that no buffer is kept. No row is dropped whatever the
-    routing.
+    ``tokens * k`` rows, takes the step; where twice a uniform router's rows
+    are every pair (one choice a token, half of the experts held) there is
+    the one buffer. Either is recomputed in the backward pass, so that no
+    buffer is kept. No row is dropped whatever the routing.
 
     Returns ``(y, aux)``: ``aux["rows_held"]`` the rows routed to held
     experts, ``aux["load_max_over_mean"]`` the fullest held expert's rows
-    over the mean."""
+    over the mean, and, where the router keeps a state, ``aux["router_state"]``
+    ``[B, S, hidden]``: what the next layer's router is to be given as
+    ``router_state``."""
 
     num_experts: int
     mlp_dim: int
-    top_k: int
+    top_k: int = 0
     held: Tuple[int, int] = (0, 0)
     routed_scale: float = 1.0
     shared_dim: int = 0
     dtype: Dtype = jnp.bfloat16
     implementation: str = "auto"
+    router: Optional[nn.Module] = None
 
     @nn.compact
-    def __call__(self, x: jnp.ndarray
+    def __call__(self, x: jnp.ndarray, router_state=None
                  ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
         from .transformer import GatedMlp
 
         b, s, f = x.shape
-        e, k = self.num_experts, self.top_k
+        e = self.num_experts
         first, count = self.held if self.held[1] else (0, e)
-        if k > e or not 0 < count <= e:
-            raise ValueError(f"top_k {k}, held {self.held}, experts {e}")
+        if not 0 < count <= e:
+            raise ValueError(f"held {self.held}, experts {e}")
         m = x.reshape(b * s, f).astype(self.dtype)
-        pairs = b * s * k
+        if (self.router is None) == (self.top_k == 0):
+            raise ValueError("an expert layer is given a router or top_k, "
+                             "and not both")
+        if self.top_k > e:
+            raise ValueError(f"top_k={self.top_k} > num_experts={e}")
+        router = self.router if self.router is not None else \
+            SigmoidTopKRouter(e, self.top_k, self.routed_scale, name="router")
 
         with jax.named_scope("moe_router"):
-            logits = nn.Dense(
-                e, use_bias=False, dtype=jnp.float32,
-                param_dtype=jnp.float32, precision=jax.lax.Precision.HIGHEST,
-                kernel_init=nn.initializers.xavier_uniform(),
-                name="router")(m.astype(jnp.float32))
-            top, chosen = jax.lax.top_k(jax.nn.sigmoid(logits), k)
-            pair_weight = (self.routed_scale * top
-                           / jnp.sum(top, axis=-1, keepdims=True)).reshape(-1)
+            chosen, weight, state = router(
+                m, None if router_state is None
+                else router_state.reshape(b * s, -1))
+            k = chosen.shape[-1]
+            pair_weight = weight.reshape(-1)
+        pairs = b * s * k
         with jax.named_scope("moe_dispatch"):
             # Held experts become groups 0 .. count - 1, every other expert
             # the group ``count``, which sorts last and is never computed.
@@ -348,4 +437,6 @@ class HeldExpertsMlp(nn.Module):
         aux = {"rows_held": n_held.astype(jnp.float32),
                "load_max_over_mean": jnp.max(load)
                / jnp.maximum(jnp.mean(load), 1e-9)}
+        if state is not None:
+            aux["router_state"] = state.reshape(b, s, -1)
         return y.reshape(b, s, f), aux

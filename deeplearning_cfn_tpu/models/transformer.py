@@ -153,7 +153,26 @@ class BlockStyle:
 
     ``mlp``: ``"swiglu"`` (``(silu(x W1) * x W3) W2``, width ``mlp_dim``) or
     ``"experts"`` (``models/moe.py:HeldExpertsMlp`` built from ``experts``, a
-    tuple of its keyword pairs)."""
+    tuple of its keyword pairs; ``router`` empty leaves the layer the router
+    of its own ``top_k``, else it is the keyword pairs of a
+    ``models/moe.py:MlpStateRouter``, which chooses one expert a token and
+    hands its state to the next block's router).
+
+    ``latent_mix`` makes the attention Zyphra's CCA (arXiv 2510.04476): q and
+    k, projected *down* into a latent of ``heads * head_dim``, pass two causal
+    convolutions along the sequence, of ``latent_mix[0]`` taps a channel and
+    of ``latent_mix[1]`` taps with one ``head_dim``-square matrix a head; the
+    mean of a query head and its K/V head, from before the convolutions, is
+    added back; the second half of the K/V heads' values are the previous
+    token's; q and k are scaled to the norm ``sqrt(head_dim)``, k times a
+    learned temperature a K/V head. Rotary positions, the softmax and the
+    output projection follow as in any styled block.
+
+    ``residual_scale``: a sublayer's result ``f`` joins the stream ``h`` as
+    ``(h + b_r) * s_r + (f + b_o) * s_o``, four learned vectors a sublayer
+    (biases 0, scales 1 from the seed), not ``h + f``. ``from_embedding``
+    says the block's input is the embedding itself: its attention sublayer
+    then has no ``s_r``, ``b_r``."""
 
     num_kv_heads: int = 0          # 0: as many as query heads
     head_dim: int = 0              # 0: hidden size / heads
@@ -163,6 +182,47 @@ class BlockStyle:
     rms_eps: float = 1e-6
     mlp: str = "swiglu"
     experts: Tuple[Tuple[str, Any], ...] = ()
+    router: Tuple[Tuple[str, Any], ...] = ()
+    latent_mix: Tuple[int, ...] = ()   # (): q, k, v straight from x
+    residual_scale: bool = False
+    from_embedding: bool = False
+
+
+class Leaf(nn.Module):
+    """One float32 parameter under a module name of its own, called ``kind``
+    (``kernel``, ``scale`` or ``bias``: what initialisers, weight decay's
+    mask and seeded weights go by)."""
+
+    kind: str
+    shape: Tuple[int, ...]
+
+    @nn.compact
+    def __call__(self):
+        init = {"kernel": nn.initializers.xavier_uniform(),
+                "scale": nn.initializers.ones,
+                "bias": nn.initializers.zeros}[self.kind]
+        return self.param(self.kind, init, self.shape, jnp.float32)
+
+
+class ShiftScale(nn.Module):
+    """``(x + bias) * scale`` in float32, a learned vector each."""
+
+    @nn.compact
+    def __call__(self, x):
+        shape = (x.shape[-1],)
+        bias = self.param("bias", nn.initializers.zeros, shape, jnp.float32)
+        scale = self.param("scale", nn.initializers.ones, shape, jnp.float32)
+        return (x.astype(jnp.float32) + bias) * scale
+
+
+def shift_later(x: jnp.ndarray, n: int = 1) -> jnp.ndarray:
+    """``x [B, S, ...]`` moved ``n`` positions later along the sequence,
+    zeros before its first position: ``y[:, t] = x[:, t - n]``."""
+    if n == 0:
+        return x
+    pad = [(0, 0)] * x.ndim
+    pad[1] = (n, 0)
+    return jnp.pad(x[:, :x.shape[1] - n], pad)
 
 
 class QuantDense(nn.Module):
@@ -321,10 +381,56 @@ class MultiHeadAttention(nn.Module):
         def heads(t, n):  # [B,S,n*D] -> [B,S,n,D]
             return t.reshape(*t.shape[:2], n, head_dim)
 
-        q = heads(dense("query", self.num_heads * head_dim)(x),
-                  self.num_heads)
-        k = heads(dense("key", kv_heads * head_dim)(kv), kv_heads)
-        v = heads(dense("value", kv_heads * head_dim)(kv), kv_heads)
+        def latent_mix(q, k):
+            """q ``[B,S,H*D]`` and k ``[B,S,KV*D]`` as projected, through the
+            two convolutions, the q-k mean and the norm: ``[B,S,n,D]``
+            each. Float32 but for the operands of the grouped product."""
+            b, s = q.shape[:2]
+            h, g = self.num_heads, self.num_heads // kv_heads
+            taps, head_taps = st.latent_mix
+            c = jnp.concatenate([q, k], axis=-1).astype(jnp.float32)
+            w = Leaf("kernel", (taps, c.shape[-1]), name="conv_depth")()
+            c = sum(w[j] * shift_later(c, taps - 1 - j) for j in range(taps))
+            c = heads(c.astype(self.dtype), h + kv_heads)
+            w = Leaf("kernel", (head_taps * head_dim, (h + kv_heads)
+                                * head_dim), name="conv_heads")()
+            w = w.astype(self.dtype).reshape(head_taps, head_dim,
+                                             h + kv_heads, head_dim)
+            c = sum(jnp.einsum("bsgi,igo->bsgo",
+                               shift_later(c, head_taps - 1 - j), w[j],
+                               preferred_element_type=jnp.float32)
+                    for j in range(head_taps))
+            mean_q = 0.5 * (
+                q.astype(jnp.float32).reshape(b, s, kv_heads, g, head_dim)
+                + k.astype(jnp.float32).reshape(b, s, kv_heads, 1, head_dim))
+            q = c[:, :, :h] + mean_q.reshape(b, s, h, head_dim)
+            k = c[:, :, h:] + jnp.mean(mean_q, axis=3)
+            unit = lambda t: t * jax.lax.rsqrt(
+                jnp.mean(jnp.square(t), axis=-1, keepdims=True))
+            temperature = Leaf("scale", (kv_heads,), name="key_temp")()
+            return unit(q).astype(self.dtype), \
+                (unit(k) * temperature[:, None]).astype(self.dtype)
+
+        if st.latent_mix:
+            get_tracer().registry.counter(
+                "attention.cca.calls",
+                "attention calls traced that mix q and k in the latent",
+            ).inc()
+            q = dense("query", self.num_heads * head_dim)(x)
+            k = dense("key", kv_heads * head_dim)(kv)
+            # The first K/V heads see the token, the others the one before.
+            prev = kv_heads // 2
+            v = heads(jnp.concatenate([
+                dense("value", (kv_heads - prev) * head_dim)(kv),
+                shift_later(dense("value_prev", prev * head_dim)(kv))], -1),
+                kv_heads)
+            with jax.named_scope("cca_mix"):
+                q, k = latent_mix(q, k)
+        else:
+            q = heads(dense("query", self.num_heads * head_dim)(x),
+                      self.num_heads)
+            k = heads(dense("key", kv_heads * head_dim)(kv), kv_heads)
+            v = heads(dense("value", kv_heads * head_dim)(kv), kv_heads)
         if st.rope is not None:
             with jax.named_scope("rope"):
                 q, k = (rope_to_heads(t, st.rope, self.attention_impl)
@@ -635,7 +741,9 @@ class TransformerLayer(nn.Module):
     ``style`` makes it a current block (:class:`BlockStyle`): pre-norm with
     RMSNorm, no biases, no dropout, the style's attention and MLP. With
     ``style.mlp == "experts"`` it returns ``(x, aux)`` too, where ``aux``
-    holds what the expert layer counted.
+    holds what the expert layer counted and, where its router keeps a state,
+    ``aux["router_state"]``: what the next block is to be called with as
+    ``router_state`` (the first block is called with none).
     """
 
     num_heads: int
@@ -652,36 +760,55 @@ class TransformerLayer(nn.Module):
     kv_quant: str = ""
     style: Optional[BlockStyle] = None
 
-    def _styled(self, x, causal):
+    def _styled(self, x, causal, router_state):
         st = self.style
         norm = lambda name: RMSNorm(st.rms_eps, self.dtype, name=name)
-        x = x + MultiHeadAttention(
+
+        def join(sub, x, f, stream=True):
+            if not st.residual_scale:
+                return x + f
+            # Under the sublayer's own scope, so that a trace counts the
+            # merge with the sublayer whose result it merges.
+            with jax.named_scope(sub):
+                h = ShiftScale(name=f"{sub}_stream")(x) if stream \
+                    else x.astype(jnp.float32)
+                return (h + ShiftScale(name=f"{sub}_result")(f)) \
+                    .astype(self.dtype)
+
+        x = join("self_attn", x, MultiHeadAttention(
             self.num_heads, self.dtype, 0.0, self.attention_impl,
             style=st, name="self_attn")(
-                norm("self_attn_norm")(x), causal=causal)
+                norm("self_attn_norm")(x), causal=causal),
+            stream=not st.from_embedding)
         y = norm("mlp_norm")(x)
         if st.mlp == "experts":
-            from .moe import HeldExpertsMlp
+            from .moe import HeldExpertsMlp, MlpStateRouter
 
-            out, aux = HeldExpertsMlp(mlp_dim=self.mlp_dim, dtype=self.dtype,
-                                      name="mlp", **dict(st.experts))(y)
-            return x + out, aux
+            experts = dict(st.experts)
+            # Unparented: the layer it is given to adopts it, as `router`.
+            router = MlpStateRouter(experts["num_experts"], parent=None,
+                                    **dict(st.router)) if st.router else None
+            out, aux = HeldExpertsMlp(
+                mlp_dim=self.mlp_dim, dtype=self.dtype, name="mlp",
+                router=router, **experts)(y, router_state)
+            return join("mlp", x, out), aux
         if st.mlp != "swiglu":
             raise ValueError(f"unknown BlockStyle.mlp {st.mlp!r}")
-        return x + GatedMlp(self.mlp_dim, self.dtype, name="mlp")(y)
+        return join("mlp", x, GatedMlp(self.mlp_dim, self.dtype,
+                                       name="mlp")(y))
 
     @nn.compact
     def __call__(self, x, enc=None, self_bias=None, cross_bias=None,
                  causal=False, deterministic=True, decode=False,
                  max_decode_len: int = 0, decode_pos=None,
                  block_tables=None, kv_num_blocks: int = 0,
-                 kv_block_size: int = 0):
+                 kv_block_size: int = 0, router_state=None):
         if self.style is not None:
             if decode or enc is not None or self_bias is not None:
                 raise NotImplementedError(
                     "a styled block runs self-attention over whole "
                     "sequences: no decode step, encoder or bias yet")
-            return self._styled(x, causal)
+            return self._styled(x, causal, router_state)
         ln = lambda name: nn.LayerNorm(
             dtype=self.dtype, param_dtype=jnp.float32, name=name)
         attn = lambda name: MultiHeadAttention(

@@ -337,6 +337,39 @@ def _laguna_xs2() -> ExperimentConfig:
     )
 
 
+@register_preset("zaya1_8b_lm")
+def _zaya1_8b() -> ExperimentConfig:
+    """ZAYA1-8B (Zyphra, 8.4 B parameters, 0.76 B active: attention inside a
+    compressed, convolved latent, 16 experts of width 2048 one a token by an
+    MLP router that carries its state from layer to layer, a scaled
+    residual, a tied head) pre-trained on one chip's share of a pod: the
+    chip is one of 8 that share each layer, experts 0-7 of 16 (each half of
+    the experts on 4 of the 8 chips, which split the batch) and 32,896 of
+    the 262,272 vocabulary rows here (the eighth, in whole 128-lane tiles),
+    attention and the router whole, and it holds layers 0-4 of 40 as one
+    pipeline stage. The exchange with the other chips is not here
+    (models/moe.py). Sequences of 4096, the length of the source's first
+    pre-training phase. Recipe: gpt_small_lm's (the source's own is not in
+    its config), no auxiliary loss; a router's balancing bias is moved
+    after every step by its load (models/moe.py:MlpStateRouter: the
+    source's own controller is not published either)."""
+    return ExperimentConfig(
+        model=ModelConfig(
+            name="gpt_zaya1_8b",
+            kwargs=dict(layers_held=(0, 1, 2, 3, 4), experts_held=(0, 8)),
+        ),
+        data=DataConfig(name="lm_text", seq_len=4096, vocab_size=32_896),
+        train=TrainConfig(global_batch=2, steps=100_000, dtype="bfloat16",
+                          shard_opt_state=False),
+        optimizer=OptimizerConfig(name="adamw", b1=0.9, b2=0.95,
+                                  weight_decay=0.1, grad_clip_norm=1.0),
+        schedule=ScheduleConfig(name="cosine", base_lr=6e-4,
+                                warmup_steps=2000),
+        mesh=MeshConfig(data=-1),
+        stack=StackConfig(slice_type="v5e-8"),
+    )
+
+
 @register_preset("transformer_nmt_wmt")
 def _nmt() -> ExperimentConfig:
     """Transformer NMT WMT En-De (reference: Sockeye + MXNet

@@ -12,6 +12,7 @@ from __future__ import annotations
 from typing import Any, Callable, Optional
 
 import flax.struct
+import flax.traverse_util
 import jax
 import jax.numpy as jnp
 import optax
@@ -30,9 +31,16 @@ class TrainState(flax.struct.PyTreeNode):
     ema_params: Optional[PyTree] = None
 
     def apply_gradients(self, grads: PyTree, tx: optax.GradientTransformation,
-                        ema_decay: float = 0.0) -> "TrainState":
+                        ema_decay: float = 0.0, nudges: PyTree = None
+                        ) -> "TrainState":
+        """One optimizer step. ``nudges`` is a sparse tree under the
+        parameters' own names: what the model's forward pass asked to have
+        added to a parameter that no gradient reaches (a router's balancing
+        bias), added after the optimizer's update."""
         updates, new_opt_state = tx.update(grads, self.opt_state, self.params)
         new_params = optax.apply_updates(self.params, updates)
+        if nudges:
+            new_params = _nudged(new_params, nudges)
         new_ema = self.ema_params
         if new_ema is not None and ema_decay > 0:
             with jax.named_scope("ema"):
@@ -44,6 +52,18 @@ class TrainState(flax.struct.PyTreeNode):
             step=self.step + 1, params=new_params, opt_state=new_opt_state,
             ema_params=new_ema,
         )
+
+
+def _nudged(params: PyTree, nudges: PyTree) -> PyTree:
+    steps = flax.traverse_util.flatten_dict(nudges)
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(params)
+    names = [tuple(k.key for k in path) for path, _ in leaves]
+    if not set(steps) <= set(names):
+        raise KeyError(f"nudges for no parameter: "
+                       f"{sorted(set(steps) - set(names))[:4]}")
+    return jax.tree_util.tree_unflatten(treedef, [
+        leaf + steps[name] if name in steps else leaf
+        for name, (_, leaf) in zip(names, leaves)])
 
 
 def create_train_state(

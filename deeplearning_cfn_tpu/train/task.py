@@ -409,12 +409,17 @@ class CausalLmTask:
 
     def loss_fn(self, params, batch_stats, batch, rng, train):
         rngs = {"dropout": rng} if (train and rng is not None) else None
+        # A training step also collects what the model sows as `nudges`:
+        # steps for parameters that no gradient moves (a router's balancing
+        # bias), which the trainer adds after the optimizer's.
         apply = lambda p, ids: self.model.apply(
-            {"params": p}, ids, train=train, rngs=rngs)
+            {"params": p}, ids, train=train, rngs=rngs,
+            mutable=["nudges"] if train else False)
         if train and self.remat:
             apply = jax.checkpoint(apply)
         inputs = batch["tokens"][:, :-1]
-        out = apply(params, inputs)
+        out, sown = apply(params, inputs) if train \
+            else (apply(params, inputs), {})
         logits, moe_aux = out if isinstance(out, tuple) else (out, None)
         # The program's own scope (docs/OBSERVABILITY.md): nothing below is
         # inside a flax module, so without it a trace cannot tell the loss
@@ -452,6 +457,8 @@ class CausalLmTask:
                 # perplexity (Jensen); see eval_derived below.
                 aux["perplexity"] = jnp.exp(jnp.minimum(ce_loss, 20.0))
                 aux["batch_stats"] = batch_stats
+                if sown.get("nudges"):
+                    aux["nudges"] = sown["nudges"]
             else:
                 # Every eval metric here (incl. the losses) is
                 # token-weighted: the default normalizer is the batch's real

@@ -179,3 +179,56 @@ def test_laguna_step_compiles_and_fits_a_v5e(v5e_chip):
     assert sum("rope_fwd" in line for line in kernels) == 10
     assert sum("rope_bwd" in line for line in kernels) == 10
     assert all("/self_attn/rope/" in line for line in kernels)
+
+
+def test_zaya1_step_compiles_and_fits_a_v5e(v5e_chip):
+    """The whole train step of ``zaya1_8b_train_4k`` at the cell's shapes:
+    Mosaic takes the flash and rotary kernels at 8 query heads over 2 K/V
+    heads of 128 (a group of 4), megablox its 8 groups of 2048 x 4096, the
+    latent mixing is in the step under its scope, and arguments plus
+    temporaries read under 15 GB of the chip's 16 (and over the quarter of
+    it a cell has to fill). PERF.md section 4 has the number."""
+    import os
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path.insert(0, bench)
+    try:
+        from harness import manifest
+        import rehearse_compile
+    finally:
+        sys.path.remove(bench)
+    from deeplearning_cfn_tpu.obs.trace import get_tracer
+
+    registry = get_tracer().registry
+    mixed = registry.counter("attention.cca.calls")
+    turned = registry.counter("attention.rope.calls")
+    before = (mixed.value(), turned.value(path="kernel"),
+              turned.value(path="xla"))
+    cell = manifest.Cell(manifest.load_manifest(), "zaya1_8b_train_4k")
+    _, compiled, _ = rehearse_compile.compile_step(cell)
+    # Traced twice (the parameters' shapes, the step), five layers each.
+    assert (mixed.value() - before[0], turned.value(path="kernel")
+            - before[1], turned.value(path="xla") - before[2]) == (10, 20, 0)
+    assert registry.gauge("moe.router.state_layers").value() == 4
+    mem = compiled.memory_analysis()
+    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    assert 4e9 < total < 15e9, total
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    flash = [line for line in kernels if "core_attention/flash_" in line]
+    assert len(flash) == 15
+    # K/V are not repeated to the query heads: 2 heads under 8.
+    assert all("bf16[2,2,4096,128]" in line and "bf16[2,8,4096,128]" in line
+               for line in flash)
+    rope = [line for line in kernels if "/self_attn/rope/" in line]
+    assert sum("rope_fwd" in line for line in rope) == 10
+    assert sum("rope_bwd" in line for line in rope) == 10
+    # Forward, the forward again (recomputed) and backward: eight grouped
+    # matmuls a layer, all under the scope the readers know.
+    assert sum("/moe_experts/jit(" in line and "/mlp/" in line
+               for line in kernels) == 40
+    assert "/self_attn/cca_mix/" in text and "/mlp/moe_router/" in text

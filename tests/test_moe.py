@@ -167,3 +167,119 @@ def test_expert_parallel_matches_data_parallel(devices):
     assert "moe_load_balance" in metrics and "moe_router_z" in metrics
     # 10 adamw steps on the tiny task must move the loss.
     assert loss_ep[-1] < loss_ep[0]
+
+
+# -- the routers of the expert layer that is told which experts it holds ----
+
+
+def _mlp_router_case(with_state=True):
+    from deeplearning_cfn_tpu.models.moe import MlpStateRouter
+
+    router = MlpStateRouter(num_experts=8, hidden=16)
+    m = jax.random.normal(jax.random.PRNGKey(0), (24, 32))
+    state = jax.random.normal(jax.random.PRNGKey(1), (24, 16)) \
+        if with_state else None
+    params = router.init(jax.random.PRNGKey(2), m, state)
+    return router, params, m, state
+
+
+def test_mlp_router_weighs_its_one_choice_by_its_probability():
+    """One expert a token; the weight is the chosen expert's softmax
+    probability itself, so it is under 1 and the router feels the loss."""
+    router, params, m, state = _mlp_router_case()
+    chosen, weight, z = router.apply(params, m, state)
+    assert chosen.shape == weight.shape == (24, 1) and z.shape == (24, 16)
+    assert np.all(np.asarray(weight) < 1.0) \
+        and np.all(np.asarray(weight) >= 1.0 / 8)
+    grads = jax.grad(lambda p: jnp.sum(router.apply(p, m, state)[1]))(params)
+    flat = {"/".join(str(k.key) for k in path): g for path, g in
+            jax.tree_util.tree_flatten_with_path(grads["params"])[0]}
+    assert set(flat) == {
+        "down/kernel", "down/bias", "scale", "norm/scale",
+        "hidden_0/kernel", "hidden_0/bias", "hidden_1/kernel",
+        "hidden_1/bias", "out/kernel", "bias"}
+    # The balancing bias enters the choice and nothing else.
+    assert not np.any(np.asarray(flat.pop("bias")))
+    assert all(np.any(np.asarray(g)) for g in flat.values())
+
+
+def test_mlp_router_state_and_balancing_bias():
+    router, params, m, state = _mlp_router_case()
+    chosen, weight, z = router.apply(params, m, state)
+    # Handed none, it has no gamma and hands on its own projection.
+    bare, bare_params, _, _ = _mlp_router_case(with_state=False)
+    assert "scale" not in bare_params["params"]
+    _, _, z0 = bare.apply(bare_params, m)
+    np.testing.assert_allclose(np.asarray(z - z0), np.asarray(state),
+                               atol=1e-5)
+    # A bias on expert 5 above every probability moves every choice there,
+    # and the weight stays that expert's probability, bias not added.
+    beta = jnp.zeros(8).at[5].set(2.0)
+    tilted = {"params": dict(params["params"], bias=beta)}
+    chosen_b, weight_b, _ = router.apply(tilted, m, state)
+    assert np.all(np.asarray(chosen_b) == 5)
+    assert np.all(np.asarray(weight_b) < 1.0)
+    assert np.any(np.asarray(chosen) != 5)
+
+
+def test_mlp_router_sows_its_balancing_biases_step():
+    """``beta_e <- beta_e - BALANCE_RATE * min(n_e / mean(n) - 1, 1)``: sown
+    as ``nudges/bias`` where that collection is asked for, and nowhere
+    else."""
+    from deeplearning_cfn_tpu.models.moe import BALANCE_RATE
+
+    router, params, m, state = _mlp_router_case()
+    assert set(params) == {"params"}
+    (chosen, _, _), sown = router.apply(params, m, state, mutable=["nudges"])
+    load = np.bincount(np.asarray(chosen)[:, 0], minlength=8)
+    assert load.sum() == 24 and load.max() > 6
+    np.testing.assert_allclose(
+        np.asarray(sown["nudges"]["bias"]),
+        -BALANCE_RATE * np.minimum(load / 3.0 - 1.0, 1.0), atol=1e-7)
+    assert len(router.apply(params, m, state)) == 3
+
+
+def test_held_experts_layer_is_given_a_router_or_top_k():
+    from deeplearning_cfn_tpu.models.moe import HeldExpertsMlp, \
+        MlpStateRouter
+
+    x = jnp.zeros((1, 8, 32))
+    for kwargs in (dict(), dict(top_k=2, router=MlpStateRouter(8, 16)),
+                   dict(top_k=9)):
+        with pytest.raises(ValueError):
+            HeldExpertsMlp(num_experts=8, mlp_dim=16, **kwargs).init(
+                jax.random.PRNGKey(0), x)
+
+
+def test_held_experts_layer_keeps_its_first_routers_names():
+    """Told no router it has the sigmoid top-k one, its matrix where it was
+    (``router/kernel``); told an MLP router, that one's tree under the same
+    name, and its state beside what the layer counted."""
+    from deeplearning_cfn_tpu.models.moe import HeldExpertsMlp, \
+        MlpStateRouter
+
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 16, 32))
+    first = HeldExpertsMlp(num_experts=8, mlp_dim=16, top_k=2, held=(0, 4),
+                           routed_scale=2.5, shared_dim=8, dtype=jnp.float32)
+    params = first.init(jax.random.PRNGKey(1), x)["params"]
+    assert set(params) == {"router", "experts_in", "experts_out", "shared"}
+    assert params["router"]["kernel"].shape == (32, 8)
+    out, aux = first.apply({"params": params}, x)
+    assert set(aux) == {"rows_held", "load_max_over_mean"}
+
+    second = HeldExpertsMlp(num_experts=8, mlp_dim=16, held=(0, 4),
+                            dtype=jnp.float32,
+                            router=MlpStateRouter(8, 16))
+    state = jnp.ones((2, 16, 16))
+    params = second.init(jax.random.PRNGKey(1), x, state)["params"]
+    assert set(params) == {"router", "experts_in", "experts_out"}
+    assert set(params["router"]) == {"down", "scale", "norm", "hidden_0",
+                                     "hidden_1", "out", "bias"}
+    out, aux = second.apply({"params": params}, x, state)
+    assert aux["router_state"].shape == (2, 16, 16)
+    assert 0 <= float(aux["rows_held"]) <= 32
+    # One choice a token and half of the experts held: twice a uniform
+    # router's rows are every pair, so there is one buffer and no branch.
+    text = str(jax.make_jaxpr(lambda p: second.apply({"params": p}, x,
+                                                     state))(params))
+    assert " cond[" not in text
