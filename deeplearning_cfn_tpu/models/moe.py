@@ -40,6 +40,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..obs.trace import get_tracer
+
 Dtype = Any
 
 # Param-path rules for the 'expert' mesh axis (see
@@ -240,26 +242,116 @@ class ExpertStack(nn.Module):
             self.experts, self.d_in, self.d_out)
 
 
-def _held_rows(m, pair_weight, order, sizes, n_held, w_in, w_out, *,
+def inverse_permutation(order: jnp.ndarray) -> jnp.ndarray:
+    """``inv`` with ``inv[order[r]] = r``: where the sort put each pair. A
+    second sort: on the chip it takes 0.05 ms for 65,536 pairs where a
+    scatter of ``arange`` takes 0.30 (tools/moe_rows_sweep.py; PERF.md,
+    PR 33)."""
+    return jnp.argsort(order).astype(order.dtype)
+
+
+def _rows_of_tokens(y, weight, inv, n_live, top_k: int):
+    """``[tokens, F]`` float32: for every token the sum over its ``top_k``
+    pairs of the row of ``y`` the pair lies at (``inv``), times the pair's
+    ``weight`` where one is given, over the pairs whose row is live
+    (``inv < n_live``), in the choices' order; a dead pair reads the last
+    row and is masked.
+
+    Under the usual buffer a gather a choice, added in turn: in Laguna's
+    step one gather ``[tokens, k, F]`` is written out in float32 before it
+    is summed and loses what the gathers win (PERF.md, PR 33). Under the
+    buffer of every pair, which few steps take and where both forms cost
+    the same alone, the one gather: eight gathers in both branches of every
+    layer are 2-3 s more program to load at set-up."""
+    rows = y.shape[0]
+    at = jnp.minimum(inv, rows - 1).reshape(-1, top_k)
+    live = (inv < n_live).reshape(-1, top_k, 1)
+    weight = None if weight is None else weight.reshape(-1, top_k, 1)
+
+    def terms(got, j):
+        got = got.astype(jnp.float32)
+        if weight is not None:
+            got = got * weight[:, j]
+        return jnp.where(live[:, j], got, 0)
+
+    if top_k > 1 and rows == inv.shape[0]:
+        return jnp.sum(terms(y[at], slice(None)), axis=1)
+    total = terms(y[at[:, 0]], 0)
+    for j in range(1, top_k):
+        total = total + terms(y[at[:, j]], j)
+    return total
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def take_rows(m, token, inv, n_live, top_k: int):
+    """``xs[r] = m[token[r]]`` for the live rows ``r < n_live`` of the
+    buffer, 0 for the others. Its transpose is :func:`sum_rows` without the
+    weights, and is written down as that: autodiff would make a scatter-add
+    of it."""
+    live = (jnp.arange(token.shape[0]) < n_live)[:, None]
+    return jnp.where(live, m[token], 0)
+
+
+def _take_rows_fwd(m, token, inv, n_live, top_k):
+    return take_rows(m, token, inv, n_live, top_k), (inv, n_live)
+
+
+def _take_rows_bwd(top_k, kept, d_xs):
+    inv, n_live = kept
+    d_m = _rows_of_tokens(d_xs, None, inv, n_live, top_k)
+    return d_m.astype(d_xs.dtype), None, None, None
+
+
+take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def sum_rows(y, weight, order, inv, n_live, top_k: int):
+    """``out[t] = sum over j of weight[t k + j] * y[inv[t k + j]]`` over the
+    pairs whose row is live, products and sum in float32, in ``y``'s dtype:
+    every row of the buffer added into its token by a gather through the
+    sort's inverse. Its transpose is :func:`take_rows` times the weights:
+    ``d y[r] = weight[order[r]] * d out[token[r]]``, and ``d weight[p]`` is
+    the dot of pair ``p``'s row with its token's cotangent."""
+    return _rows_of_tokens(y, weight, inv, n_live, top_k).astype(y.dtype)
+
+
+def _sum_rows_fwd(y, weight, order, inv, n_live, top_k):
+    return sum_rows(y, weight, order, inv, n_live, top_k), \
+        (y, weight, order, inv, n_live)
+
+
+def _sum_rows_bwd(top_k, kept, d_out):
+    y, weight, order, inv, n_live = kept
+    rows = y.shape[0]
+    pair = order[:rows]
+    live = (jnp.arange(rows) < n_live)[:, None]
+    g = d_out[pair // top_k].astype(jnp.float32)
+    d_y = jnp.where(live, g * weight[pair][:, None], 0).astype(y.dtype)
+    dots = jnp.sum(jnp.where(live, y.astype(jnp.float32) * g, 0), axis=1)
+    d_weight = jnp.where(inv < n_live, dots[jnp.minimum(inv, rows - 1)], 0)
+    return d_y, d_weight.astype(weight.dtype), None, None, None
+
+
+sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
+
+
+def _held_rows(m, pair_weight, order, inv, sizes, n_held, w_in, w_out, *,
                rows: int, top_k: int, implementation: str):
     """The held experts' part of the layer's result from a buffer of ``rows``
     rows: the first ``rows`` (token, choice) pairs in ``order`` (sorted by
-    expert, those of held experts first, ``n_held`` of them)."""
-    tokens, dtype = m.shape[0], m.dtype
-    pair = order[:rows]
-    token = pair // top_k
-    valid = (jnp.arange(rows) < n_held)[:, None]
+    expert, those of held experts first, ``n_held`` of them); ``inv`` is
+    where ``order`` put each pair."""
+    n_live = jnp.minimum(n_held, rows)
+    valid = (jnp.arange(rows) < n_live)[:, None]
     with jax.named_scope("moe_dispatch"):
-        xs = jnp.where(valid, m[token], 0)
+        xs = take_rows(m, order[:rows] // top_k, inv, n_live, top_k)
     with jax.named_scope("moe_experts"):
         h = grouped_matmul(xs, w_in, sizes, implementation)
         gate, up = jnp.split(jnp.where(valid, h, 0), 2, axis=-1)
         y = grouped_matmul(nn.silu(gate) * up, w_out, sizes, implementation)
     with jax.named_scope("moe_combine"):
-        y = jnp.where(valid, y, 0).astype(jnp.float32) \
-            * pair_weight[pair][:, None]
-        return jax.ops.segment_sum(y, token, num_segments=tokens) \
-            .astype(dtype)
+        return sum_rows(y, pair_weight, order, inv, n_live, top_k)
 
 
 class SigmoidTopKRouter(nn.Module):
@@ -351,11 +443,16 @@ class HeldExpertsMlp(nn.Module):
     whole layer; the exchange between ranks is not here.
 
     **Dropless over the experts held.** The (token, choice) pairs are sorted
-    by expert with the held experts first, their rows gathered, multiplied
-    expert by expert in one grouped matmul over the sorted rows, and summed
-    back into their tokens. The row buffer is static: twice what a uniform
-    router would send (``_BUFFER_SHARE``), and where a step's routing sends more
-    (``lax.cond`` on the count) a second buffer of every pair,
+    by expert with the held experts first, their rows gathered
+    (:func:`take_rows`), multiplied expert by expert in one grouped matmul
+    over the sorted rows, and summed back into their tokens by a second
+    gather (:func:`sum_rows`: the sort is a permutation, so every token reads
+    its ``k`` rows through the inverse and adds them in float32; each
+    gather's backward pass is the other, so no row is scatter-added in
+    either direction; ``moe.rows.calls`` counts the calls). The row buffer is
+    static: twice what a uniform router would send (``_BUFFER_SHARE``), and
+    where a step's routing sends more (``lax.cond`` on the count) a second
+    buffer of every pair,
     ``tokens * k`` rows, takes the step; where twice a uniform router's rows
     are every pair (one choice a token, half of the experts held) there is
     the one buffer. Either is recomputed in the backward pass, so that no
@@ -411,6 +508,13 @@ class HeldExpertsMlp(nn.Module):
             sizes = jnp.sum(group[:, None] == jnp.arange(count)[None, :],
                             axis=0, dtype=jnp.int32)
             n_held = jnp.sum(sizes)
+            inv = inverse_permutation(order)
+        # Rows go to the buffer and back by gathers through ``order`` and
+        # ``inv``; counted here, once a layer call, as the path is static.
+        get_tracer().registry.counter(
+            "moe.rows.calls",
+            "expert-layer calls traced, by the way their rows move",
+        ).inc(path="gather")
 
         w_in = ExpertStack(count, f, 2 * self.mlp_dim, self.dtype,
                            name="experts_in")()
@@ -423,7 +527,7 @@ class HeldExpertsMlp(nn.Module):
             _held_rows, rows=rows, top_k=k,
             implementation=self.implementation))
         usual = _whole_tiles(int(_BUFFER_SHARE * pairs * count / e))
-        operands = (m, pair_weight, order, sizes, n_held, w_in, w_out)
+        operands = (m, pair_weight, order, inv, sizes, n_held, w_in, w_out)
         if usual >= pairs:
             y = part(pairs)(*operands)
         else:

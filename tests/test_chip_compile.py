@@ -137,6 +137,27 @@ def test_rope_kernel_compiles_for_v5e(v5e_chip, name, heads, rope, what):
     assert ("rope_fwd" if what == "forward" else "rope_bwd") in text
 
 
+def _row_scatters(text):
+    """The instructions of a compiled step that scatter rows of 2048 under
+    an expert layer's ``moe_dispatch`` or ``moe_combine``: the rows go to the
+    buffer and back by gathers (``models/moe.py:take_rows``, ``sum_rows``),
+    so there are none; what is still scattered there is integers."""
+    import re
+
+    return [line.strip()[:200] for line in text.splitlines()
+            if re.search(r"= \w+\[\d+,2048\]\S* scatter\(", line)
+            and re.search(r"/moe_(dispatch|combine)/", line)]
+
+
+def _rows_calls(since=None):
+    """``moe.rows.calls`` by path, less what it read at ``since``."""
+    from deeplearning_cfn_tpu.obs.trace import get_tracer
+
+    calls = get_tracer().registry.counter("moe.rows.calls")
+    return {path: calls.value(path=path) - (since[path] if since else 0)
+            for path in ("gather", "scatter_add")}
+
+
 def test_laguna_step_compiles_and_fits_a_v5e(v5e_chip):
     """The whole train step of ``laguna_xs2_train_4k`` at the cell's shapes
     (``benchmark/rehearse_compile.py``, the builder's rehearsal): the chip's
@@ -158,8 +179,12 @@ def test_laguna_step_compiles_and_fits_a_v5e(v5e_chip):
 
     calls = get_tracer().registry.counter("attention.rope.calls")
     before = {path: calls.value(path=path) for path in ("kernel", "xla")}
+    rows_before = _rows_calls()
     cell = manifest.Cell(manifest.load_manifest(), "laguna_xs2_train_4k")
     _, compiled, _ = rehearse_compile.compile_step(cell)
+    # Four expert layers, each traced twice, every one moving its rows by
+    # gathers under either buffer.
+    assert _rows_calls(rows_before) == {"gather": 8, "scatter_add": 0}
     # ``compile_step`` traces the model twice, once for the parameters'
     # shapes and once in the step: each trace turns q and k of five layers.
     assert {path: calls.value(path=path) - n
@@ -171,6 +196,8 @@ def test_laguna_step_compiles_and_fits_a_v5e(v5e_chip):
     # 5 forward and 10 backward flash kernels, and the grouped matmuls.
     text = compiled.as_text()
     assert text.count("tpu_custom_call") > 15
+    assert "/moe_dispatch/" in text and "/moe_combine/" in text
+    assert _row_scatters(text) == []
     # q and k of five layers, turned forward and back by the kernel, which
     # keeps the scope that ``blocks_ms`` counts it under.
     kernels = [line for line in text.splitlines()
@@ -206,8 +233,10 @@ def test_zaya1_step_compiles_and_fits_a_v5e(v5e_chip):
     turned = registry.counter("attention.rope.calls")
     before = (mixed.value(), turned.value(path="kernel"),
               turned.value(path="xla"))
+    rows_before = _rows_calls()
     cell = manifest.Cell(manifest.load_manifest(), "zaya1_8b_train_4k")
     _, compiled, _ = rehearse_compile.compile_step(cell)
+    assert _rows_calls(rows_before) == {"gather": 10, "scatter_add": 0}
     # Traced twice (the parameters' shapes, the step), five layers each.
     assert (mixed.value() - before[0], turned.value(path="kernel")
             - before[1], turned.value(path="xla") - before[2]) == (10, 20, 0)
@@ -232,3 +261,5 @@ def test_zaya1_step_compiles_and_fits_a_v5e(v5e_chip):
     assert sum("/moe_experts/jit(" in line and "/mlp/" in line
                for line in kernels) == 40
     assert "/self_attn/cca_mix/" in text and "/mlp/moe_router/" in text
+    assert "/moe_dispatch/" in text and "/moe_combine/" in text
+    assert _row_scatters(text) == []

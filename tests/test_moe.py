@@ -7,6 +7,8 @@ and the expert-parallel mesh proven numerically invisible vs pure DP while
 the expert weights are asserted actually sharded.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -283,3 +285,131 @@ def test_held_experts_layer_keeps_its_first_routers_names():
     text = str(jax.make_jaxpr(lambda p: second.apply({"params": p}, x,
                                                      state))(params))
     assert " cond[" not in text
+
+
+# -- rows to the buffer and back: take_rows and sum_rows ----------------------
+
+_TOKENS, _EXPERTS, _WIDTH = 24, 8, 16
+# name -> (experts held, what the buffer holds beside the rows held; None is
+# the buffer of every pair)
+_BUFFERS = {
+    "live_rows_under_the_buffer": (4, 5),
+    "live_rows_exactly_at_the_buffer": (4, 0),
+    "the_buffer_of_every_pair": (4, None),
+    "every_pair_held": (_EXPERTS, None),
+    # Two experts are held, and the routing draws from the others alone.
+    "no_row_held": (0, 8),
+}
+
+
+def _sorted_routing(seed, top_k, held):
+    """A token's ``top_k`` choices, distinct experts, and what the layer
+    makes of them: ``(chosen, group, order, inv, n_held)``. ``held`` 0 holds
+    two experts that no token chooses."""
+    from deeplearning_cfn_tpu.models.moe import inverse_permutation
+
+    rng = np.random.RandomState(seed)
+    count = held or 2
+    drawn_from = _EXPERTS if held else _EXPERTS - count
+    chosen = np.stack([rng.permutation(drawn_from)[:top_k]
+                       for _ in range(_TOKENS)])
+    if not held:
+        chosen = chosen + count
+    group = jnp.minimum(jnp.asarray(chosen.reshape(-1), jnp.int32), count)
+    order = jnp.argsort(group, stable=True).astype(jnp.int32)
+    return chosen, group, order, inverse_permutation(order), \
+        int(jnp.sum(group < count))
+
+
+@pytest.mark.parametrize("top_k", [1, 4])
+@pytest.mark.parametrize("buffer", sorted(_BUFFERS))
+def test_rows_move_as_the_plain_forms_move_them(buffer, top_k):
+    """``take_rows`` and ``sum_rows`` against what they replace, the masked
+    gather ``where(valid, m[token], 0)`` and ``segment_sum`` of the masked,
+    weighted rows, in value and in every gradient (``m``; ``y`` and the
+    weights), in float32."""
+    from deeplearning_cfn_tpu.models.moe import sum_rows, take_rows
+
+    held, spare = _BUFFERS[buffer]
+    chosen, group, order, inv, n_held = _sorted_routing(7, top_k, held)
+    pairs = _TOKENS * top_k
+    rows = pairs if spare is None else n_held + spare
+    assert 0 < rows <= pairs and 0 <= n_held <= pairs
+    if held in (0, _EXPERTS):
+        assert n_held == (pairs if held else 0)
+    # A token's choices are distinct experts, so no group holds a token
+    # twice: a token's live rows are as many as the held experts it chose.
+    assert all(len(set(row)) == top_k for row in chosen.tolist())
+    pair = order[:rows]
+    token = pair // top_k
+    n_live = jnp.minimum(n_held, rows)
+    valid = (jnp.arange(rows) < n_live)[:, None]
+    keys = jax.random.split(jax.random.PRNGKey(3), 5)
+    m = jax.random.normal(keys[0], (_TOKENS, _WIDTH))
+    y = jax.random.normal(keys[1], (rows, _WIDTH))
+    weight = jax.random.uniform(keys[2], (pairs,))
+    d_xs = jax.random.normal(keys[3], (rows, _WIDTH))
+    d_out = jax.random.normal(keys[4], (_TOKENS, _WIDTH))
+
+    def plain_take(m):
+        return jnp.where(valid, m[token], 0)
+
+    def plain_sum(y, weight):
+        return jax.ops.segment_sum(
+            jnp.where(valid, y, 0) * weight[pair][:, None], token,
+            num_segments=_TOKENS)
+
+    want, back = jax.vjp(plain_take, m)
+    got, back_got = jax.vjp(
+        lambda m: take_rows(m, token, inv, n_live, top_k), m)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_allclose(back_got(d_xs)[0], back(d_xs)[0], atol=1e-5)
+
+    want, back = jax.vjp(plain_sum, y, weight)
+    got, back_got = jax.vjp(
+        lambda y, w: sum_rows(y, w, order, inv, n_live, top_k), y, weight)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    for a, b in zip(back_got(d_out), back(d_out)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(a, b, atol=1e-5)
+    if not n_held:
+        assert not np.any(got) and not np.any(back_got(d_out)[0])
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("top_k,held", [(1, 4), (4, 4), (2, 0), (8, 8)])
+def test_inverse_of_the_sort_and_where_dead_pairs_lie(top_k, held, seed):
+    _, group, order, inv, n_held = _sorted_routing(seed, top_k, held)
+    pairs = _TOKENS * top_k
+    np.testing.assert_array_equal(inv[order], np.arange(pairs))
+    np.testing.assert_array_equal(order[inv], np.arange(pairs))
+    dead = np.asarray(group) == (held or 2)
+    assert dead.sum() == pairs - n_held
+    assert np.all(np.asarray(inv)[dead] >= n_held)
+    assert np.all(np.asarray(inv)[~dead] < n_held)
+
+
+@pytest.mark.parametrize("top_k,held,branches", [(1, 4, 0), (2, 2, 1)])
+def test_held_experts_layer_counts_a_call_once(top_k, held, branches):
+    """``moe.rows.calls`` counts a layer call when it is traced: once, not
+    once a branch of the ``lax.cond`` over the two buffers nor again where
+    ``jax.checkpoint`` traces the rows' part for the backward pass."""
+    from deeplearning_cfn_tpu.models.moe import HeldExpertsMlp
+    from deeplearning_cfn_tpu.obs.trace import get_tracer
+
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 16, 48))
+    layer = HeldExpertsMlp(num_experts=8, mlp_dim=16, top_k=top_k,
+                           held=(0, held), dtype=jnp.float32)
+    params = layer.init(jax.random.PRNGKey(1), x)["params"]
+    calls = get_tracer().registry.counter("moe.rows.calls")
+    before = {path: calls.value(path=path)
+              for path in ("gather", "scatter_add")}
+    loss = lambda p: jnp.sum(layer.apply({"params": p}, x)[0] ** 2)
+    text = str(jax.make_jaxpr(jax.grad(loss))(params))
+    assert {path: calls.value(path=path) - n for path, n in before.items()} \
+        == {"gather": 1, "scatter_add": 0}
+    assert (" cond[" in text) == bool(branches)
+    # No row-wide scatter-add either way: what is scattered is integers
+    # (the sort's inverse) or a pair's scalar (the router's top-k).
+    assert "scatter" in text
+    assert not re.findall(r",48\] = scatter", text)
