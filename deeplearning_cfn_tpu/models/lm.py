@@ -57,7 +57,13 @@ class TransformerCausalLm(nn.Module):
     expert layer among them ``__call__`` returns ``(logits, aux)``, ``aux``
     what the expert layers counted; a router's state goes from each expert
     layer to the next one here. ``tie_embeddings=False`` gives the output
-    head a matrix of its own (``lm_head/kernel``)."""
+    head a matrix of its own (``lm_head/kernel``).
+
+    ``mesh`` is the mesh the step is compiled for (``CausalLmTask`` hands it
+    on): on more than one device the blocks' Pallas kernels run under a
+    ``shard_map`` over its batch axes and an expert layer exchanges tokens
+    over its ``expert`` axis (``parallel/kernels.py``, ``models/moe.py``);
+    the parameter tree is the same on any mesh."""
 
     vocab_size: int
     hidden_size: int = 768
@@ -77,6 +83,7 @@ class TransformerCausalLm(nn.Module):
     moe_top_k: int = 2
     blocks: Tuple[Tuple[int, int, int, BlockStyle], ...] = ()
     tie_embeddings: bool = True
+    mesh: Any = None
 
     def _is_moe(self, i: int) -> bool:
         return is_moe_layer(i, self.num_experts, self.moe_every)
@@ -94,7 +101,8 @@ class TransformerCausalLm(nn.Module):
             self.layers = [
                 TransformerLayer(heads, mlp_dim, dtype=self.dtype,
                                  attention_impl=self.attention_impl,
-                                 style=style, name=f"layer_{index}")
+                                 style=style, mesh=self.mesh,
+                                 name=f"layer_{index}")
                 for index, heads, mlp_dim, style in self.blocks]
             self.final_norm = RMSNorm(self.blocks[-1][3].rms_eps, self.dtype)
             return
@@ -111,7 +119,7 @@ class TransformerCausalLm(nn.Module):
                 attention_impl=self.attention_impl,
                 num_experts=self.num_experts if self._is_moe(i) else 0,
                 moe_capacity_factor=self.moe_capacity_factor,
-                moe_top_k=self.moe_top_k,
+                moe_top_k=self.moe_top_k, mesh=self.mesh,
                 name=f"layer_{i}")
             for i in range(self.num_layers)
         ]
@@ -158,10 +166,12 @@ class TransformerCausalLm(nn.Module):
         logits = self._logits(self.final_norm(x))
         if not counted:
             return logits
-        return logits, {
-            "rows_held": sum(a["rows_held"] for a in counted),
-            "load_max_over_mean": jnp.max(jnp.stack(
-                [a["load_max_over_mean"] for a in counted]))}
+        worst = lambda name: jnp.max(jnp.stack([a[name] for a in counted]))
+        aux = {"rows_held": sum(a["rows_held"] for a in counted),
+               "load_max_over_mean": worst("load_max_over_mean")}
+        if "rank_load_max_over_mean" in counted[0]:
+            aux["rank_load_max_over_mean"] = worst("rank_load_max_over_mean")
+        return logits, aux
 
     def __call__(self, tokens, train: bool = False):
         if self.blocks:
@@ -340,7 +350,7 @@ def _grouped_matmul_for(attention_impl: str) -> str:
 
 
 def _laguna(sizes, dtype, vocab_size, layers_held, experts_held,
-            attention_impl):
+            attention_impl, mesh=None):
     """The ``laguna`` decoder at ``sizes``, or one chip's share of it:
     ``layers_held`` are the layers of this pipeline stage (None: all),
     ``experts_held`` the ``(first, count)`` of each layer's routed experts on
@@ -371,7 +381,7 @@ def _laguna(sizes, dtype, vocab_size, layers_held, experts_held,
 
     return TransformerCausalLm(
         vocab_size=vocab_size, hidden_size=z["hidden_size"], dtype=dtype,
-        attention_impl=attention_impl, tie_embeddings=False,
+        attention_impl=attention_impl, tie_embeddings=False, mesh=mesh,
         blocks=tuple(block(i) for i in layers))
 
 
@@ -379,21 +389,21 @@ def _laguna(sizes, dtype, vocab_size, layers_held, experts_held,
 def gpt_laguna_xs2(num_classes: int = 0, dtype=jnp.bfloat16, *,
                    vocab_size: int = 100_352, max_len: int = 4096,
                    layers_held=None, experts_held=None,
-                   attention_impl: str = "auto"):
+                   attention_impl: str = "auto", mesh=None):
     # Every width is the published one. num_classes and max_len are not
     # read (rotary positions have no table to size); they are accepted for
     # the registry's and CausalLmTask's sake.
     return _laguna(_LAGUNA_XS2, dtype, vocab_size, layers_held, experts_held,
-                   attention_impl)
+                   attention_impl, mesh)
 
 
 @register_model("gpt_laguna_tiny")
 def gpt_laguna_tiny(num_classes: int = 0, dtype=jnp.float32, *,
                     vocab_size: int = 96, max_len: int = 32,
                     layers_held=None, experts_held=None,
-                    attention_impl: str = "auto"):
+                    attention_impl: str = "auto", mesh=None):
     return _laguna(_LAGUNA_TINY, dtype, vocab_size, layers_held, experts_held,
-                   attention_impl)
+                   attention_impl, mesh)
 
 
 # ZAYA1-8B as Zyphra published it (config.json, `model_type: zaya`, 8.4 B
@@ -418,7 +428,7 @@ _ZAYA1_TINY = dict(
 
 
 def _zaya1(sizes, dtype, vocab_size, layers_held, experts_held,
-           attention_impl):
+           attention_impl, mesh=None):
     """The ``zaya`` decoder at ``sizes``, or one chip's share of it, told as
     :func:`_laguna` is: the layers of this pipeline stage, the ``(first,
     count)`` of each layer's experts on this rank, the vocabulary rows of
@@ -442,7 +452,7 @@ def _zaya1(sizes, dtype, vocab_size, layers_held, experts_held,
 
     return TransformerCausalLm(
         vocab_size=vocab_size, hidden_size=z["hidden_size"], dtype=dtype,
-        attention_impl=attention_impl, tie_embeddings=True,
+        attention_impl=attention_impl, tie_embeddings=True, mesh=mesh,
         blocks=tuple(block(i) for i in layers))
 
 
@@ -450,17 +460,93 @@ def _zaya1(sizes, dtype, vocab_size, layers_held, experts_held,
 def gpt_zaya1_8b(num_classes: int = 0, dtype=jnp.bfloat16, *,
                  vocab_size: int = 262_272, max_len: int = 4096,
                  layers_held=None, experts_held=None,
-                 attention_impl: str = "auto"):
+                 attention_impl: str = "auto", mesh=None):
     # Every width is the published one; num_classes and max_len are not read,
     # as in gpt_laguna_xs2.
     return _zaya1(_ZAYA1_8B, dtype, vocab_size, layers_held, experts_held,
-                  attention_impl)
+                  attention_impl, mesh)
 
 
 @register_model("gpt_zaya1_tiny")
 def gpt_zaya1_tiny(num_classes: int = 0, dtype=jnp.float32, *,
                    vocab_size: int = 96, max_len: int = 32,
                    layers_held=None, experts_held=None,
-                   attention_impl: str = "auto"):
+                   attention_impl: str = "auto", mesh=None):
     return _zaya1(_ZAYA1_TINY, dtype, vocab_size, layers_held, experts_held,
-                  attention_impl)
+                  attention_impl, mesh)
+
+
+# Mellum2-12B-A2.5B as JetBrains published it (config.json, `model_type:
+# mellum`): 28 layers of hidden size 2304 in periods of three sliding-window
+# layers (window 1024) and one full-attention layer, 32 query heads over 4
+# K/V heads of 128, rotary positions on the whole head at theta 500,000 (the
+# full layers with YaRN's frequencies: factor 16 over 8192 original
+# positions), every MLP 64 experts of width 896, 8 a token by softmax scores
+# normalised over the chosen, no shared expert, RMSNorm 1e-6, an untied head
+# over 98,304 tokens. benchmark/configs/mellum2_12b.json lists what the
+# source leaves unsaid and how it was read.
+_MELLUM2_12B = dict(
+    hidden_size=2304, num_layers=28, period=4, head_dim=128, heads=32,
+    kv_heads=4, window=1024, experts=64, top_k=8, expert_width=896,
+    full_rope=Rope(theta=500_000.0, yarn_factor=16.0, original_len=8192,
+                   beta_fast=32.0, beta_slow=1.0,
+                   attention_factor=1.2772588722239782),
+    sliding_rope=Rope(theta=500_000.0))
+# The same block at sizes a CPU test holds: sliding, sliding, sliding, full.
+_MELLUM2_TINY = dict(
+    hidden_size=64, num_layers=4, period=4, head_dim=16, heads=4,
+    kv_heads=2, window=8, experts=16, top_k=4, expert_width=32,
+    full_rope=Rope(theta=500_000.0, yarn_factor=4.0, original_len=16,
+                   beta_fast=2.0, beta_slow=1.0,
+                   attention_factor=1.1386294361119891),
+    sliding_rope=Rope(theta=500_000.0))
+
+
+def _mellum2(sizes, dtype, vocab_size, layers_held, experts_held,
+             attention_impl, mesh):
+    """The ``mellum`` decoder at ``sizes``, told as :func:`_laguna` is. A
+    layer is full-attention where it ends a period (index 3, 7, ...), else
+    sliding. ``experts_held`` are the experts the whole mesh holds (None:
+    all); an ``expert`` axis divides them among its ranks
+    (``models/moe.py:HeldExpertsMlp``)."""
+    z = sizes
+    layers = range(z["num_layers"]) if layers_held is None \
+        else tuple(layers_held)
+    first, count = experts_held or (0, z["experts"])
+    experts = (("num_experts", z["experts"]),
+               ("held", (int(first), int(count))),
+               ("implementation", _grouped_matmul_for(attention_impl)))
+    router = (("kind", "softmax_top_k"), ("top_k", z["top_k"]))
+
+    def block(i):
+        full = i % z["period"] == z["period"] - 1
+        return (i, z["heads"], z["expert_width"], BlockStyle(
+            num_kv_heads=z["kv_heads"], head_dim=z["head_dim"], rms_eps=1e-6,
+            rope=z["full_rope"] if full else z["sliding_rope"],
+            window=0 if full else z["window"], mlp="experts",
+            experts=experts, router=router))
+
+    return TransformerCausalLm(
+        vocab_size=vocab_size, hidden_size=z["hidden_size"], dtype=dtype,
+        attention_impl=attention_impl, tie_embeddings=False, mesh=mesh,
+        blocks=tuple(block(i) for i in layers))
+
+
+@register_model("gpt_mellum2_12b")
+def gpt_mellum2_12b(num_classes: int = 0, dtype=jnp.bfloat16, *,
+                    vocab_size: int = 98_304, max_len: int = 8192,
+                    layers_held=None, experts_held=None,
+                    attention_impl: str = "auto", mesh=None):
+    # Every width is the published one; num_classes and max_len are not read,
+    # as in gpt_laguna_xs2.
+    return _mellum2(_MELLUM2_12B, dtype, vocab_size, layers_held,
+                    experts_held, attention_impl, mesh)
+
+
+@register_model("gpt_mellum2_tiny")
+def gpt_mellum2_tiny(num_classes: int = 0, dtype=jnp.float32, *,
+                     vocab_size: int = 96, max_len: int = 32,
+                     layers_held=None, experts_held=None,
+                     attention_impl: str = "auto", mesh=None):
+    return _mellum2(_MELLUM2_TINY, dtype, vocab_size, layers_held,
+                    experts_held, attention_impl, mesh)

@@ -1,38 +1,40 @@
-"""Mixture-of-Experts FFN with expert parallelism over the 'expert' axis.
+"""Expert layers: the dropless layer the styled decoders use, and the 2020
+capacity layer the BERT/GPT trunks of ``moe_every`` keep.
 
-The reference has no MoE (its workloads predate it — SURVEY.md §3.2 lists
-EP as absent); this module extends the rebuild's parallelism inventory the
-TPU-native way: the GShard/Switch formulation, where routing is expressed
-as dense one-hot einsums over STATIC shapes — argmax + cumsum position
-assignment, a fixed per-expert capacity, dropped-token masking — so the
-whole layer compiles to MXU-friendly batched matmuls with no dynamic
-shapes, and GSPMD partitions the expert dim of the stacked expert weights
-over the mesh 'expert' axis (the all-to-all dispatch/combine collectives
-are compiler-inserted, the same way the data-parallel psum is).
+**:class:`HeldExpertsMlp`** is the expert layer of the current open models
+(Laguna, ZAYA1, Mellum2 through ``BlockStyle.mlp == "experts"``): a router
+module scores every token over all the experts, the (token, choice) pairs
+are sorted by expert, the rows of the experts held go through one grouped
+matmul (megablox's Pallas ``gmm`` / ``tgmm`` on a TPU) and are summed back
+into their tokens; rows move by gathers through the sort's permutation and
+its inverse (:func:`take_rows`, :func:`sum_rows`), and no row is dropped
+whatever the routing. Which experts are held is static (``held``); on one
+device the layer computes their part of the result and nothing else (one
+expert-parallel rank of a pod, the exchange not run). **On a mesh whose
+``expert`` axis has R > 1 devices the held experts are divided R ways, the
+stacks sharded on their rows, and the exchange between the ranks is run**:
+every rank gathers the R ranks' tokens with their choices (``all_gather``),
+computes its own experts' parts for all of them, and the parts are summed in
+float32 back to the ranks the tokens came from (``psum_scatter``), all
+inside one ``shard_map`` (``parallel/kernels.py``), under the scopes
+``moe_exchange_in`` / ``moe_exchange_out``. The routers: one matrix with
+sigmoid scores (:class:`SigmoidTopKRouter`) or softmax scores
+(:class:`SoftmaxTopKRouter`), the chosen normalised; an MLP with memory and
+a balancing bias that a controller moves (:class:`MlpStateRouter`).
 
-Design notes:
-- Router runs in float32 (standard practice: bf16 router logits make
-  top-k selection noisy near ties).
-- Top-k routing (default 2, the GShard choice) with first-choice priority:
-  choice-k tokens only claim capacity left over by choices < k.
-- Load-balance aux loss (Switch form: E * sum_e f_e * p_e, where f_e is
-  the fraction of tokens whose FIRST choice is e and p_e the mean router
-  probability) plus a router z-loss (ST-MoE) for logit stability. Both are
-  returned to the caller, which owns the weighting into the total loss —
-  they are per-token means, so they stay correct under a sharded batch.
-- Expert weights are stacked [E, ...] and sharded over 'expert' by
-  MOE_PARAM_RULES; the token tensors stay batch-sharded (the 'expert' mesh
-  axis also carries batch shards outside this layer — see
-  parallel/mesh.py BATCH_AXES), so GSPMD inserts the dispatch/combine
-  resharding only around the expert einsums.
-- No dropout inside the expert MLP: the capacity-drop mechanism already
-  regularizes token→expert assignment, and keeping the expert compute a
-  pure pair of einsums lets XLA fuse the activation into the matmuls.
+**:class:`MoeMlp`** is the GShard/Switch formulation (``num_experts`` /
+``moe_every`` of the 2018 trunks): routing as dense one-hot einsums over
+static shapes, a fixed capacity an expert, dropped tokens masked, a
+load-balance and a router-z loss returned to the caller; its stacked
+weights ``[E, ...]`` are sharded over ``expert`` by ``MOE_PARAM_RULES`` and
+GSPMD inserts the dispatch and combine collectives. No benchmark cell runs
+it.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import flax.linen as nn
@@ -41,17 +43,21 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..obs.trace import get_tracer
+from ..parallel.kernels import batch_axes_of, rows_spec, shard_rows
 
 Dtype = Any
 
 # Param-path rules for the 'expert' mesh axis (see
 # parallel.sharding.param_sharding_tree): stacked expert weights shard
-# their leading expert dim; the router stays replicated.
+# their leading expert dim; the router stays replicated. An ``ExpertStack``
+# is one matrix ``[experts * d_in, d_out]`` whose rows are an expert's after
+# an expert's: sharded on its rows, each rank holds whole experts.
 MOE_PARAM_RULES = (
     (r"moe_mlp/w_in", P("expert", None, None)),
     (r"moe_mlp/w_out", P("expert", None, None)),
     (r"moe_mlp/b_in", P("expert", None)),
     (r"moe_mlp/b_out", P("expert", None)),
+    (r"mlp/experts_(in|out)/kernel", P("expert", None)),
 )
 
 
@@ -305,23 +311,26 @@ def _take_rows_bwd(top_k, kept, d_xs):
 take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def sum_rows(y, weight, order, inv, n_live, top_k: int):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def sum_rows(y, weight, order, inv, n_live, top_k: int, out_dtype=None):
     """``out[t] = sum over j of weight[t k + j] * y[inv[t k + j]]`` over the
-    pairs whose row is live, products and sum in float32, in ``y``'s dtype:
-    every row of the buffer added into its token by a gather through the
-    sort's inverse. Its transpose is :func:`take_rows` times the weights:
-    ``d y[r] = weight[order[r]] * d out[token[r]]``, and ``d weight[p]`` is
-    the dot of pair ``p``'s row with its token's cotangent."""
-    return _rows_of_tokens(y, weight, inv, n_live, top_k).astype(y.dtype)
+    pairs whose row is live, products and sum in float32, in ``y``'s dtype
+    (or ``out_dtype``: float32 where the ranks' parts are still to be
+    summed): every row of the buffer added into its token by a gather
+    through the sort's inverse. Its transpose is :func:`take_rows` times the
+    weights: ``d y[r] = weight[order[r]] * d out[token[r]]``, and
+    ``d weight[p]`` is the dot of pair ``p``'s row with its token's
+    cotangent."""
+    return _rows_of_tokens(y, weight, inv, n_live, top_k).astype(
+        out_dtype or y.dtype)
 
 
-def _sum_rows_fwd(y, weight, order, inv, n_live, top_k):
-    return sum_rows(y, weight, order, inv, n_live, top_k), \
+def _sum_rows_fwd(y, weight, order, inv, n_live, top_k, out_dtype):
+    return sum_rows(y, weight, order, inv, n_live, top_k, out_dtype), \
         (y, weight, order, inv, n_live)
 
 
-def _sum_rows_bwd(top_k, kept, d_out):
+def _sum_rows_bwd(top_k, out_dtype, kept, d_out):
     y, weight, order, inv, n_live = kept
     rows = y.shape[0]
     pair = order[:rows]
@@ -337,7 +346,7 @@ sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
 
 
 def _held_rows(m, pair_weight, order, inv, sizes, n_held, w_in, w_out, *,
-               rows: int, top_k: int, implementation: str):
+               rows: int, top_k: int, implementation: str, out_dtype=None):
     """The held experts' part of the layer's result from a buffer of ``rows``
     rows: the first ``rows`` (token, choice) pairs in ``order`` (sorted by
     expert, those of held experts first, ``n_held`` of them); ``inv`` is
@@ -351,7 +360,92 @@ def _held_rows(m, pair_weight, order, inv, sizes, n_held, w_in, w_out, *,
         gate, up = jnp.split(jnp.where(valid, h, 0), 2, axis=-1)
         y = grouped_matmul(nn.silu(gate) * up, w_out, sizes, implementation)
     with jax.named_scope("moe_combine"):
-        return sum_rows(y, pair_weight, order, inv, n_live, top_k)
+        return sum_rows(y, pair_weight, order, inv, n_live, top_k, out_dtype)
+
+
+def _in_passes(part, rows: int, m, pair_weight, order, inv, sizes, n_held,
+               w_in, w_out):
+    """The second buffer of a rank that gathers its ranks' tokens: not one
+    buffer of every pair (R times a lone rank's, 4.3 GiB more of
+    temporaries in the Mellum2 cell by the chip's compiler) but the usual
+    one again, a window of ``rows`` sorted rows at a time until every pair
+    is taken, the windows' results added in float32. ``part`` is
+    :func:`_held_rows` at ``rows``; a window sees its own slice of
+    ``order``, where that slice put each pair, and each group's rows inside
+    it."""
+    pairs = order.shape[0]
+    passes = -(-pairs // rows)
+    order = jnp.pad(order, (0, passes * rows - pairs))
+    ends = jnp.cumsum(sizes)
+
+    def one(out, lo):
+        inside = lambda at: jnp.clip(at, lo, lo + rows)
+        return out + part(
+            m, pair_weight, jax.lax.dynamic_slice(order, (lo,), (rows,)),
+            jnp.where(inv >= lo, inv - lo, pairs),
+            inside(ends) - inside(ends - sizes),
+            jnp.clip(n_held - lo, 0, rows), w_in, w_out), None
+
+    return jax.lax.scan(one, jnp.zeros(m.shape, jnp.float32),
+                        jnp.arange(passes, dtype=jnp.int32) * rows)[0]
+
+
+def _rank_part(m, chosen, weight, w_in, w_out, *, num_experts: int,
+               first: int, ranks: int, implementation: str):
+    """What one rank adds to the layer's result: ``m [T, F]`` its own tokens
+    with their ``chosen [T, k]`` experts and ``weight [T, k]``, ``w_in`` /
+    ``w_out`` the stacks of the experts it holds, ``first`` the first of
+    them where there is one rank. Returns ``(y [T, F], sizes [1, held])``:
+    the rows each held expert took.
+
+    With ``ranks`` > 1 (inside the ``shard_map``, the ``expert`` axis
+    manual) the rank's first expert follows its index on the axis, and the
+    exchange is here: the ranks' tokens, choices and weights are gathered,
+    so that every rank computes its own experts' parts for all ``ranks * T``
+    tokens, and the float32 parts are summed over the ranks and scattered
+    back, each token's sum to the rank it came from. At 8 choices over 4
+    ranks a token goes to 3.6 of them: the gather moves no more bytes than
+    an all-to-all of rows would, and the rows' sort and gathers stay what
+    they are on one rank. **Dropless**, and no rank computes another's
+    experts."""
+    e, count = num_experts, w_in.shape[0]
+    if ranks > 1:
+        with jax.named_scope("moe_exchange_in"):
+            m, chosen, weight = (
+                jax.lax.all_gather(t, "expert", axis=0, tiled=True)
+                for t in (m, chosen, weight))
+        first = first + jax.lax.axis_index("expert") * count
+    k = chosen.shape[-1]
+    pairs = chosen.size
+    with jax.named_scope("moe_dispatch"):
+        # Held experts become groups 0 .. count - 1, every other expert
+        # the group ``count``, which sorts last and is never computed.
+        group = jnp.minimum((chosen.reshape(-1) - first) % e, count)
+        order = jnp.argsort(group, stable=True).astype(jnp.int32)
+        sizes = jnp.sum(group[:, None] == jnp.arange(count)[None, :],
+                        axis=0, dtype=jnp.int32)
+        n_held = jnp.sum(sizes)
+        inv = inverse_permutation(order)
+    # Recomputed in the backward pass: little arithmetic, and the row
+    # buffers (0.3 GB a layer at the usual size, four times that at the
+    # other) are then never kept.
+    part = lambda rows: jax.checkpoint(functools.partial(
+        _held_rows, rows=rows, top_k=k, implementation=implementation,
+        out_dtype=jnp.float32 if ranks > 1 else None))
+    usual = _whole_tiles(int(_BUFFER_SHARE * pairs * count / e))
+    operands = (m, weight.reshape(-1), order, inv, sizes, n_held, w_in,
+                w_out)
+    if usual >= pairs:
+        y = part(pairs)(*operands)
+    else:
+        second = part(pairs) if ranks == 1 else functools.partial(
+            _in_passes, part(usual), usual)
+        y = jax.lax.cond(n_held <= usual, part(usual), second, *operands)
+    if ranks > 1:
+        with jax.named_scope("moe_exchange_out"):
+            y = jax.lax.psum_scatter(y, "expert", scatter_dimension=0,
+                                     tiled=True).astype(m.dtype)
+    return y, sizes[None]
 
 
 class SigmoidTopKRouter(nn.Module):
@@ -373,6 +467,27 @@ class SigmoidTopKRouter(nn.Module):
         top, chosen = jax.lax.top_k(jax.nn.sigmoid(logits), self.top_k)
         return chosen, self.routed_scale * top \
             / jnp.sum(top, axis=-1, keepdims=True), None
+
+
+class SoftmaxTopKRouter(nn.Module):
+    """One matrix, a softmax over all the experts, the ``top_k`` largest,
+    normalised (``norm_topk_prob``, the Qwen3-MoE convention): ``p =
+    softmax(x W_r)`` in float32; ``w_e = p_e / sum over the chosen``. It keeps
+    no state."""
+
+    num_experts: int
+    top_k: int
+
+    @nn.compact
+    def __call__(self, m, state=None):
+        del state
+        kernel = self.param("kernel", nn.initializers.xavier_uniform(),
+                            (m.shape[-1], self.num_experts), jnp.float32)
+        logits = jnp.dot(m.astype(jnp.float32), kernel,
+                         precision=jax.lax.Precision.HIGHEST)
+        top, chosen = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                    self.top_k)
+        return chosen, top / jnp.sum(top, axis=-1, keepdims=True), None
 
 
 class MlpStateRouter(nn.Module):
@@ -429,18 +544,38 @@ class MlpStateRouter(nn.Module):
 
 
 class HeldExpertsMlp(nn.Module):
-    """The expert layer of one expert-parallel rank. It is told the
-    ``num_experts`` experts of the whole layer, which of them it ``held``
-    (``(first, count)``; ``count`` 0 is all of them) and its ``router``, a
-    module ``(tokens [T, F], state) -> (chosen [T, k], weight [T, k], state')``
-    that scores every token over all the experts, held or not. Either a
-    router or ``top_k`` (with ``routed_scale``), never both: told ``top_k``
-    the layer makes the :class:`SigmoidTopKRouter` of them itself. It returns
-    the held experts' part of the layer's result, ``sum over chosen and held
-    e of w_e E_e(x)``, each ``E`` a gated MLP ``(silu(x W1) * x W3) W2`` of
-    width ``mlp_dim``, plus the shared expert's where ``shared_dim`` > 0.
-    Summed over the ranks (the shared expert counted once) the parts are the
-    whole layer; the exchange between ranks is not here.
+    """The expert layer of a styled block. It is told the ``num_experts``
+    experts of the whole layer, which of them are ``held`` here (``(first,
+    count)``; ``count`` 0 is all of them) and its ``router``, a module
+    ``(tokens [T, F], state) -> (chosen [T, k], weight [T, k], state')`` that
+    scores every token over all the experts, held or not. Either a router or
+    ``top_k`` (with ``routed_scale``), never both: told ``top_k`` the layer
+    makes the :class:`SigmoidTopKRouter` of them itself. It returns the held
+    experts' part of the layer's result, ``sum over chosen and held e of w_e
+    E_e(x)``, each ``E`` a gated MLP ``(silu(x W1) * x W3) W2`` of width
+    ``mlp_dim``, plus the shared expert's where ``shared_dim`` > 0.
+
+    **One device** (no ``mesh``, or a mesh of one): the layer is one
+    expert-parallel rank of a larger deployment. Summed over such ranks (the
+    shared expert counted once) the parts are the whole layer; the exchange
+    with the other ranks is not run and nothing stands in for it.
+
+    **A mesh** (``mesh``, the step's): the rows' part of the layer (sort,
+    gathers, grouped matmuls) runs under one ``shard_map`` over the mesh's
+    batch axes (:func:`_rank_part` through ``parallel/kernels.py``), every
+    device on its own tokens. Where the ``expert`` axis has R > 1 devices the
+    held experts are divided R ways (``count`` a multiple of R; the stacks'
+    rows sharded by ``MOE_PARAM_RULES``, rank r holding experts ``first + r
+    count / R`` on), and **the exchange between the ranks is run**: an
+    ``all_gather`` of the R ranks' tokens, choices and weights under
+    ``moe_exchange_in``, each rank's own experts over all of them, a float32
+    ``psum_scatter`` of the parts under ``moe_exchange_out``; the backward
+    pass is the transposes (a gather of the cotangents, a scatter-sum of the
+    tokens'). ``moe.exchange.calls`` (``path=all_gather``, ``ranks=R``)
+    counts the layer calls traced that way and the gauge
+    ``moe.exchange.bytes`` is what a rank sends a layer a step, forward and
+    backward. The router, the shared expert and the parameters stay outside,
+    data-parallel under the partitioner like the rest of the block.
 
     **Dropless over the experts held.** The (token, choice) pairs are sorted
     by expert with the held experts first, their rows gathered
@@ -451,17 +586,22 @@ class HeldExpertsMlp(nn.Module):
     gather's backward pass is the other, so no row is scatter-added in
     either direction; ``moe.rows.calls`` counts the calls). The row buffer is
     static: twice what a uniform router would send (``_BUFFER_SHARE``), and
-    where a step's routing sends more (``lax.cond`` on the count) a second
-    buffer of every pair,
-    ``tokens * k`` rows, takes the step; where twice a uniform router's rows
-    are every pair (one choice a token, half of the experts held) there is
-    the one buffer. Either is recomputed in the backward pass, so that no
-    buffer is kept. No row is dropped whatever the routing.
+    where a step's routing sends more (``lax.cond`` on the count, a rank's
+    own) a second buffer of every pair, ``tokens * k`` rows, takes the step
+    (with an exchange, where a rank's pairs are every rank's: the usual
+    buffer again, window after window of the sorted rows, :func:`_in_passes`);
+    where twice a uniform router's rows are every pair (one choice a token,
+    half of the experts held) there is the one buffer. Either is recomputed
+    in the backward pass, so that no buffer is kept. No row is dropped
+    whatever the routing.
 
-    Returns ``(y, aux)``: ``aux["rows_held"]`` the rows routed to held
-    experts, ``aux["load_max_over_mean"]`` the fullest held expert's rows
-    over the mean, and, where the router keeps a state, ``aux["router_state"]``
-    ``[B, S, hidden]``: what the next layer's router is to be given as
+    Returns ``(y, aux)``: ``aux["rows_held"]`` the rows routed to a rank's
+    experts (the mean over the ranks), ``aux["load_max_over_mean"]`` the
+    fullest held expert's rows over the mean of its rank (the worst rank's),
+    with an exchange also ``aux["rank_load_max_over_mean"]``, the fullest
+    rank's rows over the mean rank's (the step waits for that rank), and,
+    where the router keeps a state, ``aux["router_state"]`` ``[B, S,
+    hidden]``: what the next layer's router is to be given as
     ``router_state``."""
 
     num_experts: int
@@ -473,6 +613,7 @@ class HeldExpertsMlp(nn.Module):
     dtype: Dtype = jnp.bfloat16
     implementation: str = "auto"
     router: Optional[nn.Module] = None
+    mesh: Any = None
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, router_state=None
@@ -484,6 +625,12 @@ class HeldExpertsMlp(nn.Module):
         first, count = self.held if self.held[1] else (0, e)
         if not 0 < count <= e:
             raise ValueError(f"held {self.held}, experts {e}")
+        # Initialisation traces one row and is not partitioned.
+        mesh = None if self.is_initializing() else self.mesh
+        axes = batch_axes_of(mesh)
+        ranks = mesh.shape.get("expert", 1) if axes else 1
+        if count % ranks:
+            raise ValueError(f"{count} held experts over {ranks} ranks")
         m = x.reshape(b * s, f).astype(self.dtype)
         if (self.router is None) == (self.top_k == 0):
             raise ValueError("an expert layer is given a router or top_k, "
@@ -497,50 +644,53 @@ class HeldExpertsMlp(nn.Module):
             chosen, weight, state = router(
                 m, None if router_state is None
                 else router_state.reshape(b * s, -1))
-            k = chosen.shape[-1]
-            pair_weight = weight.reshape(-1)
-        pairs = b * s * k
-        with jax.named_scope("moe_dispatch"):
-            # Held experts become groups 0 .. count - 1, every other expert
-            # the group ``count``, which sorts last and is never computed.
-            group = jnp.minimum((chosen.reshape(-1) - first) % e, count)
-            order = jnp.argsort(group, stable=True).astype(jnp.int32)
-            sizes = jnp.sum(group[:, None] == jnp.arange(count)[None, :],
-                            axis=0, dtype=jnp.int32)
-            n_held = jnp.sum(sizes)
-            inv = inverse_permutation(order)
-        # Rows go to the buffer and back by gathers through ``order`` and
-        # ``inv``; counted here, once a layer call, as the path is static.
-        get_tracer().registry.counter(
+        # Rows go to the buffer and back by gathers through the sort's
+        # permutation and its inverse; counted here, once a layer call, as
+        # the path is static.
+        registry = get_tracer().registry
+        registry.counter(
             "moe.rows.calls",
             "expert-layer calls traced, by the way their rows move",
         ).inc(path="gather")
+        if ranks > 1:
+            registry.counter(
+                "moe.exchange.calls",
+                "expert-layer calls traced that exchange tokens between "
+                "expert-parallel ranks, by the collective and the ranks",
+            ).inc(path="all_gather", ranks=str(ranks))
+            # Forward a rank sends its tokens in the layer's dtype and the
+            # other ranks' parts in float32; backward the transposes.
+            registry.gauge(
+                "moe.exchange.bytes",
+                "bytes a rank sends in an expert layer's exchange, a layer "
+                "a step, forward and backward",
+            ).set(2 * (ranks - 1) * f * (jnp.dtype(self.dtype).itemsize + 4)
+                  * (b * s // math.prod(mesh.shape[a] for a in axes)))
 
         w_in = ExpertStack(count, f, 2 * self.mlp_dim, self.dtype,
                            name="experts_in")()
         w_out = ExpertStack(count, self.mlp_dim, f, self.dtype,
                             name="experts_out")()
-        # Recomputed in the backward pass: little arithmetic, and the row
-        # buffers (0.3 GB a layer at the usual size, four times that at the
-        # other) are then never kept.
-        part = lambda rows: jax.checkpoint(functools.partial(
-            _held_rows, rows=rows, top_k=k,
-            implementation=self.implementation))
-        usual = _whole_tiles(int(_BUFFER_SHARE * pairs * count / e))
-        operands = (m, pair_weight, order, inv, sizes, n_held, w_in, w_out)
-        if usual >= pairs:
-            y = part(pairs)(*operands)
-        else:
-            y = jax.lax.cond(n_held <= usual, part(usual), part(pairs),
-                             *operands)
+        rows = rows_spec(axes, 2)
+        stack = P("expert") if ranks > 1 else P()
+        y, sizes = shard_rows(
+            functools.partial(_rank_part, num_experts=e, first=first,
+                              ranks=ranks,
+                              implementation=self.implementation),
+            mesh, "gmm", (rows, rows, rows, stack, stack),
+            (rows, rows))(m, chosen, weight, w_in, w_out)
         if self.shared_dim:
             with jax.named_scope("moe_shared"):
                 y = y + GatedMlp(self.shared_dim, self.dtype,
                                  name="shared")(m)
-        load = sizes.astype(jnp.float32)
-        aux = {"rows_held": n_held.astype(jnp.float32),
-               "load_max_over_mean": jnp.max(load)
-               / jnp.maximum(jnp.mean(load), 1e-9)}
+        load = sizes.astype(jnp.float32)            # [ranks of the mesh, held]
+        held = jnp.sum(load, axis=1)
+        over_mean = lambda rows: jnp.max(rows, axis=-1) \
+            / jnp.maximum(jnp.mean(rows, axis=-1), 1e-9)
+        aux = {"rows_held": jnp.mean(held),
+               "load_max_over_mean": jnp.max(over_mean(load))}
+        if ranks > 1:
+            aux["rank_load_max_over_mean"] = over_mean(held)
         if state is not None:
             aux["router_state"] = state.reshape(b, s, -1)
         return y.reshape(b, s, f), aux

@@ -123,12 +123,13 @@ def apply_rope(x: jnp.ndarray, rope: Rope) -> jnp.ndarray:
 
 
 def rope_to_heads(x: jnp.ndarray, rope: Rope,
-                  implementation: str = "auto") -> jnp.ndarray:
+                  implementation: str = "auto", mesh=None) -> jnp.ndarray:
     """``x [B, S, H, D]`` turned by its positions, as ``[B, H, S, D]``: the
     kernel where ``ops/rope.py:kernel_engages`` says so (a head of whole lane
     tiles on a TPU), else :func:`apply_rope` and the transpose. Which one is
     static, so it is counted when the call is traced: ``attention.rope.calls``
-    labelled ``path=kernel|xla`` (docs/OBSERVABILITY.md)."""
+    labelled ``path=kernel|xla`` (docs/OBSERVABILITY.md). ``mesh`` is the
+    step's, for the kernel (``ops/rope.py:rotate_to_heads``)."""
     b, seq_len, h, head_dim = x.shape
     use_kernel, interpret = kernel_engages(implementation, seq_len, head_dim)
     get_tracer().registry.counter(
@@ -140,7 +141,7 @@ def rope_to_heads(x: jnp.ndarray, rope: Rope,
         # the caller's split of the last dimension, and XLA drops both.
         return rotate_to_heads(x.reshape(b, seq_len, h * head_dim),
                                *rope.tables(seq_len, head_dim), head_dim,
-                               interpret=interpret)
+                               interpret=interpret, mesh=mesh)
     return apply_rope(x, rope).transpose(0, 2, 1, 3)
 
 
@@ -154,9 +155,11 @@ class BlockStyle:
     ``mlp``: ``"swiglu"`` (``(silu(x W1) * x W3) W2``, width ``mlp_dim``) or
     ``"experts"`` (``models/moe.py:HeldExpertsMlp`` built from ``experts``, a
     tuple of its keyword pairs; ``router`` empty leaves the layer the router
-    of its own ``top_k``, else it is the keyword pairs of a
-    ``models/moe.py:MlpStateRouter``, which chooses one expert a token and
-    hands its state to the next block's router).
+    of its own ``top_k``, else it is the keyword pairs of a router of
+    ``models/moe.py``: ``("kind", "softmax_top_k")`` first makes them a
+    ``SoftmaxTopKRouter``'s, otherwise they are a ``MlpStateRouter``'s, which
+    chooses one expert a token and hands its state to the next block's
+    router).
 
     ``latent_mix`` makes the attention Zyphra's CCA (arXiv 2510.04476): q and
     k, projected *down* into a latent of ``heads * head_dim``, pass two causal
@@ -338,6 +341,14 @@ class MultiHeadAttention(nn.Module):
     # output. Training and evaluation only: the decode paths below keep the
     # 2018 layout.
     style: Optional[BlockStyle] = None
+    # The mesh the step is compiled for: its Pallas kernels (flash, rope) go
+    # under a shard_map over its batch axes (parallel/kernels.py). None, or
+    # a mesh of one device: nothing is wrapped; nor while the parameters are
+    # initialised, which traces one row.
+    mesh: Any = None
+
+    def _kernel_mesh(self):
+        return None if self.is_initializing() else self.mesh
 
     def core_attention(self, q, k, v, bias, causal):
         """The [B,H,S,D] attention op. Subclasses swap this for a
@@ -345,7 +356,8 @@ class MultiHeadAttention(nn.Module):
         projections/KV-cache/dropout plumbing unchanged."""
         return fused_attention(q, k, v, bias=bias, causal=causal,
                                implementation=self.attention_impl,
-                               window=self.style.window if self.style else 0)
+                               window=self.style.window if self.style else 0,
+                               mesh=self._kernel_mesh())
 
     @nn.compact
     def __call__(self, x, kv=None, bias=None, causal=False,
@@ -433,8 +445,8 @@ class MultiHeadAttention(nn.Module):
             v = heads(dense("value", kv_heads * head_dim)(kv), kv_heads)
         if st.rope is not None:
             with jax.named_scope("rope"):
-                q, k = (rope_to_heads(t, st.rope, self.attention_impl)
-                        for t in (q, k))
+                q, k = (rope_to_heads(t, st.rope, self.attention_impl,
+                                      self._kernel_mesh()) for t in (q, k))
             v = v.transpose(0, 2, 1, 3)
         else:
             q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # [B,H,S,D]
@@ -759,6 +771,7 @@ class TransformerLayer(nn.Module):
     quantized: bool = False
     kv_quant: str = ""
     style: Optional[BlockStyle] = None
+    mesh: Any = None     # the step's, for the kernels and the expert layer
 
     def _styled(self, x, causal, router_state):
         st = self.style
@@ -777,20 +790,26 @@ class TransformerLayer(nn.Module):
 
         x = join("self_attn", x, MultiHeadAttention(
             self.num_heads, self.dtype, 0.0, self.attention_impl,
-            style=st, name="self_attn")(
+            style=st, mesh=self.mesh, name="self_attn")(
                 norm("self_attn_norm")(x), causal=causal),
             stream=not st.from_embedding)
         y = norm("mlp_norm")(x)
         if st.mlp == "experts":
-            from .moe import HeldExpertsMlp, MlpStateRouter
+            from .moe import HeldExpertsMlp, MlpStateRouter, \
+                SoftmaxTopKRouter
 
-            experts = dict(st.experts)
-            # Unparented: the layer it is given to adopts it, as `router`.
-            router = MlpStateRouter(experts["num_experts"], parent=None,
-                                    **dict(st.router)) if st.router else None
+            experts, router = dict(st.experts), None
+            if st.router:
+                kw = dict(st.router)
+                kind = {"mlp_state": MlpStateRouter,
+                        "softmax_top_k": SoftmaxTopKRouter}[
+                            kw.pop("kind", "mlp_state")]
+                # Unparented: the layer it is given to adopts it, as
+                # `router`.
+                router = kind(experts["num_experts"], parent=None, **kw)
             out, aux = HeldExpertsMlp(
                 mlp_dim=self.mlp_dim, dtype=self.dtype, name="mlp",
-                router=router, **experts)(y, router_state)
+                router=router, mesh=self.mesh, **experts)(y, router_state)
             return join("mlp", x, out), aux
         if st.mlp != "swiglu":
             raise ValueError(f"unknown BlockStyle.mlp {st.mlp!r}")
@@ -814,7 +833,7 @@ class TransformerLayer(nn.Module):
         attn = lambda name: MultiHeadAttention(
             self.num_heads, self.dtype, self.dropout_rate,
             self.attention_impl, quantized=self.quantized,
-            kv_quant=self.kv_quant, name=name)
+            kv_quant=self.kv_quant, mesh=self.mesh, name=name)
 
         def residual(x, sub, name):
             if self.prenorm:

@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from ..obs.trace import get_tracer
+from ..parallel.kernels import batch_axes_of, rows_spec, shard_rows
 
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps masked softmax NaN-free
 
@@ -1121,8 +1122,16 @@ def fused_attention(
     sm_scale: Optional[float] = None,
     implementation: str = "auto",
     window: int = 0,
+    mesh=None,
 ) -> jnp.ndarray:
     """Multi-head attention, fused on TPU.
+
+    ``mesh``: the mesh the step is compiled for. Where its batch axes hold
+    more than one device the kernels run under a ``shard_map`` over them,
+    each device on its own rows of the batch (``parallel/kernels.py``; the
+    kernels keep the scope ``core_attention/flash_*`` that
+    ``MultiHeadAttention.core_attention`` gives them on one device); on one
+    device, and without a mesh, nothing is wrapped.
 
     ``k`` and ``v`` may have fewer heads than ``q``, a whole number of query
     heads to each (query head ``i`` reads K/V head ``i // group``): the
@@ -1169,5 +1178,12 @@ def fused_attention(
         use_pallas, interpret = False, False
     else:
         raise ValueError(f"unknown implementation {implementation!r}")
+    if use_pallas and bias is None:
+        rows = rows_spec(batch_axes_of(mesh), 4)
+        return shard_rows(
+            lambda q, k, v: _fused_attention(
+                q, k, v, None, causal, scale, True, interpret, window),
+            mesh, "flash", (rows, rows, rows), rows,
+            scope="core_attention")(q, k, v)
     return _fused_attention(q, k, v, bias, causal, scale, use_pallas,
                             interpret, window)

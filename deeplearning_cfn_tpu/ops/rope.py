@@ -32,6 +32,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..parallel.kernels import batch_axes_of, rows_spec, shard_rows
+
 _LANES = 128
 # Rows and heads one grid step holds. Swept on a v5e chip on 2026-09-28
 # (tools/rope_sweep.py; the table is in PERF.md, PR 27), bf16 [2,4096,H,128]:
@@ -151,9 +153,16 @@ _rotate.defvjp(_rotate_fwd, _rotate_bwd)
 
 def rotate_to_heads(x: jnp.ndarray, cos: np.ndarray, sin: np.ndarray,
                     head_dim: int, interpret: bool = False,
-                    blocks=None) -> jnp.ndarray:
+                    blocks=None, mesh=None) -> jnp.ndarray:
     """Turn ``x [B, S, H * head_dim]``, a projection's output as it lies, by
     the float32 tables ``cos``, ``sin`` ``[S, rot/2]`` and give it as
-    ``[B, H, S, head_dim]``. For a shape ``kernel_engages`` accepts."""
+    ``[B, H, S, head_dim]``. For a shape ``kernel_engages`` accepts. On a
+    ``mesh`` whose batch axes hold more than one device each device turns its
+    own rows of the batch (``parallel/kernels.py``); the tables are whole on
+    every one."""
     cos_full, sin_signed = spread_tables(cos, sin, head_dim)
-    return _rotate(x, cos_full, sin_signed, cos.shape[1], interpret, blocks)
+    axes = batch_axes_of(mesh)
+    return shard_rows(
+        lambda x: _rotate(x, cos_full, sin_signed, cos.shape[1], interpret,
+                          blocks),
+        mesh, "rope", (rows_spec(axes, 3),), rows_spec(axes, 4))(x)

@@ -370,6 +370,36 @@ def _zaya1_8b() -> ExperimentConfig:
     )
 
 
+@register_preset("mellum2_12b_lm")
+def _mellum2_12b() -> ExperimentConfig:
+    """Mellum2-12B-A2.5B (JetBrains: sliding-window and full attention 3 : 1
+    over grouped K/V heads, every MLP 64 experts of width 896, 8 a token by
+    softmax scores) pre-trained on one four-chip host of a pod whose pipeline
+    stages are a host each: this stage holds layers 0-3 of 28 (one whole
+    period: sliding x 3, full) with **all 64 experts of each**, 16 a chip on
+    the mesh's `expert` axis, everything else on every chip (the batch
+    rides the axis outside the expert layers), and the exchange between the
+    four ranks is run (models/moe.py). The stage is given an embedding and a
+    head over a quarter of the vocabulary (24,576 of 98,304 rows). Sequences
+    of 8192, the source's original context. Recipe: gpt_small_lm's (the
+    source publishes none), no auxiliary loss."""
+    return ExperimentConfig(
+        model=ModelConfig(
+            name="gpt_mellum2_12b",
+            kwargs=dict(layers_held=(0, 1, 2, 3)),
+        ),
+        data=DataConfig(name="lm_text", seq_len=8192, vocab_size=24_576),
+        train=TrainConfig(global_batch=4, steps=100_000, dtype="bfloat16",
+                          shard_opt_state=False),
+        optimizer=OptimizerConfig(name="adamw", b1=0.9, b2=0.95,
+                                  weight_decay=0.1, grad_clip_norm=1.0),
+        schedule=ScheduleConfig(name="cosine", base_lr=6e-4,
+                                warmup_steps=2000),
+        mesh=MeshConfig(data=1, expert=4),
+        stack=StackConfig(slice_type="v5e-4"),
+    )
+
+
 @register_preset("transformer_nmt_wmt")
 def _nmt() -> ExperimentConfig:
     """Transformer NMT WMT En-De (reference: Sockeye + MXNet

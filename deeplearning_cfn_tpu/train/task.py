@@ -399,6 +399,11 @@ class CausalLmTask:
             mesh = mesh if mesh is not None else build_mesh(cfg.mesh)
             kwargs.setdefault("mesh", mesh)
             kwargs.setdefault("batch_axes", batch_sharding(mesh, 1).spec[0])
+        elif mesh is not None:
+            # The trunk of blocks: on a mesh of more than one device its
+            # Pallas kernels go under a shard_map over the batch axes and
+            # an expert layer exchanges over `expert` (parallel/kernels.py).
+            kwargs.setdefault("mesh", mesh)
         self.param_rules = PARAM_RULES
         self.model = build_model(cfg.model.name, 0, dtype, **kwargs)
         self.remat = cfg.train.remat

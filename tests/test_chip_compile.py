@@ -137,16 +137,32 @@ def test_rope_kernel_compiles_for_v5e(v5e_chip, name, heads, rope, what):
     assert ("rope_fwd" if what == "forward" else "rope_bwd") in text
 
 
-def _row_scatters(text):
-    """The instructions of a compiled step that scatter rows of 2048 under
-    an expert layer's ``moe_dispatch`` or ``moe_combine``: the rows go to the
-    buffer and back by gathers (``models/moe.py:take_rows``, ``sum_rows``),
-    so there are none; what is still scattered there is integers."""
+def _row_scatters(text, width=2048):
+    """The instructions of a compiled step that scatter rows of ``width``
+    under an expert layer's ``moe_dispatch`` or ``moe_combine``: the rows go
+    to the buffer and back by gathers (``models/moe.py:take_rows``,
+    ``sum_rows``), so there are none; what is still scattered there is
+    integers."""
     import re
 
     return [line.strip()[:200] for line in text.splitlines()
-            if re.search(r"= \w+\[\d+,2048\]\S* scatter\(", line)
+            if re.search(rf"= \w+\[\d+,{width}\]\S* scatter\(", line)
             and re.search(r"/moe_(dispatch|combine)/", line)]
+
+
+def _bench():
+    import os
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path.insert(0, bench)
+    try:
+        from harness import manifest
+        import rehearse_compile
+    finally:
+        sys.path.remove(bench)
+    return manifest, rehearse_compile
 
 
 def _rows_calls(since=None):
@@ -164,18 +180,9 @@ def test_laguna_step_compiles_and_fits_a_v5e(v5e_chip):
     compiler takes it, the flash kernels, the grouped matmuls and the rotary
     kernels are in it, and arguments plus temporaries fit the chip's 16 GB.
     PERF.md section 4 has the number."""
-    import os
-    import sys
-
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark")
-    sys.path.insert(0, bench)
-    try:
-        from harness import manifest
-        import rehearse_compile
-    finally:
-        sys.path.remove(bench)
     from deeplearning_cfn_tpu.obs.trace import get_tracer
+
+    manifest, rehearse_compile = _bench()
 
     calls = get_tracer().registry.counter("attention.rope.calls")
     before = {path: calls.value(path=path) for path in ("kernel", "xla")}
@@ -215,18 +222,9 @@ def test_zaya1_step_compiles_and_fits_a_v5e(v5e_chip):
     latent mixing is in the step under its scope, and arguments plus
     temporaries read under 15 GB of the chip's 16 (and over the quarter of
     it a cell has to fill). PERF.md section 4 has the number."""
-    import os
-    import sys
-
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark")
-    sys.path.insert(0, bench)
-    try:
-        from harness import manifest
-        import rehearse_compile
-    finally:
-        sys.path.remove(bench)
     from deeplearning_cfn_tpu.obs.trace import get_tracer
+
+    manifest, rehearse_compile = _bench()
 
     registry = get_tracer().registry
     mixed = registry.counter("attention.cca.calls")
@@ -263,3 +261,99 @@ def test_zaya1_step_compiles_and_fits_a_v5e(v5e_chip):
     assert "/self_attn/cca_mix/" in text and "/mlp/moe_router/" in text
     assert "/moe_dispatch/" in text and "/moe_combine/" in text
     assert _row_scatters(text) == []
+
+
+def test_mellum2_step_compiles_for_four_v5e_chips(v5e_chip):
+    """The whole train step of ``mellum2_12b_train_8k_ep4`` at the cell's
+    shapes for the four chips of a described ``v5e:2x2`` on ``expert=4``:
+    Mosaic takes every Pallas call under its ``shard_map`` (flash, rotary,
+    megablox), the state and the temporaries fit a chip, the exchange's
+    collectives are in the text under their scopes and nothing else moves
+    rows of 2304 between chips, and no row is scattered. PERF.md section 4
+    has the number."""
+    import re
+
+    from deeplearning_cfn_tpu.obs.trace import get_tracer
+
+    manifest, rehearse_compile = _bench()
+    registry = get_tracer().registry
+    wrapped = registry.counter("parallel.shard_map.calls")
+    exchanged = registry.counter("moe.exchange.calls")
+    label = dict(path="all_gather", ranks="4")
+    before = {k: wrapped.value(kernel=k) for k in ("flash", "rope", "gmm")}
+    exchanges, rows_before = exchanged.value(**label), _rows_calls()
+    cell = manifest.Cell(manifest.load_manifest(),
+                         "mellum2_12b_train_8k_ep4")
+    assert cell.chips == 4
+    _, compiled, _ = rehearse_compile.compile_step(cell)
+    # Four layers. The step is traced once on the mesh; the trace for the
+    # parameters' shapes initialises, which wraps nothing.
+    assert {k: wrapped.value(kernel=k) - n for k, n in before.items()} \
+        == {"flash": 4, "rope": 8, "gmm": 4}
+    assert exchanged.value(**label) - exchanges == 4
+    assert _rows_calls(rows_before) == {"gather": 8, "scatter_add": 0}
+    # A rank sends 3 x 8192 tokens of 2304 in bfloat16 and float32, twice.
+    assert registry.gauge("moe.exchange.bytes").value() \
+        == 2 * 3 * 8192 * 2304 * 6
+    mem = compiled.memory_analysis()
+    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    assert 4 * 2 ** 30 < total < 15.75 * 2 ** 30, total
+    # 595.1 M parameters a chip with Adam's two moments: the stacks are
+    # sharded, 16 experts a chip.
+    assert 7.1e9 < mem.argument_size_in_bytes < 7.2e9
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    flash = [line for line in kernels if "core_attention/flash_" in line]
+    assert len(flash) == 12 and all("/shard_map/" in line for line in flash)
+    # A sequence a chip, 4 K/V heads under 32, not repeated.
+    assert all("bf16[1,4,8192,128]" in line and "bf16[1,32,8192,128]" in line
+               for line in flash)
+    rope = [line for line in kernels if "/self_attn/rope/" in line]
+    assert sum("rope_fwd" in line for line in rope) == 8
+    assert sum("rope_bwd" in line for line in rope) == 8
+    assert any("/moe_experts/" in line and "/mlp/shard_map/" in line
+               for line in kernels)
+    assert _row_scatters(text, 2304) == []
+    moved = [line for line in text.splitlines() if re.search(
+        r"= \S*\[(1,)?(8192|32768),2304\]\S* (all-gather|reduce-scatter|"
+        r"all-reduce|all-to-all|collective-permute)(-start)?\(", line)]
+    assert moved and all(re.search(r"/moe_exchange_(in|out)/", line)
+                         for line in moved)
+    kinds = {(re.search(r"= (\w+)\[", line).group(1),
+              re.search(r"\]\S* (all-gather|reduce-scatter)", line).group(1),
+              re.search(r"moe_exchange_(in|out)", line).group(0),
+              "transpose(" in line) for line in moved}
+    # Forward: the tokens gathered in bfloat16, the parts summed in float32;
+    # backward, the transposes.
+    assert kinds == {("bf16", "all-gather", "moe_exchange_in", False),
+                     ("f32", "reduce-scatter", "moe_exchange_out", False),
+                     ("f32", "all-gather", "moe_exchange_out", True),
+                     ("bf16", "reduce-scatter", "moe_exchange_in", True)}
+    assert "all-to-all" not in text
+
+
+@pytest.mark.parametrize("name,k,n", [("experts_in", 2304, 1792),
+                                      ("experts_out", 896, 2304)])
+@pytest.mark.parametrize("what", ["forward", "grad"])
+def test_grouped_matmul_compiles_at_mellum2_widths(v5e_chip, name, k, n,
+                                                   what):
+    """megablox's ``gmm`` (and through its VJP ``tgmm``) at a rank's shapes
+    in the Mellum2 cell, 131,072 buffer rows in 16 groups, widths no power
+    of two divides (2304 = 18 x 128, 1792 = 14 x 128, 896 = 7 x 128), with
+    the tile ``grouped_matmul`` chooses."""
+    from deeplearning_cfn_tpu.models.moe import grouped_matmul
+
+    sharding = SingleDeviceSharding(v5e_chip)
+    rows, groups = 131072, 16
+    lhs = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=sharding)
+    rhs = jax.ShapeDtypeStruct((groups, k, n), jnp.bfloat16,
+                               sharding=sharding)
+    sizes = jax.ShapeDtypeStruct((groups,), jnp.int32, sharding=sharding)
+    f = lambda a, b, s: grouped_matmul(a, b, s, "megablox")
+    if what == "grad":
+        f = jax.grad(lambda a, b, s: jnp.sum(grouped_matmul(
+            a, b, s, "megablox").astype(jnp.float32)), argnums=(0, 1))
+    text = jax.jit(f).lower(lhs, rhs, sizes).compile().as_text()
+    assert text.count("tpu_custom_call") == (1 if what == "forward" else 2)

@@ -61,6 +61,10 @@ SHAPES = {
     "laguna_every_pair": (8, 256, 32, 65536, 0.3),
     # ZAYA1-8B: one choice a token, the one buffer of every pair.
     "zaya1": (1, 16, 8, 8192, 1 / 2),
+    # A rank of Mellum2's four on its host, after the gather of the ranks'
+    # tokens (run it with ``--tokens 32768 --width 2304``): 262,144 pairs,
+    # 16 of 64 experts held, twice a uniform router's 65,536 rows.
+    "mellum2_rank": (8, 64, 16, 131072, 1 / 4),
 }
 
 
@@ -258,7 +262,10 @@ def main():
         _ROOT, "chiprun_out", "moe_rows_sweep.jsonl"))
     ap.add_argument("--shape", action="append", choices=sorted(SHAPES),
                     help="only this shape (repeatable)")
+    ap.add_argument("--tokens", type=int, default=TOKENS)
+    ap.add_argument("--width", type=int, default=WIDTH)
     args = ap.parse_args()
+    globals().update(TOKENS=args.tokens, WIDTH=args.width)
     if jax.default_backend() != "tpu":
         sys.exit("moe_rows_sweep: no TPU here; a time comes only from a "
                  "chip run")
@@ -271,7 +278,7 @@ def main():
             f.flush()
 
         say({"device": jax.devices()[0].device_kind, "steps": args.steps})
-        for name in args.shape or SHAPES:
+        for name in args.shape or [n for n in SHAPES if n != "mellum2_rank"]:
             measure(name, args.steps, say)
 
 
