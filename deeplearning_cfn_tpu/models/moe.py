@@ -6,9 +6,11 @@ capacity layer the BERT/GPT trunks of ``moe_every`` keep.
 module scores every token over all the experts, the (token, choice) pairs
 are sorted by expert, the rows of the experts held go through one grouped
 matmul (megablox's Pallas ``gmm`` / ``tgmm`` on a TPU) and are summed back
-into their tokens; rows move by gathers through the sort's permutation and
-its inverse (:func:`take_rows`, :func:`sum_rows`), and no row is dropped
-whatever the routing. Which experts are held is static (``held``); on one
+into their tokens; rows move through the sort's permutation and its inverse
+(:func:`take_rows`, :func:`sum_rows`), fetched by XLA's gathers or, where a
+rank's tokens are a source large enough to make those dear, the live ones
+alone by a Pallas row kernel (``ops/rows.py``, :func:`rows_path`), and no
+row is dropped whatever the routing. Which experts are held is static (``held``); on one
 device the layer computes their part of the result and nothing else (one
 expert-parallel rank of a pod, the exchange not run). **On a mesh whose
 ``expert`` axis has R > 1 devices the held experts are divided R ways, the
@@ -43,6 +45,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..obs.trace import get_tracer
+from ..ops.rows import fits as rows_kernel_fits, sort_with, sum_live_rows
 from ..parallel.kernels import batch_axes_of, rows_spec, shard_rows
 
 Dtype = Any
@@ -192,6 +195,13 @@ _BUFFER_SHARE = 2.0
 BALANCE_RATE = 0.02
 
 
+def _named(implementation: str) -> str:
+    """``auto`` read: the kernels on a TPU, XLA's forms elsewhere."""
+    if implementation != "auto":
+        return implementation
+    return "megablox" if jax.default_backend() == "tpu" else "ragged_dot"
+
+
 def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray,
                    group_sizes: jnp.ndarray,
                    implementation: str = "auto") -> jnp.ndarray:
@@ -205,10 +215,8 @@ def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray,
     from ``group_sizes`` at run time; backward through its own VJP, the
     weights' gradient by the transposed kernel); elsewhere
     ``jax.lax.ragged_dot``."""
-    if implementation == "auto":
-        implementation = "megablox" if jax.default_backend() == "tpu" \
-            else "ragged_dot"
-    if implementation == "ragged_dot":
+    implementation = _named(implementation)
+    if implementation in ("ragged_dot", "interpret"):
         return jax.lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32),
                                   preferred_element_type=lhs.dtype)
     if implementation != "megablox":
@@ -256,12 +264,55 @@ def inverse_permutation(order: jnp.ndarray) -> jnp.ndarray:
     return jnp.argsort(order).astype(order.dtype)
 
 
+# The bytes of a gather's source from which XLA's gather pays three times as
+# much a slot (128 MiB, the chip's VMEM: tools/moe_rows_sweep.py, PR 36).
+_GATHER_CLIFF = 2 ** 27
+
+
+def rows_path(implementation: str, tokens: int, width: int, dtype) -> str:
+    """How :func:`take_rows` and :func:`sum_rows` fetch a row where a rank's
+    layer has ``tokens`` rows of ``width`` in ``dtype`` (after the exchange's
+    gather): ``"kernel"`` (``ops/rows.py``: one DMA a live slot) or XLA's
+    ``"gather"``; ``"interpret"`` is the kernel interpreted, for the tests.
+
+    Alone on the chip (``tools/moe_rows_sweep.py --quick``, my chip runs,
+    PR 36, calls 1 and 2, ``chiprun_out/c1_rows_*.jsonl``,
+    ``c2_rows_kernel.jsonl``) XLA's gather pays by the slot, dead or live,
+    and what a slot costs follows the **source's size, not the row's width**:
+    14-19 ns a row of 2048 bf16 from sources of 33 to 101 MB, 46 ns from 134
+    MB (2 ** 27 bytes) on, and there in step with the width (46 / 51 / 56 ns
+    at 2048 / 2304 / 2560: 2.85 ns each 128 lanes); the source seen as 32-bit
+    words, as ``[M, F / 256, 256]`` or split at 2048 columns is no faster
+    anywhere. The kernel pays 45 ns a live row and nothing for a dead slot,
+    and one copy of its source to pairs of rows. A rank of Mellum2's four
+    (32,768 tokens of 2304, 151 MB; a buffer of 131,072 rows, 604 MB; one
+    slot in four live on the tokens' side, one in two on the buffer's): 6.70
+    / 12.98 / 13.11 / 10.51 ms the four movements by XLA, 3.68 / 6.04 / 6.10
+    / 5.75 by the kernel. Laguna's 8,192 tokens of 2048 (33.5 MB): 0.25 /
+    0.91 / 0.94 / 0.86 by XLA, 0.36 / 0.70 / 0.70 / 0.58 by the kernel, 0.5
+    ms a layer for twenty more kernels to lower at set-up; ZAYA1's one
+    choice a token: 0.10 / 0.10 / 0.10 / 0.27 by XLA, 0.24 / 0.26 / 0.27 /
+    0.28 by the kernel. **So: the kernel where the rank's tokens are a source
+    of 2 ** 27 bytes or more** (their buffer is then larger still), on a TPU
+    or where the kernels are named (``implementation="megablox"``: a compile
+    for a chip that is not attached), for rows the kernel can move; XLA's
+    gather elsewhere, and off the TPU as :func:`grouped_matmul` falls back
+    to ``ragged_dot``."""
+    if not rows_kernel_fits(width, dtype):
+        return "gather"
+    if implementation == "interpret":
+        return "interpret"
+    large = tokens * width * jnp.dtype(dtype).itemsize >= _GATHER_CLIFF
+    return "kernel" if _named(implementation) == "megablox" and large \
+        else "gather"
+
+
 def _rows_of_tokens(y, weight, inv, n_live, top_k: int):
     """``[tokens, F]`` float32: for every token the sum over its ``top_k``
     pairs of the row of ``y`` the pair lies at (``inv``), times the pair's
     ``weight`` where one is given, over the pairs whose row is live
     (``inv < n_live``), in the choices' order; a dead pair reads the last
-    row and is masked.
+    row and is masked. XLA's form, where the kernel is not taken.
 
     Under the usual buffer a gather a choice, added in turn: in Laguna's
     step one gather ``[tokens, k, F]`` is written out in float32 before it
@@ -288,31 +339,61 @@ def _rows_of_tokens(y, weight, inv, n_live, top_k: int):
     return total
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def take_rows(m, token, inv, n_live, top_k: int):
+def _live_first(inv, n_live, top_k: int, weight=None):
+    """What the kernel walks on the tokens' side: ``(at [tokens, k], count
+    [tokens], weight [tokens, k])``, each token's live pairs' rows (and
+    weights) moved to the front of its ``k`` in the choices' order, and how
+    many they are. A stable partition of ``k``, written as a one-hot sum: a
+    gather of 262,144 integers would pay XLA's price a slot."""
+    at = inv.reshape(-1, top_k)
+    live = at < n_live
+    place = jnp.cumsum(live, axis=1, dtype=jnp.int32) - 1
+    put = live[:, :, None] & (place[:, :, None] == jnp.arange(top_k))
+    first = lambda a: jnp.sum(jnp.where(put, a[:, :, None], 0), axis=1)
+    return first(at), jnp.sum(live, axis=1, dtype=jnp.int32), \
+        None if weight is None else first(weight.reshape(-1, top_k))
+
+
+def _rows_to_tokens(y, weight, inv, n_live, top_k: int, out_dtype, path):
+    """:func:`_rows_of_tokens` in ``out_dtype``, by ``path``."""
+    if path == "gather":
+        return _rows_of_tokens(y, weight, inv, n_live, top_k).astype(
+            out_dtype)
+    at, count, weight = _live_first(inv, n_live, top_k, weight)
+    return sum_live_rows(y, at, count, weight, out_dtype=out_dtype,
+                         interpret=path == "interpret")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def take_rows(m, token, inv, n_live, top_k: int, path: str = "gather"):
     """``xs[r] = m[token[r]]`` for the live rows ``r < n_live`` of the
     buffer, 0 for the others. Its transpose is :func:`sum_rows` without the
     weights, and is written down as that: autodiff would make a scatter-add
-    of it."""
-    live = (jnp.arange(token.shape[0]) < n_live)[:, None]
-    return jnp.where(live, m[token], 0)
+    of it. ``path`` (:func:`rows_path`) is how a row is fetched, here and in
+    the transpose."""
+    if path == "gather":
+        live = jnp.arange(token.shape[0]) < n_live
+        return jnp.where(live[:, None], m[token], 0)
+    return sum_live_rows(m, token[:, None], n_live,
+                         interpret=path == "interpret")
 
 
-def _take_rows_fwd(m, token, inv, n_live, top_k):
-    return take_rows(m, token, inv, n_live, top_k), (inv, n_live)
+def _take_rows_fwd(m, token, inv, n_live, top_k, path):
+    return take_rows(m, token, inv, n_live, top_k, path), (inv, n_live)
 
 
-def _take_rows_bwd(top_k, kept, d_xs):
+def _take_rows_bwd(top_k, path, kept, d_xs):
     inv, n_live = kept
-    d_m = _rows_of_tokens(d_xs, None, inv, n_live, top_k)
-    return d_m.astype(d_xs.dtype), None, None, None
+    return _rows_to_tokens(d_xs, None, inv, n_live, top_k, d_xs.dtype,
+                           path), None, None, None
 
 
 take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def sum_rows(y, weight, order, inv, n_live, top_k: int, out_dtype=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def sum_rows(y, weight, order, inv, n_live, top_k: int, out_dtype=None,
+             path: str = "gather"):
     """``out[t] = sum over j of weight[t k + j] * y[inv[t k + j]]`` over the
     pairs whose row is live, products and sum in float32, in ``y``'s dtype
     (or ``out_dtype``: float32 where the ranks' parts are still to be
@@ -321,24 +402,43 @@ def sum_rows(y, weight, order, inv, n_live, top_k: int, out_dtype=None):
     weights: ``d y[r] = weight[order[r]] * d out[token[r]]``, and
     ``d weight[p]`` is the dot of pair ``p``'s row with its token's
     cotangent."""
-    return _rows_of_tokens(y, weight, inv, n_live, top_k).astype(
-        out_dtype or y.dtype)
+    return _rows_to_tokens(y, weight, inv, n_live, top_k,
+                           out_dtype or y.dtype, path)
 
 
-def _sum_rows_fwd(y, weight, order, inv, n_live, top_k, out_dtype):
-    return sum_rows(y, weight, order, inv, n_live, top_k, out_dtype), \
+def _sum_rows_fwd(y, weight, order, inv, n_live, top_k, out_dtype, path):
+    return sum_rows(y, weight, order, inv, n_live, top_k, out_dtype, path), \
         (y, weight, order, inv, n_live)
 
 
-def _sum_rows_bwd(top_k, out_dtype, kept, d_out):
+def _sum_rows_bwd(top_k, out_dtype, path, kept, d_out):
     y, weight, order, inv, n_live = kept
     rows = y.shape[0]
     pair = order[:rows]
-    live = (jnp.arange(rows) < n_live)[:, None]
-    g = d_out[pair // top_k].astype(jnp.float32)
-    d_y = jnp.where(live, g * weight[pair][:, None], 0).astype(y.dtype)
-    dots = jnp.sum(jnp.where(live, y.astype(jnp.float32) * g, 0), axis=1)
-    d_weight = jnp.where(inv < n_live, dots[jnp.minimum(inv, rows - 1)], 0)
+    if path == "gather":
+        live = (jnp.arange(rows) < n_live)[:, None]
+        g = d_out[pair // top_k].astype(jnp.float32)
+        d_y = jnp.where(live, g * weight[pair][:, None], 0).astype(y.dtype)
+        dots = jnp.sum(jnp.where(live, y.astype(jnp.float32) * g, 0), axis=1)
+        d_weight = jnp.where(inv < n_live,
+                             dots[jnp.minimum(inv, rows - 1)], 0)
+        return d_y, d_weight.astype(weight.dtype), None, None, None
+    # The rows' dots with their tokens' cotangents ride in the pass that
+    # fetches those cotangents. A number a pair goes through the permutation
+    # by a sort (0.24 ms for 262,144), not by a gather, which pays 8.6 ns a
+    # slot for a number as it pays 50 for a row (PERF.md, PR 36): the live
+    # places ``r < n_live`` are each taken by one pair, so the weights sorted
+    # by ``inv`` begin with ``weight[pair]``.
+    d_y, dots = sum_live_rows(
+        d_out, (pair // top_k)[:, None], n_live,
+        sort_with(inv, weight)[1][:rows, None], dot_with=y,
+        out_dtype=y.dtype, interpret=path == "interpret")
+    if order.shape[0] == inv.shape[0]:
+        at_pair = sort_with(order, jnp.pad(
+            dots[:, 0], (0, inv.shape[0] - rows)))[1]
+    else:       # a window of the sorted pairs (``_in_passes``)
+        at_pair = dots[jnp.minimum(inv, rows - 1), 0]
+    d_weight = jnp.where(inv < n_live, at_pair, 0)
     return d_y, d_weight.astype(weight.dtype), None, None, None
 
 
@@ -346,7 +446,8 @@ sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
 
 
 def _held_rows(m, pair_weight, order, inv, sizes, n_held, w_in, w_out, *,
-               rows: int, top_k: int, implementation: str, out_dtype=None):
+               rows: int, top_k: int, implementation: str,
+               path: str = "gather", out_dtype=None):
     """The held experts' part of the layer's result from a buffer of ``rows``
     rows: the first ``rows`` (token, choice) pairs in ``order`` (sorted by
     expert, those of held experts first, ``n_held`` of them); ``inv`` is
@@ -354,13 +455,14 @@ def _held_rows(m, pair_weight, order, inv, sizes, n_held, w_in, w_out, *,
     n_live = jnp.minimum(n_held, rows)
     valid = (jnp.arange(rows) < n_live)[:, None]
     with jax.named_scope("moe_dispatch"):
-        xs = take_rows(m, order[:rows] // top_k, inv, n_live, top_k)
+        xs = take_rows(m, order[:rows] // top_k, inv, n_live, top_k, path)
     with jax.named_scope("moe_experts"):
         h = grouped_matmul(xs, w_in, sizes, implementation)
         gate, up = jnp.split(jnp.where(valid, h, 0), 2, axis=-1)
         y = grouped_matmul(nn.silu(gate) * up, w_out, sizes, implementation)
     with jax.named_scope("moe_combine"):
-        return sum_rows(y, pair_weight, order, inv, n_live, top_k, out_dtype)
+        return sum_rows(y, pair_weight, order, inv, n_live, top_k, out_dtype,
+                        path)
 
 
 def _in_passes(part, rows: int, m, pair_weight, order, inv, sizes, n_held,
@@ -391,7 +493,7 @@ def _in_passes(part, rows: int, m, pair_weight, order, inv, sizes, n_held,
 
 
 def _rank_part(m, chosen, weight, w_in, w_out, *, num_experts: int,
-               first: int, ranks: int, implementation: str):
+               first: int, ranks: int, implementation: str, path: str):
     """What one rank adds to the layer's result: ``m [T, F]`` its own tokens
     with their ``chosen [T, k]`` experts and ``weight [T, k]``, ``w_in`` /
     ``w_out`` the stacks of the experts it holds, ``first`` the first of
@@ -429,17 +531,22 @@ def _rank_part(m, chosen, weight, w_in, w_out, *, num_experts: int,
     # Recomputed in the backward pass: little arithmetic, and the row
     # buffers (0.3 GB a layer at the usual size, four times that at the
     # other) are then never kept.
-    part = lambda rows: jax.checkpoint(functools.partial(
+    # The second buffer, which few steps take, keeps XLA's gathers whatever
+    # ``path`` is: the row kernel there too is twenty more kernels in
+    # Mellum2's step, 38 MB more of a 342 MB program to load at every start
+    # and 3.6 s more to trace and lower (PERF.md, PR 36; PR 33 kept that
+    # branch's one gather for the same reason).
+    part = lambda rows, path=path: jax.checkpoint(functools.partial(
         _held_rows, rows=rows, top_k=k, implementation=implementation,
-        out_dtype=jnp.float32 if ranks > 1 else None))
+        path=path, out_dtype=jnp.float32 if ranks > 1 else None))
     usual = _whole_tiles(int(_BUFFER_SHARE * pairs * count / e))
     operands = (m, weight.reshape(-1), order, inv, sizes, n_held, w_in,
                 w_out)
     if usual >= pairs:
         y = part(pairs)(*operands)
     else:
-        second = part(pairs) if ranks == 1 else functools.partial(
-            _in_passes, part(usual), usual)
+        second = part(pairs, "gather") if ranks == 1 else functools.partial(
+            _in_passes, part(usual, "gather"), usual)
         y = jax.lax.cond(n_held <= usual, part(usual), second, *operands)
     if ranks > 1:
         with jax.named_scope("moe_exchange_out"):
@@ -584,7 +691,8 @@ class HeldExpertsMlp(nn.Module):
     gather (:func:`sum_rows`: the sort is a permutation, so every token reads
     its ``k`` rows through the inverse and adds them in float32; each
     gather's backward pass is the other, so no row is scatter-added in
-    either direction; ``moe.rows.calls`` counts the calls). The row buffer is
+    either direction; ``moe.rows.calls`` counts the calls, by whether XLA's
+    gather or the row kernel fetches a row: :func:`rows_path`). The row buffer is
     static: twice what a uniform router would send (``_BUFFER_SHARE``), and
     where a step's routing sends more (``lax.cond`` on the count, a rank's
     own) a second buffer of every pair, ``tokens * k`` rows, takes the step
@@ -644,14 +752,17 @@ class HeldExpertsMlp(nn.Module):
             chosen, weight, state = router(
                 m, None if router_state is None
                 else router_state.reshape(b * s, -1))
-        # Rows go to the buffer and back by gathers through the sort's
-        # permutation and its inverse; counted here, once a layer call, as
-        # the path is static.
+        # Rows go to the buffer and back through the sort's permutation and
+        # its inverse, fetched by the row kernel or by XLA's gathers (a rank
+        # sees its own tokens and, with an exchange, its ranks'); counted
+        # here, once a layer call, as the path is static.
+        path = rows_path(self.implementation, ranks * b * s // math.prod(
+            mesh.shape[a] for a in axes), f, self.dtype)
         registry = get_tracer().registry
         registry.counter(
             "moe.rows.calls",
             "expert-layer calls traced, by the way their rows move",
-        ).inc(path="gather")
+        ).inc(path="gather" if path == "gather" else "kernel")
         if ranks > 1:
             registry.counter(
                 "moe.exchange.calls",
@@ -676,7 +787,7 @@ class HeldExpertsMlp(nn.Module):
         y, sizes = shard_rows(
             functools.partial(_rank_part, num_experts=e, first=first,
                               ranks=ranks,
-                              implementation=self.implementation),
+                              implementation=self.implementation, path=path),
             mesh, "gmm", (rows, rows, rows, stack, stack),
             (rows, rows))(m, chosen, weight, w_in, w_out)
         if self.shared_dim:

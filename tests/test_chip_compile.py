@@ -171,7 +171,7 @@ def _rows_calls(since=None):
 
     calls = get_tracer().registry.counter("moe.rows.calls")
     return {path: calls.value(path=path) - (since[path] if since else 0)
-            for path in ("gather", "scatter_add")}
+            for path in ("gather", "kernel", "scatter_add")}
 
 
 def test_laguna_step_compiles_and_fits_a_v5e(v5e_chip):
@@ -190,8 +190,10 @@ def test_laguna_step_compiles_and_fits_a_v5e(v5e_chip):
     cell = manifest.Cell(manifest.load_manifest(), "laguna_xs2_train_4k")
     _, compiled, _ = rehearse_compile.compile_step(cell)
     # Four expert layers, each traced twice, every one moving its rows by
-    # gathers under either buffer.
-    assert _rows_calls(rows_before) == {"gather": 8, "scatter_add": 0}
+    # XLA's gathers under either buffer: 8,192 tokens of 2048 are a source
+    # of 33.5 MB, under the size from which the row kernel is the cheaper.
+    assert _rows_calls(rows_before) == {"gather": 8, "kernel": 0,
+                                        "scatter_add": 0}
     # ``compile_step`` traces the model twice, once for the parameters'
     # shapes and once in the step: each trace turns q and k of five layers.
     assert {path: calls.value(path=path) - n
@@ -204,7 +206,7 @@ def test_laguna_step_compiles_and_fits_a_v5e(v5e_chip):
     text = compiled.as_text()
     assert text.count("tpu_custom_call") > 15
     assert "/moe_dispatch/" in text and "/moe_combine/" in text
-    assert _row_scatters(text) == []
+    assert _row_scatters(text) == [] and "live_rows" not in text
     # q and k of five layers, turned forward and back by the kernel, which
     # keeps the scope that ``blocks_ms`` counts it under.
     kernels = [line for line in text.splitlines()
@@ -234,7 +236,8 @@ def test_zaya1_step_compiles_and_fits_a_v5e(v5e_chip):
     rows_before = _rows_calls()
     cell = manifest.Cell(manifest.load_manifest(), "zaya1_8b_train_4k")
     _, compiled, _ = rehearse_compile.compile_step(cell)
-    assert _rows_calls(rows_before) == {"gather": 10, "scatter_add": 0}
+    assert _rows_calls(rows_before) == {"gather": 10, "kernel": 0,
+                                        "scatter_add": 0}
     # Traced twice (the parameters' shapes, the step), five layers each.
     assert (mixed.value() - before[0], turned.value(path="kernel")
             - before[1], turned.value(path="xla") - before[2]) == (10, 20, 0)
@@ -260,7 +263,7 @@ def test_zaya1_step_compiles_and_fits_a_v5e(v5e_chip):
                for line in kernels) == 40
     assert "/self_attn/cca_mix/" in text and "/mlp/moe_router/" in text
     assert "/moe_dispatch/" in text and "/moe_combine/" in text
-    assert _row_scatters(text) == []
+    assert _row_scatters(text) == [] and "live_rows" not in text
 
 
 def test_mellum2_step_compiles_for_four_v5e_chips(v5e_chip):
@@ -291,7 +294,11 @@ def test_mellum2_step_compiles_for_four_v5e_chips(v5e_chip):
     assert {k: wrapped.value(kernel=k) - n for k, n in before.items()} \
         == {"flash": 4, "rope": 8, "gmm": 4}
     assert exchanged.value(**label) - exchanges == 4
-    assert _rows_calls(rows_before) == {"gather": 8, "scatter_add": 0}
+    # A rank's 32,768 tokens of 2304 (its four ranks' after the exchange's
+    # gather) are a source of 151 MB: the step's four layers fetch their rows
+    # by the row kernel; the initialisation traces one row, by XLA's gather.
+    assert _rows_calls(rows_before) == {"gather": 4, "kernel": 4,
+                                        "scatter_add": 0}
     # A rank sends 3 x 8192 tokens of 2304 in bfloat16 and float32, twice.
     assert registry.gauge("moe.exchange.bytes").value() \
         == 2 * 3 * 8192 * 2304 * 6
@@ -315,6 +322,18 @@ def test_mellum2_step_compiles_for_four_v5e_chips(v5e_chip):
     assert sum("rope_bwd" in line for line in rope) == 8
     assert any("/moe_experts/" in line and "/mlp/shard_map/" in line
                for line in kernels)
+    # The row kernel, a layer, under the usual buffer (the second buffer's
+    # branch keeps XLA's gathers): the rows to the buffer, again where the
+    # backward pass recomputes them, and their cotangents back to the tokens
+    # under ``moe_dispatch``; the rows summed into their tokens and their
+    # cotangents under ``moe_combine`` (the recomputed sum is dead code).
+    # Inside the layer's own ``shard_map``: no new wrapper.
+    rows = [line for line in kernels
+            if re.search(r'op_name="[^"]*/live_rows/pallas_call"', line)]
+    assert all("/mlp/shard_map/" in line for line in rows)
+    assert sum("/moe_dispatch/" in line for line in rows) == 4 * 3
+    assert sum("/moe_combine/" in line for line in rows) == 4 * 2
+    assert len(rows) == 20 and len(kernels) == 92 + 20
     assert _row_scatters(text, 2304) == []
     moved = [line for line in text.splitlines() if re.search(
         r"= \S*\[(1,)?(8192|32768),2304\]\S* (all-gather|reduce-scatter|"

@@ -158,18 +158,18 @@ def test_softmax_router_normalises_the_chosen():
 E, K, F, W = 16, 4, 24, 16
 
 
-def _layer(mesh):
+def _layer(mesh, implementation="ragged_dot"):
     from deeplearning_cfn_tpu.models.moe import HeldExpertsMlp, \
         SoftmaxTopKRouter
 
     return HeldExpertsMlp(num_experts=E, mlp_dim=W, dtype=jnp.float32,
                           router=SoftmaxTopKRouter(E, K), mesh=mesh,
-                          implementation="ragged_dot")
+                          implementation=implementation)
 
 
-def _case(routing):
+def _case(routing, width=F):
     rng = np.random.RandomState(3)
-    x = rng.normal(0, 1, (4, 16, F)).astype(np.float32)
+    x = rng.normal(0, 1, (4, 16, width)).astype(np.float32)
     params = jax.tree_util.tree_map(
         np.asarray, _layer(None).init(jax.random.PRNGKey(2), x)["params"])
     if routing == "skewed":
@@ -184,15 +184,16 @@ def _case(routing):
 
 def _plain(params, x):
     """Every expert over every token, weighted by the router's choice."""
-    m = x.reshape(-1, F).astype(np.float64)
+    width = x.shape[-1]
+    m = x.reshape(-1, width).astype(np.float64)
     logits = m @ params["router"]["kernel"].astype(np.float64)
     probs = np.exp(logits - logits.max(-1, keepdims=True))
     probs /= probs.sum(-1, keepdims=True)
     best = np.argsort(-probs, axis=-1, kind="stable")[:, :K]
     top = np.take_along_axis(probs, best, axis=-1)
     top /= top.sum(-1, keepdims=True)
-    w_in = params["experts_in"]["kernel"].reshape(E, F, 2 * W)
-    w_out = params["experts_out"]["kernel"].reshape(E, W, F)
+    w_in = params["experts_in"]["kernel"].reshape(E, width, 2 * W)
+    w_out = params["experts_out"]["kernel"].reshape(E, W, width)
     out = np.zeros_like(m)
     for e in range(E):
         weight = np.where(best == e, top, 0.0).sum(-1)
@@ -203,11 +204,15 @@ def _plain(params, x):
     return out.reshape(x.shape), best
 
 
-@pytest.mark.parametrize("axes", [dict(expert=4), dict(data=2, expert=2)],
-                         ids=["expert4", "data2_expert2"])
+@pytest.mark.parametrize("axes,width,impl", [
+    (dict(expert=4), F, "ragged_dot"),
+    (dict(data=2, expert=2), F, "ragged_dot"),
+    # The rows fetched by the row kernel (interpreted), a rank each.
+    (dict(expert=4), 128, "interpret")],
+    ids=["expert4", "data2_expert2", "expert4_row_kernel"])
 @pytest.mark.parametrize("routing", ["seeded", "skewed"])
 def test_exchange_gives_the_whole_layer_and_its_gradients(devices, routing,
-                                                          axes):
+                                                          axes, width, impl):
     """The layer over a mesh with an ``expert`` axis against the same
     parameters on one device and against every expert over every token:
     the result, and through the exchange's backward pass the gradient of
@@ -218,7 +223,7 @@ def test_exchange_gives_the_whole_layer_and_its_gradients(devices, routing,
 
     mesh = _mesh(devices, **axes)
     ranks = axes["expert"]
-    x, params = _case(routing)
+    x, params = _case(routing, width)
     registry = get_tracer().registry
     exchanges = registry.counter("moe.exchange.calls")
     wrapped = registry.counter("parallel.shard_map.calls")
@@ -232,14 +237,14 @@ def test_exchange_gives_the_whole_layer_and_its_gradients(devices, routing,
         return jax.jit(jax.value_and_grad(loss, argnums=(0, 1),
                                           has_aux=True))(params, x)
 
-    (_, (y4, aux4)), grads4 = run(_layer(mesh))
+    (_, (y4, aux4)), grads4 = run(_layer(mesh, impl))
     assert exchanges.value(**label) == before[0] + 1
     assert wrapped.value(kernel="gmm") == before[1] + 1
     # A rank sends its 64 / ways tokens' rows in float32 and takes the
     # other ranks' float32 parts, forward, and the transposes backward.
     ways = int(np.prod(list(axes.values())))
     assert registry.gauge("moe.exchange.bytes").value() \
-        == 2 * (ranks - 1) * (64 // ways) * F * (4 + 4)
+        == 2 * (ranks - 1) * (64 // ways) * width * (4 + 4)
     (_, (y1, aux1)), grads1 = run(_layer(None))
     want, best = _plain(params, x)
     np.testing.assert_allclose(y4, want, rtol=2e-5, atol=2e-5)
@@ -261,9 +266,13 @@ def test_exchange_gives_the_whole_layer_and_its_gradients(devices, routing,
         assert 1.0 <= float(aux4["rank_load_max_over_mean"]) < ranks
 
 
-def test_windows_of_the_second_buffer_add_up():
+@pytest.mark.parametrize("width,path", [(F, "gather"), (128, "interpret")])
+def test_windows_of_the_second_buffer_add_up(width, path):
     """``_in_passes`` alone: three windows of 40 rows over 100 sorted pairs
-    (the last one padded) give what one buffer of every pair gives."""
+    (the last one padded) give what one buffer of every pair gives, by XLA's
+    gathers and by the row kernel, in value and in every gradient (a
+    window's cotangent of the weights goes back through the window's own
+    places)."""
     import functools
 
     from deeplearning_cfn_tpu.models.moe import _held_rows, _in_passes, \
@@ -271,24 +280,34 @@ def test_windows_of_the_second_buffer_add_up():
 
     rng = np.random.RandomState(5)
     tokens, top_k, count, e = 25, 4, 3, 5
-    m = jnp.asarray(rng.normal(0, 1, (tokens, F)), jnp.float32)
+    m = jnp.asarray(rng.normal(0, 1, (tokens, width)), jnp.float32)
     chosen = jnp.asarray(rng.randint(0, e, (tokens, top_k)))
     weight = jnp.asarray(rng.uniform(0.1, 1, (tokens * top_k,)), jnp.float32)
-    w_in = jnp.asarray(rng.normal(0, 0.3, (count, F, 2 * W)), jnp.float32)
-    w_out = jnp.asarray(rng.normal(0, 0.3, (count, W, F)), jnp.float32)
+    w_in = jnp.asarray(rng.normal(0, 0.3, (count, width, 2 * W)), jnp.float32)
+    w_out = jnp.asarray(rng.normal(0, 0.3, (count, W, width)), jnp.float32)
     group = jnp.minimum(chosen.reshape(-1), count)
     order = jnp.argsort(group, stable=True).astype(jnp.int32)
     sizes = jnp.sum(group[:, None] == jnp.arange(count)[None, :], axis=0,
                     dtype=jnp.int32)
-    part = lambda rows: functools.partial(
+    part = lambda rows, path: functools.partial(
         _held_rows, rows=rows, top_k=top_k, implementation="ragged_dot",
-        out_dtype=jnp.float32)
-    operands = (m, weight, order, inverse_permutation(order), sizes,
-                jnp.sum(sizes), w_in, w_out)
+        path=path, out_dtype=jnp.float32)
+    fixed = (order, inverse_permutation(order), sizes, jnp.sum(sizes))
     assert int(jnp.sum(sizes)) > 40
-    want = part(tokens * top_k)(*operands)
-    got = _in_passes(part(40), 40, *operands)
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+    def loss(fn):
+        return lambda m, weight, w_in, w_out: jnp.sum(jnp.square(
+            fn(m, weight, *fixed, w_in, w_out)))
+
+    moved = (m, weight, w_in, w_out)
+    want = jax.value_and_grad(loss(part(tokens * top_k, "gather")),
+                              argnums=(0, 1, 2, 3))(*moved)
+    got = jax.value_and_grad(loss(functools.partial(
+        _in_passes, part(40, path), 40)), argnums=(0, 1, 2, 3))(*moved)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        scale = max(float(jnp.max(jnp.abs(b))), 1e-6)
+        np.testing.assert_allclose(a, b, rtol=0, atol=2e-5 * scale)
 
 
 # -- the whole step ------------------------------------------------------------

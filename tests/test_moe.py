@@ -299,10 +299,17 @@ _BUFFERS = {
     "every_pair_held": (_EXPERTS, None),
     # Two experts are held, and the routing draws from the others alone.
     "no_row_held": (0, 8),
+    # More pairs held than the buffer has rows: its rows are all live.
+    "more_held_than_the_buffer": (4, -3),
 }
+# (choices a token, the rows' width, how a row is fetched): XLA's gathers at
+# any width, and the row kernel interpreted, which wants whole lane tiles: 256,
+# and 384 = 3 x 128, no power of two, as Mellum2's 2304 = 18 x 128 is none.
+_FETCHES = [(1, _WIDTH, "gather"), (4, _WIDTH, "gather"),
+            (1, 256, "interpret"), (8, 384, "interpret")]
 
 
-def _sorted_routing(seed, top_k, held):
+def _sorted_routing(seed, top_k, held, experts=_EXPERTS):
     """A token's ``top_k`` choices, distinct experts, and what the layer
     makes of them: ``(chosen, group, order, inv, n_held)``. ``held`` 0 holds
     two experts that no token chooses."""
@@ -310,7 +317,7 @@ def _sorted_routing(seed, top_k, held):
 
     rng = np.random.RandomState(seed)
     count = held or 2
-    drawn_from = _EXPERTS if held else _EXPERTS - count
+    drawn_from = experts if held else experts - count
     chosen = np.stack([rng.permutation(drawn_from)[:top_k]
                        for _ in range(_TOKENS)])
     if not held:
@@ -321,22 +328,28 @@ def _sorted_routing(seed, top_k, held):
         int(jnp.sum(group < count))
 
 
-@pytest.mark.parametrize("top_k", [1, 4])
+@pytest.mark.parametrize("top_k,width,path", _FETCHES)
 @pytest.mark.parametrize("buffer", sorted(_BUFFERS))
-def test_rows_move_as_the_plain_forms_move_them(buffer, top_k):
+def test_rows_move_as_the_plain_forms_move_them(buffer, top_k, width, path):
     """``take_rows`` and ``sum_rows`` against what they replace, the masked
     gather ``where(valid, m[token], 0)`` and ``segment_sum`` of the masked,
     weighted rows, in value and in every gradient (``m``; ``y`` and the
-    weights), in float32."""
+    weights), in float32, by XLA's gathers and by the row kernel."""
     from deeplearning_cfn_tpu.models.moe import sum_rows, take_rows
 
     held, spare = _BUFFERS[buffer]
-    chosen, group, order, inv, n_held = _sorted_routing(7, top_k, held)
+    # Eight distinct choices want more than eight experts to draw from.
+    experts = max(_EXPERTS, 2 * top_k)
+    held = experts if held == _EXPERTS else held
+    chosen, group, order, inv, n_held = _sorted_routing(7, top_k, held,
+                                                        experts)
     pairs = _TOKENS * top_k
-    rows = pairs if spare is None else n_held + spare
+    rows = pairs if spare is None else max(n_held + spare, 1)
     assert 0 < rows <= pairs and 0 <= n_held <= pairs
-    if held in (0, _EXPERTS):
+    if held in (0, experts):
         assert n_held == (pairs if held else 0)
+    if spare is not None and spare < 0:
+        assert n_held > rows
     # A token's choices are distinct experts, so no group holds a token
     # twice: a token's live rows are as many as the held experts it chose.
     assert all(len(set(row)) == top_k for row in chosen.tolist())
@@ -345,11 +358,11 @@ def test_rows_move_as_the_plain_forms_move_them(buffer, top_k):
     n_live = jnp.minimum(n_held, rows)
     valid = (jnp.arange(rows) < n_live)[:, None]
     keys = jax.random.split(jax.random.PRNGKey(3), 5)
-    m = jax.random.normal(keys[0], (_TOKENS, _WIDTH))
-    y = jax.random.normal(keys[1], (rows, _WIDTH))
+    m = jax.random.normal(keys[0], (_TOKENS, width))
+    y = jax.random.normal(keys[1], (rows, width))
     weight = jax.random.uniform(keys[2], (pairs,))
-    d_xs = jax.random.normal(keys[3], (rows, _WIDTH))
-    d_out = jax.random.normal(keys[4], (_TOKENS, _WIDTH))
+    d_xs = jax.random.normal(keys[3], (rows, width))
+    d_out = jax.random.normal(keys[4], (_TOKENS, width))
 
     def plain_take(m):
         return jnp.where(valid, m[token], 0)
@@ -361,19 +374,34 @@ def test_rows_move_as_the_plain_forms_move_them(buffer, top_k):
 
     want, back = jax.vjp(plain_take, m)
     got, back_got = jax.vjp(
-        lambda m: take_rows(m, token, inv, n_live, top_k), m)
+        lambda m: take_rows(m, token, inv, n_live, top_k, path), m)
     np.testing.assert_array_equal(got, want)
     np.testing.assert_allclose(back_got(d_xs)[0], back(d_xs)[0], atol=1e-5)
 
     want, back = jax.vjp(plain_sum, y, weight)
     got, back_got = jax.vjp(
-        lambda y, w: sum_rows(y, w, order, inv, n_live, top_k), y, weight)
+        lambda y, w: sum_rows(y, w, order, inv, n_live, top_k, None, path),
+        y, weight)
     np.testing.assert_allclose(got, want, atol=1e-5)
     for a, b in zip(back_got(d_out), back(d_out)):
         assert a.shape == b.shape and a.dtype == b.dtype
-        np.testing.assert_allclose(a, b, atol=1e-5)
+        np.testing.assert_allclose(a, b, atol=1e-4 if width > 128 else 1e-5)
     if not n_held:
         assert not np.any(got) and not np.any(back_got(d_out)[0])
+
+
+def test_a_tokens_live_pairs_come_first_in_the_choices_order():
+    """``_live_first``: what the kernel walks on the tokens' side."""
+    from deeplearning_cfn_tpu.models.moe import _live_first
+
+    inv = jnp.asarray([5, 90, 2, 70, 80, 60, 50, 40, 1, 0, 3, 4], jnp.int32)
+    weight = jnp.arange(12, dtype=jnp.float32) + 1
+    at, count, first = _live_first(inv, 6, 4, weight)
+    np.testing.assert_array_equal(count, [2, 0, 4])
+    np.testing.assert_array_equal(at[0, :2], [5, 2])
+    np.testing.assert_array_equal(first[0, :2], [1.0, 3.0])
+    np.testing.assert_array_equal(at[2], [1, 0, 3, 4])
+    np.testing.assert_array_equal(first[2], [9.0, 10.0, 11.0, 12.0])
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
@@ -389,27 +417,60 @@ def test_inverse_of_the_sort_and_where_dead_pairs_lie(top_k, held, seed):
     assert np.all(np.asarray(inv)[~dead] < n_held)
 
 
+@pytest.mark.parametrize("width,impl,path", [(48, "auto", "gather"),
+                                             (128, "interpret", "kernel")])
 @pytest.mark.parametrize("top_k,held,branches", [(1, 4, 0), (2, 2, 1)])
-def test_held_experts_layer_counts_a_call_once(top_k, held, branches):
+def test_held_experts_layer_counts_a_call_once(top_k, held, branches, width,
+                                               impl, path):
     """``moe.rows.calls`` counts a layer call when it is traced: once, not
     once a branch of the ``lax.cond`` over the two buffers nor again where
-    ``jax.checkpoint`` traces the rows' part for the backward pass."""
+    ``jax.checkpoint`` traces the rows' part for the backward pass; under
+    ``path=kernel`` where the row kernel fetches the rows (rows of whole
+    lane tiles, the kernels named or a TPU), else ``path=gather``."""
     from deeplearning_cfn_tpu.models.moe import HeldExpertsMlp
     from deeplearning_cfn_tpu.obs.trace import get_tracer
 
-    x = jax.random.normal(jax.random.PRNGKey(0), (2, 16, 48))
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 16, width))
     layer = HeldExpertsMlp(num_experts=8, mlp_dim=16, top_k=top_k,
-                           held=(0, held), dtype=jnp.float32)
+                           held=(0, held), dtype=jnp.float32,
+                           implementation=impl)
     params = layer.init(jax.random.PRNGKey(1), x)["params"]
     calls = get_tracer().registry.counter("moe.rows.calls")
-    before = {path: calls.value(path=path)
-              for path in ("gather", "scatter_add")}
+    paths = ("gather", "kernel", "scatter_add")
+    before = {p: calls.value(path=p) for p in paths}
     loss = lambda p: jnp.sum(layer.apply({"params": p}, x)[0] ** 2)
     text = str(jax.make_jaxpr(jax.grad(loss))(params))
-    assert {path: calls.value(path=path) - n for path, n in before.items()} \
-        == {"gather": 1, "scatter_add": 0}
-    assert (" cond[" in text) == bool(branches)
+    assert {p: calls.value(path=p) - n for p, n in before.items()} \
+        == {p: int(p == path) for p in paths}
+    # (The kernel's own text has its ``pl.when``s.)
+    assert path == "kernel" or (" cond[" in text) == bool(branches)
+    assert ("live_rows" in text) == (path == "kernel")
     # No row-wide scatter-add either way: what is scattered is integers
     # (the sort's inverse) or a pair's scalar (the router's top-k).
     assert "scatter" in text
-    assert not re.findall(r",48\] = scatter", text)
+    assert not re.findall(rf",{width}\] = scatter", text)
+
+
+@pytest.mark.parametrize("tokens,width,dtype,impl,path", [
+    # A rank of Mellum2's four: 32,768 tokens of 2304 in bfloat16, 151 MB.
+    (32768, 2304, jnp.bfloat16, "megablox", "kernel"),
+    (32768, 2048, jnp.bfloat16, "megablox", "kernel"),      # 2 ** 27 bytes
+    (16384, 2048, jnp.float32, "megablox", "kernel"),
+    (24, 2304, jnp.bfloat16, "interpret", "interpret"),
+    # Under 2 ** 27 bytes of tokens XLA's gather is the cheaper: Laguna's
+    # and ZAYA1's 8,192 tokens of 2048, and 24,576 of them.
+    (8192, 2048, jnp.bfloat16, "megablox", "gather"),
+    (24576, 2048, jnp.bfloat16, "megablox", "gather"),
+    # Off the TPU, as ``grouped_matmul`` falls back to ``ragged_dot``.
+    (32768, 2304, jnp.bfloat16, "auto", "gather"),
+    (32768, 2304, jnp.bfloat16, "ragged_dot", "gather"),
+    # Rows the kernel cannot move: not whole lane tiles; 16-bit floats.
+    (32768, 2304 + 64, jnp.bfloat16, "megablox", "gather"),
+    (32768, 2304, jnp.float16, "interpret", "gather"),
+])
+def test_how_a_row_is_fetched_follows_what_the_layer_sees(tokens, width,
+                                                          dtype, impl, path):
+    from deeplearning_cfn_tpu.models.moe import rows_path
+
+    assert jax.default_backend() == "cpu"
+    assert rows_path(impl, tokens, width, dtype) == path

@@ -2,10 +2,14 @@
 """Time the ways an expert layer moves its rows, alone on the chip.
 
     python3 tools/moe_rows_sweep.py [--steps 20] [--shape zaya1]
+    python3 tools/moe_rows_sweep.py --quick --case mellum2_rank:32768:2304
 
 For each of the expert cells' buffer shapes (rows of 2048 in bfloat16, the
 (token, choice) pairs of 8,192 tokens sorted by expert as ``HeldExpertsMlp``
-sorts them) it times, forward and backward apart:
+sorts them; ``--case shape:tokens:width`` names another size, and
+``--separating`` the cases that tell a row's width from its source's size:
+a rank of Mellum2's routing at widths 2048 / 2304 / 2560 and 8,192 / 32,768
+tokens, Laguna's at width 2304) it times, forward and backward apart:
 
 * ``order``: the sort the layer already makes, and the three ways to its
   inverse ``inv`` (a scatter of ``arange``, a second ``argsort``, counting
@@ -24,12 +28,21 @@ sorts them) it times, forward and backward apart:
   ``models/moe.py:sum_rows`` in the same three forms, and backward either
   way.
 
+* with ``--quick`` only what ``models/moe.py`` does today and its candidates:
+  each of the four movements by XLA's gathers (``path="gather"``) and by the
+  row kernel (``ops/rows.py``, ``path="kernel"``: the source's copy to pairs
+  of rows and the indices' preparation are in its time; ``view`` is that
+  copy alone), and with ``--views`` XLA's gather over the source seen
+  another way: as 32-bit words ``[M, F / 2]``, as ``[M, F / 256, 256]``,
+  split at 2048 columns.
+
 A time is the device's busy time a call: the sum of the durations of every
 operation the call put on the device, from the profiler's trace. Beside it
 the least the chip's bandwidth allows: the live rows read once and the
 result written once. ``unequal`` is the largest difference from the
-scatter-add form's result. ``models/moe.py`` takes its path from this table
-(PERF.md, PR 33). A chip run only: it stops where jax finds no TPU.
+scatter-add form's result (with ``--quick``, from XLA's gather). ``models/moe
+.py`` takes its path from this table (PERF.md, PRs 33 and 36). A chip run
+only: it stops where jax finds no TPU.
 """
 
 from __future__ import annotations
@@ -52,20 +65,24 @@ import jax.numpy as jnp  # noqa: E402
 from deeplearning_cfn_tpu.models import moe  # noqa: E402
 
 TOKENS, WIDTH = 8192, 2048
-# name -> (choices a token, experts, experts held, rows of the buffer, the
-# share of a token's choices that fall on held experts)
+# name -> (choices a token, experts, experts held, whether the buffer holds
+# every pair or the usual twice a uniform router's rows, the share of a
+# token's choices that fall on held experts)
 SHAPES = {
     # Laguna-XS.2's usual buffer: twice a uniform router's 8,192 rows.
-    "laguna": (8, 256, 32, 16384, 1 / 8),
+    "laguna": (8, 256, 32, False, 1 / 8),
     # Its other buffer, every pair, taken where a step sends over 16,384.
-    "laguna_every_pair": (8, 256, 32, 65536, 0.3),
+    "laguna_every_pair": (8, 256, 32, True, 0.3),
     # ZAYA1-8B: one choice a token, the one buffer of every pair.
-    "zaya1": (1, 16, 8, 8192, 1 / 2),
+    "zaya1": (1, 16, 8, True, 1 / 2),
     # A rank of Mellum2's four on its host, after the gather of the ranks'
-    # tokens (run it with ``--tokens 32768 --width 2304``): 262,144 pairs,
-    # 16 of 64 experts held, twice a uniform router's 65,536 rows.
-    "mellum2_rank": (8, 64, 16, 131072, 1 / 4),
+    # tokens (``mellum2_rank:32768:2304``): 262,144 pairs, 16 of 64 experts
+    # held, twice a uniform router's 65,536 rows.
+    "mellum2_rank": (8, 64, 16, False, 1 / 4),
 }
+# What tells a row's width from its source's size (PERF.md, PR 36).
+SEPARATING = [f"mellum2_rank:{t}:{w}" for t in (8192, 32768)
+              for w in (2048, 2304, 2560)] + ["laguna:8192:2304"]
 
 
 def _busy_ms(fn, args, steps):
@@ -176,8 +193,54 @@ GATHERS = {"module": moe._rows_of_tokens, "by_choice": rows_by_choice,
            "token_major": rows_token_major, "choice_major": rows_choice_major}
 
 
-def measure(name, steps, say):
-    top_k, experts, held, rows, share = SHAPES[name]
+# -- the source seen another way, for XLA's gather -----------------------------
+
+
+def as_words(src):
+    """``[M, F]`` bfloat16 as 32-bit words ``[M, F / 2]``, and the way back."""
+    seen = jax.lax.bitcast_convert_type(
+        src.reshape(src.shape[0], -1, 2), jnp.uint32)
+    return (seen,), lambda got: jax.lax.bitcast_convert_type(
+        got[0], jnp.bfloat16).reshape(got[0].shape[0], -1)
+
+
+def as_tiles256(src):
+    seen = src.reshape(src.shape[0], -1, 256)
+    return (seen,), lambda got: got[0].reshape(got[0].shape[0], -1)
+
+
+def split_at_2048(src):
+    return (src[:, :2048], src[:, 2048:]), \
+        lambda got: jnp.concatenate(got, axis=1)
+
+
+VIEWS = {"words": as_words, "tiles256": as_tiles256, "split2048": split_at_2048}
+
+
+def viewed_take(view, m, token, n_live):
+    parts, back = view(m)
+    live = (jnp.arange(token.shape[0]) < n_live)[:, None]
+    return jnp.where(live, back([p[token] for p in parts]), 0)
+
+
+def viewed_sum(view, y, weight, inv, n_live, top_k):
+    """``rows_by_choice`` over the viewed source."""
+    parts, back = view(y)
+    at = jnp.minimum(inv, y.shape[0] - 1).reshape(-1, top_k)
+    total = jnp.zeros((at.shape[0], y.shape[1]), jnp.float32)
+    for j in range(top_k):
+        got = back([p[at[:, j]] for p in parts]).astype(jnp.float32) \
+            * weight.reshape(-1, top_k)[:, j, None]
+        total = total + jnp.where(
+            (inv.reshape(-1, top_k)[:, j] < n_live)[:, None], got, 0)
+    return total.astype(y.dtype)
+
+
+def measure(name, steps, say, quick=False, views=False):
+    top_k, experts, held, every_pair, share = SHAPES[name]
+    pairs = TOKENS * top_k
+    rows = pairs if every_pair else min(pairs, moe._whole_tiles(
+        int(moe._BUFFER_SHARE * pairs * held / experts)))
     keys = jax.random.split(jax.random.PRNGKey(0), 5)
     group = routing(keys[0], top_k, experts, held, share)
     order = order_of(group)
@@ -194,8 +257,8 @@ def measure(name, steps, say):
 
     peak = device.peaks_of(jax.devices()[0].device_kind)["hbm_bytes_per_s"]
     live_rows = int(n_live)
-    head = {"shape": name, "top_k": top_k, "rows": rows, "pairs":
-            TOKENS * top_k, "live_rows": live_rows}
+    head = {"shape": name, "tokens": TOKENS, "width": WIDTH, "top_k": top_k,
+            "rows": rows, "pairs": pairs, "live_rows": live_rows}
     # The live rows read, the tokens written, both in bfloat16.
     floor_ms = (live_rows + TOKENS) * WIDTH * 2 / peak * 1e3
 
@@ -219,6 +282,50 @@ def measure(name, steps, say):
         say(line)
         return got
 
+    take_back = lambda path: lambda d, t, i, n: jax.vjp(
+        lambda m: moe.take_rows(m, t, i, n, top_k, path), m)[1](d)[0]
+    sum_there = lambda path: lambda y, w, o, i, n: moe.sum_rows(
+        y, w, o, i, n, top_k, None, path)
+    sum_back = lambda path: lambda y, w, o, i, n, g: jax.vjp(
+        lambda y, w: moe.sum_rows(y, w, o, i, n, top_k, None, path),
+        y, w)[1](g)
+    d_xs = y
+    there = 2 * live_rows * WIDTH * 2 / peak * 1e3
+    if quick:
+        # The four movements as the module makes them, by XLA's gathers and
+        # by the row kernel, and XLA's gather over the re-viewed source.
+        views = {form: view for form, view in VIEWS.items()
+                 if views and (form != "split2048" or WIDTH > 2048)}
+        for src, seen in ((m, "tokens"), (y, "buffer")):
+            timed("view", seen, lambda s: s.reshape(-1, 2, s.shape[1]),
+                  (src,), floor=2 * src.size * 2 / peak * 1e3)
+        want = timed("dispatch_fwd", "gather", plain_take,
+                     (m, token, n_live), floor=there)
+        timed("dispatch_fwd", "kernel", lambda m, t, i, n: moe.take_rows(
+            m, t, i, n, top_k, "kernel"), (m, token, inv, n_live), want=want,
+            floor=there)
+        for form, view in views.items():
+            timed("dispatch_fwd", form, functools.partial(viewed_take, view),
+                  (m, token, n_live), want=want, floor=there)
+        want = timed("dispatch_bwd", "take_rows", take_back("gather"),
+                     (d_xs, token, inv, n_live), floor=floor_ms)
+        timed("dispatch_bwd", "kernel", take_back("kernel"),
+              (d_xs, token, inv, n_live), want=want, floor=floor_ms)
+        want = timed("combine_fwd", "module", sum_there("gather"),
+                     (y, weight, order, inv, n_live), floor=floor_ms)
+        timed("combine_fwd", "kernel", sum_there("kernel"),
+              (y, weight, order, inv, n_live), want=want, floor=floor_ms)
+        for form, view in views.items():
+            timed("combine_fwd", form, lambda y, w, i, n, view=view:
+                  viewed_sum(view, y, w, i, n, top_k),
+                  (y, weight, inv, n_live), want=want, floor=floor_ms)
+        want = timed("combine_bwd", "sum_rows", sum_back("gather"),
+                     (y, weight, order, inv, n_live, g_out), floor=floor_ms)
+        timed("combine_bwd", "kernel", sum_back("kernel"),
+              (y, weight, order, inv, n_live, g_out), want=want,
+              floor=floor_ms)
+        return
+
     timed("order", "argsort", order_of, (group,))
     for form, fn in (("scatter", inv_by_scatter), ("argsort", inv_by_argsort),
                      ("counting", functools.partial(inv_by_counting,
@@ -227,8 +334,7 @@ def measure(name, steps, say):
 
     # Dispatch: forward the one gather; backward by scatter-add and by gather.
     timed("dispatch_fwd", "gather", plain_take, (m, token, n_live),
-          floor=2 * live_rows * WIDTH * 2 / peak * 1e3)
-    d_xs = y
+          floor=there)
     want = timed("dispatch_bwd", "scatter_add", lambda d, t, n: jax.vjp(
         lambda m: plain_take(m, t, n), m)[1](d)[0], (d_xs, token, n_live),
         floor=floor_ms)
@@ -236,9 +342,8 @@ def measure(name, steps, say):
         timed("dispatch_bwd", form, lambda d, i, n, gather=gather: gather(
             d, None, i, n, top_k).astype(d.dtype), (d_xs, inv, n_live),
             want=want, floor=floor_ms)
-    timed("dispatch_bwd", "take_rows", lambda d, t, i, n: jax.vjp(
-        lambda m: moe.take_rows(m, t, i, n, top_k), m)[1](d)[0],
-        (d_xs, token, inv, n_live), want=want, floor=floor_ms)
+    timed("dispatch_bwd", "take_rows", take_back("gather"),
+          (d_xs, token, inv, n_live), want=want, floor=floor_ms)
 
     # Combine: forward by segment_sum and by gather; backward either way.
     want = timed("combine_fwd", "scatter_add", lambda y, w, p, n: plain_sum(
@@ -250,9 +355,8 @@ def measure(name, steps, say):
     want = timed("combine_bwd", "scatter_add", lambda y, w, p, n, g: jax.vjp(
         lambda y, w: plain_sum(y, w, p, n, top_k), y, weight)[1](g),
         (y, weight, pair, n_live, g_out), floor=floor_ms)
-    timed("combine_bwd", "sum_rows", lambda y, w, o, i, n, g: jax.vjp(
-        lambda y, w: moe.sum_rows(y, w, o, i, n, top_k), y, weight)[1](g),
-        (y, weight, order, inv, n_live, g_out), want=want, floor=floor_ms)
+    timed("combine_bwd", "sum_rows", sum_back("gather"),
+          (y, weight, order, inv, n_live, g_out), want=want, floor=floor_ms)
 
 
 def main():
@@ -264,11 +368,25 @@ def main():
                     help="only this shape (repeatable)")
     ap.add_argument("--tokens", type=int, default=TOKENS)
     ap.add_argument("--width", type=int, default=WIDTH)
+    ap.add_argument("--case", action="append", default=[],
+                    metavar="SHAPE:TOKENS:WIDTH",
+                    help="a shape at its own size (repeatable)")
+    ap.add_argument("--separating", action="store_true",
+                    help="the cases that tell the width from the size")
+    ap.add_argument("--quick", action="store_true",
+                    help="the module's four movements by XLA's gathers and "
+                    "by the row kernel")
+    ap.add_argument("--views", action="store_true",
+                    help="with --quick: XLA's gather over the re-viewed "
+                    "source too")
     args = ap.parse_args()
-    globals().update(TOKENS=args.tokens, WIDTH=args.width)
     if jax.default_backend() != "tpu":
         sys.exit("moe_rows_sweep: no TPU here; a time comes only from a "
                  "chip run")
+    cases = args.case + (SEPARATING if args.separating else [])
+    if not cases:
+        cases = [f"{name}:{args.tokens}:{args.width}" for name in args.shape
+                 or [n for n in SHAPES if n != "mellum2_rank"]]
     os.makedirs(os.path.dirname(args.out), exist_ok=True)
     with open(args.out, "w") as f:
         def say(line):
@@ -278,8 +396,10 @@ def main():
             f.flush()
 
         say({"device": jax.devices()[0].device_kind, "steps": args.steps})
-        for name in args.shape or [n for n in SHAPES if n != "mellum2_rank"]:
-            measure(name, args.steps, say)
+        for case in cases:
+            name, tokens, width = case.split(":")
+            globals().update(TOKENS=int(tokens), WIDTH=int(width))
+            measure(name, args.steps, say, args.quick, args.views)
 
 
 if __name__ == "__main__":
