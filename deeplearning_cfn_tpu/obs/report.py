@@ -11,8 +11,10 @@ tool), and reports only the sections it has data for.
 Sections:
 
 - **train** — steps reached, step-time p50/p95 (from the additive
-  ``step_time_s`` boundary key), examples/sec (last + peak), compile
-  time, eval/final-eval metrics, checkpoint store retries.
+  ``step_time_s`` boundary key), examples/sec (last + peak), the first
+  step's seconds (the record key ``compile_s``) and, from the spans
+  ``train.first_step`` / ``train.init_state`` / ``train.retrace``, what
+  they went to, eval/final-eval metrics, checkpoint store retries.
 - **serve** — from the last ``serve_*`` snapshot: tokens/sec, queue
   wait / TTFT / latency / step-latency percentiles, admission counters.
 - **spans** — per-name count and duration p50/p95 from span records
@@ -83,6 +85,37 @@ def collect(path: str) -> Tuple[List[Dict[str, Any]], List[str], int]:
     return records, [path], skipped
 
 
+def _start_of(train: List[Dict[str, Any]],
+              spans: List[Dict[str, Any]]) -> Optional[Dict[str, Any]]:
+    """Where the start of the run went, from the first ``train.first_step``
+    record (jax's seconds for the step's trace, lowering and backend
+    compile, which on a cache hit is the load; what the cache was asked,
+    answered and saved; the step's own wait for its batch), the first
+    ``train.init_state`` and every retrace. None for a run that wrote no
+    ``train.first_step`` (spans off, or a run from before the span)."""
+    first = next((r for r in spans if r["span"] == "train.first_step"), None)
+    if first is None:
+        return None
+    named = sum(first.get(k) or 0.0 for k in
+                ("trace_s", "lower_s", "backend_compile_s", "next_batch_s"))
+    init = next((r for r in spans if r["span"] == "train.init_state"), {})
+    return {
+        "first_step_s": first["dur_s"],
+        **{k: first.get(k) for k in (
+            "trace_s", "lower_s", "backend_compile_s", "next_batch_s",
+            "cache_requests", "cache_hits", "cache_misses",
+            "cache_retrieval_s", "cache_saved_s")},
+        "other_s": first["dur_s"] - named,
+        "init_state_s": init.get("dur_s"),
+        "params": init.get("params"),
+        "state_bytes": init.get("bytes"),
+        "retraces": sum(r["retraces"] for r in train
+                        if isinstance(r.get("retraces"), (int, float))),
+        "retrace_steps": [r.get("step") for r in spans
+                          if r["span"] == "train.retrace"],
+    }
+
+
 def _pct_pair(xs: List[float]) -> Dict[str, Optional[float]]:
     return {"p50": percentile(xs, 50), "p95": percentile(xs, 95)}
 
@@ -148,6 +181,7 @@ def summarize(path: str,
                 "last": losses[-1] if losses else None,
             },
             "compile_s": compile_s,
+            "start": _start_of(train, spans),
             "ckpt_store_retries": retries[-1] if retries else None,
             "eval": evals or None,
         }
@@ -306,7 +340,28 @@ def render_report(summary: Dict[str, Any]) -> str:
         lo = t["loss"]
         L.append(f"  loss                {_fmt(lo['first'])} -> "
                  f"{_fmt(lo['last'])}")
-        L.append(f"  compile             {_fmt(t['compile_s'], 's')}")
+        L.append(f"  first step          {_fmt(t['compile_s'], 's')}")
+        st0 = t.get("start")
+        if st0:
+            how = "cache load" if st0["cache_hits"] else "compile"
+            L.append(f"    trace / lower     {_fmt(st0['trace_s'], 's')} / "
+                     f"{_fmt(st0['lower_s'], 's')}")
+            L.append(f"    {how:<17} {_fmt(st0['backend_compile_s'], 's')}"
+                     f"  (cache: {_fmt(st0['cache_hits'])} hit(s), "
+                     f"{_fmt(st0['cache_misses'])} stored of "
+                     f"{_fmt(st0['cache_requests'])} request(s); read in "
+                     f"{_fmt(st0['cache_retrieval_s'], 's')}, saved "
+                     f"{_fmt(st0['cache_saved_s'], 's')})")
+            L.append(f"    first batch       "
+                     f"{_fmt(st0['next_batch_s'], 's')}")
+            L.append(f"    other             {_fmt(st0['other_s'], 's')}")
+            L.append(f"  state build         "
+                     f"{_fmt(st0['init_state_s'], 's')}  "
+                     f"({_fmt(st0['params'])} params, "
+                     f"{_fmt(st0['state_bytes'])} bytes)")
+            at = (f"  (at step {', '.join(map(str, st0['retrace_steps']))})"
+                  if st0["retrace_steps"] else "")
+            L.append(f"  retraces            {st0['retraces']}{at}")
         L.append(f"  ckpt store retries  {_fmt(t['ckpt_store_retries'])}")
         if t["eval"]:
             for k, v in sorted(t["eval"].items()):

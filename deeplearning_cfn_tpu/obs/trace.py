@@ -72,6 +72,8 @@ class _NullSpan:
     """Shared do-nothing context manager for the disabled path."""
 
     __slots__ = ()
+    span_id = None
+    dur_s = None
 
     def __enter__(self):
         return self
@@ -95,7 +97,7 @@ def _profiler_annotation(name: str):
 
 class _Span:
     __slots__ = ("_tracer", "name", "span_id", "parent_id", "_t0",
-                 "attrs", "_annotation")
+                 "attrs", "_annotation", "dur_s")
 
     def __init__(self, tracer: "Tracer", name: str, span_id: int,
                  parent_id: Optional[int], attrs: Dict):
@@ -104,6 +106,7 @@ class _Span:
         self.span_id = span_id
         self.parent_id = parent_id
         self.attrs = attrs
+        self.dur_s: Optional[float] = None  # its seconds, once it is closed
         self._t0 = time.monotonic()
 
     def annotate(self, **attrs) -> None:
@@ -120,7 +123,7 @@ class _Span:
     def __exit__(self, exc_type, exc, tb):
         if self._annotation is not None:
             self._annotation.__exit__(exc_type, exc, tb)
-        dur = time.monotonic() - self._t0
+        self.dur_s = dur = time.monotonic() - self._t0
         self._tracer._pop(self, dur, ok=exc_type is None)
         return False
 

@@ -141,39 +141,12 @@ def run_experiment(
 
     rng = jax.random.PRNGKey(cfg.train.seed)
     init_rng, data_rng, train_rng = jax.random.split(rng, 3)
-    state = create_train_state(
-        init_rng, task.init, tx, mesh,
-        param_rules=getattr(task, "param_rules", ()),
-        ema=cfg.train.ema_decay > 0,
-        shard_opt_state=cfg.train.shard_opt_state,
-    )
-
     workdir, ckpt_dir = _workdir_and_ckpt_dir(cfg)
-    ckpt_every = cfg.checkpoint.every_steps or steps_per_epoch
-    manager = CheckpointManager(ckpt_dir, every_steps=ckpt_every,
-                                keep=cfg.checkpoint.keep,
-                                async_write=cfg.checkpoint.async_write,
-                                retry=retry_policy_from_config(cfg.checkpoint))
-    if cfg.checkpoint.resume:
-        # Sweep torn step dirs left by a crashed attempt BEFORE anything
-        # else touches the store: no save is in flight yet, and a later
-        # re-save of a swept step must start from an empty directory.
-        if jax.process_index() == 0:
-            orphans = manager.sweep_orphans()
-            if orphans:
-                print(f"[dlcfn-tpu] swept {len(orphans)} uncommitted "
-                      f"checkpoint dir(s): steps {orphans}")
-        restored, at_step = manager.restore_or_none(state)
-        if restored is not None:
-            state = restored
-            if jax.process_index() == 0:
-                print(f"[dlcfn-tpu] resumed from step {at_step}")
-
-    trainer = _build_trainer(cfg, task, tx, mesh)
     metrics_path = os.path.join(workdir, "metrics.jsonl")
     writer = MetricsWriter(metrics_path)
-    # Span records (train.dispatch/realize/eval, ckpt.save/restore/retry)
-    # flow into the SAME metrics.jsonl — additive lines with a "span" key,
+    # Span records (train.init_state/first_step/dispatch/realize/eval,
+    # ckpt.save/restore/retry) flow into the SAME metrics.jsonl, from the
+    # state's build on — additive lines with a "span" key,
     # not on stdout (spans are high-rate; stdout stays the human stream).
     # Existing keys keep their bytes.
     span_sink = None
@@ -181,25 +154,53 @@ def run_experiment(
         span_sink = JsonlSink(MetricsWriter(metrics_path,
                                             also_stdout=False))
         get_tracer().add_sink(span_sink)
-    if jax.process_index() == 0:
-        print(f"[dlcfn-tpu] {describe(mesh)}")
-        print(f"[dlcfn-tpu] total_steps={total_steps} "
-              f"steps_per_epoch={steps_per_epoch} "
-              f"global_batch={cfg.train.global_batch}")
-
-    def ckpt_hook(step, st, _metrics):
-        manager.save(step, st)
-
-    # ckpt_hook first, chaos kill (test harness, env-gated) after it: the
-    # SIGKILL then lands between a dispatched save and the next one — the
-    # torn-commit window the recovery contract must survive.
-    hooks = [ckpt_hook]
-    chaos_hook = chaos_kill_hook_from_env()
-    if chaos_hook is not None:
-        hooks.append(chaos_hook)
-
-    eval_every = cfg.train.eval_every_steps or steps_per_epoch
     try:
+        state = create_train_state(
+            init_rng, task.init, tx, mesh,
+            param_rules=getattr(task, "param_rules", ()),
+            ema=cfg.train.ema_decay > 0,
+            shard_opt_state=cfg.train.shard_opt_state,
+        )
+
+        ckpt_every = cfg.checkpoint.every_steps or steps_per_epoch
+        manager = CheckpointManager(
+            ckpt_dir, every_steps=ckpt_every, keep=cfg.checkpoint.keep,
+            async_write=cfg.checkpoint.async_write,
+            retry=retry_policy_from_config(cfg.checkpoint))
+        if cfg.checkpoint.resume:
+            # Sweep torn step dirs left by a crashed attempt BEFORE anything
+            # else touches the store: no save is in flight yet, and a later
+            # re-save of a swept step must start from an empty directory.
+            if jax.process_index() == 0:
+                orphans = manager.sweep_orphans()
+                if orphans:
+                    print(f"[dlcfn-tpu] swept {len(orphans)} uncommitted "
+                          f"checkpoint dir(s): steps {orphans}")
+            restored, at_step = manager.restore_or_none(state)
+            if restored is not None:
+                state = restored
+                if jax.process_index() == 0:
+                    print(f"[dlcfn-tpu] resumed from step {at_step}")
+
+        trainer = _build_trainer(cfg, task, tx, mesh)
+        if jax.process_index() == 0:
+            print(f"[dlcfn-tpu] {describe(mesh)}")
+            print(f"[dlcfn-tpu] total_steps={total_steps} "
+                  f"steps_per_epoch={steps_per_epoch} "
+                  f"global_batch={cfg.train.global_batch}")
+
+        def ckpt_hook(step, st, _metrics):
+            manager.save(step, st)
+
+        # ckpt_hook first, chaos kill (test harness, env-gated) after it: the
+        # SIGKILL then lands between a dispatched save and the next one — the
+        # torn-commit window the recovery contract must survive.
+        hooks = [ckpt_hook]
+        chaos_hook = chaos_kill_hook_from_env()
+        if chaos_hook is not None:
+            hooks.append(chaos_hook)
+
+        eval_every = cfg.train.eval_every_steps or steps_per_epoch
         state = trainer.fit(
             state,
             train_pipe.epochs(start_epoch=int(state.step) // steps_per_epoch,

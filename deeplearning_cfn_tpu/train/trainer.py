@@ -25,14 +25,19 @@ import optax
 from jax.sharding import Mesh
 
 from ..config import ExperimentConfig
-from ..obs.trace import get_tracer, span
+from ..obs.trace import get_tracer, obs_enabled, span
 from ..parallel.mesh import build_mesh, validate_batch
 from ..parallel.sharding import batch_sharding, replicated
+from ..runtime import jit_events
 from .state import TrainState
 
 PyTree = Any
 Batch = Dict[str, np.ndarray]
 LossFn = Callable[..., Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]]
+
+# What jax's events call the step: the name of the function
+# ``Trainer._train_step_fn`` returns (``runtime/jit_events.py``).
+STEP_FUN = "train_step"
 
 
 class _LazyShardedJit:
@@ -132,6 +137,13 @@ class Trainer:
         # the mean of per-batch exp(CE) (Jensen), so it cannot be a
         # per-batch eval metric.
         self.eval_derived = dict(eval_derived or {})
+        # The start of this trainer's life, told apart from the rest of it:
+        # until its first step is synced, a trace or a compile of the step
+        # is that step's own (``train.first_step``); after it, a retrace.
+        jit_events.install()
+        self._first_synced = False
+        self._retrace: Optional[list] = None  # [t0, t1, jax's s] if open
+        self._retraces_unreported = 0
 
     # -- sharding helpers ---------------------------------------------------
 
@@ -342,12 +354,18 @@ class Trainer:
         device (``device_batch``) on a background thread, d deep, so
         host→device transfer overlaps the previous step's compute.
 
-        The first dispatched step carries trace+compile cost; the loop
-        syncs on it, reports the wall time as ``compile_s`` on the first
-        logged record, and restarts the throughput window — so the first
-        ``examples_per_sec`` measures post-compile steps only (a boundary
-        with no post-compile steps yet omits the throughput keys rather
-        than report a compile-polluted rate).
+        The first dispatched step carries the step's trace, lowering and
+        compile (or its load from the cache); the loop syncs on it and
+        restarts the throughput window — so the first ``examples_per_sec``
+        measures later steps only (a boundary with none yet omits the
+        throughput keys). Its wall time, from before the first batch to the
+        end of that sync, is the span record ``train.first_step`` (with
+        jax's own seconds for the three, ``runtime/jit_events.py``) and,
+        from the same two clock reads, the key ``compile_s`` of the first
+        logged record: the first step's seconds, whatever the key's name
+        says. Once this trainer's first step is synced, a trace or compile
+        of the step is a retrace: counter ``train.step_retraces``, a
+        ``train.retrace`` span record, ``retraces`` on the next record.
 
         ``trace_dir`` + ``trace_steps``: capture a jax.profiler trace of
         ``trace_steps`` hot-loop steps (skipping the first, compile-heavy
@@ -376,6 +394,8 @@ class Trainer:
         gb = self.cfg.train.global_batch
         compile_s: Optional[float] = None
         first_sync_done = False
+        first_t0 = time.monotonic()  # the tracer's clock
+        jit_before = jit_events.totals(STEP_FUN)
 
         batch_iter = None  # device-staging wrapper, when enabled
         if self.cfg.train.device_prefetch > 0:
@@ -391,6 +411,7 @@ class Trainer:
             def next_batch():
                 return self.device_batch(next(train_iter))
 
+        former_watch = jit_events.watch(STEP_FUN, self._on_step_jit)
         # finally: stop a prefetched iterator's worker thread (and free its
         # buffered batches) instead of abandoning it blocked on a full
         # queue for the rest of the process.
@@ -400,14 +421,16 @@ class Trainer:
                     trace_stack.enter_context(profiler_trace(trace_dir))
                     tracing = True
                 # What the loop waited for its input, prefetcher and all.
-                with span("train.next_batch", step=step):
+                with span("train.next_batch", step=step) as waited:
                     batch = next_batch()
                 # The span brackets DISPATCH of the compiled step alone
                 # (async — not device time; honest step time is the
                 # boundary-derived step_time_s key below).
                 # DLCFN_OBS_OFF=1 makes this a shared no-op.
-                with span("train.dispatch", step=step):
+                with span("train.dispatch", step=step) as dispatch:
                     state, metrics = self.train_step(state, batch, rng)
+                if self._retrace is not None:
+                    self._close_retrace(dispatch.span_id, step)
                 del batch  # the device keeps what the step still reads
                 window_examples += gb
                 step += 1
@@ -418,10 +441,12 @@ class Trainer:
 
                 if not first_sync_done:
                     # The first dispatch traced + compiled; sync on it,
-                    # record compile_s, and restart the throughput window
+                    # record its seconds, and restart the throughput window
                     # so the first logged examples_per_sec is honest.
                     jax.block_until_ready(metrics)
-                    compile_s = time.perf_counter() - window_start
+                    compile_s = self._close_first_step(
+                        step - 1, first_t0, jit_before,
+                        next_batch_s=waited.dur_s, dispatch_s=dispatch.dur_s)
                     window_start = time.perf_counter()
                     window_examples = 0
                     first_sync_done = True
@@ -468,6 +493,9 @@ class Trainer:
                     if compile_s is not None:
                         realized["compile_s"] = compile_s
                         compile_s = None
+                    if self._retraces_unreported:
+                        realized["retraces"] = self._retraces_unreported
+                        self._retraces_unreported = 0
                     if metrics_writer is not None:
                         metrics_writer.write(realized)
                     last_realized = realized
@@ -513,6 +541,8 @@ class Trainer:
                         watchdog.beat()
             return state
         finally:
+            jit_events.watch(*former_watch)
+            self._retrace = None
             if watchdog is not None:
                 watchdog.stop()
             trace_stack.close()  # no-op unless exited mid-capture
@@ -522,6 +552,70 @@ class Trainer:
                 close = getattr(train_iter, "close", None)
                 if close is not None:
                     close()
+
+    def _close_first_step(self, step: int, t0: float,
+                          jit_before: Dict[str, float],
+                          **spans_s: Optional[float]) -> float:
+        """The seconds from ``t0`` (``time.monotonic``) to now, which is the
+        end of the first step's sync, and with spans on the record
+        ``train.first_step``: jax's seconds for the step's trace, lowering
+        and backend compile inside it, what the persistent cache was asked,
+        answered and saved (``jit_events.totals``, the differences over the
+        span), and the seconds of the step's own ``train.next_batch`` and
+        ``train.dispatch`` (``spans_s``). The first such record of the
+        process also stays in the gauge ``train.first_step_s{part}``
+        (``whole`` and every attr in seconds), where a reader in the process
+        finds the start's split after later steps and compiles have moved
+        the counters on."""
+        dur = time.monotonic() - t0
+        self._first_synced = True
+        if obs_enabled():
+            now = jit_events.totals(STEP_FUN)
+            held = {k: v - jit_before[k] for k, v in now.items()}
+            held.update({k: v for k, v in spans_s.items() if v is not None})
+            held = {k: round(v, 6) for k, v in held.items()}
+            tracer = get_tracer()
+            tracer.record_span("train.first_step", t0, dur, step=step,
+                               **held)
+            first = tracer.registry.gauge(
+                "train.first_step_s",
+                "the process's first train.first_step, by part")
+            if first.value(part="whole") is None:
+                first.set(dur, part="whole")
+                for k, v in held.items():
+                    if k.endswith("_s"):
+                        first.set(v, part=k[:-2])
+        return dur
+
+    def _on_step_jit(self, phase: str, seconds: float) -> None:
+        """``jit_events``' watcher while ``fit`` runs, called after each
+        trace, lowering and backend compile of the step on the thread that
+        dispatched it. Before this trainer's first sync they are the first
+        step's own. After it they are a retrace, kept open as [start, end,
+        jax's seconds] until the loop closes it behind the dispatch that
+        provoked it (``_close_retrace``)."""
+        if not self._first_synced:
+            return
+        now = time.monotonic()
+        if self._retrace is None:
+            self._retrace = [now - seconds, now, 0.0]
+        self._retrace[1] = now
+        self._retrace[2] += seconds
+
+    def _close_retrace(self, dispatch_id: Optional[int], step: int) -> None:
+        """Count the open retrace and write it down as a ``train.retrace``
+        record under the ``train.dispatch`` span that provoked it, whichever
+        of jax's three phases it went through (a trace whose jaxpr jax had
+        compiled before stops there)."""
+        (t0, t1, jit_s), self._retrace = self._retrace, None
+        self._retraces_unreported += 1
+        tracer = get_tracer()
+        tracer.registry.counter(
+            "train.step_retraces",
+            "traces or compiles of the step after the first sync").inc()
+        tracer.record_span("train.retrace", t0, t1 - t0,
+                           parent_id=dispatch_id, step=step,
+                           jit_s=round(jit_s, 6))
 
     def evaluate(self, state: TrainState, eval_iter: Iterator[Batch],
                  max_steps: int = 0, watchdog=None) -> Dict[str, float]:

@@ -725,6 +725,101 @@ def test_summarize_empty_input(tmp_path):
     assert "no train" in render_report(s)  # renders, no raise
 
 
+# -- where the start of a run went (PR 37) -----------------------------------
+
+_START_RECORDS = [
+    {"span": "train.init_state", "span_id": 1, "parent_id": None,
+     "t0_s": 0.5, "dur_s": 4.25, "ok": True, "params": 1784000000,
+     "bytes": 28544000000},
+    {"span": "train.next_batch", "span_id": 2, "parent_id": None,
+     "t0_s": 5.0, "dur_s": 0.5, "ok": True, "step": 0},
+    {"span": "train.dispatch", "span_id": 3, "parent_id": None,
+     "t0_s": 5.5, "dur_s": 40.0, "ok": True, "step": 0},
+    {"span": "train.first_step", "span_id": 4, "parent_id": None,
+     "t0_s": 5.0, "dur_s": 47.5, "ok": True, "step": 0, "trace_s": 12.0,
+     "lower_s": 8.0, "backend_compile_s": 5.0, "next_batch_s": 0.5,
+     "dispatch_s": 40.0, "cache_requests": 1, "cache_hits": 1,
+     "cache_misses": 0, "cache_retrieval_s": 4.0, "cache_saved_s": 76.0},
+    {"step": 1, "loss": 2.0, "compile_s": 47.5},
+    {"span": "train.next_batch", "span_id": 5, "parent_id": None,
+     "t0_s": 53.0, "dur_s": 0.001, "ok": True, "step": 1},
+    {"span": "train.retrace", "span_id": 7, "parent_id": 6, "t0_s": 60.0,
+     "dur_s": 30.0, "ok": True, "step": 6, "jit_s": 29.0},
+    {"step": 7, "loss": 1.5, "retraces": 1},
+    {"span": "train.first_step", "span_id": 9, "parent_id": None,
+     "t0_s": 95.0, "dur_s": 0.6, "ok": True, "step": 7, "trace_s": 0.0,
+     "lower_s": 0.0, "backend_compile_s": 0.0, "next_batch_s": 0.001,
+     "dispatch_s": 0.002, "cache_requests": 0, "cache_hits": 0,
+     "cache_misses": 0, "cache_retrieval_s": 0.0, "cache_saved_s": 0.0},
+]
+
+
+def _jsonl(tmp_path, records):
+    p = tmp_path / "m.jsonl"
+    p.write_text("".join(json.dumps(r) + "\n" for r in records))
+    return str(p)
+
+
+def test_summarize_splits_the_first_step_and_counts_retraces(tmp_path):
+    start = summarize(_jsonl(tmp_path, _START_RECORDS))["train"]["start"]
+    assert start["first_step_s"] == 47.5  # the run's first, not its last
+    assert (start["trace_s"], start["lower_s"],
+            start["backend_compile_s"]) == (12.0, 8.0, 5.0)
+    assert start["next_batch_s"] == 0.5  # the batch of the span's own step
+    assert start["other_s"] == pytest.approx(47.5 - 25.5)
+    assert (start["cache_hits"], start["cache_misses"],
+            start["cache_requests"]) == (1, 0, 1)
+    assert (start["cache_retrieval_s"], start["cache_saved_s"]) == (4.0, 76.0)
+    assert start["init_state_s"] == 4.25
+    assert start["params"] == 1784000000
+    assert start["retraces"] == 1 and start["retrace_steps"] == [6]
+
+
+_HIT = {"cache_hits": 1, "cache_misses": 0, "cache_retrieval_s": 4.0,
+        "cache_saved_s": 76.0}
+_STORED = {"cache_hits": 0, "cache_misses": 1, "cache_retrieval_s": 0.0,
+           "cache_saved_s": 0.0}
+
+
+@pytest.mark.parametrize("cache,label,said", [
+    (_HIT, "cache load", "1 hit(s), 0 stored of 1 request(s); read in 4s, "
+                         "saved 76s"),
+    (_STORED, "compile", "0 hit(s), 1 stored of 1 request(s); read in 0s, "
+                         "saved 0s"),
+])
+def test_render_report_shows_the_start_under_first_step(tmp_path, cache,
+                                                        label, said):
+    records = [dict(r, **cache) if r.get("span") ==
+               "train.first_step" and r["step"] == 0 else r
+               for r in _START_RECORDS]
+    lines = render_report(summarize(_jsonl(tmp_path, records))).splitlines()
+    at = lines.index("  first step          47.5s")
+    assert lines[at + 1:at + 8] == [
+        "    trace / lower     12s / 8s",
+        f"    {label:<17} 5s  (cache: {said})",
+        "    first batch       0.5s",
+        "    other             22s",
+        "  state build         4.25s  (1784000000 params, 28544000000 "
+        "bytes)",
+        "  retraces            1  (at step 6)",
+        "  ckpt store retries  -",
+    ]
+
+
+def test_a_run_without_the_span_keeps_the_one_line_under_its_new_label():
+    """The committed fixture predates ``train.first_step``: its
+    ``compile_s`` is shown as what it always was, the first step's
+    seconds, and no split is made up."""
+    s = summarize(os.path.join(FIXTURES, "train"))
+    assert s["train"]["start"] is None
+    lines = render_report(s).splitlines()
+    at = next(i for i, l in enumerate(lines)
+              if l.startswith("  first step "))
+    assert lines[at].split() == ["first", "step", "5.258s"]
+    assert lines[at + 1].startswith("  ckpt store retries")
+    assert not any(l.startswith("  compile ") for l in lines)
+
+
 # -- CLI verb ----------------------------------------------------------------
 
 

@@ -551,7 +551,8 @@ def test_fit_spans_are_siblings(devices, prefetch):
     finally:
         configured(None)
     assert calls == [1, 2, 3, 4]
-    names = [r["span"] for r in sink.records if r["span"] != "train.realize"]
+    names = [r["span"] for r in sink.records
+             if r["span"] not in ("train.realize", "train.first_step")]
     assert names == ["train.next_batch", "train.dispatch",
                      "train.hooks"] * 4
     assert all(r["parent_id"] is None for r in sink.records)
@@ -656,3 +657,199 @@ def test_fit_in_two_calls_equals_one(devices):
     assert len(one_losses) == 6 and one_losses == two_losses
     for a, b in zip(one_leaves, two_leaves):
         np.testing.assert_array_equal(a, b)
+
+
+# -- the start of a trainer's life: first step, state's build, retraces (PR 37)
+
+
+@pytest.fixture(scope="module")
+def start_of_life(devices):
+    """One tiny trainer through three ``fit`` calls under a tracer of its
+    own: 3 steps, 2 more of the same shape, then 2 of another sequence
+    length. Returns the span records, the records ``fit`` wrote in each
+    call, and the registry."""
+    from deeplearning_cfn_tpu.obs import MemorySink, Tracer, configured
+
+    tracer, sink = Tracer(), MemorySink()
+    tracer.add_sink(sink)
+    configured(tracer)
+    try:
+        _, trainer, state = _tiny_gpt(devices)
+        calls = []
+        for upto, batches in ((3, _lm_batches(3)), (5, _lm_batches(2)),
+                              (7, _lm_batches(2, seq=16))):
+            writer = _Recorder()
+            state = trainer.fit(state, iter(batches), num_steps=upto,
+                                rng=jax.random.PRNGKey(7), log_every=1,
+                                metrics_writer=writer)
+            calls.append(writer.records)
+        n_params = sum(x.size for x in
+                       jax.tree_util.tree_leaves(state.params))
+    finally:
+        configured(None)
+    return {"sink": sink, "calls": calls, "registry": tracer.registry,
+            "n_params": n_params}
+
+
+def test_first_step_span_is_the_records_compile_s(start_of_life):
+    """One ``train.first_step`` a ``fit`` call, each the duration its
+    call's first record holds as ``compile_s``, from the same two clock
+    reads (the span record is rounded to the microsecond)."""
+    firsts = start_of_life["sink"].by_span("train.first_step")
+    assert [r["step"] for r in firsts] == [0, 3, 5]
+    for span_rec, records in zip(firsts, start_of_life["calls"]):
+        assert ["compile_s" in r for r in records] == \
+            [True] + [False] * (len(records) - 1)
+        assert span_rec["dur_s"] == pytest.approx(
+            records[0]["compile_s"], abs=1e-6)
+        assert span_rec["ok"] and span_rec["parent_id"] is None
+    durations = start_of_life["registry"].histogram("span_dur_s")
+    assert durations.samples(name="train.first_step")[0] == \
+        pytest.approx(start_of_life["calls"][0][0]["compile_s"], abs=1e-9)
+
+
+def test_first_step_holds_jaxs_seconds_for_the_step(start_of_life):
+    """The first ``fit``'s span carries what jax reported for
+    ``train_step`` inside it, part by part and together no more than the
+    span or its dispatch; the second call, which traced nothing, carries
+    zeros; the gauge ``train.first_step_s`` keeps the first call's and no
+    later one's."""
+    first, second, third = start_of_life["sink"].by_span("train.first_step")
+    parts = ("trace_s", "lower_s", "backend_compile_s")
+    assert all(first[k] > 0 for k in parts)
+    assert sum(first[k] for k in parts) <= first["dispatch_s"]
+    assert first["next_batch_s"] + first["dispatch_s"] <= first["dur_s"]
+    assert [second[k] for k in parts] == [0.0, 0.0, 0.0]
+    assert all(third[k] > 0 for k in parts)
+    registry = start_of_life["registry"]
+    kept = {dict(key)["part"]: v for key, v in
+            registry.gauge("train.first_step_s").series().items()}
+    assert kept.pop("whole") == pytest.approx(first["dur_s"], abs=1e-6)
+    assert kept == {k[:-2]: first[k] for k in first if k.endswith("_s")
+                    and k not in ("dur_s", "t0_s")}
+    assert set(kept) == {"trace", "lower", "backend_compile", "next_batch",
+                         "dispatch", "cache_retrieval", "cache_saved"}
+    # The counters are the process's totals under the step's own label.
+    for k in parts:
+        total = registry.counter(f"jit.{k}").value(fun="train_step")
+        assert total == pytest.approx(first[k] + third[k], abs=1e-5)
+        assert registry.counter(f"jit.{k[:-2]}_count").value(
+            fun="train_step") == 2
+
+
+def test_first_step_holds_what_the_cache_was_asked_and_answered(
+        start_of_life):
+    """The persistent cache is off under test, so jax asks it nothing: the
+    five are on the record all the same, as numbers, and a hit or a store
+    is never more than a request."""
+    for first in start_of_life["sink"].by_span("train.first_step"):
+        assert first["cache_requests"] >= \
+            first["cache_hits"] + first["cache_misses"] >= 0
+        assert first["cache_retrieval_s"] >= 0
+        assert first["cache_saved_s"] >= 0
+
+
+def test_a_second_fit_adds_no_retrace_and_another_shape_exactly_one(
+        start_of_life):
+    sink, calls = start_of_life["sink"], start_of_life["calls"]
+    assert not any("retraces" in r for r in calls[0] + calls[1])
+    assert [r.get("retraces") for r in calls[2]] == [1, None]
+    assert start_of_life["registry"].counter(
+        "train.step_retraces").value() == 1
+    (retrace,) = sink.by_span("train.retrace")
+    assert retrace["step"] == 5 and retrace["jit_s"] > 0
+    assert retrace["dur_s"] >= retrace["jit_s"] - 1e-3
+    (parent,) = [r for r in sink.records
+                 if r["span_id"] == retrace["parent_id"]]
+    assert parent["span"] == "train.dispatch" and parent["step"] == 5
+
+
+def test_a_retrace_that_stops_at_the_trace_is_closed_with_its_dispatch(
+        devices):
+    """jax traces the step again and finds the jaxpr compiled already: no
+    lowering and no backend compile follows. The loop closes what the
+    watcher opened, behind the dispatch, as one counted record. And ``fit``
+    hands the watch back to whoever had it."""
+    from deeplearning_cfn_tpu.obs import MemorySink, Tracer, configured
+    from deeplearning_cfn_tpu.runtime import jit_events
+
+    def elder(phase, seconds):
+        pass
+
+    tracer, sink = Tracer(), MemorySink()
+    tracer.add_sink(sink)
+    configured(tracer)
+    outer = jit_events.watch("train_step", elder)
+    try:
+        _, trainer, state = _tiny_gpt(devices)
+        trainer.fit(state, iter(_lm_batches(1)), num_steps=1,
+                    rng=jax.random.PRNGKey(7), log_every=1)
+        assert jit_events.watch("train_step", elder) == ("train_step", elder)
+        assert sink.by_span("train.retrace") == []
+        trainer._on_step_jit("trace", 0.25)
+        trainer._close_retrace(41, 9)
+    finally:
+        jit_events.watch(*outer)
+        configured(None)
+    (retrace,) = sink.by_span("train.retrace")
+    assert (retrace["step"], retrace["parent_id"]) == (9, 41)
+    assert retrace["jit_s"] == 0.25
+    assert retrace["dur_s"] == pytest.approx(0.25, abs=1e-3)
+    assert tracer.registry.counter("train.step_retraces").value() == 1
+    assert trainer._retrace is None and trainer._retraces_unreported == 1
+
+
+def test_create_train_state_emits_init_state_with_params_and_bytes(
+        start_of_life):
+    (build,) = start_of_life["sink"].by_span("train.init_state")
+    assert build["params"] == start_of_life["n_params"]
+    # float32 parameters, their EMA, Adam's two moments, and the counters.
+    assert 16 * build["params"] < build["bytes"] < 16 * build["params"] + 64
+    assert build["dur_s"] > 0 and build["ok"]
+    assert start_of_life["registry"].counter(
+        "jit.backend_compile_count").value(fun="make_state") == 1
+
+
+def test_with_obs_off_no_span_is_made_and_fit_still_writes_compile_s(
+        devices, monkeypatch):
+    from deeplearning_cfn_tpu.obs import MemorySink, Tracer, configured
+
+    monkeypatch.setenv("DLCFN_OBS_OFF", "1")
+    tracer, sink = Tracer(), MemorySink()
+    tracer.add_sink(sink)
+    configured(tracer)
+    writer = _Recorder()
+    try:
+        _, trainer, state = _tiny_gpt(devices)
+        trainer.fit(state, iter(_lm_batches(2)), num_steps=2,
+                    rng=jax.random.PRNGKey(7), log_every=1,
+                    metrics_writer=writer)
+    finally:
+        configured(None)
+    assert sink.records == []
+    assert writer.records[0]["compile_s"] > 0
+    assert "compile_s" not in writer.records[1]
+    assert tracer.registry.counter("jit.trace_count").series() == {}
+    assert tracer.registry.histogram("span_dur_s").series() == {}
+
+
+def test_installing_the_listener_twice_counts_once(devices):
+    from deeplearning_cfn_tpu.obs import Tracer, configured
+    from deeplearning_cfn_tpu.runtime import jit_events
+
+    def counted_once_pr37(x):
+        return x * 2 + 1
+
+    tracer = Tracer()
+    configured(tracer)
+    try:
+        jit_events.install()
+        jit_events.install()
+        jax.jit(counted_once_pr37)(jnp.ones(3)).block_until_ready()
+    finally:
+        configured(None)
+    for phase in ("trace", "lower", "backend_compile"):
+        assert tracer.registry.counter(f"jit.{phase}_count").value(
+            fun="counted_once_pr37") == 1
+        assert tracer.registry.counter(f"jit.{phase}_s").value(
+            fun="counted_once_pr37") > 0
