@@ -5,8 +5,9 @@ capacity layer the BERT/GPT trunks of ``moe_every`` keep.
 (Laguna, ZAYA1, Mellum2 through ``BlockStyle.mlp == "experts"``): a router
 module scores every token over all the experts, the (token, choice) pairs
 are sorted by expert, the rows of the experts held go through one grouped
-matmul (megablox's Pallas ``gmm`` / ``tgmm`` on a TPU) and are summed back
-into their tokens; rows move through the sort's permutation and its inverse
+matmul (megablox's Pallas ``gmm`` / ``tgmm`` on a TPU, each kernel at a tile
+chosen from its own shape: :func:`gmm_tile`) and are summed back into their
+tokens; rows move through the sort's permutation and its inverse
 (:func:`take_rows`, :func:`sum_rows`), fetched by XLA's gathers or, where a
 rank's tokens are a source large enough to make those dear, the live ones
 alone by a Pallas row kernel (``ops/rows.py``, :func:`rows_path`), and no
@@ -179,11 +180,23 @@ class MoeMlp(nn.Module):
 # ---------------------------------------------------------------------------
 
 
-# Row, contraction and column tile of the grouped matmul on the chip: of
-# seven tilings at 16,384 rows, 32 groups, 2048 x 1024 and 512 x 2048 this one
-# and (256, 1024, 1024) read within 5 % of each other, 128- and 512-row tiles
-# 20-40 % slower (my chip run, PR 26, call 1).
-_GMM_TILE = (256, 1024, 512)
+# A grouped product's tile is chosen from its own shape, a kernel at a time
+# (:func:`gmm_tile`), by constants read from the three kernels timed apart at
+# the three expert cells' shapes (tools/gmm_tile_sweep.py; my chip run, PR 38,
+# call A, the lines in tools/gmm_tile_sweep_pr38.jsonl): ``gmm``, forward and
+# transposed, is fastest with its contraction whole (the weights' block stays
+# in VMEM over a group's row tiles), ``tgmm`` with a result block of a million
+# elements, its rows under 1152. The contraction tile's cap, a kernel:
+_GMM_CONTRACTION = {"gmm": 4096, "gmm_t": 4096, "tgmm": 1152}
+# The longest row tile, and the buffer rows a group from which it pays (in
+# ``tgmm`` 512 rows are 3 % ahead of 256 at Mellum2's 8,192 a group, 10 and
+# 26 % behind at ZAYA1's 1,024 and Laguna's 512).
+_ROW_TILE = 512
+_LONG_GROUP = 2048
+# Of the 16 MiB a kernel may use. Every tile up to 13.75 MiB by
+# :func:`gmm_tile_vmem` compiled and ran (call A); (256, 1152, 1792) in
+# ``tgmm``, 19.5 MiB, "ran out of memory in memory space vmem" (PR 35).
+_GMM_VMEM = 13 * 2 ** 20
 # The usual row buffer, in rows a uniform router would send to the experts
 # held: twice them.
 _BUFFER_SHARE = 2.0
@@ -202,6 +215,108 @@ def _named(implementation: str) -> str:
     return "megablox" if jax.default_backend() == "tpu" else "ragged_dot"
 
 
+def gmm_tile_vmem(kernel: str, tm: int, tk: int, tn: int) -> int:
+    """The bytes of VMEM a grid step of ``kernel`` holds at a tile, bfloat16
+    operands: its three blocks twice over (Pallas fetches the next while one
+    is computed) and the float32 accumulator, ``[tm, tn]`` in ``gmm``,
+    ``[tk, tn]`` in ``tgmm``, whose row tile is its contraction."""
+    blocks, acc = (tm * (tk + tn) + tk * tn, tk * tn) if kernel == "tgmm" \
+        else (tk * (tm + tn) + tm * tn, tm * tn)
+    return 2 * 2 * blocks + 4 * acc
+
+
+def _dividing(x: int, cap: int) -> int:
+    """The tile of a dimension ``x``: the whole of it under ``cap``, else the
+    largest multiple of 128 lanes up to ``cap`` that divides it, else (no
+    divisor over half of ``cap``: a padded tile wastes less than a narrow
+    one) ``cap``'s whole lane tiles, the last tile padded and masked."""
+    if x <= cap:
+        return x
+    cap -= cap % 128
+    return next((t for t in range(cap, cap // 2, -128) if x % t == 0), cap)
+
+
+def gmm_tile(kernel: str, m: int, k: int, n: int, groups: int
+             ) -> Tuple[int, int, int]:
+    """``(tm, tk, tn)`` for one megablox kernel of a grouped product, from
+    what the kernel is asked: ``"gmm"`` ``[m, k] x [groups, k, n]``,
+    ``"gmm_t"`` the rows' gradient (the same kernel, the weights transposed:
+    ``k`` is the forward's columns, ``n`` its contraction), ``"tgmm"`` the
+    weights' gradient ``[k, m] x [m, n]`` (``tm`` cuts its contraction, the
+    rows; ``[tk, tn]`` is a group's block of the result).
+
+    ``tk`` is the whole of ``k`` under the kernel's ``_GMM_CONTRACTION``,
+    else what divides it (:func:`_dividing`); ``tn`` the widest tile that
+    divides ``n`` with :func:`gmm_tile_vmem` under ``_GMM_VMEM`` (where not
+    even 512 columns fit, ``tk`` is halved); ``tm`` 256 rows, or
+    ``_ROW_TILE`` where the buffer gives a group ``_LONG_GROUP`` rows or more
+    and the rows are the contraction (``tgmm``) or the contraction is cut
+    (the weights' block is then fetched again for every row tile), in either
+    case a row tile that divides ``m``."""
+    cap = _GMM_CONTRACTION[kernel]
+    while cap >= 128:
+        tk = _dividing(k, cap)
+        want = _ROW_TILE if m >= _LONG_GROUP * groups and (
+            kernel == "tgmm" or tk < k) else _ROW_TILE // 2
+        tm = next((t for t in (want, want // 2, want // 4) if m % t == 0), m)
+        tn = n
+        while tn >= min(n, 512):
+            tn = _dividing(n, tn)
+            if gmm_tile_vmem(kernel, tm, tk, tn) <= _GMM_VMEM:
+                return tm, tk, tn
+            tn -= 128
+        cap //= 2
+    raise ValueError(f"no tile of {kernel} at {(m, k, n)} fits VMEM")
+
+
+def _tile_of(kernel: str, m: int, k: int, n: int, groups: int):
+    """:func:`gmm_tile`, counted: ``moe.gmm.calls`` once a kernel traced, by
+    the kernel, its tile and whether the tile divides the contraction and the
+    columns it is asked (``divides=no``: a last tile padded and masked)."""
+    tile = gmm_tile(kernel, m, k, n, groups)
+    get_tracer().registry.counter(
+        "moe.gmm.calls",
+        "grouped-matmul kernels traced, by kernel, tile and whether the "
+        "tile divides the product's contraction and columns",
+    ).inc(kernel=kernel, tile="x".join(map(str, tile)),
+          divides="no" if k % tile[1] or n % tile[2] else "yes")
+    return tile
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def megablox_gmm(lhs, rhs, group_sizes, interpret: bool = False):
+    """megablox's ``gmm`` with the tile of :func:`gmm_tile`, and a VJP that
+    gives the backward ``gmm`` and ``tgmm`` each its own (megablox's own VJP
+    hands all three kernels the forward's)."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    (m, k), (groups, _, n) = lhs.shape, rhs.shape
+    return gmm(lhs, rhs, group_sizes, lhs.dtype,
+               _tile_of("gmm", m, k, n, groups), interpret=interpret)
+
+
+def _megablox_gmm_fwd(lhs, rhs, group_sizes, interpret):
+    return megablox_gmm(lhs, rhs, group_sizes, interpret), \
+        (lhs, rhs, group_sizes)
+
+
+def _megablox_gmm_bwd(interpret, kept, grad):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+
+    lhs, rhs, group_sizes = kept
+    (m, k), (groups, _, n) = lhs.shape, rhs.shape
+    d_lhs = gmm(grad, rhs, group_sizes, lhs.dtype,
+                _tile_of("gmm_t", m, n, k, groups), transpose_rhs=True,
+                interpret=interpret)
+    d_rhs = tgmm(lhs.swapaxes(0, 1), grad, group_sizes, rhs.dtype,
+                 _tile_of("tgmm", m, k, n, groups), num_actual_groups=groups,
+                 interpret=interpret)
+    return d_lhs, d_rhs, None
+
+
+megablox_gmm.defvjp(_megablox_gmm_fwd, _megablox_gmm_bwd)
+
+
 def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray,
                    group_sizes: jnp.ndarray,
                    implementation: str = "auto") -> jnp.ndarray:
@@ -212,28 +327,23 @@ def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray,
 
     On a TPU the Pallas grouped matmul of ``jax.experimental.pallas.ops.tpu
     .megablox`` (its grid is the row tiles that hold a group's rows, found
-    from ``group_sizes`` at run time; backward through its own VJP, the
-    weights' gradient by the transposed kernel); elsewhere
-    ``jax.lax.ragged_dot``."""
+    from ``group_sizes`` at run time; backward by the same kernel with the
+    weights transposed, the weights' gradient by the transposed kernel
+    ``tgmm``), each kernel at the tile :func:`gmm_tile` chooses for its
+    shape (:func:`megablox_gmm`); elsewhere ``jax.lax.ragged_dot``."""
     implementation = _named(implementation)
     if implementation in ("ragged_dot", "interpret"):
         return jax.lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32),
                                   preferred_element_type=lhs.dtype)
     if implementation != "megablox":
         raise ValueError(f"unknown implementation {implementation!r}")
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
-
-    rows, k = lhs.shape
-    n = rhs.shape[-1]
-    tiling = (min(_GMM_TILE[0], rows), min(_GMM_TILE[1], k),
-              min(_GMM_TILE[2], n))
-    return gmm(lhs, rhs, group_sizes.astype(jnp.int32),
-               preferred_element_type=lhs.dtype, tiling=tiling)
+    return megablox_gmm(lhs, rhs, group_sizes.astype(jnp.int32))
 
 
 def _whole_tiles(rows: int) -> int:
-    """``rows`` rounded up to what the grouped matmul's row tile divides."""
-    tile = _GMM_TILE[0] if rows > _GMM_TILE[0] else 8
+    """``rows`` rounded up to what any row tile of :func:`gmm_tile`
+    divides."""
+    tile = _ROW_TILE if rows > _ROW_TILE else 8
     return -(-rows // tile) * tile
 
 
@@ -692,7 +802,9 @@ class HeldExpertsMlp(nn.Module):
     its ``k`` rows through the inverse and adds them in float32; each
     gather's backward pass is the other, so no row is scatter-added in
     either direction; ``moe.rows.calls`` counts the calls, by whether XLA's
-    gather or the row kernel fetches a row: :func:`rows_path`). The row buffer is
+    gather or the row kernel fetches a row: :func:`rows_path`;
+    ``moe.gmm.calls`` the grouped-matmul kernels traced, by kernel, tile and
+    whether the tile divides its product: :func:`gmm_tile`). The row buffer is
     static: twice what a uniform router would send (``_BUFFER_SHARE``), and
     where a step's routing sends more (``lax.cond`` on the count, a rank's
     own) a second buffer of every pair, ``tokens * k`` rows, takes the step

@@ -174,6 +174,18 @@ def _rows_calls(since=None):
             for path in ("gather", "kernel", "scatter_add")}
 
 
+def _gmm_calls(since=None):
+    """``moe.gmm.calls`` as ``{(kernel, tile, divides)}``: the series that
+    moved since ``since`` (a call's own return), or every series' count."""
+    from deeplearning_cfn_tpu.obs.trace import get_tracer
+
+    series = get_tracer().registry.counter("moe.gmm.calls").series()
+    if since is None:
+        return series
+    return {tuple(dict(key)[label] for label in ("kernel", "tile", "divides"))
+            for key, n in series.items() if n > since.get(key, 0)}
+
+
 def test_laguna_step_compiles_and_fits_a_v5e(v5e_chip):
     """The whole train step of ``laguna_xs2_train_4k`` at the cell's shapes
     (``benchmark/rehearse_compile.py``, the builder's rehearsal): the chip's
@@ -186,9 +198,18 @@ def test_laguna_step_compiles_and_fits_a_v5e(v5e_chip):
 
     calls = get_tracer().registry.counter("attention.rope.calls")
     before = {path: calls.value(path=path) for path in ("kernel", "xla")}
-    rows_before = _rows_calls()
+    rows_before, gmm_before = _rows_calls(), _gmm_calls()
     cell = manifest.Cell(manifest.load_manifest(), "laguna_xs2_train_4k")
     _, compiled, _ = rehearse_compile.compile_step(cell)
+    # Each grouped matmul at the tile of its own shape and kernel
+    # (``models/moe.py:gmm_tile``), none padded: the usual buffer's 16,384
+    # rows in 256-row tiles, the contraction whole in ``gmm``; the second
+    # buffer's 65,536 the same but ``tgmm``'s rows, 512 (long groups).
+    assert _gmm_calls(gmm_before) == {
+        ("gmm", "256x2048x1024", "yes"), ("gmm", "256x512x2048", "yes"),
+        ("gmm_t", "256x1024x2048", "yes"), ("gmm_t", "256x2048x512", "yes"),
+        ("tgmm", "256x1024x1024", "yes"), ("tgmm", "256x512x2048", "yes"),
+        ("tgmm", "512x1024x1024", "yes"), ("tgmm", "512x512x2048", "yes")}
     # Four expert layers, each traced twice, every one moving its rows by
     # XLA's gathers under either buffer: 8,192 tokens of 2048 are a source
     # of 33.5 MB, under the size from which the row kernel is the cheaper.
@@ -233,9 +254,15 @@ def test_zaya1_step_compiles_and_fits_a_v5e(v5e_chip):
     turned = registry.counter("attention.rope.calls")
     before = (mixed.value(), turned.value(path="kernel"),
               turned.value(path="xla"))
-    rows_before = _rows_calls()
+    rows_before, gmm_before = _rows_calls(), _gmm_calls()
     cell = manifest.Cell(manifest.load_manifest(), "zaya1_8b_train_4k")
     _, compiled, _ = rehearse_compile.compile_step(cell)
+    # The one buffer of 8,192 rows: the contraction whole in ``gmm`` forward
+    # and transposed (4096 in the first product's backward), a result block
+    # of 1024 x 1024 in ``tgmm``; none padded.
+    assert _gmm_calls(gmm_before) == {
+        ("gmm", "256x2048x1024", "yes"), ("gmm_t", "256x4096x512", "yes"),
+        ("gmm_t", "256x2048x1024", "yes"), ("tgmm", "256x1024x1024", "yes")}
     assert _rows_calls(rows_before) == {"gather": 10, "kernel": 0,
                                         "scatter_add": 0}
     # Traced twice (the parameters' shapes, the step), five layers each.
@@ -285,6 +312,7 @@ def test_mellum2_step_compiles_for_four_v5e_chips(v5e_chip):
     label = dict(path="all_gather", ranks="4")
     before = {k: wrapped.value(kernel=k) for k in ("flash", "rope", "gmm")}
     exchanges, rows_before = exchanged.value(**label), _rows_calls()
+    gmm_before = _gmm_calls()
     cell = manifest.Cell(manifest.load_manifest(),
                          "mellum2_12b_train_8k_ep4")
     assert cell.chips == 4
@@ -299,6 +327,13 @@ def test_mellum2_step_compiles_for_four_v5e_chips(v5e_chip):
     # by the row kernel; the initialisation traces one row, by XLA's gather.
     assert _rows_calls(rows_before) == {"gather": 4, "kernel": 4,
                                         "scatter_add": 0}
+    # A rank's buffer of 131,072 rows in 16 groups at 2304, 1792 and 896:
+    # every kernel's tile divides what it is asked (``divides=yes``), each
+    # product's three kernels at a tile of their own.
+    assert _gmm_calls(gmm_before) == {
+        ("gmm", "256x2304x896", "yes"), ("gmm", "256x896x1152", "yes"),
+        ("gmm_t", "256x1792x1152", "yes"), ("gmm_t", "256x2304x896", "yes"),
+        ("tgmm", "512x1152x896", "yes"), ("tgmm", "512x896x1152", "yes")}
     # A rank sends 3 x 8192 tokens of 2304 in bfloat16 and float32, twice.
     assert registry.gauge("moe.exchange.bytes").value() \
         == 2 * 3 * 8192 * 2304 * 6
@@ -353,19 +388,26 @@ def test_mellum2_step_compiles_for_four_v5e_chips(v5e_chip):
     assert "all-to-all" not in text
 
 
-@pytest.mark.parametrize("name,k,n", [("experts_in", 2304, 1792),
-                                      ("experts_out", 896, 2304)])
+@pytest.mark.parametrize("name,rows,groups,k,n", [
+    ("mellum2_in", 131072, 16, 2304, 1792),
+    ("mellum2_out", 131072, 16, 896, 2304),
+    ("zaya1_in", 8192, 8, 2048, 4096), ("zaya1_out", 8192, 8, 2048, 2048),
+    ("laguna_in", 16384, 32, 2048, 1024),
+    ("laguna_out", 16384, 32, 512, 2048),
+    # Laguna's second buffer, of every pair: long groups, 512-row tiles.
+    ("laguna_every_pair_in", 65536, 32, 2048, 1024)])
 @pytest.mark.parametrize("what", ["forward", "grad"])
-def test_grouped_matmul_compiles_at_mellum2_widths(v5e_chip, name, k, n,
-                                                   what):
-    """megablox's ``gmm`` (and through its VJP ``tgmm``) at a rank's shapes
-    in the Mellum2 cell, 131,072 buffer rows in 16 groups, widths no power
-    of two divides (2304 = 18 x 128, 1792 = 14 x 128, 896 = 7 x 128), with
-    the tile ``grouped_matmul`` chooses."""
+def test_grouped_matmul_compiles_at_the_cells_widths(v5e_chip, name, rows,
+                                                     groups, k, n, what):
+    """megablox's ``gmm`` and, through ``models/moe.py:megablox_gmm``'s VJP,
+    the backward ``gmm`` and ``tgmm`` at a rank's shapes in the three expert
+    cells, each kernel with the tile ``gmm_tile`` chooses for it: Mellum2's
+    131,072 buffer rows in 16 groups at widths no power of two divides (2304
+    = 18 x 128, 1792 = 14 x 128, 896 = 7 x 128), ZAYA1's 8,192 in 8, Laguna's
+    16,384 in 32. Mosaic takes each (VMEM), and the VJP adds no kernel."""
     from deeplearning_cfn_tpu.models.moe import grouped_matmul
 
     sharding = SingleDeviceSharding(v5e_chip)
-    rows, groups = 131072, 16
     lhs = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=sharding)
     rhs = jax.ShapeDtypeStruct((groups, k, n), jnp.bfloat16,
                                sharding=sharding)
@@ -374,5 +416,10 @@ def test_grouped_matmul_compiles_at_mellum2_widths(v5e_chip, name, k, n,
     if what == "grad":
         f = jax.grad(lambda a, b, s: jnp.sum(grouped_matmul(
             a, b, s, "megablox").astype(jnp.float32)), argnums=(0, 1))
+    since = _gmm_calls()
     text = jax.jit(f).lower(lhs, rhs, sizes).compile().as_text()
     assert text.count("tpu_custom_call") == (1 if what == "forward" else 2)
+    calls = _gmm_calls(since)
+    assert {kernel for kernel, _, _ in calls} == (
+        {"gmm"} if what == "forward" else {"gmm", "gmm_t", "tgmm"})
+    assert all(divides == "yes" for _, _, divides in calls)
