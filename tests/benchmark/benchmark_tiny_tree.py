@@ -31,6 +31,32 @@ TINY_GPT = {
 }
 
 
+# What every training cell of the committed manifest is listed on: the ten of
+# PRs 23 and 24 and set-up's five of PR 37. A configuration's own test adds
+# its cell's own.
+EVERY_TRAINING_CELL = {
+    "train_tokens_per_s", "step_ms", "device_idle.train", "hbm_peak_gb",
+    "head_loss_ms", "blocks_ms", "optimizer_ms", "unscoped_share",
+    "input_stall_ms", "dispatch_ms", "first_step_s", "step_trace_s",
+    "step_lower_s", "state_init_s", "first_step_other_s"}
+
+
+def list_like(manifest: dict, name: str, like: str) -> None:
+    """Append the cell ``name`` to every ``workloads`` list that names the
+    cell ``like``: how a later PR's cell joins the metrics of one it
+    resembles."""
+    for group in ("end_to_end", "per_layer"):
+        for m in manifest[group]:
+            if like in m.get("workloads", ()):
+                m["workloads"].append(name)
+
+
+def metrics_listing(manifest: dict, cell: str) -> set:
+    """The names of the metrics whose ``workloads`` list names ``cell``."""
+    return {m["name"] for group in ("end_to_end", "per_layer")
+            for m in manifest[group] if cell in m.get("workloads", ())}
+
+
 def build(dst: str) -> str:
     """Copy ``BENCHMARK.json`` and ``benchmark/`` to ``dst`` and add the tiny
     configurations, mixes and cells beside what is there."""
@@ -65,10 +91,7 @@ def build(dst: str) -> str:
         manifest["workloads"].append({
             "name": name, "config": config, "traffic": traffic,
             "chips": chips, "why": "CPU rehearsal"})
-        for group in ("end_to_end", "per_layer"):
-            for m in manifest[group]:
-                if like in m.get("workloads", ()):
-                    m["workloads"].append(name)
+        list_like(manifest, name, like)
 
     add_config("gpt2_tiny", "gpt2_small", TINY_GPT)
     add_traffic("tiny_train", "train_packed_1k", {

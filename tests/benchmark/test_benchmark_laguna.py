@@ -2,8 +2,9 @@
 the program against the plain reference (logits, loss, every gradient), the
 expert-parallel shares adding up to the whole layer, a routing no static
 buffer was sized for, the cell rehearsed end to end through ``run.py`` in a
-tiny tree built by adding files, and the control that leaves one held expert
-out reading ``correct`` false."""
+tiny tree built by adding files, what the committed manifest lists the cell
+on, and the control that leaves one held expert out reading ``correct``
+false."""
 
 import json
 import os
@@ -294,13 +295,7 @@ def tree(tmp_path_factory):
     m["workloads"].append({
         "name": "tiny_laguna", "config": "laguna_tiny",
         "traffic": "tiny_train_4k", "chips": 1, "why": "CPU rehearsal"})
-    # The nine metrics of this configuration wait in a file: the pin below
-    # keeps them out of the real manifest. The copy takes them at its end.
-    m["per_layer"] += _load("benchmark/per_layer_pending.json")
-    for group in ("end_to_end", "per_layer"):
-        for metric in m[group]:
-            if "laguna_xs2_train_4k" in metric.get("workloads", ()):
-                metric["workloads"].append("tiny_laguna")
+    benchmark_tiny_tree.list_like(m, "tiny_laguna", "laguna_xs2_train_4k")
     with open(os.path.join(dst, "BENCHMARK.json"), "w") as fh:
         json.dump(m, fh, indent=1)
     return dst
@@ -308,24 +303,15 @@ def tree(tmp_path_factory):
 
 def test_the_tiny_tree_keeps_every_metric_through_like(tree):
     """Every per-layer metric a real cell lists reaches the tiny cell built
-    `like` it, and each has its reader in the copy. The real manifest's list
-    is the parent's, entry for entry: ``test_benchmark_sections.py`` holds
-    PR 24's seven to the list's end and the driver holds new entries to the
-    end too, so the nine this PR brings wait in
-    ``benchmark/per_layer_pending.json`` for a PR that may lift that pin."""
+    `like` it and no other does, each has its reader in the copy, and the
+    copy's list is the real one's, name for name: nothing is appended twice."""
     with open(os.path.join(tree, "BENCHMARK.json")) as fh:
         m = json.load(fh)
-    real = _load("BENCHMARK.json")
-    pending = _load("benchmark/per_layer_pending.json")
-    names = [metric["name"] for metric in m["per_layer"]]
-    assert names == [metric["name"] for metric in real["per_layer"]] + [
-        "moe_ms", "moe_gmm_roofline", "flash_window_fwd_roofline",
-        "flash_window_bwd_roofline", "attn_core_ms", "moe_load_max_over_mean",
-        "flash_full_fwd_roofline", "flash_full_bwd_roofline", "mfu_sparse"]
-    assert names[:8] == [
-        "compile_s", "step_ms", "input_wait_ms", "mfu", "flash_fwd_roofline",
-        "flash_bwd_roofline", "device_idle.train", "hbm_peak_gb"]
-    for metric, was in zip(m["per_layer"], real["per_layer"] + pending):
+    real = {metric["name"]: metric for metric in _load(
+        "BENCHMARK.json")["per_layer"]}
+    assert [metric["name"] for metric in m["per_layer"]] == list(real)
+    for metric in m["per_layer"]:
+        was = real[metric["name"]]
         for real_cell, tiny in (("gpt2_small_train", "tiny_train"),
                                 ("laguna_xs2_train_4k", "tiny_laguna")):
             assert (tiny in metric.get("workloads", ())) \
@@ -335,30 +321,25 @@ def test_the_tiny_tree_keeps_every_metric_through_like(tree):
             tree, "benchmark", "layer_metrics", metric["name"] + ".py"))
 
 
-PENDING = _load("benchmark/per_layer_pending.json")
+# Every training cell's, and the nine PR 26 brought.
+LISTS_THE_CELL = benchmark_tiny_tree.EVERY_TRAINING_CELL | {
+    "moe_ms", "moe_gmm_roofline", "flash_window_fwd_roofline",
+    "flash_window_bwd_roofline", "attn_core_ms", "moe_load_max_over_mean",
+    "flash_full_fwd_roofline", "flash_full_bwd_roofline", "mfu_sparse"}
 
 
-@pytest.mark.parametrize("name", [m["name"] for m in PENDING])
-def test_pending_per_layer_entry_is_well_formed_and_has_a_reader(name):
-    """What ``test_benchmark_manifest.py`` holds a listed metric to, for an
-    entry that waits: appended as it stands it is a sound entry."""
+def test_the_real_manifest_lists_the_cell_on_what_it_reads():
+    """Found by name; a later PR may list the cell on more."""
     real = _load("BENCHMARK.json")
-    metric = next(m for m in PENDING if m["name"] == name)
-    assert metric["workloads"] == ["laguna_xs2_train_4k"]
-    assert set(metric) == {"name", "unit", "better", "source", "layer",
-                           "moves", "workloads"}
-    assert name not in {e["name"] for e in real["per_layer"]}
-    assert metric["layer"] in {e["layer"] for e in real["per_layer"]}
-    assert metric["better"] in ("lower", "higher")
-    assert metric["source"] in ("device_trace", "program_span",
-                                "program_counter", "host_clock")
-    moved = next(e for e in real["end_to_end"]
-                 if e["name"] == metric["moves"])
-    assert "laguna_xs2_train_4k" in moved["workloads"]
-    assert os.path.exists(os.path.join(REPO, "benchmark", "layer_metrics",
-                                       name + ".py"))
-    if name.endswith("_roofline") or "mfu" in name:
-        assert metric["unit"] == "%"
+    name = "laguna_xs2_train_4k"
+    mine = next(w for w in real["workloads"] if w["name"] == name)
+    assert mine["chips"] == 1 and mine["config"] == "laguna_xs2"
+    listed = benchmark_tiny_tree.metrics_listing(real, name)
+    assert listed >= LISTS_THE_CELL
+    # What counts GPT-2's block and its dropout, and what times the
+    # pipeline's thread (PERF.md section 3).
+    assert not listed & {"mfu", "flash_fwd_roofline", "flash_bwd_roofline",
+                         "dropout_ms", "input_wait_ms"}
 
 
 @pytest.mark.parametrize("trace", [0, 1])

@@ -378,8 +378,8 @@ def test_the_tiny_tree_picks_the_new_metrics_up_through_like(tree):
             tree, "benchmark", "layer_metrics", f"{name}.py"))
     assert {by_name[n]["source"] for n in NEW_DEVICE} == {"device_trace"}
     assert {by_name[n]["source"] for n in NEW_SPAN} == {"program_span"}
-    # New entries stand at the end of the list, in the issue's order.
-    assert [m["name"] for m in manifest["per_layer"]][-7:] == \
+    # In the issue's order among themselves, wherever later entries stand.
+    assert [n for n in by_name if n in NEW_DEVICE + NEW_SPAN] == \
         NEW_DEVICE + NEW_SPAN
 
 

@@ -1,9 +1,9 @@
 """The five readers of the start path (``first_step_s``, ``step_trace_s``,
 ``step_lower_s``, ``state_init_s``, ``first_step_other_s``): what they read
-from the program's registry, on the tiny cell end to end on the CPU with the
-entries that wait in ``benchmark/per_layer_pending_setup.json`` appended in
-the copy, and that they read nothing and raise nothing from a program that
-has no such span or counter, as this PR's parent has not."""
+from the program's registry, on the tiny cell end to end on the CPU, which
+cells the committed manifest lists them for, and that they read nothing and
+raise nothing from a program that has no such span or counter, as PR 37's
+parent has not."""
 
 import json
 import os
@@ -17,8 +17,6 @@ from benchmark_tiny_tree import REPO, build, env
 
 NAMES = ["first_step_s", "step_trace_s", "step_lower_s", "state_init_s",
          "first_step_other_s"]
-CELLS = ["gpt2_small_train", "laguna_xs2_train_4k", "zaya1_8b_train_4k",
-         "mellum2_12b_train_8k_ep4"]
 
 
 def _load(relpath):
@@ -26,39 +24,25 @@ def _load(relpath):
         return json.load(fh)
 
 
-PENDING = _load("benchmark/per_layer_pending_setup.json")
-
-
-def test_the_pending_file_holds_the_five_in_the_issues_order():
-    assert [m["name"] for m in PENDING] == NAMES
-
-
 @pytest.mark.parametrize("name", NAMES)
-def test_pending_entry_is_well_formed_waits_and_has_a_reader(name):
-    """Appended as it stands it is a sound entry; the committed manifest
-    has none of the five (``test_benchmark_sections.py`` pins PR 24's seven
-    to the list's end)."""
+def test_the_real_manifest_lists_every_training_cell_for_a_setup_metric(
+        name):
+    """The five time ``Trainer.fit``'s first step and the state's build, so
+    they list every cell that reports ``train_tokens_per_s``, whatever those
+    are, and a later serving cell owes them nothing: an explicit list, which
+    a PR that adds a training cell extends by its name."""
     real = _load("BENCHMARK.json")
-    metric = next(m for m in PENDING if m["name"] == name)
-    assert set(metric) == {"name", "unit", "better", "source", "layer",
-                           "moves", "workloads"}
+    metric = next(m for m in real["per_layer"] if m["name"] == name)
     assert (metric["unit"], metric["better"], metric["moves"]) == \
         ("s", "lower", "setup_s")
-    assert metric["workloads"] == CELLS == \
-        [w["name"] for w in real["workloads"]]
+    rate = next(m for m in real["end_to_end"]
+                if m["name"] == "train_tokens_per_s")
+    training = rate.get("workloads", [w["name"] for w in real["workloads"]])
+    assert sorted(metric["workloads"]) == sorted(training)
     assert metric["source"] == (
         "program_counter" if name.startswith("step_") else "program_span")
     assert metric["layer"] == (
         "entry points" if name.startswith("step_") else "train loop")
-    assert metric["layer"] in {m["layer"] for m in real["per_layer"]}
-    taken = {m["name"] for m in real["per_layer"]} | {
-        m["name"] for f in ("per_layer_pending.json",
-                            "per_layer_pending_zaya1_8b.json",
-                            "per_layer_pending_mellum2_12b.json")
-        for m in _load("benchmark/" + f)}
-    assert name not in taken
-    assert os.path.exists(os.path.join(REPO, "benchmark", "layer_metrics",
-                                       name + ".py"))
 
 
 # -- the readers against a registry -------------------------------------------
@@ -152,18 +136,10 @@ def test_other_is_left_out_with_a_reason_where_it_would_be_negative(tracer):
 
 @pytest.fixture(scope="module")
 def traced_run(tmp_path_factory):
-    """``tiny_train --trace 1`` from a copy whose manifest has the five
-    appended at the end of ``per_layer``, the tiny cell added to their
-    lists as ``build`` adds it to the others'."""
+    """``tiny_train --trace 1`` from the tiny tree: ``build`` adds the tiny
+    cell to the five's lists as to every list that names the cell it is
+    `like`."""
     tree = build(str(tmp_path_factory.mktemp("bench_setup_spans")))
-    path = os.path.join(tree, "BENCHMARK.json")
-    with open(path) as fh:
-        manifest = json.load(fh)
-    for metric in PENDING:
-        manifest["per_layer"].append(
-            dict(metric, workloads=metric["workloads"] + ["tiny_train"]))
-    with open(path, "w") as fh:
-        json.dump(manifest, fh, indent=1)
     p = subprocess.run(
         [sys.executable, "benchmark/run.py", "--workload", "tiny_train",
          "--seed", "3700000019", "--seconds", "2", "--trace", "1"],

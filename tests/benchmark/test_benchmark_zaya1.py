@@ -3,8 +3,8 @@ the program against the plain reference (logits, loss, every gradient, three
 AdamW steps), the two expert-parallel shares adding up to the whole layer
 with the router counted once, the cell rehearsed end to end through
 ``run.py`` in a tiny tree built by adding files, the configuration's own
-controls reading ``correct`` false, the operations a token by hand, and the
-five per-layer entries that wait in a file."""
+controls reading ``correct`` false, the operations a token by hand, and what
+the committed manifest lists the cell on."""
 
 import json
 import os
@@ -301,35 +301,7 @@ def test_operations_a_token_are_the_count_by_hand():
     assert 0.40 < parts["head"] / sum(parts.values()) < 0.50
 
 
-# -- the five per-layer entries that wait ------------------------------------
-
-PENDING = _load("benchmark/per_layer_pending_zaya1_8b.json")
-
-
-@pytest.mark.parametrize("name", [m["name"] for m in PENDING])
-def test_pending_per_layer_entry_is_well_formed_and_has_a_reader(name):
-    """What ``test_benchmark_manifest.py`` holds a listed metric to, for an
-    entry that waits (``test_benchmark_sections.py`` pins PR 24's seven to
-    the list's end): appended as it stands it is a sound entry."""
-    real = _load("BENCHMARK.json")
-    metric = next(m for m in PENDING if m["name"] == name)
-    assert metric["workloads"] == ["zaya1_8b_train_4k"]
-    assert set(metric) == {"name", "unit", "better", "source", "layer",
-                           "moves", "workloads"}
-    taken = {e["name"] for e in real["per_layer"]} | {
-        e["name"] for e in _load("benchmark/per_layer_pending.json")}
-    assert name not in taken
-    assert metric["layer"] in {e["layer"] for e in real["per_layer"]}
-    assert metric["better"] in ("lower", "higher")
-    assert metric["source"] in ("device_trace", "program_span",
-                                "program_counter", "host_clock")
-    moved = next(e for e in real["end_to_end"]
-                 if e["name"] == metric["moves"])
-    assert "zaya1_8b_train_4k" in moved["workloads"]
-    assert os.path.exists(os.path.join(REPO, "benchmark", "layer_metrics",
-                                       name + ".py"))
-    if name.endswith("_roofline") or "mfu" in name:
-        assert metric["unit"] == "%"
+# -- the five per-layer metrics PR 31 brought --------------------------------
 
 
 def test_the_new_readers_find_their_scopes_and_nothing_elsewhere():
@@ -394,8 +366,8 @@ def test_the_new_readers_find_their_scopes_and_nothing_elsewhere():
 @pytest.fixture(scope="module")
 def tree(tmp_path_factory):
     """``benchmark_tiny_tree``'s copy with a tiny ``zaya`` configuration,
-    traffic and cell added beside what is there, and the five waiting
-    entries at the end of the copy's list."""
+    traffic and cell added beside what is there, on every list that names
+    the real cell."""
     dst = benchmark_tiny_tree.build(str(tmp_path_factory.mktemp("zaya1")))
     bench = os.path.join(dst, "benchmark")
     with open(os.path.join(dst, "BENCHMARK.json")) as fh:
@@ -416,31 +388,35 @@ def tree(tmp_path_factory):
     m["workloads"].append({
         "name": "tiny_zaya1", "config": "zaya1_tiny",
         "traffic": "tiny_train_zaya1", "chips": 1, "why": "CPU rehearsal"})
-    m["per_layer"] += PENDING
-    for group in ("end_to_end", "per_layer"):
-        for metric in m[group]:
-            if "zaya1_8b_train_4k" in metric.get("workloads", ()):
-                metric["workloads"].append("tiny_zaya1")
+    benchmark_tiny_tree.list_like(m, "tiny_zaya1", "zaya1_8b_train_4k")
     with open(os.path.join(dst, "BENCHMARK.json"), "w") as fh:
         json.dump(m, fh, indent=1)
     return dst
 
 
-def test_the_real_cell_lists_what_lagunas_lists(tree):
-    """The new cell is on the ``workloads`` list of every metric that lists
-    ``laguna_xs2_train_4k`` and on no other; nothing else of the manifest's
-    metrics differs from what ``per_layer_pending.json``'s test pins."""
+# Every training cell's, the three of Laguna's that read sensibly here, the
+# five PR 31 brought.
+LISTS_THE_CELL = benchmark_tiny_tree.EVERY_TRAINING_CELL | {
+    "moe_ms", "attn_core_ms", "moe_load_max_over_mean",
+    "cca_mix_ms", "moe_router_ms", "mfu_zaya1", "flash_hybrid_fwd_roofline",
+    "flash_hybrid_bwd_roofline"}
+
+
+def test_the_real_manifest_lists_the_cell_on_what_it_reads():
+    """Found by name; a later PR may list the cell on more."""
     real = _load("BENCHMARK.json")
-    for group in ("end_to_end", "per_layer"):
-        for metric in real[group]:
-            listed = metric.get("workloads", ())
-            assert ("zaya1_8b_train_4k" in listed) \
-                == ("laguna_xs2_train_4k" in listed), metric["name"]
-    with open(os.path.join(tree, "BENCHMARK.json")) as fh:
-        names = [metric["name"] for metric in json.load(fh)["per_layer"]]
-    assert names == [metric["name"] for metric in real["per_layer"]] + [
-        "cca_mix_ms", "moe_router_ms", "mfu_zaya1",
-        "flash_hybrid_fwd_roofline", "flash_hybrid_bwd_roofline"]
+    name = "zaya1_8b_train_4k"
+    mine = next(w for w in real["workloads"] if w["name"] == name)
+    assert mine["chips"] == 1 and mine["config"] == "zaya1_8b"
+    listed = benchmark_tiny_tree.metrics_listing(real, name)
+    assert listed >= LISTS_THE_CELL
+    # These ask the configuration for ``mlp_layer_types`` and for sliding or
+    # full layers and find nothing in one whose layers are all ``hybrid``,
+    # and ``mfu_sparse`` raises on it (PERF.md section 3).
+    assert not listed & {
+        "moe_gmm_roofline", "mfu_sparse", "flash_window_fwd_roofline",
+        "flash_window_bwd_roofline", "flash_full_fwd_roofline",
+        "flash_full_bwd_roofline"}
 
 
 @pytest.mark.parametrize("trace", [0, 1])
