@@ -96,7 +96,12 @@ class TrainConfig:
     log_every_steps: int = 50
     seed: int = 0
     dtype: str = "bfloat16"  # compute dtype; params stay f32
-    remat: bool = False  # jax.checkpoint the model apply
+    # jax.checkpoint round the whole model's apply (train/task.py): the
+    # backward pass recomputes the whole forward and then holds every
+    # block's intermediates at once, so it lowers no peak. A block at a time
+    # is BlockStyle.remat (models/transformer.py), asked for by a preset's
+    # model kwargs (granite4_h_micro_lm: remat_blocks).
+    remat: bool = False
     # Capture a device+host profiler trace of this many hot-loop steps
     # (starting after the compile step) to <workdir>/<preset>/profile —
     # the Horovod-timeline role, natively. 0 = off.

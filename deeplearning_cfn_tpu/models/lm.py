@@ -57,7 +57,15 @@ class TransformerCausalLm(nn.Module):
     expert layer among them ``__call__`` returns ``(logits, aux)``, ``aux``
     what the expert layers counted; a router's state goes from each expert
     layer to the next one here. ``tie_embeddings=False`` gives the output
-    head a matrix of its own (``lm_head/kernel``).
+    head a matrix of its own (``lm_head/kernel``). A block's style also
+    decides what mixes its tokens (attention or a state-space mixer), the
+    constant its sublayers' results are multiplied by before they join the
+    stream, and whether the block is recomputed in the backward pass
+    (``BlockStyle.remat``: the layer under ``flax.linen.remat``, one block's
+    intermediates alive at a time, where ``train.remat`` recomputes the whole
+    model at once and lowers no peak). ``embedding_multiplier`` and
+    ``logits_scaling`` are Granite's: the embedding times the one, the logits
+    over the other, in float32.
 
     ``mesh`` is the mesh the step is compiled for (``CausalLmTask`` hands it
     on): on more than one device the blocks' Pallas kernels run under a
@@ -84,6 +92,8 @@ class TransformerCausalLm(nn.Module):
     blocks: Tuple[Tuple[int, int, int, BlockStyle], ...] = ()
     tie_embeddings: bool = True
     mesh: Any = None
+    embedding_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     def _is_moe(self, i: int) -> bool:
         return is_moe_layer(i, self.num_experts, self.moe_every)
@@ -98,11 +108,19 @@ class TransformerCausalLm(nn.Module):
                 param_dtype=jnp.float32,
                 kernel_init=nn.initializers.xavier_uniform())
         if self.blocks:
+            # `causal` (the sixth argument, the module counted) is read by
+            # Python: static under the recomputation.
+            recomputed = nn.remat(TransformerLayer, static_argnums=(5,))
+            if any(style.remat and style.mlp == "experts"
+                   for _, _, _, style in self.blocks):
+                raise NotImplementedError(
+                    "a recomputed block hands no router state on: "
+                    "BlockStyle.remat is for blocks without an expert layer")
             self.layers = [
-                TransformerLayer(heads, mlp_dim, dtype=self.dtype,
-                                 attention_impl=self.attention_impl,
-                                 style=style, mesh=self.mesh,
-                                 name=f"layer_{index}")
+                (recomputed if style.remat else TransformerLayer)(
+                    heads, mlp_dim, dtype=self.dtype,
+                    attention_impl=self.attention_impl, style=style,
+                    mesh=self.mesh, name=f"layer_{index}")
                 for index, heads, mlp_dim, style in self.blocks]
             self.final_norm = RMSNorm(self.blocks[-1][3].rms_eps, self.dtype)
             return
@@ -130,13 +148,17 @@ class TransformerCausalLm(nn.Module):
         # A scope of the program's own (docs/OBSERVABILITY.md): flax names
         # the tied head and the embedding lookup alike, after `token`.
         with jax.named_scope("lm_head"):
-            if self.tie_embeddings:
-                return self.token.attend(x.astype(jnp.float32))
-            return self.lm_head(x.astype(jnp.float32))
+            logits = self.token.attend(x.astype(jnp.float32)) \
+                if self.tie_embeddings else self.lm_head(x.astype(jnp.float32))
+            return logits if self.logits_scaling == 1.0 \
+                else logits / self.logits_scaling
 
     def _embed(self, tokens, pos_emb, train: bool):
         if self.blocks:
-            return self.token(tokens).astype(self.dtype)
+            x = self.token(tokens)
+            if self.embedding_multiplier != 1.0:
+                x = x * self.embedding_multiplier
+            return x.astype(self.dtype)
         x = self.token(tokens) + pos_emb
         x = self.embed_norm(x.astype(self.dtype))
         if self.dropout_rate > 0:
@@ -150,13 +172,23 @@ class TransformerCausalLm(nn.Module):
         # no router does).
         state, received, counted = None, 0, []
         for (_, _, _, style), lyr in zip(self.blocks, self.layers):
-            if style.mlp == "experts":
+            if style.remat:
+                # Positional: x, enc, self_bias, cross_bias, causal.
+                x = lyr(x, None, None, None, True)
+            elif style.mlp == "experts":
                 received += state is not None
                 x, aux = lyr(x, causal=True, router_state=state)
                 state = aux.pop("router_state", None)
                 counted.append(aux)
             else:
                 x = lyr(x, causal=True)
+        recomputed = sum(style.remat for _, _, _, style in self.blocks)
+        if recomputed:
+            get_tracer().registry.counter(
+                "model.blocks.recomputed",
+                "blocks of the traced model that are recomputed in the "
+                "backward pass, one at a time",
+            ).inc(recomputed)
         if state is not None:
             get_tracer().registry.gauge(
                 "moe.router.state_layers",
@@ -550,3 +582,82 @@ def gpt_mellum2_tiny(num_classes: int = 0, dtype=jnp.float32, *,
                      attention_impl: str = "auto", mesh=None):
     return _mellum2(_MELLUM2_TINY, dtype, vocab_size, layers_held,
                     experts_held, attention_impl, mesh)
+
+
+# granite-4.0-h-micro as IBM published it (config.json, `model_type:
+# granitemoehybrid`, 3 B parameters, dense: `num_local_experts` 0): 40 layers
+# of hidden size 2048 in periods of ten, nine with a Mamba-2 mixer (64 heads
+# of 64, a state of 128, one group of B and C, a causal depthwise convolution
+# of 4 taps with a bias over 4,352 channels, chunks of 256) and one (index 5
+# of each ten) with attention of 32 query heads over 8 K/V heads of 64 with
+# no positions at all and scores times 1/64; every layer a gated MLP of 8192;
+# the embedding times 12, each sublayer's result times 0.22 into the stream,
+# the logits over 8; RMSNorm 1e-5; a tied head over 100,352 tokens.
+# benchmark/configs/granite4_h_micro.json lists what the source leaves unsaid
+# and how it was read.
+_GRANITE4_H_MICRO = dict(
+    hidden_size=2048, num_layers=40, period=10, attention_at=5, heads=32,
+    kv_heads=8, head_dim=64, attn_scale=0.015625, mlp_width=8192,
+    ssm=dict(heads=64, head_dim=64, state=128, groups=1, conv_taps=4,
+             chunk=256),
+    residual_multiplier=0.22, embedding_multiplier=12.0, logits_scaling=8.0,
+    rms_eps=1e-5)
+# The same block at sizes a CPU test holds: mamba, attention, mamba, mamba,
+# with chunks of 8 so that 32 positions cross three boundaries.
+_GRANITE4_H_TINY = dict(
+    hidden_size=64, num_layers=4, period=4, attention_at=1, heads=4,
+    kv_heads=2, head_dim=16, attn_scale=0.0625, mlp_width=128,
+    ssm=dict(heads=4, head_dim=32, state=16, groups=1, conv_taps=4, chunk=8),
+    residual_multiplier=0.22, embedding_multiplier=12.0, logits_scaling=8.0,
+    rms_eps=1e-5)
+
+
+def _granite4_h(sizes, dtype, vocab_size, layers_held, remat_blocks,
+                attention_impl, mesh=None):
+    """The ``granitemoehybrid`` decoder at ``sizes`` without experts, or one
+    chip's share of it, told as :func:`_laguna` is: the layers of this
+    pipeline stage and the vocabulary rows of the embedding, which is the
+    head too. A layer is attention where its index is ``attention_at`` into
+    its period, else Mamba-2. ``remat_blocks`` recomputes every block in the
+    backward pass, one at a time."""
+    z = sizes
+    layers = range(z["num_layers"]) if layers_held is None \
+        else tuple(layers_held)
+    ssm = tuple(z["ssm"].items())
+
+    def block(i):
+        attention = i % z["period"] == z["attention_at"]
+        return (i, z["heads"], z["mlp_width"], BlockStyle(
+            num_kv_heads=z["kv_heads"], head_dim=z["head_dim"],
+            rms_eps=z["rms_eps"], attn_scale=z["attn_scale"],
+            mixer="attention" if attention else "mamba2",
+            ssm=() if attention else ssm,
+            residual_multiplier=z["residual_multiplier"],
+            remat=bool(remat_blocks)))
+
+    return TransformerCausalLm(
+        vocab_size=vocab_size, hidden_size=z["hidden_size"], dtype=dtype,
+        attention_impl=attention_impl, tie_embeddings=True, mesh=mesh,
+        embedding_multiplier=z["embedding_multiplier"],
+        logits_scaling=z["logits_scaling"],
+        blocks=tuple(block(i) for i in layers))
+
+
+@register_model("gpt_granite4_h_micro")
+def gpt_granite4_h_micro(num_classes: int = 0, dtype=jnp.bfloat16, *,
+                         vocab_size: int = 100_352, max_len: int = 8192,
+                         layers_held=None, remat_blocks: bool = False,
+                         attention_impl: str = "auto", mesh=None):
+    # Every width is the published one; num_classes and max_len are not read,
+    # as in gpt_laguna_xs2 (no layer has a table of positions to size).
+    return _granite4_h(_GRANITE4_H_MICRO, dtype, vocab_size, layers_held,
+                       remat_blocks, attention_impl, mesh)
+
+
+@register_model("gpt_granite4_h_tiny")
+def gpt_granite4_h_tiny(num_classes: int = 0, dtype=jnp.float32, *,
+                        vocab_size: int = 96, max_len: int = 32,
+                        layers_held=None, remat_blocks: bool = False,
+                        attention_impl: str = "auto", mesh=None):
+    return _granite4_h(_GRANITE4_H_TINY, dtype, vocab_size, layers_held,
+                       remat_blocks, attention_impl, mesh)

@@ -175,7 +175,28 @@ class BlockStyle:
     ``(h + b_r) * s_r + (f + b_o) * s_o``, four learned vectors a sublayer
     (biases 0, scales 1 from the seed), not ``h + f``. ``from_embedding``
     says the block's input is the embedding itself: its attention sublayer
-    then has no ``s_r``, ``b_r``."""
+    then has no ``s_r``, ``b_r``.
+
+    ``mixer``: what mixes tokens in the block's first sublayer.
+    ``"attention"`` is the attention above; ``"mamba2"`` a state-space mixer
+    (``models/ssm.py:Mamba2Mixer`` built from ``ssm``, a tuple of its keyword
+    pairs), which reads none of the attention's fields. Either is the module
+    ``self_attn`` under the norm ``self_attn_norm``: the name says where the
+    sublayer stands, and what is sorted by it (a trace's sections, sharding
+    rules) finds the mixer there.
+
+    ``residual_multiplier`` ``m`` other than 1: a sublayer's result joins
+    the stream as ``h + m * f`` (Granite's ``residual_multiplier``; one
+    constant for the model, where ``residual_scale`` is learned vectors).
+    ``attn_scale`` other than 0 is what the scores ``q . k`` are multiplied
+    by in place of ``1 / sqrt(head_dim)`` (Granite's
+    ``attention_multiplier``). ``rope=None`` is attention with no positional
+    signal at all.
+
+    ``remat``: the block is recomputed in the backward pass
+    (``flax.linen.remat`` round the layer, ``models/lm.py``), so that only
+    its input is kept from the forward pass and one block's intermediates
+    are alive at a time."""
 
     num_kv_heads: int = 0          # 0: as many as query heads
     head_dim: int = 0              # 0: hidden size / heads
@@ -189,6 +210,11 @@ class BlockStyle:
     latent_mix: Tuple[int, ...] = ()   # (): q, k, v straight from x
     residual_scale: bool = False
     from_embedding: bool = False
+    mixer: str = "attention"
+    ssm: Tuple[Tuple[str, Any], ...] = ()
+    residual_multiplier: float = 1.0
+    attn_scale: float = 0.0        # 0: 1 / sqrt(head_dim)
+    remat: bool = False
 
 
 class Leaf(nn.Module):
@@ -354,10 +380,11 @@ class MultiHeadAttention(nn.Module):
         """The [B,H,S,D] attention op. Subclasses swap this for a
         distributed strategy (SeqParallelAttention) while inheriting the
         projections/KV-cache/dropout plumbing unchanged."""
+        st = self.style or BlockStyle()
         return fused_attention(q, k, v, bias=bias, causal=causal,
+                               sm_scale=st.attn_scale or None,
                                implementation=self.attention_impl,
-                               window=self.style.window if self.style else 0,
-                               mesh=self._kernel_mesh())
+                               window=st.window, mesh=self._kernel_mesh())
 
     @nn.compact
     def __call__(self, x, kv=None, bias=None, causal=False,
@@ -779,7 +806,15 @@ class TransformerLayer(nn.Module):
 
         def join(sub, x, f, stream=True):
             if not st.residual_scale:
-                return x + f
+                if st.residual_multiplier == 1.0:
+                    return x + f
+                # In float32: in bfloat16 the constant itself would be
+                # rounded (0.22 to 0.2197), every sublayer's share of the
+                # stream with it, and the loss by a part in ten thousand.
+                # Under the sublayer's scope, as the merge below is.
+                with jax.named_scope(sub):
+                    return (x.astype(jnp.float32) + st.residual_multiplier
+                            * f.astype(jnp.float32)).astype(self.dtype)
             # Under the sublayer's own scope, so that a trace counts the
             # merge with the sublayer whose result it merges.
             with jax.named_scope(sub):
@@ -788,11 +823,20 @@ class TransformerLayer(nn.Module):
                 return (h + ShiftScale(name=f"{sub}_result")(f)) \
                     .astype(self.dtype)
 
-        x = join("self_attn", x, MultiHeadAttention(
-            self.num_heads, self.dtype, 0.0, self.attention_impl,
-            style=st, mesh=self.mesh, name="self_attn")(
-                norm("self_attn_norm")(x), causal=causal),
-            stream=not st.from_embedding)
+        if st.mixer == "mamba2":
+            from .ssm import Mamba2Mixer
+
+            mixed = Mamba2Mixer(dtype=self.dtype, rms_eps=st.rms_eps,
+                                name="self_attn", **dict(st.ssm))(
+                                    norm("self_attn_norm")(x))
+        elif st.mixer == "attention":
+            mixed = MultiHeadAttention(
+                self.num_heads, self.dtype, 0.0, self.attention_impl,
+                style=st, mesh=self.mesh, name="self_attn")(
+                    norm("self_attn_norm")(x), causal=causal)
+        else:
+            raise ValueError(f"unknown BlockStyle.mixer {st.mixer!r}")
+        x = join("self_attn", x, mixed, stream=not st.from_embedding)
         y = norm("mlp_norm")(x)
         if st.mlp == "experts":
             from .moe import HeldExpertsMlp, MlpStateRouter, \

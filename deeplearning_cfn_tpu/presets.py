@@ -400,6 +400,38 @@ def _mellum2_12b() -> ExperimentConfig:
     )
 
 
+@register_preset("granite4_h_micro_lm")
+def _granite4_h_micro() -> ExperimentConfig:
+    """granite-4.0-h-micro (IBM, 3 B parameters, dense: nine layers in ten a
+    Mamba-2 mixer, 64 heads of 64 with a state of 128 scanned in chunks of
+    256, and one attention over 8 K/V heads with no positions; Granite's
+    four multipliers; a tied head) pre-trained on one chip's share of a pod:
+    the chip holds layers 0-9 of 40 as one pipeline stage of four (one whole
+    period: Mamba x 5, attention, Mamba x 4) and 12,544 of the 100,352
+    vocabulary rows (8 chips share the vocabulary, embedding and tied head
+    alike); every layer whole, every width published. Sequences of 8192.
+    Every block is recomputed in the backward pass, one at a time
+    (`remat_blocks`): ten blocks' intermediates at 8,192 tokens do not fit
+    beside 12.4 GB of weights, gradients and Adam's moments, and
+    `train.remat` would hold them all at once. Recipe: gpt_small_lm's (the
+    source's own is not in its config)."""
+    return ExperimentConfig(
+        model=ModelConfig(
+            name="gpt_granite4_h_micro",
+            kwargs=dict(layers_held=tuple(range(10)), remat_blocks=True),
+        ),
+        data=DataConfig(name="lm_text", seq_len=8192, vocab_size=12_544),
+        train=TrainConfig(global_batch=1, steps=100_000, dtype="bfloat16",
+                          shard_opt_state=False),
+        optimizer=OptimizerConfig(name="adamw", b1=0.9, b2=0.95,
+                                  weight_decay=0.1, grad_clip_norm=1.0),
+        schedule=ScheduleConfig(name="cosine", base_lr=6e-4,
+                                warmup_steps=2000),
+        mesh=MeshConfig(data=-1),
+        stack=StackConfig(slice_type="v5e-8"),
+    )
+
+
 @register_preset("transformer_nmt_wmt")
 def _nmt() -> ExperimentConfig:
     """Transformer NMT WMT En-De (reference: Sockeye + MXNet

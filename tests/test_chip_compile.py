@@ -293,6 +293,67 @@ def test_zaya1_step_compiles_and_fits_a_v5e(v5e_chip):
     assert _row_scatters(text) == [] and "live_rows" not in text
 
 
+def test_granite4h_step_compiles_and_fits_a_v5e(v5e_chip):
+    """The whole train step of ``granite4_h_micro_train_8k`` at the cell's
+    shapes: nine Mamba-2 mixers and one attention layer at 8,192 tokens,
+    every block recomputed in the backward pass. Mosaic takes the flash
+    kernels at 32 query heads over 8 K/V heads of 64 with no rotary kernel
+    beside them; the mixers' five scopes are in the text, forward, recomputed
+    and backward; no row is scattered inside a block; and 12.4 GB of state
+    with one block's intermediates fit the chip, over the quarter of it a
+    cell has to fill. PERF.md section 4 has the number."""
+    import re
+
+    from deeplearning_cfn_tpu.obs.trace import get_tracer
+
+    manifest, rehearse_compile = _bench()
+    registry = get_tracer().registry
+    scans = registry.counter("ssm.scan.calls")
+    blocks = registry.counter("model.blocks.recomputed")
+    turned = registry.counter("attention.rope.calls")
+    before = (scans.value(path="xla", chunk="256"), blocks.value(),
+              turned.value(path="kernel") + turned.value(path="xla"))
+    cell = manifest.Cell(manifest.load_manifest(),
+                         "granite4_h_micro_train_8k")
+    assert cell.chips == 1
+    _, compiled, _ = rehearse_compile.compile_step(cell)
+    # Traced twice (the parameters' shapes, the step): nine mixers and ten
+    # recomputed blocks each; the backward pass traces nothing again, and
+    # nothing turns q or k.
+    assert (scans.value(path="xla", chunk="256") - before[0],
+            blocks.value() - before[1], turned.value(path="kernel")
+            + turned.value(path="xla") - before[2]) == (18, 20, 0)
+    assert registry.gauge("ssm.scan.chunks").value() == 32
+    assert registry.gauge("ssm.state_bytes").value() == 64 * 64 * 128 * 4
+    mem = compiled.memory_analysis()
+    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    assert 4 * 2 ** 30 < total < 15.75 * 2 ** 30, total
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    # The one attention layer: forward, forward again (recomputed), dK/dV,
+    # dQ; K/V are not repeated to the query heads.
+    assert len(kernels) == 4
+    assert all("/layer_5/" in line and "core_attention/flash_" in line
+               and "bf16[1,8,8192,64]" in line
+               and "bf16[1,32,8192,64]" in line for line in kernels)
+    for scope in ("ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm",
+                  "ssm_out_proj"):
+        for layer in (0, 4, 6, 9):
+            held = re.findall(rf'op_name="([^"]*/layer_{layer}/[^"]*'
+                              rf'/self_attn/{scope}/[^"]*)"', text)
+            assert any("transpose(jvp" not in name for name in held), scope
+            assert any("rematted_computation" in name for name in held), \
+                scope
+    assert "/layer_5/" in text and not re.search(
+        r"/layer_5/[^\"]*/self_attn/ssm_", text)
+    # What is scattered is the loss's one-hot and the embedding's gradient.
+    assert not [line.strip()[:200] for line in text.splitlines()
+                if re.search(r"= \S+ scatter\(", line)
+                and re.search(r"/layer_\d+/", line)]
+
+
 def test_mellum2_step_compiles_for_four_v5e_chips(v5e_chip):
     """The whole train step of ``mellum2_12b_train_8k_ep4`` at the cell's
     shapes for the four chips of a described ``v5e:2x2`` on ``expert=4``:

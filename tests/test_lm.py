@@ -470,12 +470,21 @@ def _tree_digest(name, **kw):
     ("gpt_laguna_tiny", {}, (36, "a8568ff88e01a690")),
     ("gpt_laguna_xs2", dict(vocab_size=12544, layers_held=(0, 1, 2, 3, 4),
                             experts_held=(0, 32)), (60, "d036ae9448e190f7")),
+    # Recorded at the commit before ``BlockStyle`` gained its mixer, constant
+    # multiplier, score scale and recomputation (PR 41): default fields leave
+    # the styled models that were there as they were.
+    ("gpt_zaya1_tiny", {}, (89, "007ab5a8a9cf01ab")),
+    ("gpt_zaya1_8b", dict(vocab_size=32896, layers_held=(0, 1, 2, 3, 4),
+                          experts_held=(0, 8)), (149, "f77c4fa43f6f3243")),
+    ("gpt_mellum2_tiny", {}, (39, "966c2af0aa03351a")),
+    ("gpt_mellum2_12b", dict(vocab_size=24576, layers_held=(0, 1, 2, 3)),
+     (39, "6419822411503a6b")),
 ])
 def test_parameter_trees_of_the_other_decoders_are_as_recorded(name, kw,
                                                                want):
-    """Leaf for leaf, names and shapes: the benchmark's references for
-    ``gpt2_small`` and ``laguna_xs2`` look their parameters up by name.
-    Recorded at the commit before the router became a module of its own."""
+    """Leaf for leaf, names and shapes: the benchmark's references look
+    their parameters up by name. The first four recorded at the commit
+    before the router became a module of its own."""
     assert _tree_digest(name, **kw) == want
 
 

@@ -1,0 +1,216 @@
+"""The chunked state-space scan (``ops/ssd.py``) against the recurrence it
+computes, a token at a time, in float32: values and every gradient; and the
+pieces of Mamba-2's mixer around it (``models/ssm.py``): the causal depthwise
+convolution against explicit shifts, the gate before the norm, the constants
+a head."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deeplearning_cfn_tpu.models.ssm import (CausalConv, Mamba2Mixer,
+                                             conv_gain, head_constants)
+from deeplearning_cfn_tpu.ops.ssd import ssd_recurrence, ssd_scan
+
+HEADS, HEAD_DIM, STATE = 4, 8, 16
+
+
+def _inputs(seq, groups, seed=0):
+    """Step sizes and rates whose product ``dt * a`` runs from 0.001 (a head
+    that remembers a thousand tokens) to 2 (one that forgets inside one)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    x = jax.random.normal(ks[0], (2, seq, HEADS, HEAD_DIM))
+    a = -jnp.asarray([0.1, 1.0, 4.0, 16.0])
+    dt = jnp.exp(jax.random.uniform(ks[1], (2, seq, HEADS),
+                                    minval=jnp.log(0.01),
+                                    maxval=jnp.log(0.125)))
+    dt = dt.at[0, 0].set(0.01).at[0, 1].set(0.125)      # both ends, surely
+    b = jax.random.normal(ks[2], (2, seq, groups, STATE))
+    c = jax.random.normal(ks[3], (2, seq, groups, STATE))
+    return x, dt, a, b, c
+
+
+# Both sides are float32 on the CPU and differ in the order of their sums
+# (a running product of decays against one exponential of a running sum): a
+# few float32 roundings of sums over up to 40 tokens.
+TOL = 5e-6
+
+
+def _close(got, want, what, tol=TOL):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    assert got.shape == want.shape, what
+    assert np.max(np.abs(got - want)) <= tol * max(np.max(np.abs(want)), 1.0), \
+        (what, np.max(np.abs(got - want)), np.max(np.abs(want)))
+
+
+@pytest.mark.parametrize("seq,groups", [
+    (24, 1),    # 3 chunks
+    (40, 1),    # 5 chunks
+    (40, 2),    # 5 chunks, two groups of B and C
+    (8, 1),     # one chunk: nothing is handed on
+    (5, 1),     # shorter than a chunk
+])
+def test_chunked_scan_is_the_recurrence_with_every_gradient(seq, groups):
+    args = _inputs(seq, groups)
+    span = np.asarray(args[1])[..., None, :] * -np.asarray(args[2])
+    assert span.min() < 0.0011 and span.max() > 1.99
+    scan = lambda *t: ssd_scan(*t, chunk=8)
+    _close(jax.jit(scan)(*args), ssd_recurrence(*args), "y")
+    w = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
+    grads = lambda f: jax.jit(jax.grad(
+        lambda *t: jnp.sum(f(*t) * w), argnums=(0, 1, 2, 3, 4)))(*args)
+    for name, got, want in zip(("x", "dt", "a", "b", "c"), grads(scan),
+                               grads(ssd_recurrence)):
+        assert np.any(np.asarray(want)), name
+        _close(got, want, f"d{name}")
+
+
+def test_the_state_a_chunk_enters_with_is_most_of_a_slow_heads_result():
+    """What the benchmark's control (a) leaves out: past the first chunk a
+    head that forgets slowly owes most of its result to earlier chunks, so
+    the scan of the last chunk alone is far from the scan of the whole."""
+    x, dt, a, b, c = _inputs(24, 1)
+    whole = ssd_scan(x, dt, a, b, c, chunk=8)
+    alone = ssd_scan(x[:, 16:], dt[:, 16:], a, b[:, 16:], c[:, 16:], chunk=8)
+    gap = jnp.max(jnp.abs(whole[:, 16:] - alone), axis=(0, 1, 3))
+    assert float(gap[0]) > 0.5          # a = 0.1: remembers every token
+    assert float(gap[3]) < float(gap[0])
+
+
+def test_bad_shapes_are_refused():
+    x, dt, a, b, c = _inputs(24, 1)
+    with pytest.raises(ValueError, match="whole chunks"):
+        ssd_scan(x[:, :20], dt[:, :20], a, b[:, :20], c[:, :20], chunk=8)
+    with pytest.raises(ValueError, match="cannot share"):
+        ssd_scan(x, dt, a, jnp.tile(b, (1, 1, 3, 1)),
+                 jnp.tile(c, (1, 1, 3, 1)), chunk=8)
+
+
+def test_bfloat16_operands_keep_the_state_and_the_sums_in_float32():
+    """The training dtype: products with bfloat16 operands, float32 sums.
+    Against the float32 recurrence of the same rounded inputs the result is
+    right to bfloat16's 3 digits, far from what a bfloat16 running sum over
+    40 tokens would leave."""
+    x, dt, a, b, c = _inputs(40, 1)
+    half = lambda t: t.astype(jnp.bfloat16)
+    got = ssd_scan(half(x), dt, a, half(b), half(c), chunk=8)
+    assert got.dtype == jnp.bfloat16
+    want = ssd_recurrence(half(x), dt, a, half(b), half(c))
+    _close(got.astype(jnp.float32), want, "y", tol=2e-2)
+
+
+def test_causal_convolution_is_four_shifted_multiplies():
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 11, 6))
+    conv = CausalConv(4)
+    params = conv.init(jax.random.PRNGKey(1), x)["params"]
+    assert params["kernel"].shape == (4, 6) and params["bias"].shape == (6,)
+    params = dict(params, bias=jnp.arange(6.0))
+    got = conv.apply({"params": params}, x)
+    w = conv_gain(4, 6) * np.asarray(params["kernel"])
+    want = np.zeros((2, 11, 6))
+    for t in range(11):
+        for j in range(4):
+            if t - (3 - j) >= 0:
+                want[:, t] += w[j] * np.asarray(x)[:, t - (3 - j)]
+    _close(got, want + np.arange(6.0), "conv")
+    # Causal: a later token changes no earlier output.
+    moved = conv.apply({"params": params}, x.at[:, 7].add(1.0))
+    assert np.array_equal(np.asarray(moved[:, :7]), np.asarray(got[:, :7]))
+    assert not np.array_equal(np.asarray(moved[:, 7]), np.asarray(got[:, 7]))
+
+
+def test_conv_gain_makes_xavier_taps_conv1ds():
+    """A Xavier-uniform ``[4, 4352]`` kernel is uniform over +-sqrt(6 /
+    4356); times the gain it is uniform over +-1/2, ``nn.Conv1d``'s bound
+    for 4 taps a channel."""
+    assert conv_gain(4, 4352) * np.sqrt(6 / 4356) == pytest.approx(0.5)
+    assert conv_gain(4, 4352) == pytest.approx(13.472, abs=1e-3)
+
+
+def test_head_constants_are_the_grid():
+    a, c = head_constants(64)
+    dt = np.log1p(np.exp(c))
+    assert a.shape == c.shape == (64,)
+    assert sorted(set(np.round(a, 4))) == pytest.approx(
+        16.0 ** np.linspace(-1, 1, 8), abs=1e-4)
+    assert dt.min() == pytest.approx(1e-3, rel=1e-4)
+    assert dt.max() == pytest.approx(1e-1, rel=1e-4)
+    # Every rate meets every step size once.
+    assert len({(round(float(x), 4), round(float(y), 6))
+                for x, y in zip(a, dt)}) == 64
+    assert (a * dt).min() == pytest.approx(1e-3 / 16, rel=1e-4)
+    assert (a * dt).max() == pytest.approx(1.6, rel=1e-4)
+    # A third of the heads remember past a chunk of 256 tokens.
+    assert np.sum(a * dt < 1 / 256) == 23
+    with pytest.raises(ValueError, match="square grid"):
+        head_constants(48)
+
+
+def _mixer(**kw):
+    return Mamba2Mixer(heads=HEADS, head_dim=HEAD_DIM, state=STATE, chunk=8,
+                       dtype=jnp.float32, **kw)
+
+
+def test_mixer_is_its_equations_written_out():
+    """The module against the recurrence and plain numpy around it: the
+    split of the projection, the convolution over x, B and C alike, the
+    constants a head, D's skip, the gate before the norm."""
+    u = jax.random.normal(jax.random.PRNGKey(0), (2, 24, 32))
+    params = _mixer().init(jax.random.PRNGKey(1), u)["params"]
+    assert {k: tuple(v[next(iter(v))].shape) for k, v in params.items()
+            if k not in ("conv",)} == {
+        "in_proj": (32, 2 * 32 + 2 * 16 + 4), "out_proj": (32, 32),
+        "a_log": (4,), "dt_bias": (4,), "d_skip": (4,),
+        "gate_norm": (32,)}
+    rng = np.random.RandomState(0)
+    params = jax.tree_util.tree_map(
+        lambda p: p + 0.1 * rng.standard_normal(p.shape).astype(np.float32),
+        params)
+    got = _mixer().apply({"params": params}, u)
+    p = jax.tree_util.tree_map(np.asarray, params)
+    inner, bc = 32, 16
+    zxbcdt = np.asarray(u) @ p["in_proj"]["kernel"]
+    z, xbc, dt = np.split(zxbcdt, (inner, 2 * inner + 2 * bc), axis=-1)
+    w = conv_gain(4, inner + 2 * bc) * p["conv"]["kernel"]
+    pad = np.concatenate([np.zeros((2, 3, xbc.shape[-1])), xbc], axis=1)
+    xbc = p["conv"]["bias"] + sum(w[j] * pad[:, j:j + 24] for j in range(4))
+    xbc = xbc / (1 + np.exp(-xbc))
+    x, b, c = np.split(xbc, (inner, inner + bc), axis=-1)
+    a_h, c_h = head_constants(HEADS)
+    a = -a_h * np.exp(p["a_log"]["bias"])
+    dt = np.log1p(np.exp(dt + c_h + p["dt_bias"]["bias"]))
+    x = x.reshape(2, 24, HEADS, HEAD_DIM)
+    y = np.asarray(ssd_recurrence(
+        jnp.asarray(x, jnp.float32), jnp.asarray(dt, jnp.float32),
+        jnp.asarray(a, jnp.float32),
+        jnp.asarray(b.reshape(2, 24, 1, STATE), jnp.float32),
+        jnp.asarray(c.reshape(2, 24, 1, STATE), jnp.float32)))
+    y = (y + p["d_skip"]["scale"][:, None] * x).reshape(2, 24, inner)
+    gated = y * (z / (1 + np.exp(-z)))
+    normed = gated / np.sqrt(np.mean(gated ** 2, -1, keepdims=True) + 1e-5) \
+        * p["gate_norm"]["scale"]
+    _close(got, normed @ p["out_proj"]["kernel"], "mixer", tol=2e-5)
+    # Norm-then-gate is another function.
+    other = y / np.sqrt(np.mean(y ** 2, -1, keepdims=True) + 1e-5) \
+        * p["gate_norm"]["scale"] * (z / (1 + np.exp(-z)))
+    assert np.max(np.abs(other @ p["out_proj"]["kernel"]
+                         - np.asarray(got))) > 0.05
+
+
+def test_mixer_counts_its_calls_when_traced():
+    from deeplearning_cfn_tpu.obs.trace import get_tracer
+
+    registry = get_tracer().registry
+    calls = registry.counter("ssm.scan.calls")
+    before = calls.value(path="xla", chunk="8")
+    u = jnp.zeros((1, 24, 32))
+    mixer = _mixer()
+    params = mixer.init(jax.random.PRNGKey(0), u)
+    jax.jit(jax.grad(lambda p: jnp.sum(mixer.apply(p, u))))(params)
+    # Once for the parameters, once for the step; the backward pass traces
+    # nothing again.
+    assert calls.value(path="xla", chunk="8") - before == 2
+    assert registry.gauge("ssm.scan.chunks").value() == 3
+    assert registry.gauge("ssm.state_bytes").value() \
+        == 4 * HEADS * HEAD_DIM * STATE
