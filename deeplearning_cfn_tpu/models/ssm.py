@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs.trace import get_tracer
-from ..ops.ssd import ssd_scan
+from ..ops.ssd import scan_path, ssd_scan
 from .transformer import Leaf, RMSNorm, shift_later
 
 Dtype = Any
@@ -100,10 +100,14 @@ class Mamba2Mixer(nn.Module):
     checkpoint of the source loads by subtracting, or dividing by, the
     constants.
 
+    ``scan_impl`` is the block's ``attention_impl``: with the shapes it
+    decides whether the scan runs as ``ops/ssd.py``'s kernels or its einsums
+    (``ops/ssd.py:scan_path``); ``mesh`` is the step's, for the kernels.
+
     Scopes, for the trace: ``ssm_in_proj``, ``ssm_conv``, ``ssm_scan``,
     ``ssm_gate_norm``, ``ssm_out_proj``. Counted when a call is traced:
-    ``ssm.scan.calls`` by ``path`` and ``chunk``; gauges ``ssm.scan.chunks``
-    and ``ssm.state_bytes`` (docs/OBSERVABILITY.md)."""
+    ``ssm.scan.calls`` by ``path`` (the one taken) and ``chunk``; gauges
+    ``ssm.scan.chunks`` and ``ssm.state_bytes`` (docs/OBSERVABILITY.md)."""
 
     heads: int
     head_dim: int
@@ -113,6 +117,8 @@ class Mamba2Mixer(nn.Module):
     chunk: int = 256
     rms_eps: float = 1e-5
     dtype: Dtype = jnp.bfloat16
+    scan_impl: str = "auto"
+    mesh: Any = None
 
     @nn.compact
     def __call__(self, u):
@@ -125,7 +131,9 @@ class Mamba2Mixer(nn.Module):
         registry.counter(
             "ssm.scan.calls",
             "state-space mixer calls traced, by the scan's path and chunk",
-        ).inc(path="xla", chunk=str(self.chunk))
+        ).inc(path=scan_path(
+            self.scan_impl, (bsz, seq, self.heads, self.head_dim),
+            self.state, self.groups, self.chunk)[0], chunk=str(self.chunk))
         registry.gauge(
             "ssm.scan.chunks", "chunks a sequence's scan is cut into",
         ).set(max(seq // self.chunk, 1))
@@ -151,7 +159,7 @@ class Mamba2Mixer(nn.Module):
             y = ssd_scan(x, dt, a,
                          b.reshape(bsz, seq, self.groups, self.state),
                          c.reshape(bsz, seq, self.groups, self.state),
-                         self.chunk)
+                         self.chunk, self.scan_impl, mesh=self.mesh)
             skip = Leaf("scale", head, name="d_skip")()
             y = (y.astype(jnp.float32) + skip[:, None] * x).reshape(
                 bsz, seq, inner)

@@ -827,7 +827,9 @@ class TransformerLayer(nn.Module):
             from .ssm import Mamba2Mixer
 
             mixed = Mamba2Mixer(dtype=self.dtype, rms_eps=st.rms_eps,
-                                name="self_attn", **dict(st.ssm))(
+                                scan_impl=self.attention_impl,
+                                mesh=self.mesh, name="self_attn",
+                                **dict(st.ssm))(
                                     norm("self_attn_norm")(x))
         elif st.mixer == "attention":
             mixed = MultiHeadAttention(

@@ -298,10 +298,12 @@ def test_granite4h_step_compiles_and_fits_a_v5e(v5e_chip):
     shapes: nine Mamba-2 mixers and one attention layer at 8,192 tokens,
     every block recomputed in the backward pass. Mosaic takes the flash
     kernels at 32 query heads over 8 K/V heads of 64 with no rotary kernel
-    beside them; the mixers' five scopes are in the text, forward, recomputed
-    and backward; no row is scattered inside a block; and 12.4 GB of state
-    with one block's intermediates fit the chip, over the quarter of it a
-    cell has to fill. PERF.md section 4 has the number."""
+    beside them, and the scan's two kernels (``ops/ssd.py``) in every Mamba
+    layer, forward, recomputed and backward, with no decay matrix in HBM;
+    the mixers' five scopes are in the text, forward, recomputed and
+    backward; no row is scattered inside a block; and 12.4 GB of state with
+    one block's intermediates fit the chip, over the quarter of it a cell
+    has to fill. PERF.md section 4 has the number."""
     import re
 
     from deeplearning_cfn_tpu.obs.trace import get_tracer
@@ -311,18 +313,20 @@ def test_granite4h_step_compiles_and_fits_a_v5e(v5e_chip):
     scans = registry.counter("ssm.scan.calls")
     blocks = registry.counter("model.blocks.recomputed")
     turned = registry.counter("attention.rope.calls")
-    before = (scans.value(path="xla", chunk="256"), blocks.value(),
+    scanned = lambda: tuple(scans.value(path=p, chunk="256")
+                            for p in ("kernel", "xla"))
+    before = (scanned(), blocks.value(),
               turned.value(path="kernel") + turned.value(path="xla"))
     cell = manifest.Cell(manifest.load_manifest(),
                          "granite4_h_micro_train_8k")
     assert cell.chips == 1
     _, compiled, _ = rehearse_compile.compile_step(cell)
-    # Traced twice (the parameters' shapes, the step): nine mixers and ten
-    # recomputed blocks each; the backward pass traces nothing again, and
-    # nothing turns q or k.
-    assert (scans.value(path="xla", chunk="256") - before[0],
+    # Traced twice (the parameters' shapes, the step): nine mixers, each
+    # through the scan's kernels, and ten recomputed blocks each; the
+    # backward pass traces nothing again, and nothing turns q or k.
+    assert (tuple(n - m for n, m in zip(scanned(), before[0])),
             blocks.value() - before[1], turned.value(path="kernel")
-            + turned.value(path="xla") - before[2]) == (18, 20, 0)
+            + turned.value(path="xla") - before[2]) == ((18, 0), 20, 0)
     assert registry.gauge("ssm.scan.chunks").value() == 32
     assert registry.gauge("ssm.state_bytes").value() == 64 * 64 * 128 * 4
     mem = compiled.memory_analysis()
@@ -334,10 +338,30 @@ def test_granite4h_step_compiles_and_fits_a_v5e(v5e_chip):
                if 'custom_call_target="tpu_custom_call"' in line]
     # The one attention layer: forward, forward again (recomputed), dK/dV,
     # dQ; K/V are not repeated to the query heads.
-    assert len(kernels) == 4
-    assert all("/layer_5/" in line and "core_attention/flash_" in line
+    flash = [line for line in kernels if "/layer_5/" in line]
+    assert len(flash) == 4
+    assert all("core_attention/flash_" in line
                and "bf16[1,8,8192,64]" in line
-               and "bf16[1,32,8192,64]" in line for line in kernels)
+               and "bf16[1,32,8192,64]" in line for line in flash)
+    # Every Mamba layer's scan: the forward, the forward again that keeps
+    # the states (recomputed) and the backward, all under the scope the
+    # readers know; x is read as the projection left it, [B, S, H * P].
+    scan = [line for line in kernels if line not in flash]
+    assert len(scan) == 27 and len(kernels) == 31
+    for layer in (0, 1, 2, 3, 4, 6, 7, 8, 9):
+        own = [re.search(r'op_name="([^"]*)"', line).group(1)
+               for line in scan if f"/layer_{layer}/" in line]
+        assert len(own) == 3 and all("/self_attn/ssm_scan/" in name
+                                     for name in own), own
+        assert sorted(("rematted_computation" in name,
+                       "transpose(jvp" in name,
+                       re.search(r"/(ssd_\w+)", name).group(1))
+                      for name in own) == [
+            (False, False, "ssd_fwd"), (False, True, "ssd_bwd"),
+            (True, True, "ssd_fwd")], own
+    assert all("bf16[1,8192,4096]" in line for line in scan)
+    # The decay matrix of 64 heads never reaches HBM.
+    assert not re.search(r"f32\[[\d,]*,256,256\]", text)
     for scope in ("ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm",
                   "ssm_out_proj"):
         for layer in (0, 4, 6, 9):

@@ -129,6 +129,38 @@ def test_flash_kernels_run_a_device_each_under_the_wrapper(devices):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
 
 
+def test_scan_kernels_run_a_device_each_under_the_wrapper(devices):
+    """Four sequences over a data=2 x expert=2 mesh through the state-space
+    scan's kernels (interpret mode), forward and backward, against the
+    einsums on no mesh; the wrapper was entered once for the call."""
+    from deeplearning_cfn_tpu.obs.trace import get_tracer
+    from deeplearning_cfn_tpu.ops.ssd import ssd_scan
+
+    mesh = _mesh(devices, data=2, expert=2)
+    rng = np.random.RandomState(0)
+    x = jnp.asarray(rng.normal(0, 1, (4, 256, 2, 64)), jnp.float32)
+    dt = jnp.asarray(rng.uniform(0.01, 0.1, (4, 256, 2)), jnp.float32)
+    a = jnp.asarray([-0.5, -4.0])
+    b, c = (jnp.asarray(rng.normal(0, 1, (4, 256, 1, 128)), jnp.float32)
+            for _ in range(2))
+    calls = get_tracer().registry.counter("parallel.shard_map.calls")
+    before = calls.value(kernel="ssd")
+
+    def loss(**how):
+        return lambda *t: jnp.sum(jnp.square(ssd_scan(*t, chunk=128, **how)))
+
+    got = jax.jit(jax.value_and_grad(
+        loss(implementation="interpret", mesh=mesh),
+        argnums=(0, 1, 2, 3, 4)))(x, dt, a, b, c)
+    assert calls.value(kernel="ssd") == before + 1
+    want = jax.value_and_grad(loss(implementation="reference"),
+                              argnums=(0, 1, 2, 3, 4))(x, dt, a, b, c)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        scale = float(jnp.max(jnp.abs(w)))
+        np.testing.assert_allclose(g, w, rtol=0, atol=2e-5 * scale)
+
+
 # -- the softmax router --------------------------------------------------------
 
 

@@ -11,23 +11,24 @@ import pytest
 
 from deeplearning_cfn_tpu.models.ssm import (CausalConv, Mamba2Mixer,
                                              conv_gain, head_constants)
-from deeplearning_cfn_tpu.ops.ssd import ssd_recurrence, ssd_scan
+from deeplearning_cfn_tpu.ops.ssd import (head_block, scan_path,
+                                         ssd_recurrence, ssd_scan)
 
 HEADS, HEAD_DIM, STATE = 4, 8, 16
 
 
-def _inputs(seq, groups, seed=0):
+def _inputs(seq, groups, seed=0, head_dim=HEAD_DIM, state=STATE):
     """Step sizes and rates whose product ``dt * a`` runs from 0.001 (a head
     that remembers a thousand tokens) to 2 (one that forgets inside one)."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
-    x = jax.random.normal(ks[0], (2, seq, HEADS, HEAD_DIM))
+    x = jax.random.normal(ks[0], (2, seq, HEADS, head_dim))
     a = -jnp.asarray([0.1, 1.0, 4.0, 16.0])
     dt = jnp.exp(jax.random.uniform(ks[1], (2, seq, HEADS),
                                     minval=jnp.log(0.01),
                                     maxval=jnp.log(0.125)))
     dt = dt.at[0, 0].set(0.01).at[0, 1].set(0.125)      # both ends, surely
-    b = jax.random.normal(ks[2], (2, seq, groups, STATE))
-    c = jax.random.normal(ks[3], (2, seq, groups, STATE))
+    b = jax.random.normal(ks[2], (2, seq, groups, state))
+    c = jax.random.normal(ks[3], (2, seq, groups, state))
     return x, dt, a, b, c
 
 
@@ -44,26 +45,90 @@ def _close(got, want, what, tol=TOL):
         (what, np.max(np.abs(got - want)), np.max(np.abs(want)))
 
 
-@pytest.mark.parametrize("seq,groups", [
-    (24, 1),    # 3 chunks
-    (40, 1),    # 5 chunks
-    (40, 2),    # 5 chunks, two groups of B and C
-    (8, 1),     # one chunk: nothing is handed on
-    (5, 1),     # shorter than a chunk
+# The Pallas kernels in interpreter mode, at the smallest shapes they tile:
+# a state of 128 and heads of 64 (two to a lane tile) or 128, chunks of whole
+# 128-square sub-tiles. Sums over up to 256 tokens of 128 states.
+KERNEL = dict(implementation="interpret", head_dim=64, state=128, tol=2e-5)
+
+
+@pytest.mark.parametrize("seq,groups,how", [
+    (24, 1, {}),    # 3 chunks
+    (40, 1, {}),    # 5 chunks
+    (40, 2, {}),    # 5 chunks, two groups of B and C
+    (8, 1, {}),     # one chunk: nothing is handed on
+    (5, 1, {}),     # shorter than a chunk
+    # 3 chunks, two groups, a head block a group: two a grid's chunk step.
+    (384, 2, dict(KERNEL, chunk=128, block_heads=2)),
+    # A chunk of four sub-tiles: the one above the diagonal skipped, the one
+    # left of it C B^T between two scalings; two head blocks of one group
+    # add into its dB and dC.
+    (512, 1, dict(KERNEL, chunk=256, block_heads=2)),
+    # The same with all heads a block: two units of its loop.
+    (512, 1, dict(KERNEL, chunk=256, block_heads=4)),
+    # Three sub-tiles: two rows of them left of the diagonal.
+    (384, 1, dict(KERNEL, chunk=384, block_heads=4)),
+    # A head of a whole lane tile, and of two.
+    (256, 2, dict(KERNEL, chunk=128, block_heads=1, head_dim=128)),
+    (256, 1, dict(KERNEL, chunk=128, block_heads=2, head_dim=256)),
+    # Asked for by name and shorter than a chunk: the einsums.
+    (100, 1, dict(KERNEL, chunk=128)),
 ])
-def test_chunked_scan_is_the_recurrence_with_every_gradient(seq, groups):
-    args = _inputs(seq, groups)
+def test_chunked_scan_is_the_recurrence_with_every_gradient(seq, groups,
+                                                            how):
+    how = dict(dict(chunk=8, head_dim=HEAD_DIM, state=STATE, tol=TOL), **how)
+    tol, sizes = how.pop("tol"), (how.pop("head_dim"), how.pop("state"))
+    args = _inputs(seq, groups, 0, *sizes)
     span = np.asarray(args[1])[..., None, :] * -np.asarray(args[2])
     assert span.min() < 0.0011 and span.max() > 1.99
-    scan = lambda *t: ssd_scan(*t, chunk=8)
-    _close(jax.jit(scan)(*args), ssd_recurrence(*args), "y")
+    path, _ = scan_path(how.get("implementation", "auto"), args[0].shape,
+                        sizes[1], groups, how["chunk"])
+    assert path == ("kernel" if "block_heads" in how else "xla")
+    scan = lambda *t: ssd_scan(*t, **how)
+    _close(jax.jit(scan)(*args), ssd_recurrence(*args), "y", tol)
     w = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
     grads = lambda f: jax.jit(jax.grad(
         lambda *t: jnp.sum(f(*t) * w), argnums=(0, 1, 2, 3, 4)))(*args)
     for name, got, want in zip(("x", "dt", "a", "b", "c"), grads(scan),
                                grads(ssd_recurrence)):
         assert np.any(np.asarray(want)), name
-        _close(got, want, f"d{name}")
+        _close(got, want, f"d{name}", tol)
+
+
+@pytest.mark.parametrize("shape,state,groups,chunk,path", [
+    ((1, 8192, 64, 64), 128, 1, 256, "kernel"),   # granite-4.0-h's
+    ((2, 512, 8, 128), 256, 2, 128, "kernel"),
+    ((1, 8192, 64, 64), 128, 1, 64, "xla"),       # no whole sub-tile
+    ((1, 8192, 64, 64), 16, 1, 256, "xla"),       # a state short of a tile
+    ((1, 8192, 64, 48), 128, 1, 256, "xla"),      # heads that split a tile
+    ((1, 8192, 3, 64), 128, 1, 256, "xla"),       # a tile with half a head
+    ((1, 200, 64, 64), 128, 1, 256, "xla"),       # shorter than a chunk
+    ((1, 8192 + 128, 64, 64), 128, 1, 256, "xla"),
+])
+def test_the_kernels_take_the_shapes_they_tile(shape, state, groups, chunk,
+                                               path):
+    """Which carrier runs is read from the call alone: on this CPU ``auto``
+    is the einsums whatever the shape, and the kernels asked for by name
+    engage only where the shape tiles."""
+    assert scan_path("pallas", shape, state, groups, chunk) == (path, False)
+    assert scan_path("interpret", shape, state, groups, chunk) \
+        == (path, True)
+    assert scan_path("auto", shape, state, groups, chunk) == ("xla", False)
+    assert scan_path("reference", shape, state, groups, chunk) \
+        == ("xla", False)
+    with pytest.raises(ValueError, match="unknown implementation"):
+        scan_path("mosaic", shape, state, groups, chunk)
+
+
+def test_head_block_is_whole_tiles_of_a_group():
+    assert head_block(64, 1, 64) == 8           # granite-4.0-h's
+    assert head_block(64, 1, 64, wanted=64) == 64
+    assert head_block(64, 4, 64, wanted=32) == 16   # a group's 16 heads
+    assert head_block(64, 16, 64) == 4
+    assert head_block(4, 1, 64) == 4
+    assert head_block(24, 1, 128) == 8
+    assert head_block(20, 1, 128) == 5
+    assert head_block(6, 1, 64) == 6            # pairs: never 3
+    assert head_block(2, 1, 64, wanted=1) == 2  # a lane tile at the least
 
 
 def test_the_state_a_chunk_enters_with_is_most_of_a_slow_heads_result():
@@ -87,17 +152,33 @@ def test_bad_shapes_are_refused():
                  jnp.tile(c, (1, 1, 3, 1)), chunk=8)
 
 
-def test_bfloat16_operands_keep_the_state_and_the_sums_in_float32():
+@pytest.mark.parametrize("seq,sizes,how", [
+    (40, (HEAD_DIM, STATE), dict(chunk=8)),
+    (512, (64, 128), dict(chunk=256, implementation="interpret",
+                          block_heads=2)),
+])
+def test_bfloat16_operands_keep_the_state_and_the_sums_in_float32(seq, sizes,
+                                                                  how):
     """The training dtype: products with bfloat16 operands, float32 sums.
     Against the float32 recurrence of the same rounded inputs the result is
     right to bfloat16's 3 digits, far from what a bfloat16 running sum over
-    40 tokens would leave."""
-    x, dt, a, b, c = _inputs(40, 1)
+    40 tokens would leave; so is every gradient, the kernels' as the
+    einsums', whose cotangents come back in the inputs' dtypes."""
+    x, dt, a, b, c = _inputs(seq, 1, 0, *sizes)
     half = lambda t: t.astype(jnp.bfloat16)
-    got = ssd_scan(half(x), dt, a, half(b), half(c), chunk=8)
+    args = (half(x), dt, a, half(b), half(c))
+    got = ssd_scan(*args, **how)
     assert got.dtype == jnp.bfloat16
-    want = ssd_recurrence(half(x), dt, a, half(b), half(c))
-    _close(got.astype(jnp.float32), want, "y", tol=2e-2)
+    _close(got.astype(jnp.float32), ssd_recurrence(*args), "y", tol=2e-2)
+    w = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+    grads = lambda f: jax.grad(lambda *t: jnp.sum(
+        f(*t).astype(jnp.float32) * w), argnums=(0, 1, 2, 3, 4))(*args)
+    for name, got, want in zip(("x", "dt", "a", "b", "c"),
+                               grads(lambda *t: ssd_scan(*t, **how)),
+                               grads(ssd_recurrence)):
+        assert got.dtype == want.dtype, name
+        _close(got.astype(jnp.float32), want.astype(jnp.float32),
+               f"d{name}", tol=2e-2)
 
 
 def test_causal_convolution_is_four_shifted_multiplies():
@@ -148,8 +229,9 @@ def test_head_constants_are_the_grid():
 
 
 def _mixer(**kw):
-    return Mamba2Mixer(heads=HEADS, head_dim=HEAD_DIM, state=STATE, chunk=8,
-                       dtype=jnp.float32, **kw)
+    return Mamba2Mixer(**dict(dict(heads=HEADS, head_dim=HEAD_DIM,
+                                   state=STATE, chunk=8, dtype=jnp.float32),
+                              **kw))
 
 
 def test_mixer_is_its_equations_written_out():
@@ -198,19 +280,51 @@ def test_mixer_is_its_equations_written_out():
                          - np.asarray(got))) > 0.05
 
 
-def test_mixer_counts_its_calls_when_traced():
+KERNEL_MIXER = dict(head_dim=64, state=128, chunk=128)
+
+
+@pytest.mark.parametrize("seq,how,path", [
+    (24, {}, "xla"),                            # off the TPU: the einsums
+    (24, dict(scan_impl="interpret"), "xla"),   # a chunk of 8 tiles nothing
+    (100, dict(KERNEL_MIXER, scan_impl="interpret"), "xla"),  # short
+    (256, dict(KERNEL_MIXER), "xla"),           # it would tile: off the TPU
+    (256, dict(KERNEL_MIXER, scan_impl="interpret"), "kernel"),
+])
+def test_mixer_counts_its_calls_when_traced(seq, how, path):
     from deeplearning_cfn_tpu.obs.trace import get_tracer
 
     registry = get_tracer().registry
     calls = registry.counter("ssm.scan.calls")
-    before = calls.value(path="xla", chunk="8")
-    u = jnp.zeros((1, 24, 32))
-    mixer = _mixer()
+    mixer = _mixer(**how)
+    chunk = str(mixer.chunk)
+    before = {p: calls.value(path=p, chunk=chunk) for p in ("xla", "kernel")}
+    u = jnp.zeros((1, seq, 32))
     params = mixer.init(jax.random.PRNGKey(0), u)
     jax.jit(jax.grad(lambda p: jnp.sum(mixer.apply(p, u))))(params)
-    # Once for the parameters, once for the step; the backward pass traces
-    # nothing again.
-    assert calls.value(path="xla", chunk="8") - before == 2
-    assert registry.gauge("ssm.scan.chunks").value() == 3
+    # Once for the parameters, once for the step, under the path taken; the
+    # backward pass traces nothing again.
+    assert {p: calls.value(path=p, chunk=chunk) - n
+            for p, n in before.items()} \
+        == {path: 2, "kernel" if path == "xla" else "xla": 0}
+    assert registry.gauge("ssm.scan.chunks").value() \
+        == max(seq // mixer.chunk, 1)
     assert registry.gauge("ssm.state_bytes").value() \
-        == 4 * HEADS * HEAD_DIM * STATE
+        == 4 * HEADS * mixer.head_dim * mixer.state
+
+
+def test_mixer_is_the_same_function_through_the_kernels():
+    """The module with the kernels (interpreter mode) against itself with
+    the einsums: the result and the gradient of every parameter."""
+    u = jax.random.normal(jax.random.PRNGKey(0), (2, 256, 32))
+    by = {impl: _mixer(**KERNEL_MIXER, scan_impl=impl)
+          for impl in ("interpret", "reference")}
+    params = by["reference"].init(jax.random.PRNGKey(1), u)
+    w = jax.random.normal(jax.random.PRNGKey(2), u.shape)
+    loss = lambda impl: lambda p: jnp.sum(by[impl].apply(p, u) * w)
+    _close(by["interpret"].apply(params, u), by["reference"].apply(params, u),
+           "mixer", tol=2e-5)
+    got, want = (jax.grad(loss(impl))(params)
+                 for impl in ("interpret", "reference"))
+    for (path, g), t in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree_util.tree_leaves(want)):
+        _close(g, t, jax.tree_util.keystr(path), tol=5e-5)
