@@ -102,6 +102,11 @@ class TrainConfig:
     # is BlockStyle.remat (models/transformer.py), asked for by a preset's
     # model kwargs (granite4_h_micro_lm: remat_blocks).
     remat: bool = False
+    # > 0: a decoder (``gpt_*``) is trained as a block-diffusion model with
+    # blocks of this many tokens (train/task.py:BlockDiffusionLmTask): a
+    # noised copy of each row beside the clean row, the loss on the masked
+    # tokens over their rate. 0: next-token prediction (CausalLmTask).
+    block_diffusion: int = 0
     # Capture a device+host profiler trace of this many hot-loop steps
     # (starting after the compile step) to <workdir>/<preset>/profile —
     # the Horovod-timeline role, natively. 0 = off.

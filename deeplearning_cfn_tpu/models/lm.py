@@ -67,6 +67,13 @@ class TransformerCausalLm(nn.Module):
     ``logits_scaling`` are Granite's: the embedding times the one, the logits
     over the other, in float32.
 
+    ``__call__``'s ``layout`` (``ops/attention.py:BlockDiffusion``, static)
+    makes the call a block-diffusion training pass: ``tokens`` are ``[B, 2
+    L]``, a noised copy of each row before the clean row; every attention
+    block masks by the layout and turns both copies by the positions ``0 ..
+    L - 1``; logits come back for the noised copy alone, ``[B, L, V]``.
+    Without it the same model is called causally, a position a token.
+
     ``mesh`` is the mesh the step is compiled for (``CausalLmTask`` hands it
     on): on more than one device the blocks' Pallas kernels run under a
     ``shard_map`` over its batch axes and an expert layer exchanges tokens
@@ -111,11 +118,12 @@ class TransformerCausalLm(nn.Module):
             # `causal` (the sixth argument, the module counted) is read by
             # Python: static under the recomputation.
             recomputed = nn.remat(TransformerLayer, static_argnums=(5,))
-            if any(style.remat and style.mlp == "experts"
-                   for _, _, _, style in self.blocks):
+            if any(style.remat and style.mlp == "experts" and style.router
+                   and dict(style.router).get("kind", "mlp_state")
+                   == "mlp_state" for _, _, _, style in self.blocks):
                 raise NotImplementedError(
                     "a recomputed block hands no router state on: "
-                    "BlockStyle.remat is for blocks without an expert layer")
+                    "BlockStyle.remat is for blocks whose router keeps none")
             self.layers = [
                 (recomputed if style.remat else TransformerLayer)(
                     heads, mlp_dim, dtype=self.dtype,
@@ -165,7 +173,7 @@ class TransformerCausalLm(nn.Module):
             x = self.dropout(x, deterministic=not train)
         return x
 
-    def _styled(self, tokens):
+    def _styled(self, tokens, layout=None):
         x = self._embed(tokens, None, False)
         # Carried from block to block beside x: the state of a router that
         # keeps one (None before the first such block, and for good where
@@ -174,14 +182,18 @@ class TransformerCausalLm(nn.Module):
         for (_, _, _, style), lyr in zip(self.blocks, self.layers):
             if style.remat:
                 # Positional: x, enc, self_bias, cross_bias, causal.
-                x = lyr(x, None, None, None, True)
+                x = lyr(x, None, None, None, True, layout=layout)
+                if style.mlp == "experts":
+                    x, aux = x
+                    counted.append(aux)
             elif style.mlp == "experts":
                 received += state is not None
-                x, aux = lyr(x, causal=True, router_state=state)
+                x, aux = lyr(x, causal=True, router_state=state,
+                             layout=layout)
                 state = aux.pop("router_state", None)
                 counted.append(aux)
             else:
-                x = lyr(x, causal=True)
+                x = lyr(x, causal=True, layout=layout)
         recomputed = sum(style.remat for _, _, _, style in self.blocks)
         if recomputed:
             get_tracer().registry.counter(
@@ -195,6 +207,11 @@ class TransformerCausalLm(nn.Module):
                 "expert layers of the traced model whose router was given "
                 "the state of the layer before",
             ).set(received)
+        if layout is not None:
+            # The noised copy alone is scored; the task's scope, as the
+            # layout's other halves are (train/task.py).
+            with jax.named_scope("bd_noise"):
+                x = x[:, :layout.length]
         logits = self._logits(self.final_norm(x))
         if not counted:
             return logits
@@ -205,9 +222,12 @@ class TransformerCausalLm(nn.Module):
             aux["rank_load_max_over_mean"] = worst("rank_load_max_over_mean")
         return logits, aux
 
-    def __call__(self, tokens, train: bool = False):
+    def __call__(self, tokens, train: bool = False, layout=None):
         if self.blocks:
-            return self._styled(tokens)
+            return self._styled(tokens, layout)
+        if layout is not None:
+            raise NotImplementedError(
+                "a block-diffusion layout is for a decoder of styled blocks")
         x = self._embed(tokens,
                         self.position[None, :tokens.shape[1], :], train)
         acc = MoeAuxAccumulator()
@@ -661,3 +681,67 @@ def gpt_granite4_h_tiny(num_classes: int = 0, dtype=jnp.float32, *,
                         attention_impl: str = "auto", mesh=None):
     return _granite4_h(_GRANITE4_H_TINY, dtype, vocab_size, layers_held,
                        remat_blocks, attention_impl, mesh)
+
+
+# SDAR-30B-A3B-Chat as JetLM published it (config.json, `model_type:
+# sdar_moe`, 30.5 B parameters, 3.3 B active): Qwen3-MoE's layer 48 times, of
+# hidden size 2048, 32 query heads over 4 K/V heads of 128 with an RMSNorm on
+# each head's q and k, rotary positions on the whole head at theta 1e6, every
+# MLP 128 experts of width 768, 8 a token by softmax scores normalised over
+# the chosen, no shared expert, RMSNorm 1e-6, an untied head over 151,936
+# tokens. It is trained and run as a block-diffusion model: the model's call
+# takes the layout (``TransformerCausalLm.__call__``), the noise and the loss
+# are ``train/task.py:BlockDiffusionLmTask``'s. benchmark/configs/
+# sdar_30b_a3b.json lists what the source leaves unsaid and how it was read.
+_SDAR_30B_A3B = dict(
+    hidden_size=2048, num_layers=48, head_dim=128, heads=32, kv_heads=4,
+    experts=128, top_k=8, expert_width=768, rope=Rope(theta=1_000_000.0))
+# The same block at sizes a CPU test holds.
+_SDAR_TINY = dict(
+    hidden_size=64, num_layers=2, head_dim=16, heads=4, kv_heads=2,
+    experts=8, top_k=2, expert_width=32, rope=Rope(theta=1_000_000.0))
+
+
+def _sdar(sizes, dtype, vocab_size, layers_held, experts_held, remat_blocks,
+          attention_impl, mesh=None):
+    """The ``sdar_moe`` decoder at ``sizes``, or one chip's share of it, told
+    as :func:`_laguna` is; ``remat_blocks`` as :func:`_granite4_h`'s."""
+    z = sizes
+    layers = range(z["num_layers"]) if layers_held is None \
+        else tuple(layers_held)
+    first, count = experts_held or (0, z["experts"])
+    experts = (("num_experts", z["experts"]),
+               ("held", (int(first), int(count))),
+               ("implementation", _grouped_matmul_for(attention_impl)))
+    router = (("kind", "softmax_top_k"), ("top_k", z["top_k"]))
+    style = BlockStyle(
+        num_kv_heads=z["kv_heads"], head_dim=z["head_dim"], rms_eps=1e-6,
+        rope=z["rope"], qk_norm=True, mlp="experts", experts=experts,
+        router=router, remat=bool(remat_blocks))
+    return TransformerCausalLm(
+        vocab_size=vocab_size, hidden_size=z["hidden_size"], dtype=dtype,
+        attention_impl=attention_impl, tie_embeddings=False, mesh=mesh,
+        blocks=tuple((i, z["heads"], z["expert_width"], style)
+                     for i in layers))
+
+
+@register_model("gpt_sdar_30b_a3b")
+def gpt_sdar_30b_a3b(num_classes: int = 0, dtype=jnp.bfloat16, *,
+                     vocab_size: int = 151_936, max_len: int = 8192,
+                     layers_held=None, experts_held=None,
+                     remat_blocks: bool = False,
+                     attention_impl: str = "auto", mesh=None):
+    # Every width is the published one; num_classes and max_len are not read,
+    # as in gpt_laguna_xs2.
+    return _sdar(_SDAR_30B_A3B, dtype, vocab_size, layers_held, experts_held,
+                 remat_blocks, attention_impl, mesh)
+
+
+@register_model("gpt_sdar_tiny")
+def gpt_sdar_tiny(num_classes: int = 0, dtype=jnp.float32, *,
+                  vocab_size: int = 96, max_len: int = 64,
+                  layers_held=None, experts_held=None,
+                  remat_blocks: bool = False,
+                  attention_impl: str = "auto", mesh=None):
+    return _sdar(_SDAR_TINY, dtype, vocab_size, layers_held, experts_held,
+                 remat_blocks, attention_impl, mesh)

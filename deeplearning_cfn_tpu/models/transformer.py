@@ -94,16 +94,21 @@ class Rope:
         return (1.0 / (self.yarn_factor * pos_freqs)) * ramp \
             + (1.0 / pos_freqs) * (1.0 - ramp)
 
-    def tables(self, seq_len: int, head_dim: int):
-        """``(cos, sin)``, each float32 ``[seq_len, rotary_dim / 2]``."""
-        angles = np.arange(seq_len, dtype=np.float64)[:, None] \
+    def tables(self, seq_len: int, head_dim: int, positions=None):
+        """``(cos, sin)``, each float32 ``[seq_len, rotary_dim / 2]``, for
+        the positions ``0 .. seq_len - 1`` or for the ``seq_len`` position
+        ids listed (a block-diffusion row's repeat)."""
+        positions = np.arange(seq_len) if positions is None \
+            else np.asarray(positions)
+        angles = positions.astype(np.float64)[:, None] \
             * self.inv_freq(head_dim)[None, :]
         return tuple((f(angles) * self.attention_factor).astype(np.float32)
                      for f in (np.cos, np.sin))
 
 
-def apply_rope(x: jnp.ndarray, rope: Rope) -> jnp.ndarray:
-    """Turn ``x [B, S, H, D]`` by its positions ``0 .. S - 1``: the plain
+def apply_rope(x: jnp.ndarray, rope: Rope, positions=None) -> jnp.ndarray:
+    """Turn ``x [B, S, H, D]`` by its positions (``0 .. S - 1`` unless
+    ``positions`` lists ``S`` ids, known while tracing): the plain
     form, the two turning halves computed apart and joined. At a 128-lane
     head XLA does each slice and the join through HBM in float32 (30 ms of
     the Laguna cell's 281 ms step, and 2.4 % more written as one multiply-add
@@ -113,7 +118,7 @@ def apply_rope(x: jnp.ndarray, rope: Rope) -> jnp.ndarray:
     head_dim = x.shape[-1]
     rot = rope.rotary_dim or head_dim
     cos, sin = (jnp.asarray(t)[None, :, None, :]
-                for t in rope.tables(x.shape[1], head_dim))
+                for t in rope.tables(x.shape[1], head_dim, positions))
     x32 = x.astype(jnp.float32)
     x1, x2 = x32[..., :rot // 2], x32[..., rot // 2:rot]
     turned = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
@@ -123,13 +128,16 @@ def apply_rope(x: jnp.ndarray, rope: Rope) -> jnp.ndarray:
 
 
 def rope_to_heads(x: jnp.ndarray, rope: Rope,
-                  implementation: str = "auto", mesh=None) -> jnp.ndarray:
+                  implementation: str = "auto", mesh=None,
+                  positions=None) -> jnp.ndarray:
     """``x [B, S, H, D]`` turned by its positions, as ``[B, H, S, D]``: the
     kernel where ``ops/rope.py:kernel_engages`` says so (a head of whole lane
     tiles on a TPU), else :func:`apply_rope` and the transpose. Which one is
     static, so it is counted when the call is traced: ``attention.rope.calls``
     labelled ``path=kernel|xla`` (docs/OBSERVABILITY.md). ``mesh`` is the
-    step's, for the kernel (``ops/rope.py:rotate_to_heads``)."""
+    step's, for the kernel (``ops/rope.py:rotate_to_heads``). ``positions``
+    lists the rows' position ids where they are not ``0 .. S - 1``: the
+    kernel takes its tables from the host, so only the tables change."""
     b, seq_len, h, head_dim = x.shape
     use_kernel, interpret = kernel_engages(implementation, seq_len, head_dim)
     get_tracer().registry.counter(
@@ -140,9 +148,9 @@ def rope_to_heads(x: jnp.ndarray, rope: Rope,
         # The kernel reads the projection's output as it lies: this undoes
         # the caller's split of the last dimension, and XLA drops both.
         return rotate_to_heads(x.reshape(b, seq_len, h * head_dim),
-                               *rope.tables(seq_len, head_dim), head_dim,
-                               interpret=interpret, mesh=mesh)
-    return apply_rope(x, rope).transpose(0, 2, 1, 3)
+                               *rope.tables(seq_len, head_dim, positions),
+                               head_dim, interpret=interpret, mesh=mesh)
+    return apply_rope(x, rope, positions).transpose(0, 2, 1, 3)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -196,7 +204,11 @@ class BlockStyle:
     ``remat``: the block is recomputed in the backward pass
     (``flax.linen.remat`` round the layer, ``models/lm.py``), so that only
     its input is kept from the forward pass and one block's intermediates
-    are alive at a time."""
+    are alive at a time.
+
+    ``qk_norm``: an RMSNorm over each head's channels on q and on k, a
+    learned scale of ``head_dim`` each (``query_norm``, ``key_norm``), before
+    the rotary turn (Qwen3's)."""
 
     num_kv_heads: int = 0          # 0: as many as query heads
     head_dim: int = 0              # 0: hidden size / heads
@@ -215,6 +227,7 @@ class BlockStyle:
     residual_multiplier: float = 1.0
     attn_scale: float = 0.0        # 0: 1 / sqrt(head_dim)
     remat: bool = False
+    qk_norm: bool = False
 
 
 class Leaf(nn.Module):
@@ -376,22 +389,29 @@ class MultiHeadAttention(nn.Module):
     def _kernel_mesh(self):
         return None if self.is_initializing() else self.mesh
 
-    def core_attention(self, q, k, v, bias, causal):
+    def core_attention(self, q, k, v, bias, causal, layout=None):
         """The [B,H,S,D] attention op. Subclasses swap this for a
         distributed strategy (SeqParallelAttention) while inheriting the
-        projections/KV-cache/dropout plumbing unchanged."""
+        projections/KV-cache/dropout plumbing unchanged. A ``layout``
+        (``ops/attention.py:BlockDiffusion``) is the call's whole mask, in
+        place of ``causal`` and the style's window."""
         st = self.style or BlockStyle()
+        if layout is not None:
+            causal, window = False, 0
+        else:
+            window = st.window
         return fused_attention(q, k, v, bias=bias, causal=causal,
                                sm_scale=st.attn_scale or None,
                                implementation=self.attention_impl,
-                               window=st.window, mesh=self._kernel_mesh())
+                               window=window, mesh=self._kernel_mesh(),
+                               layout=layout)
 
     @nn.compact
     def __call__(self, x, kv=None, bias=None, causal=False,
                  deterministic=True, decode=False,
                  max_decode_len: int = 0, decode_pos=None,
                  block_tables=None, kv_num_blocks: int = 0,
-                 kv_block_size: int = 0):
+                 kv_block_size: int = 0, layout=None):
         self_attention = kv is None
         kv = x if kv is None else kv
         features = x.shape[-1]
@@ -470,10 +490,23 @@ class MultiHeadAttention(nn.Module):
                       self.num_heads)
             k = heads(dense("key", kv_heads * head_dim)(kv), kv_heads)
             v = heads(dense("value", kv_heads * head_dim)(kv), kv_heads)
+        if layout is not None and self.style is None:
+            raise NotImplementedError(
+                "a block-diffusion layout is for a styled block's "
+                "self-attention in training and evaluation")
+        if st.qk_norm:
+            with jax.named_scope("qk_norm"):
+                q = RMSNorm(st.rms_eps, self.dtype, name="query_norm")(q)
+                k = RMSNorm(st.rms_eps, self.dtype, name="key_norm")(k)
         if st.rope is not None:
+            # Both copies of a block-diffusion row stand at the row's own
+            # positions: 0 .. L - 1 twice.
+            positions = None if layout is None \
+                else np.tile(np.arange(layout.length), 2)
             with jax.named_scope("rope"):
                 q, k = (rope_to_heads(t, st.rope, self.attention_impl,
-                                      self._kernel_mesh()) for t in (q, k))
+                                      self._kernel_mesh(), positions)
+                        for t in (q, k))
             v = v.transpose(0, 2, 1, 3)
         else:
             q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # [B,H,S,D]
@@ -711,6 +744,8 @@ class MultiHeadAttention(nn.Module):
                 )[:, None, :, :].astype(jnp.float32)
             out = fused_attention(q, ck.value, cv.value, bias=step_bias,
                                   causal=False, implementation="reference")
+        elif layout is not None:
+            out = self.core_attention(q, k, v, bias, causal, layout)
         else:
             out = self.core_attention(q, k, v, bias, causal)
         b, h, s, d = out.shape
@@ -800,7 +835,7 @@ class TransformerLayer(nn.Module):
     style: Optional[BlockStyle] = None
     mesh: Any = None     # the step's, for the kernels and the expert layer
 
-    def _styled(self, x, causal, router_state):
+    def _styled(self, x, causal, router_state, layout):
         st = self.style
         norm = lambda name: RMSNorm(st.rms_eps, self.dtype, name=name)
 
@@ -835,7 +870,7 @@ class TransformerLayer(nn.Module):
             mixed = MultiHeadAttention(
                 self.num_heads, self.dtype, 0.0, self.attention_impl,
                 style=st, mesh=self.mesh, name="self_attn")(
-                    norm("self_attn_norm")(x), causal=causal)
+                    norm("self_attn_norm")(x), causal=causal, layout=layout)
         else:
             raise ValueError(f"unknown BlockStyle.mixer {st.mixer!r}")
         x = join("self_attn", x, mixed, stream=not st.from_embedding)
@@ -867,13 +902,17 @@ class TransformerLayer(nn.Module):
                  causal=False, deterministic=True, decode=False,
                  max_decode_len: int = 0, decode_pos=None,
                  block_tables=None, kv_num_blocks: int = 0,
-                 kv_block_size: int = 0, router_state=None):
+                 kv_block_size: int = 0, router_state=None, layout=None):
+        if layout is not None and (self.style is None
+                                   or self.style.mixer != "attention"):
+            raise NotImplementedError(
+                "a block-diffusion layout is for a styled attention block")
         if self.style is not None:
             if decode or enc is not None or self_bias is not None:
                 raise NotImplementedError(
                     "a styled block runs self-attention over whole "
                     "sequences: no decode step, encoder or bias yet")
-            return self._styled(x, causal, router_state)
+            return self._styled(x, causal, router_state, layout)
         ln = lambda name: nn.LayerNorm(
             dtype=self.dtype, param_dtype=jnp.float32, name=name)
         attn = lambda name: MultiHeadAttention(

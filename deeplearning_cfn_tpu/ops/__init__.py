@@ -9,11 +9,12 @@ compiler. Every kernel has a pure-jnp reference implementation that is the
 numerics oracle in tests and the fallback on non-TPU backends.
 """
 
-from .attention import attention_reference, fused_attention
+from .attention import BlockDiffusion, attention_reference, fused_attention
 from .ring_attention import ring_attention, ring_attention_sharded
 from .ulysses import ulysses_attention, ulysses_attention_sharded
 
 __all__ = [
+    "BlockDiffusion",
     "attention_reference",
     "fused_attention",
     "ring_attention",

@@ -87,8 +87,48 @@ def _ceil8(n: int) -> int:
     return max(8, -(-n // 8) * 8)
 
 
+@jax.tree_util.register_static
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusion:
+    """The mask of a block-diffusion training row (``fused_attention``'s
+    ``layout``): ``2 * length`` positions, a noised copy of the row then the
+    clean row, each cut into blocks of ``block``. With ``B(i)`` the block of
+    a position within its copy, a noised position ``i`` sees the noised
+    ``j`` with ``B(j) == B(i)`` and the clean ``j`` with ``B(j) < B(i)``; a
+    clean ``i`` sees the clean ``j`` with ``B(j) <= B(i)`` and no noised
+    position. Of the ``(2 length)**2`` pairs ``length**2 + length * block``
+    are live. Static wherever it goes: a pytree without leaves, so that a
+    recomputed block (``flax.linen.remat``) takes it as a keyword."""
+    length: int
+    block: int
+
+    def __post_init__(self):
+        if self.block < 1 or self.length < 1 or self.length % self.block:
+            raise ValueError(f"a row of {self.length} positions is not a "
+                             f"whole number of blocks of {self.block}")
+
+    def mask(self) -> jnp.ndarray:
+        """``[2 length, 2 length]`` booleans, straight from the definition:
+        what the XLA path and ``attention_reference`` mask by."""
+        at = jnp.arange(2 * self.length)
+        clean, blk = at >= self.length, (at % self.length) // self.block
+        q_clean, k_clean = clean[:, None], clean[None, :]
+        qb, kb = blk[:, None], blk[None, :]
+        return jnp.where(k_clean, jnp.where(q_clean, kb <= qb, kb < qb),
+                         ~q_clean & (kb == qb))
+
+
+def _mask_name(window: int = 0, layout: Optional[BlockDiffusion] = None
+               ) -> str:
+    """The ``mask`` label of a call's flash gauges and counter."""
+    if layout is not None:
+        return "block_diffusion"
+    return "window" if window else ""
+
+
 def _tile_plan(sq: int, sk: int, d: int, causal: bool,
-               backward: bool = False, window: int = 0
+               backward: bool = False, window: int = 0,
+               layout: Optional[BlockDiffusion] = None
                ) -> Tuple[int, int, int, int]:
     """``(block_q, block_k, sub_q, sub_k)`` for one call's forward kernel or
     its two backward kernels, from what the call can observe (pure,
@@ -103,12 +143,20 @@ def _tile_plan(sq: int, sk: int, d: int, causal: bool,
     above). A call with a ``window`` has sub-tiles in all three kernels: a
     band of rows needs ``window + sub`` columns whatever the length, so the
     forward leaves out more than the pieces cost it, and most at 128-square,
-    where the band has few pieces to trace."""
+    where the band has few pieces to trace. A ``layout`` is planned over one
+    copy of its row (each is padded to whole grid tiles, which are whole
+    numbers of the layout's blocks) with the sub-tiles of a windowed call:
+    its edges are as narrow, three tiles a band of rows, and it was not
+    swept apart."""
     del d  # one rule held for d = 64 and d = 128
-    fwd_sub = _WINDOW_FWD_SUB if window and not backward else None
+    edges = window or layout is not None
+    fwd_sub = _WINDOW_FWD_SUB if edges and not backward else None
+    if layout is not None:
+        sq = sk = layout.length
 
     def one(s, block, sub):
-        if not (causal and (backward or window)) or s <= sub:
+        if not ((causal or layout is not None) and (backward or edges)) \
+                or s <= sub:
             b = min(block, _ceil8(s))
             return b, b
         return min(block, -(-s // sub) * sub), sub
@@ -116,6 +164,19 @@ def _tile_plan(sq: int, sk: int, d: int, causal: bool,
     block_q, sub_q = one(sq, _BLOCK_Q, fwd_sub or _SUB_Q)
     block_k, sub_k = one(sk, _BLOCK_K, fwd_sub or _SUB_K)
     return block_q, block_k, sub_q, sub_k
+
+
+def _check_layout_plan(layout: BlockDiffusion, plan) -> None:
+    """What the layout's kernels ask of a plan, ``_tile_plan``'s or a test's
+    (``_flash_forward`` and ``_flash_backward`` call it on either)."""
+    block_q, block_k = plan[:2]
+    if block_q != block_k or block_q % layout.block \
+            or layout.block & (layout.block - 1):
+        raise ValueError(
+            f"the flash kernels take a block-diffusion layout whose block "
+            f"length is a power of two that divides their square grid tile;"
+            f" got blocks of {layout.block} under tiles of {block_q} x "
+            f"{block_k}: implementation='reference' takes any")
 
 
 # The three cases of a sub-tile, decided from positions alone (a row's
@@ -134,14 +195,38 @@ def _tile_plan(sq: int, sk: int, d: int, causal: bool,
 
 
 _CAUSAL, _WINDOW = 1, 2
+# A block-diffusion layout's three edges, each within one quadrant of the
+# ``[noised | clean]`` square (the fourth, clean rows over noised columns, is
+# dead): a position's block against a column's. They are also what a grid
+# tile on an edge is called (``_BlockWalk.rel``); 0 is a tile under an edge.
+_SAME_BLOCK, _EARLIER_BLOCKS, _BLOCKS_UP_TO = 4, 8, 16
+_BLOCK_EDGES = _SAME_BLOCK | _EARLIER_BLOCKS | _BLOCKS_UP_TO
 
 
-def _subtile_kind(q0: int, q1: int, k0: int, k1: int, window: int):
+def _subtile_kind(q0: int, q1: int, k0: int, k1: int, window: int,
+                  edge: int = 0, block: int = 0):
     """A sub-tile of rows at positions ``[q0, q1)`` and columns ``[k0, k1)``:
     None where nothing of it is needed (its first column lies past its last
     row, or its last column ``window`` or more before its first row), else
     the masks it needs: ``_CAUSAL`` where the diagonal crosses it,
-    ``_WINDOW`` where the window's far edge does, 0 where neither."""
+    ``_WINDOW`` where the window's far edge does, 0 where neither.
+
+    With an ``edge`` the positions are those within a copy of a
+    block-diffusion row cut into blocks of ``block``, in the quadrant whose
+    edge it is, and the answer is by the blocks the rows and the columns
+    touch: None where no pair is live, 0 where every pair is, else the
+    ``edge`` itself."""
+    if edge:
+        qb0, qb1 = q0 // block, (q1 - 1) // block
+        kb0, kb1 = k0 // block, (k1 - 1) // block
+        if edge == _SAME_BLOCK:
+            if kb0 > qb1 or kb1 < qb0:
+                return None
+            return 0 if qb0 == qb1 == kb0 == kb1 else edge
+        reach = edge == _BLOCKS_UP_TO  # a clean row sees its own block too
+        if kb0 >= qb1 + reach:
+            return None
+        return 0 if kb1 < qb0 + reach else edge
     if k0 > q1 - 1 or (window and q0 - (k1 - 1) >= window):
         return None
     kind = _CAUSAL if k1 - 1 > q0 else 0
@@ -150,7 +235,8 @@ def _subtile_kind(q0: int, q1: int, k0: int, k1: int, window: int):
     return kind
 
 
-def _staircase(rel: int, plan, by_columns: bool, window: int = 0):
+def _staircase(rel: int, plan, by_columns: bool, window: int = 0,
+               edge: int = 0, block: int = 0):
     """The pieces of a grid tile whose first row stands at position ``rel``
     of its own columns, band by band: ``[((o0, o1), [(i0, i1, kind),
     ...]), ...]``. A band is ``sub_q`` rows with pieces of columns (forward,
@@ -166,7 +252,8 @@ def _staircase(rel: int, plan, by_columns: bool, window: int = 0):
         for i in range(n_r if by_columns else n_c):
             r, c = (i, o) if by_columns else (o, i)
             kind = _subtile_kind(rel + r * sub_q, rel + (r + 1) * sub_q,
-                                 c * sub_k, (c + 1) * sub_k, window)
+                                 c * sub_k, (c + 1) * sub_k, window, edge,
+                                 block)
             if kind is None:
                 continue
             if pieces and pieces[-1][1:] == (i * inner, kind):
@@ -188,7 +275,8 @@ def _grid_rels(sq: int, sk: int, block_q: int, block_k: int):
 
 
 def _schedule(sq: int, sk: int, plan, causal: bool, by_columns: bool = False,
-              mask_whole: bool = False, window: int = 0):
+              mask_whole: bool = False, window: int = 0,
+              layout: Optional[BlockDiffusion] = None):
     """What a grid step computes, as cases ``(lo, hi, bands)``: the bands
     (as ``_staircase`` gives them) for a tile whose ``rel`` is ``lo == hi``,
     or at least ``lo`` where ``hi`` is None, or anything where both are. A
@@ -200,9 +288,19 @@ def _schedule(sq: int, sk: int, plan, causal: bool, by_columns: bool = False,
     before the sub-tiles. Otherwise a tile the diagonal crosses gets its
     staircase, and a tile below it is one unmasked piece. With a ``window``
     a tile far below the diagonal is not computed either, so every offset
-    the grid has gets its own case, or none."""
+    the grid has gets its own case, or none.
+
+    A ``layout``'s cases are keyed by what ``_BlockWalk.rel`` calls a tile:
+    the edge it lies on (the diagonal tile of a quadrant: its staircase
+    under that edge) or 0 (a tile under a staircase: one unmasked piece)."""
     block_q, block_k, sub_q, sub_k = plan
     outer, inner = (block_k, block_q) if by_columns else (block_q, block_k)
+    if layout is not None:
+        cases = [(0, 0, [((0, outer), [(0, inner, 0)])])] + [
+            (edge, edge, _staircase(0, plan, by_columns, edge=edge,
+                                    block=layout.block))
+            for edge in (_SAME_BLOCK, _EARLIER_BLOCKS, _BLOCKS_UP_TO)]
+        return [case for case in cases if case[2]]
     if window:
         cases = [(rel, rel, _staircase(rel, plan, by_columns, window))
                  for rel in sorted(set(_grid_rels(sq, sk, block_q, block_k)))]
@@ -230,11 +328,17 @@ def _bands_at(cases, rel: int):
     return []
 
 
-def _subtile_counts(sq: int, sk: int, plan, cases) -> Tuple[int, int, int]:
+def _subtile_counts(sq: int, sk: int, plan, cases, walk=None
+                    ) -> Tuple[int, int, int]:
     """``(all, computed, masked)`` sub-tiles of one head of a call, counted
-    from the ``cases`` (``_schedule``) its kernel runs."""
+    from the ``cases`` (``_schedule``) its kernel runs; those of a
+    block-diffusion layout's padded square through its ``walk``, which
+    names its tiles."""
     block_q, block_k, sub_q, sub_k = plan
-    tiles = _grid_rels(sq, sk, block_q, block_k)
+    tiles = [walk.rel(o, block) for o in range(walk.outer_blocks)
+             for block in range(walk.outer_blocks)] \
+        if isinstance(walk, _BlockWalk) \
+        else _grid_rels(sq, sk, block_q, block_k)
     live = masked = 0
     for rel in tiles:
         for (o0, o1), pieces in _bands_at(cases, rel):
@@ -329,11 +433,105 @@ class _Walk:
         return (block * self.inner - o * self.outer if self.by_columns
                 else o * self.outer - block * self.inner) + self.shift
 
+    def place(self, o, block):
+        """``(rel, q0, k0)``: what the tile's case is looked up by, and the
+        positions its masks count its first row and first column from."""
+        rel = self.rel(o, block)
+        return rel, rel, 0
+
+    @property
+    def mask(self) -> str:
+        """The ``mask`` label of the call's flash gauges (``_mask_name``)."""
+        return _mask_name(self.window)
+
+
+def _pick(cond, a, b):
+    """``a if cond else b`` of Python ints or of traced scalars."""
+    if isinstance(cond, bool):
+        return a if cond else b
+    return jnp.where(cond, a, b)
+
+
+@dataclasses.dataclass(frozen=True)
+class _BlockWalk(_Walk):
+    """The innermost grid axis under a block-diffusion layout whose copies
+    are ``half`` square grid tiles each: ``2 half`` outer blocks, noised
+    before clean. An outer block has inner blocks in two runs, noised ones
+    then clean ones (``_runs``), and a step stands for the next block of
+    the first run, then of the second. A block of noised rows has its own
+    noised columns and the clean columns up to its own block (to the block
+    before where a tile is one block of the layout: ``strict``); a block of
+    clean rows the clean columns up to its own; a block of noised columns
+    its own rows; a block of clean columns the noised rows from its own
+    block (the next, ``strict``) on and the clean rows from its own on. The
+    axis has as many ``steps`` as the longest pair of runs, and a step past
+    the runs names the last block of them, as a windowed call's does."""
+    half: int = 0
+    strict: int = 0
+
+    def _runs(self, o):
+        """``(first, count)`` of noised inner blocks, then of clean ones."""
+        n, clean = self.half, o >= self.half
+        at = o - _pick(clean, n, 0)
+        if self.by_columns:
+            return (_pick(clean, at + self.strict, at),
+                    _pick(clean, n - at - self.strict, 1),
+                    n + at, _pick(clean, n - at, 0))
+        return (at, _pick(clean, 0, 1), n,
+                at + 1 - _pick(clean, 0, self.strict))
+
+    @functools.cached_property
+    def steps(self) -> int:
+        return (2 * self.half if self.by_columns else self.half + 1) \
+            - self.strict
+
+    def _at(self, o, j):
+        first_a, n_a, first_b, n_b = self._runs(o)
+        return _pick(j < n_a, first_a + j, first_b + j - n_a), n_a + n_b
+
+    def block(self, o, j):
+        block, count = self._at(o, j)
+        return block, j < count
+
+    def named(self, o, j):
+        count = self._at(o, j)[1]
+        return self._at(o, _clip(j, 0, count - 1))[0]
+
+    def _halves(self, o, block):
+        """The row block and the column block of a tile, each as ``(index
+        within its copy, whether the copy is the clean one)``."""
+        q, k = (block, o) if self.by_columns else (o, block)
+        q_clean, k_clean = q >= self.half, k >= self.half
+        return (q - _pick(q_clean, self.half, 0), q_clean,
+                k - _pick(k_clean, self.half, 0), k_clean)
+
+    def rel(self, o, block):
+        """What a tile is called: the edge it lies on, 0 under an edge, -1
+        where nothing of it is live."""
+        q, q_clean, k, k_clean = self._halves(o, block)
+        on_edge = _pick(q_clean, _pick(k_clean, _BLOCKS_UP_TO, -1),
+                        _pick(k_clean, _EARLIER_BLOCKS, _SAME_BLOCK))
+        return _pick(q == k, on_edge,
+                     _pick(k_clean, _pick(q > k, 0, -1), -1))
+
+    def place(self, o, block):
+        q, _, k, _ = self._halves(o, block)
+        return self.rel(o, block), q * self.outer, k * self.inner
+
+    mask = "block_diffusion"
+
 
 def _walk(sq: int, sk: int, plan, causal: bool, window: int,
-          by_columns: bool) -> _Walk:
-    """The ``_Walk`` of one kernel of a call."""
+          by_columns: bool, layout: Optional[BlockDiffusion] = None
+          ) -> _Walk:
+    """The ``_Walk`` of one kernel of a call (``sq`` and ``sk`` of a
+    ``layout``: its padded square's)."""
     block_q, block_k = plan[:2]
+    if layout is not None:
+        half = sq // (2 * block_q)
+        return _BlockWalk(block_q, block_q, 2 * half, 2 * half, 0, 0,
+                          by_columns, True, half,
+                          int(block_q == layout.block))
     n_q, n_k = -(-sq // block_q), -(-sk // block_k)
     clamps = causal and (n_q, n_k) != (1, 1)
     if by_columns:
@@ -345,10 +543,10 @@ def _walk(sq: int, sk: int, plan, causal: bool, window: int,
 _GRID_GAUGES = ("grid_steps", "dead_grid_steps", "dead_step_copies")
 
 
-def _labels(kernel: str, window: int) -> dict:
-    """A flash gauge's labels: a windowed call's say so beside the kernel."""
-    return dict(kernel=kernel, mask="window") if window \
-        else dict(kernel=kernel)
+def _labels(kernel: str, mask: str) -> dict:
+    """A flash gauge's labels: the kernel, and the call's ``mask`` where it
+    has one of its own (``_mask_name``)."""
+    return dict(kernel=kernel, mask=mask) if mask else dict(kernel=kernel)
 
 
 def _grid_steps(walk: _Walk, cases, group: int = 1):
@@ -390,24 +588,28 @@ def _record_grid(kernel: str, walk: _Walk, cases, group: int) -> None:
             "grid steps of a flash kernel", "those in which no case runs",
             "dead steps that copy a block no live step reads")):
         registry.gauge(f"attention.flash.{name}", f"{what}, a query head"
-                       ).set(count / heads, **_labels(kernel, walk.window))
+                       ).set(count / heads, **_labels(kernel, walk.mask))
 
 
-def _grid_gauges(kernel: str, window: int = 0) -> Tuple[float, ...]:
+def _grid_gauges(kernel: str, window: int = 0,
+                 layout: Optional[BlockDiffusion] = None
+                 ) -> Tuple[float, ...]:
     """What ``_record_grid`` last set for a kernel: ``(grid_steps,
     dead_grid_steps, dead_step_copies)`` a query head."""
     registry = get_tracer().registry
     return tuple(registry.gauge(f"attention.flash.{name}").value(
-        **_labels(kernel, window)) for name in _GRID_GAUGES)
+        **_labels(kernel, _mask_name(window, layout)))
+        for name in _GRID_GAUGES)
 
 
 def _record_subtiles(kernel: str, counts: Tuple[int, int, int],
-                     window: int = 0) -> None:
+                     mask: str = "") -> None:
     """The mechanism's engagement is static, so it is two gauges set when a
-    kernel is traced (docs/OBSERVABILITY.md); a windowed call's are labelled
-    ``mask="window"`` beside the kernel."""
+    kernel is traced (docs/OBSERVABILITY.md); those of a call with a mask of
+    its own are labelled ``mask="window"`` or ``mask="block_diffusion"``
+    beside the kernel."""
     total, live, masked = counts
-    labels = _labels(kernel, window)
+    labels = _labels(kernel, mask)
     registry = get_tracer().registry
     registry.gauge(
         "attention.flash.live_subtile_share",
@@ -462,6 +664,20 @@ def _inside_window(shape, q0, k0, window):
     return rel > q0 - k0 - window
 
 
+def _by_blocks(shape, q0, k0, edge, block):
+    """A block-diffusion edge on a score tile whose first row is at position
+    ``q0`` of its copy and whose first column is at ``k0`` of its own: the
+    column's block against the row's (``block`` is a power of two)."""
+    shift = block.bit_length() - 1
+    qb = jax.lax.shift_right_logical(
+        q0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0), shift)
+    kb = jax.lax.shift_right_logical(
+        k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1), shift)
+    if edge == _SAME_BLOCK:
+        return kb == qb
+    return kb < qb if edge == _EARLIER_BLOCKS else kb <= qb
+
+
 # ---------------------------------------------------------------------------
 # Reference implementation (oracle + fallback + backward)
 # ---------------------------------------------------------------------------
@@ -475,12 +691,13 @@ def attention_reference(
     causal: bool = False,
     sm_scale: Optional[float] = None,
     window: int = 0,
+    layout: Optional[BlockDiffusion] = None,
 ) -> jnp.ndarray:
     """Plain jnp attention; computes in f32 regardless of input dtype (the
     softmax accumulator precision the kernel also uses). Fewer K/V heads
     than query heads are repeated to them here (the kernels index them
     instead); ``window`` keeps, of a causal row ``i``, columns ``j`` with
-    ``i - j < window``."""
+    ``i - j < window``; a ``layout`` keeps the pairs of its definition."""
     *_, sq, d = q.shape
     sk = k.shape[-2]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
@@ -497,6 +714,8 @@ def attention_reference(
         logits = jnp.where(k_pos <= q_pos, logits, _NEG_INF)
         if window:
             logits = jnp.where(q_pos - k_pos < window, logits, _NEG_INF)
+    if layout is not None:
+        logits = jnp.where(layout.mask(), logits, _NEG_INF)
     weights = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", weights.astype(v.dtype), v,
                       preferred_element_type=jnp.float32).astype(q.dtype)
@@ -510,7 +729,7 @@ def attention_reference(
 def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                   m_scr, l_scr, acc_scr, *,
                   causal: bool, sm_scale: float, cases, one_tile: bool,
-                  walk: _Walk, window: int = 0):
+                  walk: _Walk, window: int = 0, block: int = 0):
     """One (batch, head, q-block, kv-block) grid step of the online softmax.
 
     The kv-block axis is the innermost ("arbitrary") grid dimension: the
@@ -541,7 +760,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
     step = 0 if one_tile else pl.program_id(3)
     last_step = 0 if one_tile else pl.num_programs(3) - 1
     kb, live = walk.block(iq, step)
-    rel = walk.rel(iq, kb)
+    rel, q0, k0 = walk.place(iq, kb)
 
     @_when(step == 0)
     def _init():
@@ -561,14 +780,20 @@ def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
             if bias_ref is not None:  # the plan gives a bias one piece
                 s = s + bias_ref[0, 0, :, :].astype(jnp.float32)
             if masked & _CAUSAL:
-                s = jnp.where(_below_diagonal(s.shape, rel + r0, c0),
+                s = jnp.where(_below_diagonal(s.shape, q0 + r0, k0 + c0),
                               s, _NEG_INF)
             if masked & _WINDOW:
                 # A row may see nothing of its first piece; what it then
                 # sums (p = 1 at m = -1e30) is wiped by alpha = 0 when its
                 # own diagonal comes.
-                s = jnp.where(_inside_window(s.shape, rel + r0, c0, window),
-                              s, _NEG_INF)
+                s = jnp.where(_inside_window(s.shape, q0 + r0, k0 + c0,
+                                             window), s, _NEG_INF)
+            if masked & _BLOCK_EDGES:
+                # Every row has seen its own block by then: a noised row's
+                # first step is its own columns, a clean row's first piece
+                # holds its block or lies under the edge.
+                s = jnp.where(_by_blocks(s.shape, q0 + r0, k0 + c0, masked,
+                                         block), s, _NEG_INF)
             scores.append(s)
         m_prev = m_scr[r0:r1, :1]
         l_prev = l_scr[r0:r1, :1]
@@ -646,11 +871,39 @@ def _pad_to(x: jnp.ndarray, axis: int, multiple: int) -> jnp.ndarray:
     return jnp.pad(x, widths)
 
 
+def _pad_copies(x: jnp.ndarray, layout: Optional[BlockDiffusion],
+                tile: int, fill=0) -> jnp.ndarray:
+    """Each copy of a block-diffusion row (axis 2 of ``x``) padded to whole
+    grid tiles. The padding opens blocks of its own after the row's last, so
+    no real row sees a padded column: the layout's edges are the only masks
+    its kernels need."""
+    if layout is None or layout.length % tile == 0:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[2] = (0, -layout.length % tile)
+    return jnp.concatenate([
+        jnp.pad(copy, widths, constant_values=fill)
+        for copy in jnp.split(x, 2, axis=2)], axis=2)
+
+
+def _unpad_copies(x: jnp.ndarray, layout: Optional[BlockDiffusion]
+                  ) -> jnp.ndarray:
+    """``_pad_copies`` undone."""
+    if layout is None or x.shape[2] == 2 * layout.length:
+        return x
+    return jnp.concatenate([copy[:, :, :layout.length] for copy in
+                            jnp.split(x, 2, axis=2)], axis=2)
+
+
 def _flash_forward(q, k, v, bias, causal, sm_scale, interpret=False,
-                   return_stats=False, plan=None, window=0):
+                   return_stats=False, plan=None, window=0, layout=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if layout is not None:
+        plan = plan or _tile_plan(0, 0, q.shape[-1], causal, layout=layout)
+        _check_layout_plan(layout, plan)
+        q, k, v = (_pad_copies(t, layout, plan[0]) for t in (q, k, v))
     b, h, sq, d = q.shape
     sk = k.shape[-2]
     group = h // k.shape[1]  # query heads to one K/V head
@@ -666,9 +919,11 @@ def _flash_forward(q, k, v, bias, causal, sm_scale, interpret=False,
     # block-shape rule. ``plan`` is for tests, which force small sub-tiles.
     plan = plan or _tile_plan(sq, sk, d, causal, window=window)
     block_q, block_k = plan[:2]
-    cases = _schedule(sq, sk, plan, causal, window=window)
-    _record_subtiles("flash_fwd", _subtile_counts(sq, sk, plan, cases),
-                     window)
+    cases = _schedule(sq, sk, plan, causal, window=window, layout=layout)
+    walk = _walk(sq, sk, plan, causal, window, by_columns=False,
+                 layout=layout)
+    _record_subtiles("flash_fwd",
+                     _subtile_counts(sq, sk, plan, cases, walk), walk.mask)
 
     qp = _pad_to(q, 2, block_q)
     kp = _pad_to(k, 2, block_k)
@@ -690,7 +945,6 @@ def _flash_forward(q, k, v, bias, causal, sm_scale, interpret=False,
             jnp.arange(sk_p) < sk, 0.0, _NEG_INF)[None, None, None, :]
         bias = pad_bias if bias is None else bias + pad_bias
 
-    walk = _walk(sq, sk, plan, causal, window, by_columns=False)
     _record_grid("flash_fwd", walk, cases, group)
     kv_spec = pl.BlockSpec((1, 1, block_k, d), _kv_by_inner(walk, group))
     in_specs = [
@@ -705,6 +959,8 @@ def _flash_forward(q, k, v, bias, causal, sm_scale, interpret=False,
     kernel_kw = dict(causal=causal, sm_scale=sm_scale, cases=cases,
                      window=window, walk=walk,
                      one_tile=(sq_p, sk_p) == (block_q, block_k))
+    if layout is not None:
+        kernel_kw["block"] = layout.block
     if bias is not None:
         # Keep broadcast dims at size 1 (indexed with block 0) instead of
         # materializing [B,H,Sq,Sk] in HBM.
@@ -762,8 +1018,9 @@ def _flash_forward(q, k, v, bias, causal, sm_scale, interpret=False,
     )(*args)
     if return_stats:
         out, lse = result
-        return out[:, :, :sq, :], lse[:, :, :sq, 0]
-    return result[:, :, :sq, :]
+        return _unpad_copies(out[:, :, :sq, :], layout), \
+            _unpad_copies(lse[:, :, :sq, 0], layout)
+    return _unpad_copies(result[:, :, :sq, :], layout)
 
 
 # ---------------------------------------------------------------------------
@@ -794,10 +1051,14 @@ def _bwd_mask(s, q0, k0, causal, cols):
     return jnp.where(live, s, _NEG_INF)
 
 
-def _piece_mask(kind, q0, k0, causal, cols, window):
+def _piece_mask(kind, q0, k0, causal, cols, window, block=0):
     """The mask of one piece of a backward kernel that needs one: the call's
     own mask as ever or, in a windowed call, the edges that cross the
-    piece."""
+    piece; under a block-diffusion layout the edge of the piece's quadrant
+    (its padded columns are blocks no real row sees)."""
+    if kind & _BLOCK_EDGES:
+        return lambda s: jnp.where(
+            _by_blocks(s.shape, q0, k0, kind, block), s, _NEG_INF)
     if not window:
         return functools.partial(_bwd_mask, q0=q0, k0=k0, causal=causal,
                                  cols=cols)
@@ -844,7 +1105,7 @@ def _bwd_piece(rows, k_blk, v_blk, *, sm_scale, mask):
 def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                            dk_ref, dv_ref, dk_scr, dv_scr, *, causal,
                            sm_scale, seq_k, cases, one_tile, walk,
-                           window=0, group=1):
+                           window=0, group=1, block=0):
     """One (batch, head, kv-block, q-block) grid step: accumulate this q
     block's contribution to dK/dV of one kv block in VMEM scratch; write on
     the last q step. Same block-mapped structure as the forward kernel, and
@@ -865,7 +1126,7 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     else:
         qi = 0 if walk.steps == 1 else jax.lax.rem(step, walk.steps)
     qi, live = walk.block(ik, qi)
-    rel = walk.rel(ik, qi)
+    rel, q0, k0 = walk.place(ik, qi)
     cols = seq_k - ik * block_k
 
     @_when(step == 0)
@@ -879,8 +1140,8 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk = dk_scr[c0:c1, :]
         dv = dv_scr[c0:c1, :]
         for r0, r1, masked in pieces:
-            mask = _piece_mask(masked, rel + r0, c0, causal, cols,
-                               window) if masked else None
+            mask = _piece_mask(masked, q0 + r0, k0 + c0, causal, cols,
+                               window, block) if masked else None
             rows = _bwd_rows(q_ref, do_ref, lse_ref, delta_ref, r0, r1)
             q_blk, do_blk = rows[:2]
             p, ds = _bwd_piece(rows, k_blk, v_blk, sm_scale=sm_scale,
@@ -904,7 +1165,7 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, dq_scr, *, causal, sm_scale, seq_k, cases,
-                         one_tile, walk, window=0):
+                         one_tile, walk, window=0, block=0):
     """One (batch, head, q-block, kv-block) grid step: accumulate one kv
     block's contribution to dQ of one q block; write on the last kv step.
     Band of rows by band of rows, as the forward is."""
@@ -915,7 +1176,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     step = 0 if one_tile else pl.program_id(3)
     last_step = 0 if one_tile else pl.num_programs(3) - 1
     kb, live = walk.block(iq, step)
-    rel = walk.rel(iq, kb)
+    rel, q0, k0 = walk.place(iq, kb)
     cols = seq_k - kb * block_k
 
     @_when(step == 0)
@@ -928,8 +1189,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         for c0, c1, masked in pieces:
             k_blk = k_ref[0, 0, c0:c1, :].astype(jnp.float32)
             v_blk = v_ref[0, 0, c0:c1, :].astype(jnp.float32)
-            mask = _piece_mask(masked, rel + r0, c0, causal, cols,
-                               window) if masked else None
+            mask = _piece_mask(masked, q0 + r0, k0 + c0, causal, cols,
+                               window, block) if masked else None
             _, ds = _bwd_piece(rows, k_blk, v_blk, sm_scale=sm_scale,
                                mask=mask)
             dq = dq + sm_scale * jax.lax.dot_general(
@@ -945,11 +1206,19 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, interpret,
-                    plan=None, window=0):
+                    plan=None, window=0, layout=None):
     """dq, dk, dv via the blocked kernels (bias-free path)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if layout is not None:
+        plan = plan or _tile_plan(0, 0, q.shape[-1], causal, backward=True,
+                                  layout=layout)
+        _check_layout_plan(layout, plan)
+        q, k, v, out, g = (_pad_copies(t, layout, plan[0])
+                           for t in (q, k, v, out, g))
+        # A padded row's +LARGE makes exp(s - lse) underflow to 0.
+        lse = _pad_copies(lse, layout, plan[0], fill=-_NEG_INF)
     b, h, sq, d = q.shape
     hk, sk = k.shape[1], k.shape[-2]
     group = h // hk
@@ -958,12 +1227,16 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, interpret,
     block_q, block_k = plan[:2]
     cases = {"flash_bwd_dkdv": _schedule(sq, sk, plan, causal,
                                          by_columns=True, mask_whole=True,
-                                         window=window),
+                                         window=window, layout=layout),
              "flash_bwd_dq": _schedule(sq, sk, plan, causal,
-                                       mask_whole=True, window=window)}
+                                       mask_whole=True, window=window,
+                                       layout=layout)}
+    walks = {name: _walk(sq, sk, plan, causal, window,
+                         by_columns=name == "flash_bwd_dkdv", layout=layout)
+             for name in cases}
     for name, kernel_cases in cases.items():
-        _record_subtiles(name, _subtile_counts(sq, sk, plan, kernel_cases),
-                         window)
+        _record_subtiles(name, _subtile_counts(
+            sq, sk, plan, kernel_cases, walks[name]), walks[name].mask)
 
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
 
@@ -985,9 +1258,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, interpret,
 
     common = dict(causal=causal, sm_scale=sm_scale, seq_k=sk, window=window,
                   one_tile=(sq_p, sk_p) == (block_q, block_k))
-    walks = {name: _walk(sq, sk, plan, causal, window,
-                         by_columns=name == "flash_bwd_dkdv")
-             for name in cases}
+    if layout is not None:
+        common["block"] = layout.block
     for name, walk in walks.items():
         _record_grid(name, walk, cases[name], group)
     semantics = pltpu.CompilerParams(
@@ -1040,7 +1312,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, interpret,
         name="flash_bwd_dq",
     )(qp, kp, vp, dop, lse_p, delta_p)
 
-    return dq[:, :, :sq, :], dk[:, :, :sk, :], dv[:, :, :sk, :]
+    return tuple(_unpad_copies(t, layout) for t in (
+        dq[:, :, :sq, :], dk[:, :, :sk, :], dv[:, :, :sk, :]))
 
 
 # ---------------------------------------------------------------------------
@@ -1048,40 +1321,86 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, interpret,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+# A block-diffusion layout's kernels are one jitted function each, which
+# every layer of a model shares: its forward is traced three times a
+# recomputed block (the pass, the rule of the custom_vjp, the recomputation)
+# and a schedule of three staircases is half a second of tracing each time;
+# through ``jax.jit`` the second call finds the first one's jaxpr and the
+# step's text holds each kernel once. ``core_attention`` is opened again
+# inside, as ``shard_rows`` does: the wrapper's name (``jit(...)``) stands
+# in an operation's ``op_name`` between the caller's scope and the kernel's.
+# (``causal`` and ``window`` calls are traced a call site at a time, as
+# they were: PERF.md section 7.)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "sm_scale", "interpret", "return_stats", "layout"))
+def _layout_forward(q, k, v, *, sm_scale, interpret, return_stats, layout):
+    with jax.named_scope("core_attention"):
+        return _flash_forward(q, k, v, None, False, sm_scale,
+                              interpret=interpret, return_stats=return_stats,
+                              layout=layout)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "sm_scale", "interpret", "layout"))
+def _layout_backward(q, k, v, out, lse, g, *, sm_scale, interpret, layout):
+    with jax.named_scope("core_attention"):
+        return _flash_backward(q, k, v, out, lse, g, False, sm_scale,
+                               interpret, layout=layout)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def _fused_attention(q, k, v, bias, causal, sm_scale, use_pallas, interpret,
-                     window):
+                     window, layout):
+    if use_pallas and layout is not None:
+        return _layout_forward(q, k, v, sm_scale=sm_scale,
+                               interpret=interpret, return_stats=False,
+                               layout=layout)
     if use_pallas:
         return _flash_forward(q, k, v, bias, causal, sm_scale,
                               interpret=interpret, window=window)
-    return attention_reference(q, k, v, bias, causal, sm_scale, window)
+    return attention_reference(q, k, v, bias, causal, sm_scale, window,
+                               layout)
 
 
-def _fwd(q, k, v, bias, causal, sm_scale, use_pallas, interpret, window):
+def _fwd(q, k, v, bias, causal, sm_scale, use_pallas, interpret, window,
+         layout):
     if use_pallas and bias is None:
         # Full flash path: keep O + logsumexp so the backward kernels can
         # rebuild P per block — O(S) residual memory in training too.
-        out, lse = _flash_forward(q, k, v, None, causal, sm_scale,
-                                  interpret=interpret, return_stats=True,
-                                  window=window)
+        if layout is not None:
+            out, lse = _layout_forward(q, k, v, sm_scale=sm_scale,
+                                       interpret=interpret,
+                                       return_stats=True, layout=layout)
+        else:
+            out, lse = _flash_forward(q, k, v, None, causal, sm_scale,
+                                      interpret=interpret, return_stats=True,
+                                      window=window)
         return out, (q, k, v, None, out, lse)
     out = _fused_attention(q, k, v, bias, causal, sm_scale, use_pallas,
-                           interpret, window)
+                           interpret, window, layout)
     return out, (q, k, v, bias, None, None)
 
 
-def _bwd(causal, sm_scale, use_pallas, interpret, window, res, g):
+def _bwd(causal, sm_scale, use_pallas, interpret, window, layout, res, g):
     q, k, v, bias, out, lse = res
     if use_pallas and bias is None:
-        dq, dk, dv = _flash_backward(q, k, v, out, lse, g, causal,
-                                     sm_scale, interpret, window=window)
+        if layout is not None:
+            dq, dk, dv = _layout_backward(q, k, v, out, lse, g,
+                                          sm_scale=sm_scale,
+                                          interpret=interpret, layout=layout)
+        else:
+            dq, dk, dv = _flash_backward(q, k, v, out, lse, g, causal,
+                                         sm_scale, interpret, window=window)
         return dq, dk, dv, None
     # Bias path (trainable biases must receive a cotangent, and dS would be
     # a full [Sq,Sk] output anyway): recompute through the reference
     # formulation — XLA fuses it. Costs O(S²) backward memory; bias-free
     # training (the long-context path) never lands here.
     def f(q, k, v, bias):
-        return attention_reference(q, k, v, bias, causal, sm_scale, window)
+        return attention_reference(q, k, v, bias, causal, sm_scale, window,
+                                   layout)
     _, vjp = jax.vjp(f, q, k, v, bias)
     dq, dk, dv, dbias = vjp(g)
     return dq, dk, dv, None if bias is None else dbias
@@ -1123,6 +1442,7 @@ def fused_attention(
     implementation: str = "auto",
     window: int = 0,
     mesh=None,
+    layout: Optional[BlockDiffusion] = None,
 ) -> jnp.ndarray:
     """Multi-head attention, fused on TPU.
 
@@ -1138,7 +1458,14 @@ def fused_attention(
     kernels index them, nothing is repeated in HBM, and dK/dV come back
     summed over the group. ``window`` > 0 (causal calls without a bias)
     keeps, of row ``i``, the columns ``j`` with ``i - j < window``; tiles
-    and sub-tiles wholly outside it are not computed.
+    and sub-tiles wholly outside it are not computed. ``layout`` (a
+    :class:`BlockDiffusion`; calls that are not causal, with neither bias
+    nor window, over its ``2 * length`` positions) keeps the pairs of a
+    block-diffusion training row instead: a third static mask, whose dead
+    quadrant, dead tiles and dead sub-tiles the kernels leave out as they do
+    a window's. Each mask of its own is counted when a call is traced:
+    ``attention.flash.calls`` labelled ``mask`` (``causal``, ``window``,
+    ``block_diffusion``, ``none``) and ``path`` (docs/OBSERVABILITY.md).
 
     implementation: 'auto' (on TPU: flash kernel, except the measured
     short-sequence window — Sk < 1024 with the quadratic backward
@@ -1157,6 +1484,14 @@ def fused_attention(
         raise ValueError("a window needs a causal call without a bias, and "
                          f"a positive size; got window={window}, "
                          f"causal={causal}, bias given: {bias is not None}")
+    if layout is not None and (
+            causal or window or bias is not None
+            or not q.shape[-2] == k.shape[-2] == 2 * layout.length):
+        raise ValueError(
+            f"a block-diffusion layout is the whole mask of a call over its "
+            f"2 x {layout.length} positions: no causal flag, window or "
+            f"bias; got causal={causal}, window={window}, bias given: "
+            f"{bias is not None}, {q.shape[-2]} rows, {k.shape[-2]} columns")
     if causal and q.shape[-2] > k.shape[-2]:
         # Ill-defined: ends are aligned, so the leading queries would
         # precede every key (and the kernel/reference paths would disagree
@@ -1178,12 +1513,18 @@ def fused_attention(
         use_pallas, interpret = False, False
     else:
         raise ValueError(f"unknown implementation {implementation!r}")
+    get_tracer().registry.counter(
+        "attention.flash.calls",
+        "attention calls traced, by their static mask and the path taken",
+    ).inc(mask=_mask_name(window, layout) or ("causal" if causal else "none"),
+          path="kernel" if use_pallas else "xla")
     if use_pallas and bias is None:
         rows = rows_spec(batch_axes_of(mesh), 4)
         return shard_rows(
             lambda q, k, v: _fused_attention(
-                q, k, v, None, causal, scale, True, interpret, window),
+                q, k, v, None, causal, scale, True, interpret, window,
+                layout),
             mesh, "flash", (rows, rows, rows), rows,
             scope="core_attention")(q, k, v)
     return _fused_attention(q, k, v, bias, causal, scale, use_pallas,
-                            interpret, window)
+                            interpret, window, layout)

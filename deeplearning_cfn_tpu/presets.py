@@ -432,6 +432,37 @@ def _granite4_h_micro() -> ExperimentConfig:
     )
 
 
+@register_preset("sdar_30b_a3b_lm")
+def _sdar_30b_a3b() -> ExperimentConfig:
+    """SDAR-30B-A3B-Chat (JetLM: Qwen3-MoE's layer, 32 query heads over 4
+    K/V heads with an RMSNorm on q and k, 128 experts of width 768 8 a
+    token, trained as a block-diffusion model) adapted on one chip's share of
+    a pod in which 8 chips share each layer: the chip holds layers 0-5 of 48
+    as one pipeline stage of eight, experts 0-15 of each layer's 128 and
+    19,072 of the 151,936 vocabulary rows (embedding and untied head alike);
+    attention whole, every width published. Rows of 8192 tokens in blocks of
+    4 (`train.block_diffusion`): a step runs the noised copy and the clean
+    row, 16,384 positions, through every layer. Every block is recomputed in
+    the backward pass (`remat_blocks`). Recipe: gpt_small_lm's (the source
+    publishes none), no auxiliary loss."""
+    return ExperimentConfig(
+        model=ModelConfig(
+            name="gpt_sdar_30b_a3b",
+            kwargs=dict(layers_held=tuple(range(6)), experts_held=(0, 16),
+                        remat_blocks=True),
+        ),
+        data=DataConfig(name="lm_text", seq_len=8192, vocab_size=19_072),
+        train=TrainConfig(global_batch=1, steps=100_000, dtype="bfloat16",
+                          shard_opt_state=False, block_diffusion=4),
+        optimizer=OptimizerConfig(name="adamw", b1=0.9, b2=0.95,
+                                  weight_decay=0.1, grad_clip_norm=1.0),
+        schedule=ScheduleConfig(name="cosine", base_lr=6e-4,
+                                warmup_steps=2000),
+        mesh=MeshConfig(data=-1),
+        stack=StackConfig(slice_type="v5e-8"),
+    )
+
+
 @register_preset("transformer_nmt_wmt")
 def _nmt() -> ExperimentConfig:
     """Transformer NMT WMT En-De (reference: Sockeye + MXNet

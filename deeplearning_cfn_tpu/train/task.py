@@ -17,6 +17,7 @@ import optax
 
 from ..config import ExperimentConfig
 from ..models import build_model
+from ..obs.trace import get_tracer
 
 PyTree = Any
 
@@ -412,20 +413,26 @@ class CausalLmTask:
         ids = jnp.zeros((1, self.cfg.data.seq_len), jnp.int32)
         return self.model.init(rng, ids, train=False)
 
-    def loss_fn(self, params, batch_stats, batch, rng, train):
-        rngs = {"dropout": rng} if (train and rng is not None) else None
-        # A training step also collects what the model sows as `nudges`:
-        # steps for parameters that no gradient moves (a router's balancing
-        # bias), which the trainer adds after the optimizer's.
+    def _apply(self, params, inputs, train, **call):
+        """``(logits, what the expert layers report or None, what the model
+        sowed)`` of the model over ``inputs``; ``call`` goes to its
+        ``__call__``. A training step also collects what the model sows as
+        `nudges`: steps for parameters that no gradient moves (a router's
+        balancing bias), which the trainer adds after the optimizer's."""
         apply = lambda p, ids: self.model.apply(
-            {"params": p}, ids, train=train, rngs=rngs,
-            mutable=["nudges"] if train else False)
+            {"params": p}, ids, train=train,
+            mutable=["nudges"] if train else False, **call)
         if train and self.remat:
             apply = jax.checkpoint(apply)
-        inputs = batch["tokens"][:, :-1]
         out, sown = apply(params, inputs) if train \
             else (apply(params, inputs), {})
         logits, moe_aux = out if isinstance(out, tuple) else (out, None)
+        return logits, moe_aux, sown
+
+    def loss_fn(self, params, batch_stats, batch, rng, train):
+        rngs = {"dropout": rng} if (train and rng is not None) else None
+        inputs = batch["tokens"][:, :-1]
+        logits, moe_aux, sown = self._apply(params, inputs, train, rngs=rngs)
         # The program's own scope (docs/OBSERVABILITY.md): nothing below is
         # inside a flax module, so without it a trace cannot tell the loss
         # and its backward pass from the optimizer.
@@ -481,8 +488,100 @@ class CausalLmTask:
     }
 
 
+# The token a masked position shows the model, and the least rate a row is
+# noised at. SDAR's own mask id lies outside any slice of the vocabulary a
+# chip holds; 3 is an id no data source draws (they start at 4).
+BD_MASK_ID = 3
+BD_MIN_RATE = 1e-3
+
+
+def draw_block_diffusion_noise(rng: jax.Array, rows: int, length: int):
+    """``(rate [rows], masked [rows, length])``, a pure function of ``rng``:
+    a rate ``t = eps + (1 - eps) u`` a row, ``u`` uniform, and each token
+    masked independently with probability ``t``."""
+    k_rate, k_mask = jax.random.split(rng)
+    rate = BD_MIN_RATE + (1.0 - BD_MIN_RATE) * jax.random.uniform(
+        k_rate, (rows,), jnp.float32)
+    masked = jax.random.uniform(k_mask, (rows, length), jnp.float32) \
+        < rate[:, None]
+    return rate, masked
+
+
+class BlockDiffusionLmTask(CausalLmTask):
+    """A decoder trained as a block-diffusion model (SDAR, BD3-LM): a row
+    of ``L = data.seq_len`` tokens is cut into blocks of ``b =
+    train.block_diffusion``; the step draws a rate ``t`` a row and masks each
+    token with probability ``t`` (``draw_block_diffusion_noise``, from the
+    step's key), lays the noised copy before the clean row, ``[B, 2 L]``,
+    and calls the model with the layout (``ops/attention.py:BlockDiffusion``:
+    a noised block sees itself in both directions and the clean blocks before
+    it; both copies stand at the positions ``0 .. L - 1``). Logits come back
+    for the noised copy alone, for the token *at* each position (no shift),
+    and the loss is ``sum over masked i of -log p(x_i) / t`` over the rows'
+    ``L`` positions. The same model and parameters as ``CausalLmTask``'s:
+    ``init`` traces the plain causal call. Batch contract as there (the
+    row's last token, the next-token target, is not read)."""
+
+    def __init__(self, cfg: ExperimentConfig, mesh=None):
+        from ..ops.attention import BlockDiffusion
+
+        super().__init__(cfg, mesh)
+        self.layout = BlockDiffusion(cfg.data.seq_len,
+                                     cfg.train.block_diffusion)
+        registry = get_tracer().registry
+        registry.gauge(
+            "train.bd.block_length",
+            "tokens a block of the block-diffusion objective holds",
+        ).set(self.layout.block)
+        registry.gauge(
+            "train.bd.positions_per_token",
+            "positions the model runs for each data token of a step",
+        ).set(2)
+
+    def loss_fn(self, params, batch_stats, batch, rng, train):
+        length = self.layout.length
+        clean = batch["tokens"][:, :length]
+        with jax.named_scope("bd_noise"):
+            rate, masked = draw_block_diffusion_noise(
+                rng if rng is not None else jax.random.PRNGKey(0),
+                clean.shape[0], length)
+            inputs = jnp.concatenate(
+                [jnp.where(masked, BD_MASK_ID, clean), clean], axis=1)
+        logits, moe_aux, sown = self._apply(params, inputs, train,
+                                            layout=self.layout)
+        with jax.named_scope("lm_loss"):
+            rows = example_mask(batch, clean.shape[0])
+            counted = batch["loss_mask"][:, :length] * rows[:, None]
+            weights = counted * masked / rate[:, None]
+            denom = jnp.maximum(jnp.sum(counted), 1e-6)
+            loss = jnp.sum(cross_entropy(logits, clean) * weights) / denom
+            seen = jnp.maximum(jnp.sum(counted * masked), 1e-6)
+            hits = (jnp.argmax(logits, -1) == clean).astype(jnp.float32)
+            aux = {"token_accuracy": jnp.sum(hits * counted * masked) / seen,
+                   "bd_masked_share": jnp.sum(counted * masked) / denom}
+            if moe_aux is not None:
+                aux.update({f"moe_{k}": v for k, v in moe_aux.items()})
+            if train:
+                aux["batch_stats"] = batch_stats
+                if sown.get("nudges"):
+                    aux["nudges"] = sown["nudges"]
+            else:
+                aux["ce_loss"] = loss
+                aux["eval_weight"] = jnp.sum(counted)
+        return loss, aux
+
+    # The objective is a bound on the likelihood, not a cross-entropy a
+    # perplexity is defined on.
+    eval_derived = {}
+
+
 def build_task(cfg: ExperimentConfig, mesh=None):
-    """Task registry keyed by model family.
+    """Task registry keyed by model family: ``resnet*`` / ``vit*``
+    :class:`ClassificationTask`, ``bert*`` :class:`MlmTask`,
+    ``transformer_nmt*`` :class:`Seq2SeqTask`, ``maskrcnn*`` the detection
+    task, ``gpt*`` :class:`CausalLmTask` or, where the preset (or an
+    override) sets ``train.block_diffusion`` to a block length,
+    :class:`BlockDiffusionLmTask`.
 
     ``mesh``: pass the trainer's Mesh when the model needs it at
     construction time (the pipelined trunk's shard_map); tasks that don't
@@ -493,7 +592,9 @@ def build_task(cfg: ExperimentConfig, mesh=None):
     if name.startswith("resnet") or name.startswith("vit"):
         return ClassificationTask(cfg)
     if name.startswith("gpt"):
-        return CausalLmTask(cfg, mesh=mesh)
+        task = BlockDiffusionLmTask if cfg.train.block_diffusion \
+            else CausalLmTask
+        return task(cfg, mesh=mesh)
     if name.startswith("bert"):
         return MlmTask(cfg, mesh=mesh)
     if name.startswith("transformer_nmt"):

@@ -464,13 +464,17 @@ class Trainer:
                         # the registry too: the gauge holds this step, the
                         # histogram every realized step
                         # (docs/OBSERVABILITY.md).
+                        # Likewise what a block-diffusion step drew
+                        # (``bd_masked_share``).
                         registry = get_tracer().registry
                         for k, v in realized.items():
-                            if k.startswith("moe_"):
-                                name = "moe." + k[4:]
-                                registry.gauge(name).set(v)
-                                registry.histogram(
-                                    name + ".steps").observe(v)
+                            for prefix, family in (("moe_", "moe."),
+                                                   ("bd_", "train.bd.")):
+                                if k.startswith(prefix):
+                                    name = family + k[len(prefix):]
+                                    registry.gauge(name).set(v)
+                                    registry.histogram(
+                                        name + ".steps").observe(v)
                     # Throughput covers everything dispatched since the
                     # last boundary.
                     elapsed = time.perf_counter() - window_start

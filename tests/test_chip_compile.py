@@ -378,6 +378,75 @@ def test_granite4h_step_compiles_and_fits_a_v5e(v5e_chip):
                 and re.search(r"/layer_\d+/", line)]
 
 
+def test_sdar_step_compiles_and_fits_a_v5e(v5e_chip):
+    """The whole train step of ``sdar_30b_a3b_train_bd_8k`` at the cell's
+    shapes: six recomputed blocks over the 16,384 positions of a
+    block-diffusion row. Mosaic takes the flash kernels under the layout (32
+    query heads over 4 K/V heads of 128, both copies in one call, K/V not
+    repeated), the rotary kernel with the positions repeated and the grouped
+    matmuls at 2048 -> 768; the schedule computes a quarter of the square
+    and no dead step copies a block; the task's and the norm's scopes are in
+    the text; and 10.3 GB of state with one block's intermediates fit the
+    chip, over the quarter of it a cell has to fill."""
+    import re
+
+    from deeplearning_cfn_tpu.obs.trace import get_tracer
+    from deeplearning_cfn_tpu.ops.attention import BlockDiffusion, \
+        _grid_gauges
+
+    manifest, rehearse_compile = _bench()
+    registry = get_tracer().registry
+    calls = registry.counter("attention.flash.calls")
+    blocks = registry.counter("model.blocks.recomputed")
+    before = (calls.value(mask="block_diffusion", path="kernel"),
+              calls.value(mask="causal", path="kernel"), blocks.value())
+    cell = manifest.Cell(manifest.load_manifest(),
+                         "sdar_30b_a3b_train_bd_8k")
+    assert cell.chips == 1
+    _, compiled, _ = rehearse_compile.compile_step(cell)
+    # The step traces six calls under the layout; the parameters' shapes
+    # come from the plain causal call (``init``), six more.
+    assert (calls.value(mask="block_diffusion", path="kernel") - before[0],
+            calls.value(mask="causal", path="kernel") - before[1],
+            blocks.value() - before[2]) == (6, 6, 12)
+    layout = BlockDiffusion(8192, 4)
+    assert _grid_gauges("flash_fwd", layout=layout) == (144, 64, 0)
+    assert _grid_gauges("flash_bwd_dq", layout=layout) == (144, 64, 0)
+    assert _grid_gauges("flash_bwd_dkdv", layout=layout) == (256, 176, 0)
+    for kernel, share in (("flash_fwd", 0.2578125), ("flash_bwd_dq", 0.265625),
+                          ("flash_bwd_dkdv", 0.265625)):
+        assert registry.gauge("attention.flash.live_subtile_share").value(
+            kernel=kernel, mask="block_diffusion") == share
+    assert registry.gauge("train.bd.block_length").value() == 4
+    mem = compiled.memory_analysis()
+    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    assert 4 * 2 ** 30 < total < 15.75 * 2 ** 30, total
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 156
+    name = lambda line: re.search(r'op_name="([^"]*)"', line).group(1)
+    flash = [line for line in kernels if "core_attention/flash_" in line]
+    # A layer: forward, forward again (recomputed), dK/dV, dQ.
+    assert len(flash) == 24
+    assert all("bf16[1,4,16384,128]" in line and "bf16[1,32,16384,128]"
+               in line for line in flash)
+    for layer in range(6):
+        own = sorted((("rematted_computation" in name(line)),
+                      re.search(r"/(flash_\w+)", name(line)).group(1))
+                     for line in flash if f"/layer_{layer}/" in name(line))
+        assert own == [(False, "flash_bwd_dkdv"), (False, "flash_bwd_dq"),
+                       (False, "flash_fwd"), (True, "flash_fwd")], own
+    rope = [line for line in kernels if "/rope/" in name(line)]
+    assert len(rope) == 36 and all("16384" in line for line in rope)
+    assert len([line for line in kernels if "/moe_experts/" in name(line)
+                ]) == len(kernels) - 60
+    for scope in ("bd_noise", "qk_norm", "moe_router", "lm_head", "lm_loss"):
+        assert re.search(rf'op_name="[^"]*\b{scope}\b', text), scope
+    assert not _row_scatters(text)
+
+
 def test_mellum2_step_compiles_for_four_v5e_chips(v5e_chip):
     """The whole train step of ``mellum2_12b_train_8k_ep4`` at the cell's
     shapes for the four chips of a described ``v5e:2x2`` on ``expert=4``:

@@ -287,11 +287,15 @@ def test_recomputed_blocks_give_the_gradients_of_kept_blocks_bit_for_bit():
 
 
 def test_a_recomputed_expert_block_is_refused():
+    """Where its router hands a state to the next block's (an
+    ``MlpStateRouter``); a router that keeps none is recomputed with its
+    block since PR 43 (``tests/test_sdar.py``)."""
     from deeplearning_cfn_tpu.models.lm import TransformerCausalLm
     from deeplearning_cfn_tpu.models.transformer import BlockStyle
 
     model = TransformerCausalLm(vocab_size=96, hidden_size=64, blocks=(
-        (0, 4, 64, BlockStyle(mlp="experts", remat=True)),))
+        (0, 4, 64, BlockStyle(mlp="experts", remat=True,
+                              router=(("kind", "mlp_state"),))),))
     with pytest.raises(NotImplementedError, match="router state"):
         model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
 

@@ -28,9 +28,9 @@ class _ShortTables(Rope):
     multiply-add: tools/rope_sweep.py prints ``unequal`` with the real
     tables.)"""
 
-    def tables(self, seq_len, head_dim):
+    def tables(self, seq_len, head_dim, positions=None):
         return tuple(t.astype(jnp.bfloat16).astype(np.float32)
-                     for t in super().tables(seq_len, head_dim))
+                     for t in super().tables(seq_len, head_dim, positions))
 
 
 REAL = {"sliding": _LAGUNA_XS2["sliding_rope"],    # rot = 128 of 128
