@@ -100,7 +100,9 @@ class TrainConfig:
     # backward pass recomputes the whole forward and then holds every
     # block's intermediates at once, so it lowers no peak. A block at a time
     # is BlockStyle.remat (models/transformer.py), asked for by a preset's
-    # model kwargs (granite4_h_micro_lm: remat_blocks).
+    # model kwargs (granite4_h_micro_lm, sdar_30b_a3b_lm: remat_blocks); such
+    # a block keeps its input and its flash forward kernel's output and row
+    # statistics, 2 B S H D + 4 B H S bytes an attention block.
     remat: bool = False
     # > 0: a decoder (``gpt_*``) is trained as a block-diffusion model with
     # blocks of this many tokens (train/task.py:BlockDiffusionLmTask): a

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from ..obs.trace import get_tracer
+from ..ops.attention import FLASH_LSE, FLASH_OUT, flash_kept_bytes
 from . import register_model
 from .moe import MOE_PARAM_RULES
 from .transformer import (
@@ -63,7 +64,10 @@ class TransformerCausalLm(nn.Module):
     stream, and whether the block is recomputed in the backward pass
     (``BlockStyle.remat``: the layer under ``flax.linen.remat``, one block's
     intermediates alive at a time, where ``train.remat`` recomputes the whole
-    model at once and lowers no peak). ``embedding_multiplier`` and
+    model at once and lowers no peak; an attention block on the flash
+    kernels keeps the forward kernel's output and row statistics as well as
+    its input, ``2 B S H D + 4 B H S`` bytes, and runs that kernel once).
+    ``embedding_multiplier`` and
     ``logits_scaling`` are Granite's: the embedding times the one, the logits
     over the other, in float32.
 
@@ -117,7 +121,13 @@ class TransformerCausalLm(nn.Module):
         if self.blocks:
             # `causal` (the sixth argument, the module counted) is read by
             # Python: static under the recomputation.
-            recomputed = nn.remat(TransformerLayer, static_argnums=(5,))
+            # What a recomputed block keeps beside its input: its flash
+            # forward kernel's output and row statistics, so the backward
+            # pass computes q, k and v again and not the kernel.
+            recomputed = nn.remat(
+                TransformerLayer, static_argnums=(5,),
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    FLASH_OUT, FLASH_LSE))
             if any(style.remat and style.mlp == "experts" and style.router
                    and dict(style.router).get("kind", "mlp_state")
                    == "mlp_state" for _, _, _, style in self.blocks):
@@ -196,11 +206,28 @@ class TransformerCausalLm(nn.Module):
                 x = lyr(x, causal=True, layout=layout)
         recomputed = sum(style.remat for _, _, _, style in self.blocks)
         if recomputed:
-            get_tracer().registry.counter(
+            registry = get_tracer().registry
+            registry.counter(
                 "model.blocks.recomputed",
                 "blocks of the traced model that are recomputed in the "
                 "backward pass, one at a time",
             ).inc(recomputed)
+            kept = [flash_kept_bytes(
+                x.shape[0], heads, x.shape[1],
+                style.head_dim or self.hidden_size // heads, self.dtype,
+                self.attention_impl)
+                for _, heads, _, style in self.blocks
+                if style.remat and style.mixer == "attention"]
+            registry.counter(
+                "model.blocks.kept_flash",
+                "recomputed blocks of the traced model that keep their "
+                "flash forward kernel's output and row statistics",
+            ).inc(sum(map(bool, kept)))
+            registry.gauge(
+                "model.blocks.kept_bytes",
+                "bytes the traced model's recomputed blocks keep from "
+                "their flash forward kernels",
+            ).set(sum(kept))
         if state is not None:
             get_tracer().registry.gauge(
                 "moe.router.state_layers",

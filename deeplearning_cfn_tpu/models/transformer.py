@@ -202,9 +202,12 @@ class BlockStyle:
     signal at all.
 
     ``remat``: the block is recomputed in the backward pass
-    (``flax.linen.remat`` round the layer, ``models/lm.py``), so that only
-    its input is kept from the forward pass and one block's intermediates
-    are alive at a time.
+    (``flax.linen.remat`` round the layer, ``models/lm.py``), so that one
+    block's intermediates are alive at a time. Kept from the forward pass
+    are its input and, where its attention takes the flash kernels, the
+    forward kernel's output and row statistics (``2 B S H D + 4 B H S``
+    bytes in bfloat16), which the recomputation reads in place of running
+    the kernel a second time; q, k and v are computed again.
 
     ``qk_norm``: an RMSNorm over each head's channels on q and on k, a
     learned scale of ``head_dim`` each (``query_norm``, ``key_norm``), before

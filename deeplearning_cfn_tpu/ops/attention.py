@@ -35,9 +35,16 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..obs.trace import get_tracer
 from ..parallel.kernels import batch_axes_of, rows_spec, shard_rows
+
+# The names of the flash forward kernel's output and row statistics
+# (logsumexp) among a differentiated call's residuals, for
+# ``jax.checkpoint_policies.save_only_these_names``.
+FLASH_OUT = "flash_out"
+FLASH_LSE = "flash_lse"
 
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps masked softmax NaN-free
 
@@ -1377,6 +1384,11 @@ def _fwd(q, k, v, bias, causal, sm_scale, use_pallas, interpret, window,
             out, lse = _flash_forward(q, k, v, None, causal, sm_scale,
                                       interpret=interpret, return_stats=True,
                                       window=window)
+        # Named for a recomputed block's policy (models/lm.py): what the
+        # block returns and what the backward kernels read are these two,
+        # so the kernel is not run again. An identity anywhere else.
+        out = checkpoint_name(out, FLASH_OUT)
+        lse = checkpoint_name(lse, FLASH_LSE)
         return out, (q, k, v, None, out, lse)
     out = _fused_attention(q, k, v, bias, causal, sm_scale, use_pallas,
                            interpret, window, layout)
@@ -1430,6 +1442,27 @@ def _auto_use_pallas(backend: str, b: int, h: int, sq: int, sk: int) -> bool:
         return False
     ref_bytes = b * h * sq * sk * 4
     return not (sk < _SHORT_SEQ_THRESHOLD and ref_bytes <= _REF_BWD_BYTES_CAP)
+
+
+def _kernel_path(implementation: str, b: int, h: int, sq: int, sk: int
+                 ) -> Tuple[bool, bool]:
+    """``(use_pallas, interpret)`` of a call's ``implementation``."""
+    if implementation == "auto":
+        return _auto_use_pallas(jax.default_backend(), b, h, sq, sk), False
+    if implementation not in ("pallas", "interpret", "reference"):
+        raise ValueError(f"unknown implementation {implementation!r}")
+    return implementation != "reference", implementation == "interpret"
+
+
+def flash_kept_bytes(b: int, h: int, s: int, d: int, dtype,
+                     implementation: str) -> int:
+    """The bytes of :data:`FLASH_OUT` and :data:`FLASH_LSE` for a
+    self-attention call without a bias over q ``[b, h, s, d]`` of ``dtype``:
+    the output and a float32 a row, ``b h s (d itemsize + 4)``; 0 where the
+    call does not take the kernels and names nothing."""
+    if not _kernel_path(implementation, b, h, s, s)[0]:
+        return 0
+    return b * h * s * (d * jnp.dtype(dtype).itemsize + 4)
 
 
 def fused_attention(
@@ -1500,19 +1533,8 @@ def fused_attention(
             f"causal attention requires Sq <= Sk, got {q.shape[-2]} > "
             f"{k.shape[-2]}")
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    if implementation == "auto":
-        b, h, sq, _ = q.shape
-        use_pallas = _auto_use_pallas(jax.default_backend(), b, h, sq,
-                                      k.shape[-2])
-        interpret = False
-    elif implementation == "pallas":
-        use_pallas, interpret = True, False
-    elif implementation == "interpret":
-        use_pallas, interpret = True, True
-    elif implementation == "reference":
-        use_pallas, interpret = False, False
-    else:
-        raise ValueError(f"unknown implementation {implementation!r}")
+    use_pallas, interpret = _kernel_path(implementation, *q.shape[:3],
+                                         k.shape[-2])
     get_tracer().registry.counter(
         "attention.flash.calls",
         "attention calls traced, by their static mask and the path taken",

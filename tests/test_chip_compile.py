@@ -312,11 +312,13 @@ def test_granite4h_step_compiles_and_fits_a_v5e(v5e_chip):
     registry = get_tracer().registry
     scans = registry.counter("ssm.scan.calls")
     blocks = registry.counter("model.blocks.recomputed")
+    kept = registry.counter("model.blocks.kept_flash")
     turned = registry.counter("attention.rope.calls")
     scanned = lambda: tuple(scans.value(path=p, chunk="256")
                             for p in ("kernel", "xla"))
     before = (scanned(), blocks.value(),
-              turned.value(path="kernel") + turned.value(path="xla"))
+              turned.value(path="kernel") + turned.value(path="xla"),
+              kept.value())
     cell = manifest.Cell(manifest.load_manifest(),
                          "granite4_h_micro_train_8k")
     assert cell.chips == 1
@@ -327,6 +329,11 @@ def test_granite4h_step_compiles_and_fits_a_v5e(v5e_chip):
     assert (tuple(n - m for n, m in zip(scanned(), before[0])),
             blocks.value() - before[1], turned.value(path="kernel")
             + turned.value(path="xla") - before[2]) == ((18, 0), 20, 0)
+    # The attention block keeps its kernel's pair: [1, 32, 8192] rows of 64
+    # bfloat16 and a float32.
+    assert kept.value() - before[3] == 2
+    assert registry.gauge("model.blocks.kept_bytes").value() \
+        == 32 * 8192 * (64 * 2 + 4) == 34_603_008
     assert registry.gauge("ssm.scan.chunks").value() == 32
     assert registry.gauge("ssm.state_bytes").value() == 64 * 64 * 128 * 4
     mem = compiled.memory_analysis()
@@ -336,18 +343,20 @@ def test_granite4h_step_compiles_and_fits_a_v5e(v5e_chip):
     text = compiled.as_text()
     kernels = [line for line in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line]
-    # The one attention layer: forward, forward again (recomputed), dK/dV,
-    # dQ; K/V are not repeated to the query heads.
+    # The one attention layer: forward, dK/dV, dQ, the forward's output and
+    # row statistics kept across the recomputation (33.6 MB + 1 MB); K/V
+    # are not repeated to the query heads.
     flash = [line for line in kernels if "/layer_5/" in line]
-    assert len(flash) == 4
+    assert len(flash) == 3
     assert all("core_attention/flash_" in line
+               and "rematted_computation" not in line
                and "bf16[1,8,8192,64]" in line
                and "bf16[1,32,8192,64]" in line for line in flash)
     # Every Mamba layer's scan: the forward, the forward again that keeps
     # the states (recomputed) and the backward, all under the scope the
     # readers know; x is read as the projection left it, [B, S, H * P].
     scan = [line for line in kernels if line not in flash]
-    assert len(scan) == 27 and len(kernels) == 31
+    assert len(scan) == 27 and len(kernels) == 30
     for layer in (0, 1, 2, 3, 4, 6, 7, 8, 9):
         own = [re.search(r'op_name="([^"]*)"', line).group(1)
                for line in scan if f"/layer_{layer}/" in line]
@@ -398,8 +407,10 @@ def test_sdar_step_compiles_and_fits_a_v5e(v5e_chip):
     registry = get_tracer().registry
     calls = registry.counter("attention.flash.calls")
     blocks = registry.counter("model.blocks.recomputed")
+    kept = registry.counter("model.blocks.kept_flash")
     before = (calls.value(mask="block_diffusion", path="kernel"),
-              calls.value(mask="causal", path="kernel"), blocks.value())
+              calls.value(mask="causal", path="kernel"), blocks.value(),
+              kept.value())
     cell = manifest.Cell(manifest.load_manifest(),
                          "sdar_30b_a3b_train_bd_8k")
     assert cell.chips == 1
@@ -408,7 +419,12 @@ def test_sdar_step_compiles_and_fits_a_v5e(v5e_chip):
     # come from the plain causal call (``init``), six more.
     assert (calls.value(mask="block_diffusion", path="kernel") - before[0],
             calls.value(mask="causal", path="kernel") - before[1],
-            blocks.value() - before[2]) == (6, 6, 12)
+            blocks.value() - before[2], kept.value() - before[3]) \
+        == (6, 6, 12, 12)
+    # Six blocks' pairs over both copies: [1, 32, 16384] rows of 128
+    # bfloat16 and a float32, 0.82 GB.
+    assert registry.gauge("model.blocks.kept_bytes").value() \
+        == 6 * 32 * 16384 * (128 * 2 + 4) == 817_889_280
     layout = BlockDiffusion(8192, 4)
     assert _grid_gauges("flash_fwd", layout=layout) == (144, 64, 0)
     assert _grid_gauges("flash_bwd_dq", layout=layout) == (144, 64, 0)
@@ -425,11 +441,12 @@ def test_sdar_step_compiles_and_fits_a_v5e(v5e_chip):
     text = compiled.as_text()
     kernels = [line for line in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(kernels) == 156
+    assert len(kernels) == 150
     name = lambda line: re.search(r'op_name="([^"]*)"', line).group(1)
     flash = [line for line in kernels if "core_attention/flash_" in line]
-    # A layer: forward, forward again (recomputed), dK/dV, dQ.
-    assert len(flash) == 24
+    # A layer: forward, dK/dV, dQ, and none again: the recomputation reads
+    # the forward's output and row statistics, kept (136 MB a layer).
+    assert len(flash) == 18
     assert all("bf16[1,4,16384,128]" in line and "bf16[1,32,16384,128]"
                in line for line in flash)
     for layer in range(6):
@@ -437,11 +454,11 @@ def test_sdar_step_compiles_and_fits_a_v5e(v5e_chip):
                       re.search(r"/(flash_\w+)", name(line)).group(1))
                      for line in flash if f"/layer_{layer}/" in name(line))
         assert own == [(False, "flash_bwd_dkdv"), (False, "flash_bwd_dq"),
-                       (False, "flash_fwd"), (True, "flash_fwd")], own
+                       (False, "flash_fwd")], own
     rope = [line for line in kernels if "/rope/" in name(line)]
     assert len(rope) == 36 and all("16384" in line for line in rope)
     assert len([line for line in kernels if "/moe_experts/" in name(line)
-                ]) == len(kernels) - 60
+                ]) == len(kernels) - 54
     for scope in ("bd_noise", "qk_norm", "moe_router", "lm_head", "lm_loss"):
         assert re.search(rf'op_name="[^"]*\b{scope}\b', text), scope
     assert not _row_scatters(text)

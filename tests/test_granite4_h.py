@@ -286,6 +286,84 @@ def test_recomputed_blocks_give_the_gradients_of_kept_blocks_bit_for_bit():
                for a, b in zip(kept, again))
 
 
+def _kernel_calls(jaxpr, name):
+    """How many equations of ``jaxpr``, nested ones counted where they stand,
+    call the Pallas kernel ``name``."""
+    calls = 0
+    for eqn in jaxpr.eqns:
+        calls += eqn.primitive.name == "pallas_call" \
+            and eqn.params["name"] == name
+        calls += sum(_kernel_calls(inner, name)
+                     for inner in jax.core.jaxprs_in_params(eqn.params))
+    return calls
+
+
+@pytest.mark.parametrize("mask", ["causal", "window", "block_diffusion"])
+def test_a_recomputed_block_keeps_its_flash_forward(mask, monkeypatch):
+    """Two attention blocks on the kernel path (interpret mode), under each
+    of the kernels' three static masks. The gradient's program runs the
+    forward kernel once a block where the blocks are kept, once where they
+    are recomputed (``models/lm.py``'s policy keeps the kernel's output and
+    row statistics by their names) and twice with the policy taken away; the
+    backward kernels once each whichever way. What is kept changes no bit of
+    a gradient, and the registry says how many blocks kept how much."""
+    from deeplearning_cfn_tpu.models.lm import TransformerCausalLm
+    from deeplearning_cfn_tpu.models.transformer import BlockStyle
+    from deeplearning_cfn_tpu.obs.trace import get_tracer
+    from deeplearning_cfn_tpu.ops.attention import BlockDiffusion
+
+    length, heads, head_dim = 16, 4, 8
+    layout = BlockDiffusion(length, 4) if mask == "block_diffusion" else None
+    positions = 2 * length if layout else length
+    ids = (7 * jnp.arange(positions)).reshape(1, positions) % 96
+
+    def build(remat, impl="interpret"):
+        style = BlockStyle(num_kv_heads=2, remat=remat,
+                           window=8 if mask == "window" else 0)
+        return TransformerCausalLm(
+            vocab_size=96, hidden_size=heads * head_dim, dtype=jnp.float32,
+            attention_impl=impl,
+            blocks=tuple((i, heads, 64, style) for i in range(2)))
+
+    def grad_of(model):
+        return jax.grad(lambda p: jnp.mean(jnp.square(
+            model.apply({"params": p}, ids, layout=layout))))
+
+    registry = get_tracer().registry
+    kept = registry.counter("model.blocks.kept_flash")
+    kept_bytes = registry.gauge("model.blocks.kept_bytes")
+    shapes = jax.eval_shape(build(False, "reference").init,
+                            jax.random.PRNGKey(0), ids[:, :length])["params"]
+    rng = np.random.default_rng(0)
+    params = jax.tree_util.tree_map(
+        lambda s: jnp.asarray(rng.normal(0, 0.3, s.shape), s.dtype), shapes)
+    before = kept.value()
+    programs = {remat: jax.make_jaxpr(grad_of(build(remat)))(params)
+                for remat in (False, True)}
+    # One trace with recomputed blocks, two blocks; none where they are kept.
+    assert kept.value() - before == 2
+    assert kept_bytes.value() == 2 * positions * heads * (head_dim * 4 + 4)
+    # Off the kernel path a recomputed block names nothing to keep.
+    jax.eval_shape(lambda p: build(True, "reference").apply(
+        {"params": p}, ids, layout=layout), params)
+    assert kept.value() - before == 2 and kept_bytes.value() == 0
+    with monkeypatch.context() as patch:
+        patch.setattr(jax.checkpoint_policies, "save_only_these_names",
+                      lambda *names: None)
+        programs["no policy"] = jax.make_jaxpr(grad_of(build(True)))(params)
+    for kernel, calls in (("flash_fwd", (2, 2, 4)), ("flash_bwd_dq", (2,) * 3),
+                          ("flash_bwd_dkdv", (2,) * 3)):
+        assert tuple(_kernel_calls(programs[how].jaxpr, kernel)
+                     for how in (False, True, "no policy")) == calls, kernel
+    leaves = jax.tree_util.tree_leaves(params)
+    stayed, again = (jax.core.eval_jaxpr(programs[remat].jaxpr,
+                                         programs[remat].consts, *leaves)
+                     for remat in (False, True))
+    assert len(stayed) == len(again) == len(leaves)
+    assert all(np.any(np.asarray(a)) and np.array_equal(a, b)
+               for a, b in zip(stayed, again))
+
+
 def test_a_recomputed_expert_block_is_refused():
     """Where its router hands a state to the next block's (an
     ``MlpStateRouter``); a router that keeps none is recomputed with its

@@ -676,12 +676,18 @@ _UNCHANGED_JAXPRS = [
 
 
 @pytest.mark.parametrize("shape,sk,causal,digest", _UNCHANGED_JAXPRS)
-def test_ungrouped_unwindowed_jaxpr_unchanged(shape, sk, causal, digest):
+def test_ungrouped_unwindowed_jaxpr_unchanged(shape, sk, causal, digest,
+                                              monkeypatch):
     import hashlib
     import re
 
+    from deeplearning_cfn_tpu.ops import attention
+
     if jax.__version__ != "0.9.0":
         pytest.skip("the digests were recorded under jax 0.9.0")
+    # The two names a recomputed block's policy reads (PR 44) are identity
+    # equations after the forward kernel; the text is read without them.
+    monkeypatch.setattr(attention, "checkpoint_name", lambda x, name: x)
     b, h, s, d = shape
     q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     kv = jax.ShapeDtypeStruct((b, h, sk or s, d), jnp.bfloat16)

@@ -196,9 +196,12 @@ PARENT_CALLS = [
 @pytest.mark.parametrize("name,shape,kv_heads,sk,causal,window,digest",
                          PARENT_CALLS, ids=[c[0] for c in PARENT_CALLS])
 def test_the_other_cells_flash_calls_are_traced_as_before(
-        name, shape, kv_heads, sk, causal, window, digest):
+        name, shape, kv_heads, sk, causal, window, digest, monkeypatch):
     import hashlib
 
+    # The two names a recomputed block's policy reads (PR 44) are identity
+    # equations after the forward kernel; the text is read without them.
+    monkeypatch.setattr(A, "checkpoint_name", lambda x, name: x)
     q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     kv = jax.ShapeDtypeStruct((shape[0], kv_heads, sk, shape[3]),
                               jnp.bfloat16)
