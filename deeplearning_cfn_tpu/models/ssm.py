@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs.trace import get_tracer
+from ..ops.conv import causal_conv_silu, conv_path
 from ..ops.ssd import scan_path, ssd_scan
 from .transformer import Leaf, RMSNorm, shift_later
 
@@ -58,21 +59,38 @@ def conv_gain(taps: int, channels: int) -> float:
 class CausalConv(nn.Module):
     """``y[t] = bias + sum_j w[j] * x[t - (taps - 1 - j)]`` a channel, zeros
     before position 0, float32. The taps are ``gain * kernel``: see
-    :class:`Mamba2Mixer`."""
+    :class:`Mamba2Mixer`.
+
+    Called with ``splits`` it is the mixer's whole step, ``silu(y)`` in
+    ``x``'s dtype with the channels cut at ``splits`` (a tuple of arrays), by
+    the carrier ``ops/conv.py:conv_path`` names from ``implementation``, the
+    backend and the shape: ``kernel``, one Pallas kernel forward and one
+    backward that hold the shifts, the bias and silu in VMEM
+    (``ops/conv.py``, ``mesh`` the step's); or ``xla``, the shifted
+    multiply-adds below with ``nn.silu``, the cast and ``jnp.split`` after
+    them. Called with ``x`` alone it is the shifted multiply-adds, ``y``."""
 
     taps: int
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, splits=None, implementation="auto", mesh=None):
         channels = x.shape[-1]
         kernel = self.param("kernel", nn.initializers.xavier_uniform(),
                             (self.taps, channels), jnp.float32)
         bias = self.param("bias", nn.initializers.zeros, (channels,),
                           jnp.float32)
         w = conv_gain(self.taps, channels) * kernel
-        x = x.astype(jnp.float32)
-        return bias + sum(w[j] * shift_later(x, self.taps - 1 - j)
-                          for j in range(self.taps))
+        if splits is not None:
+            path, interpret = conv_path(implementation, x.shape, self.taps,
+                                        splits, x.dtype.itemsize)
+            if path == "kernel":
+                return causal_conv_silu(x, w, bias, splits, interpret, mesh)
+        wide = x.astype(jnp.float32)
+        y = bias + sum(w[j] * shift_later(wide, self.taps - 1 - j)
+                       for j in range(self.taps))
+        if splits is None:
+            return y
+        return tuple(jnp.split(nn.silu(y).astype(x.dtype), splits, axis=-1))
 
 
 class Mamba2Mixer(nn.Module):
@@ -102,12 +120,16 @@ class Mamba2Mixer(nn.Module):
 
     ``scan_impl`` is the block's ``attention_impl``: with the shapes it
     decides whether the scan runs as ``ops/ssd.py``'s kernels or its einsums
-    (``ops/ssd.py:scan_path``); ``mesh`` is the step's, for the kernels.
+    (``ops/ssd.py:scan_path``) and whether ``silu(conv(xBC))`` runs as
+    ``ops/conv.py``'s kernels, which write ``x``, ``B`` and ``C`` as the
+    scan reads them, or as XLA's shifted multiply-adds with the split after
+    (``ops/conv.py:conv_path``); ``mesh`` is the step's, for the kernels.
 
     Scopes, for the trace: ``ssm_in_proj``, ``ssm_conv``, ``ssm_scan``,
     ``ssm_gate_norm``, ``ssm_out_proj``. Counted when a call is traced:
-    ``ssm.scan.calls`` by ``path`` (the one taken) and ``chunk``; gauges
-    ``ssm.scan.chunks`` and ``ssm.state_bytes`` (docs/OBSERVABILITY.md)."""
+    ``ssm.scan.calls`` by ``path`` (the one taken) and ``chunk``,
+    ``ssm.conv.calls`` by ``path``; gauges ``ssm.scan.chunks`` and
+    ``ssm.state_bytes`` (docs/OBSERVABILITY.md)."""
 
     heads: int
     head_dim: int
@@ -134,6 +156,13 @@ class Mamba2Mixer(nn.Module):
         ).inc(path=scan_path(
             self.scan_impl, (bsz, seq, self.heads, self.head_dim),
             self.state, self.groups, self.chunk)[0], chunk=str(self.chunk))
+        cuts = (inner, inner + bc)
+        registry.counter(
+            "ssm.conv.calls",
+            "state-space mixer calls traced, by the convolution's path",
+        ).inc(path=conv_path(
+            self.scan_impl, (bsz, seq, inner + 2 * bc), self.conv_taps, cuts,
+            jnp.dtype(self.dtype).itemsize)[0])
         registry.gauge(
             "ssm.scan.chunks", "chunks a sequence's scan is cut into",
         ).set(max(seq // self.chunk, 1))
@@ -146,9 +175,8 @@ class Mamba2Mixer(nn.Module):
                 dense(2 * inner + 2 * bc + self.heads, "in_proj")(u),
                 (inner, 2 * inner + 2 * bc), axis=-1)
         with jax.named_scope("ssm_conv"):
-            xbc = nn.silu(CausalConv(self.conv_taps, name="conv")(xbc)) \
-                .astype(self.dtype)
-            x, b, c = jnp.split(xbc, (inner, inner + bc), axis=-1)
+            x, b, c = CausalConv(self.conv_taps, name="conv")(
+                xbc, cuts, self.scan_impl, self.mesh)
         with jax.named_scope("ssm_scan"):
             a_h, c_h = head_constants(self.heads)
             head = (self.heads,)

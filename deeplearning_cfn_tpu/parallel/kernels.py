@@ -7,12 +7,12 @@ a ``shard_map`` over the axes the batch is sharded on: each device runs the
 kernel over its own rows, as it would alone. :func:`shard_rows` is that one
 wrapper, used by ``ops/attention.py`` (the three flash kernels, through the
 call's own VJP), ``ops/rope.py`` (two), ``ops/ssd.py`` (two, with the running
-sums beside them) and ``models/moe.py`` (megablox's ``gmm`` / ``tgmm`` inside
-the expert layer's per-rank part). **On a mesh of
+sums beside them), ``ops/conv.py`` (two) and ``models/moe.py`` (megablox's
+``gmm`` / ``tgmm`` inside the expert layer's per-rank part). **On a mesh of
 one device, or told no mesh, it returns the function as it is**: nothing is
 entered and the program compiled is the one compiled without it.
 
-``parallel.shard_map.calls``, labelled ``kernel=flash|rope|gmm|ssd``, counts
+``parallel.shard_map.calls``, labelled ``kernel=flash|rope|gmm|ssd|conv``, counts
 the wrappers built while a step is traced (docs/OBSERVABILITY.md).
 """
 

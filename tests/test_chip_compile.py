@@ -137,6 +137,32 @@ def test_rope_kernel_compiles_for_v5e(v5e_chip, name, heads, rope, what):
     assert ("rope_fwd" if what == "forward" else "rope_bwd") in text
 
 
+@pytest.mark.parametrize("what", ["forward", "grad"])
+def test_convolution_kernels_compile_for_v5e(v5e_chip, what):
+    """The Granite cell's convolution alone: bfloat16 ``xBC [1, 8192,
+    4352]`` under 4 taps, cut at 4096 and 4224 into the scan's x, B and C.
+    One kernel a pass (the backward's residuals are the inputs, so its
+    gradient runs no forward), the token block the rule's 512."""
+    from deeplearning_cfn_tpu.ops.conv import (causal_conv_silu, conv_path,
+                                               token_block)
+
+    one_chip = SingleDeviceSharding(v5e_chip)
+    shape, cuts = (1, 8192, 4352), (4096, 4224)
+    assert conv_path("pallas", shape, 4, cuts) == ("kernel", False)
+    assert token_block(8192, 4352, 2) == 512
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((4, 4352), jnp.float32, sharding=one_chip)
+    bias = jax.ShapeDtypeStruct((4352,), jnp.float32, sharding=one_chip)
+    conv = lambda x, w, bias: causal_conv_silu(x, w, bias, cuts)
+    fn = conv if what == "forward" else jax.grad(
+        lambda *a: sum(p.astype(jnp.float32).sum() for p in conv(*a)),
+        argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(x, w, bias).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert ("causal_conv_fwd" if what == "forward"
+            else "causal_conv_bwd") in text
+
+
 def _row_scatters(text, width=2048):
     """The instructions of a compiled step that scatter rows of ``width``
     under an expert layer's ``moe_dispatch`` or ``moe_combine``: the rows go
@@ -298,10 +324,12 @@ def test_granite4h_step_compiles_and_fits_a_v5e(v5e_chip):
     shapes: nine Mamba-2 mixers and one attention layer at 8,192 tokens,
     every block recomputed in the backward pass. Mosaic takes the flash
     kernels at 32 query heads over 8 K/V heads of 64 with no rotary kernel
-    beside them, and the scan's two kernels (``ops/ssd.py``) in every Mamba
-    layer, forward, recomputed and backward, with no decay matrix in HBM;
-    the mixers' five scopes are in the text, forward, recomputed and
-    backward; no row is scattered inside a block; and 12.4 GB of state with
+    beside them, and in every Mamba layer the scan's two kernels
+    (``ops/ssd.py``) and the convolution's two (``ops/conv.py``), forward,
+    recomputed and backward, with no decay matrix and no float32 ``[1, 8192,
+    4352]`` in HBM; the mixers' five scopes are in the text, forward,
+    recomputed and backward; no row is scattered inside a block; and 12.4 GB
+    of state with
     one block's intermediates fit the chip, over the quarter of it a cell
     has to fill. PERF.md section 4 has the number."""
     import re
@@ -311,24 +339,29 @@ def test_granite4h_step_compiles_and_fits_a_v5e(v5e_chip):
     manifest, rehearse_compile = _bench()
     registry = get_tracer().registry
     scans = registry.counter("ssm.scan.calls")
+    convs = registry.counter("ssm.conv.calls")
     blocks = registry.counter("model.blocks.recomputed")
     kept = registry.counter("model.blocks.kept_flash")
     turned = registry.counter("attention.rope.calls")
     scanned = lambda: tuple(scans.value(path=p, chunk="256")
                             for p in ("kernel", "xla"))
+    convolved = lambda: tuple(convs.value(path=p) for p in ("kernel", "xla"))
     before = (scanned(), blocks.value(),
               turned.value(path="kernel") + turned.value(path="xla"),
-              kept.value())
+              kept.value(), convolved())
     cell = manifest.Cell(manifest.load_manifest(),
                          "granite4_h_micro_train_8k")
     assert cell.chips == 1
     _, compiled, _ = rehearse_compile.compile_step(cell)
     # Traced twice (the parameters' shapes, the step): nine mixers, each
-    # through the scan's kernels, and ten recomputed blocks each; the
-    # backward pass traces nothing again, and nothing turns q or k.
+    # through the scan's kernels and the convolution's, and ten recomputed
+    # blocks each; the backward pass traces nothing again, and nothing turns
+    # q or k.
     assert (tuple(n - m for n, m in zip(scanned(), before[0])),
             blocks.value() - before[1], turned.value(path="kernel")
-            + turned.value(path="xla") - before[2]) == ((18, 0), 20, 0)
+            + turned.value(path="xla") - before[2],
+            tuple(n - m for n, m in zip(convolved(), before[4]))) \
+        == ((18, 0), 20, 0, (18, 0))
     # The attention block keeps its kernel's pair: [1, 32, 8192] rows of 64
     # bfloat16 and a float32.
     assert kept.value() - before[3] == 2
@@ -354,21 +387,32 @@ def test_granite4h_step_compiles_and_fits_a_v5e(v5e_chip):
                and "bf16[1,32,8192,64]" in line for line in flash)
     # Every Mamba layer's scan: the forward, the forward again that keeps
     # the states (recomputed) and the backward, all under the scope the
-    # readers know; x is read as the projection left it, [B, S, H * P].
-    scan = [line for line in kernels if line not in flash]
-    assert len(scan) == 27 and len(kernels) == 30
+    # readers know; x is read as the projection left it, [B, S, H * P]. And
+    # its convolution the same way: the forward, the forward again and the
+    # backward under ``ssm_conv``, x, B and C written as the scan reads them.
+    mamba = [line for line in kernels if line not in flash]
+    assert len(mamba) == 54 and len(kernels) == 57
+    name_of = lambda line: re.search(r'op_name="([^"]*)"', line).group(1)
+    passes = lambda names, kernel: sorted(
+        ("rematted_computation" in name, "transpose(jvp" in name,
+         re.search(rf"/({kernel}_\w+)", name).group(1)) for name in names)
     for layer in (0, 1, 2, 3, 4, 6, 7, 8, 9):
-        own = [re.search(r'op_name="([^"]*)"', line).group(1)
-               for line in scan if f"/layer_{layer}/" in line]
-        assert len(own) == 3 and all("/self_attn/ssm_scan/" in name
-                                     for name in own), own
-        assert sorted(("rematted_computation" in name,
-                       "transpose(jvp" in name,
-                       re.search(r"/(ssd_\w+)", name).group(1))
-                      for name in own) == [
+        own = [name_of(line) for line in mamba if f"/layer_{layer}/" in line]
+        scan = [name for name in own if "/self_attn/ssm_scan/" in name]
+        conv = [name for name in own if "/self_attn/ssm_conv/" in name]
+        assert len(scan) == len(conv) == 3 and len(own) == 6, own
+        assert passes(scan, "ssd") == [
             (False, False, "ssd_fwd"), (False, True, "ssd_bwd"),
-            (True, True, "ssd_fwd")], own
-    assert all("bf16[1,8192,4096]" in line for line in scan)
+            (True, True, "ssd_fwd")], scan
+        assert passes(conv, "causal_conv") == [
+            (False, False, "causal_conv_fwd"),
+            (False, True, "causal_conv_bwd"),
+            (True, True, "causal_conv_fwd")], conv
+    assert all("bf16[1,8192,4096]" in line for line in mamba)
+    assert all("bf16[1,8192,4352]" in line for line in mamba
+               if "causal_conv_" in line)
+    # The convolution's float32 passes are gone with XLA's shifts.
+    assert "f32[1,8192,4352]" not in text
     # The decay matrix of 64 heads never reaches HBM.
     assert not re.search(r"f32\[[\d,]*,256,256\]", text)
     for scope in ("ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm",

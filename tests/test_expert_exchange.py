@@ -161,6 +161,37 @@ def test_scan_kernels_run_a_device_each_under_the_wrapper(devices):
         np.testing.assert_allclose(g, w, rtol=0, atol=2e-5 * scale)
 
 
+def test_convolution_kernels_run_a_device_each_under_the_wrapper(devices):
+    """Four sequences over a data=2 x expert=2 mesh through the causal
+    convolution's kernels (interpret mode), forward and backward, against
+    the shifted multiply-adds on no mesh; the taps' and the bias's
+    gradients are summed over the devices; the wrapper was entered once."""
+    from deeplearning_cfn_tpu.models.ssm import CausalConv
+    from deeplearning_cfn_tpu.obs.trace import get_tracer
+
+    mesh = _mesh(devices, data=2, expert=2)
+    rng = np.random.RandomState(0)
+    x = jnp.asarray(rng.normal(0, 1, (4, 64, 256)), jnp.float32)
+    params = {"params": {
+        "kernel": jnp.asarray(rng.uniform(-0.1, 0.1, (4, 256)), jnp.float32),
+        "bias": jnp.asarray(rng.normal(0, 0.3, (256,)), jnp.float32)}}
+    calls = get_tracer().registry.counter("parallel.shard_map.calls")
+    before = calls.value(kernel="conv")
+
+    def loss(*how):
+        return lambda p, x: sum(jnp.sum(jnp.square(part)) for part in
+                                CausalConv(4).apply(p, x, (128,), *how))
+
+    got = jax.jit(jax.value_and_grad(loss("interpret", mesh),
+                                     argnums=(0, 1)))(params, x)
+    assert calls.value(kernel="conv") == before + 1
+    want = jax.value_and_grad(loss("reference"), argnums=(0, 1))(params, x)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        scale = float(jnp.max(jnp.abs(w)))
+        np.testing.assert_allclose(g, w, rtol=0, atol=2e-5 * scale)
+
+
 # -- the softmax router --------------------------------------------------------
 
 

@@ -2,7 +2,8 @@
 computes, a token at a time, in float32: values and every gradient; and the
 pieces of Mamba-2's mixer around it (``models/ssm.py``): the causal depthwise
 convolution against explicit shifts, the gate before the norm, the constants
-a head."""
+a head; and that convolution with its bias and silu as the two kernels
+of ``ops/conv.py``, in interpreter mode, against the shifted form."""
 
 import jax
 import jax.numpy as jnp
@@ -11,6 +12,8 @@ import pytest
 
 from deeplearning_cfn_tpu.models.ssm import (CausalConv, Mamba2Mixer,
                                              conv_gain, head_constants)
+from deeplearning_cfn_tpu.ops.conv import (causal_conv_silu, conv_path,
+                                          token_block)
 from deeplearning_cfn_tpu.ops.ssd import (head_block, scan_path,
                                          ssd_recurrence, ssd_scan)
 
@@ -201,6 +204,161 @@ def test_causal_convolution_is_four_shifted_multiplies():
     assert not np.array_equal(np.asarray(moved[:, 7]), np.asarray(got[:, 7]))
 
 
+def _shifted_form(x, kernel, bias, splits, taps):
+    """``CausalConv``'s own arithmetic: the shifted multiply-adds in float32,
+    silu, one cast, the split."""
+    return CausalConv(taps).apply(
+        {"params": {"kernel": kernel, "bias": bias}}, x, splits, "reference")
+
+
+def _conv_inputs(shape, taps, dtype, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(ks[0], shape).astype(dtype),
+            jax.nn.initializers.xavier_uniform()(ks[1], (taps, shape[2])),
+            0.3 * jax.random.normal(ks[2], shape[2:]))
+
+
+def _within_an_ulp(got, want, what, dtype):
+    """Within one unit in the last place of ``dtype`` (8 bits of bfloat16,
+    24 of float32) of ``want``, and a few float32 roundings of the largest
+    value where the terms of a sum nearly cancel."""
+    assert got.dtype == want.dtype == dtype and got.shape == want.shape, what
+    got, want = (np.asarray(t, np.float64) for t in (got, want))
+    bits = 8 if dtype == jnp.bfloat16 else 24
+    ulp = 2.0 ** (np.floor(np.log2(np.maximum(np.abs(want), 1e-30)))
+                  - (bits - 1))
+    ulp = ulp + 1e-6 * max(np.max(np.abs(want)), 1.0)
+    assert np.all(np.abs(got - want) <= ulp), \
+        (what, np.max(np.abs(got - want) / ulp))
+
+
+@pytest.mark.parametrize("shape,splits,block,taps,dtype", [
+    # Three token blocks of two chunks, four lane chunks over three arrays:
+    # the rows before a chunk from the chunk above and, at a block's first,
+    # from the block before; a batch of 2.
+    ((2, 96, 512), (256, 384), (32, 16, 128), 4, jnp.bfloat16),
+    # The block the rule gives (the whole sequence, two chunks of it), one
+    # array, a width no 256 lanes divide: three lane chunks.
+    ((2, 64, 384), (), None, 4, jnp.bfloat16),
+    # A block of one chunk: every chunk's rows before come by the edge
+    # block, every chunk's cotangent after from the scratch.
+    ((1, 128, 256), (128,), (16, 16, 256), 4, jnp.bfloat16),
+    # Chunks of 16 rows, two to a block; three taps.
+    ((2, 128, 256), (128,), (32, 16, 128), 3, jnp.bfloat16),
+    # float32 through and through, as the CPU tests' mixers run it.
+    ((1, 64, 256), (128,), (32, 16, 256), 4, jnp.float32),
+])
+def test_convolution_kernels_are_the_shifted_form_with_every_gradient(
+        shape, splits, block, taps, dtype):
+    """``ops/conv.py``'s two kernels in interpreter mode against
+    ``CausalConv``'s shifted multiply-adds followed by silu, the cast and
+    the split: each array and ``x``'s gradient to one unit in the last place
+    of the dtype, the float32 gradients of ``kernel`` and ``bias`` (sums
+    over every token, in another order) to 1e-4 of their largest."""
+    assert conv_path("interpret", shape, taps, splits,
+                     jnp.dtype(dtype).itemsize) == ("kernel", True)
+    x, kernel, bias = _conv_inputs(shape, taps, dtype)
+    kernels = lambda x, kernel, bias: causal_conv_silu(
+        x, conv_gain(taps, shape[2]) * kernel, bias, splits, interpret=True,
+        block=block)
+    plain = lambda x, kernel, bias: _shifted_form(x, kernel, bias, splits,
+                                                  taps)
+    got, want = kernels(x, kernel, bias), plain(x, kernel, bias)
+    assert len(got) == len(want) == len(splits) + 1
+    for n, (g, t) in enumerate(zip(got, want)):
+        _within_an_ulp(g, t, f"array {n}", dtype)
+    cts = [jax.random.normal(jax.random.PRNGKey(7 + n), t.shape)
+           .astype(dtype) for n, t in enumerate(want)]
+    grads = lambda f: jax.jit(jax.grad(lambda *a: sum(
+        jnp.sum(o.astype(jnp.float32) * ct.astype(jnp.float32))
+        for o, ct in zip(f(*a), cts)), argnums=(0, 1, 2)))(x, kernel, bias)
+    (dx, dk, db), (dx_w, dk_w, db_w) = grads(kernels), grads(plain)
+    # The ends: the first taps - 1 positions' inputs feed fewer outputs
+    # before them, the last ones fewer after; both sides agree there too.
+    _within_an_ulp(dx, dx_w, "dx", dtype)
+    assert np.any(np.asarray(dx_w[:, -1], np.float32))
+    for name, g, t in (("dkernel", dk, dk_w), ("dbias", db, db_w)):
+        assert g.dtype == t.dtype == jnp.float32 and np.any(np.asarray(t))
+        _close(g, t, name, tol=1e-4)
+
+
+@pytest.mark.parametrize("shape,splits,taps,why", [
+    ((2, 100, 256), (128,), 4, "no token block divides the sequence"),
+    ((2, 64, 192), (128,), 4, "channels that are no whole lane tiles"),
+    ((2, 64, 256), (64,), 4, "a cut inside a lane tile"),
+    ((1, 64, 256), (128,), 10, "more taps than a chunk keeps rows"),
+])
+def test_convolution_off_the_kernels_is_todays_shifted_form(shape, splits,
+                                                            taps, why):
+    """A shape the kernels do not tile takes the ``xla`` path whatever is
+    asked for, and that path is today's code: ``CausalConv`` alone, then
+    ``nn.silu``, the cast and ``jnp.split``, bit for bit."""
+    for impl in ("auto", "pallas", "interpret", "reference"):
+        assert conv_path(impl, shape, taps, splits) \
+            == ("xla", impl == "interpret"), why
+    x, kernel, bias = _conv_inputs(shape, taps, jnp.bfloat16)
+    params = {"params": {"kernel": kernel, "bias": bias}}
+    got = CausalConv(taps).apply(params, x, splits, "interpret")
+    today = jnp.split(jax.nn.silu(CausalConv(taps).apply(params, x))
+                      .astype(jnp.bfloat16), splits, axis=-1)
+    assert len(got) == len(today) == len(splits) + 1
+    for g, t in zip(got, today):
+        assert g.dtype == jnp.bfloat16
+        assert np.array_equal(np.asarray(g, np.float32),
+                              np.asarray(t, np.float32))
+
+
+@pytest.mark.parametrize("shape,taps,splits,itemsize,block", [
+    ((1, 8192, 4352), 4, (4096, 4224), 2, 512),    # granite-4.0-h's
+    ((1, 8192, 4352), 4, (4096, 4224), 4, 256),    # float32: half the rows
+    ((4, 4096, 512), 4, (), 2, 512),
+    ((2, 48, 128), 2, (), 2, 16),
+    ((1, 8192, 4352), 4, (4096, 4200), 2, None),   # a cut inside a tile
+    ((1, 8200, 4352), 4, (), 2, None),             # 8 rows over
+    ((1, 8192, 4300), 4, (), 2, None),
+])
+def test_the_convolution_kernels_take_the_shapes_they_tile(shape, taps,
+                                                           splits, itemsize,
+                                                           block):
+    """Which carrier runs is read from the call alone, as the scan's is: on
+    this CPU ``auto`` is the shifted form whatever the shape."""
+    path = "kernel" if block else "xla"
+    assert conv_path("pallas", shape, taps, splits, itemsize) \
+        == (path, False)
+    assert conv_path("interpret", shape, taps, splits, itemsize) \
+        == (path, True)
+    assert conv_path("auto", shape, taps, splits, itemsize) == ("xla", False)
+    if block:
+        assert token_block(shape[1], shape[2], itemsize) == block
+    with pytest.raises(ValueError, match="unknown implementation"):
+        conv_path("mosaic", shape, taps, splits, itemsize)
+
+
+def test_convolution_kernels_are_causal_through_the_halo():
+    """Token blocks of 32: moving token 31, a block's last, changes outputs
+    31 to 34 (three of them in the next block, through the rows the edge
+    block brings) and none before; moving token 40 changes 40 to 43 and
+    none before. The cotangent runs the other way: output 32's reaches
+    inputs 29 to 32 and none after."""
+    x, kernel, bias = _conv_inputs((2, 96, 256), 4, jnp.bfloat16)
+    w = conv_gain(4, 256) * kernel
+    conv = lambda x: causal_conv_silu(x, w, bias, (128,), interpret=True,
+                                      block=(32, 16, 128))
+    rows = lambda parts: np.concatenate(
+        [np.asarray(p, np.float32) for p in parts], axis=-1)
+    got = rows(conv(x))
+    for at in (31, 40):
+        moved = rows(conv(x.at[:, at].add(1.0)))
+        assert np.array_equal(moved[:, :at], got[:, :at])
+        changed = np.any(moved != got, axis=(0, 2))
+        assert changed[at:at + 4].all() and not changed[at + 4:].any()
+    dx = jax.grad(lambda x: sum(
+        jnp.sum(p[:, 32].astype(jnp.float32)) for p in conv(x)))(x)
+    reached = np.any(np.asarray(dx, np.float32) != 0, axis=(0, 2))
+    assert reached[29:33].all() and not reached[:29].any() \
+        and not reached[33:].any()
+
+
 def test_conv_gain_makes_xavier_taps_conv1ds():
     """A Xavier-uniform ``[4, 4352]`` kernel is uniform over +-sqrt(6 /
     4356); times the gain it is uniform over +-1/2, ``nn.Conv1d``'s bound
@@ -295,9 +453,11 @@ def test_mixer_counts_its_calls_when_traced(seq, how, path):
 
     registry = get_tracer().registry
     calls = registry.counter("ssm.scan.calls")
+    convs = registry.counter("ssm.conv.calls")
     mixer = _mixer(**how)
     chunk = str(mixer.chunk)
     before = {p: calls.value(path=p, chunk=chunk) for p in ("xla", "kernel")}
+    convs_before = {p: convs.value(path=p) for p in ("xla", "kernel")}
     u = jnp.zeros((1, seq, 32))
     params = mixer.init(jax.random.PRNGKey(0), u)
     jax.jit(jax.grad(lambda p: jnp.sum(mixer.apply(p, u))))(params)
@@ -305,6 +465,10 @@ def test_mixer_counts_its_calls_when_traced(seq, how, path):
     # backward pass traces nothing again.
     assert {p: calls.value(path=p, chunk=chunk) - n
             for p, n in before.items()} \
+        == {path: 2, "kernel" if path == "xla" else "xla": 0}
+    # The convolution's kernels tile where the scan's do in these five: 64
+    # channels are no lane tile, 100 tokens no whole blocks of 16.
+    assert {p: convs.value(path=p) - n for p, n in convs_before.items()} \
         == {path: 2, "kernel" if path == "xla" else "xla": 0}
     assert registry.gauge("ssm.scan.chunks").value() \
         == max(seq // mixer.chunk, 1)
