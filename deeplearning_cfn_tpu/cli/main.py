@@ -80,8 +80,8 @@ def _cmd_stack_resize(args) -> int:
 
     # Destroy-first semantics must be visible BEFORE the irreversible step:
     # if the replacement create fails (quota, capacity) the old stack is
-    # already gone (ADVICE r3 #3; TPU slices are not elastically resizable
-    # — see provision.resize_stack).
+    # already gone (TPU slices are not elastically resizable — see
+    # provision.resize_stack).
     print(f"[dlcfn-tpu] resize: tearing down stack {args.name!r} before "
           f"creating its {args.slice_type} replacement — if the new create "
           f"fails, the old stack will NOT be restored", flush=True)

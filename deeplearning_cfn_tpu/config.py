@@ -96,14 +96,6 @@ class TrainConfig:
     log_every_steps: int = 50
     seed: int = 0
     dtype: str = "bfloat16"  # compute dtype; params stay f32
-    # jax.checkpoint round the whole model's apply (train/task.py): the
-    # backward pass recomputes the whole forward and then holds every
-    # block's intermediates at once, so it lowers no peak. A block at a time
-    # is BlockStyle.remat (models/transformer.py), asked for by a preset's
-    # model kwargs (granite4_h_micro_lm, sdar_30b_a3b_lm: remat_blocks); such
-    # a block keeps its input and its flash forward kernel's output and row
-    # statistics, 2 B S H D + 4 B H S bytes an attention block.
-    remat: bool = False
     # > 0: a decoder (``gpt_*``) is trained as a block-diffusion model with
     # blocks of this many tokens (train/task.py:BlockDiffusionLmTask): a
     # noised copy of each row beside the clean row, the loss on the masked

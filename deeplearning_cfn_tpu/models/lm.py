@@ -63,10 +63,9 @@ class TransformerCausalLm(nn.Module):
     constant its sublayers' results are multiplied by before they join the
     stream, and whether the block is recomputed in the backward pass
     (``BlockStyle.remat``: the layer under ``flax.linen.remat``, one block's
-    intermediates alive at a time, where ``train.remat`` recomputes the whole
-    model at once and lowers no peak; an attention block on the flash
-    kernels keeps the forward kernel's output and row statistics as well as
-    its input, ``2 B S H D + 4 B H S`` bytes, and runs that kernel once).
+    intermediates alive at a time; an attention block on the flash kernels
+    keeps the forward kernel's output and row statistics as well as its
+    input, ``2 B S H D + 4 B H S`` bytes, and runs that kernel once).
     ``embedding_multiplier`` and
     ``logits_scaling`` are Granite's: the embedding times the one, the logits
     over the other, in float32.

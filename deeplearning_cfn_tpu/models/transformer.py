@@ -229,6 +229,10 @@ class BlockStyle:
     ssm: Tuple[Tuple[str, Any], ...] = ()
     residual_multiplier: float = 1.0
     attn_scale: float = 0.0        # 0: 1 / sqrt(head_dim)
+    # Asked for by a preset's model kwargs (granite4_h_micro_lm,
+    # sdar_30b_a3b_lm: remat_blocks); such a block keeps its input and its
+    # flash forward kernel's output and row statistics, 2 B S H D + 4 B H S
+    # bytes an attention block.
     remat: bool = False
     qk_norm: bool = False
 

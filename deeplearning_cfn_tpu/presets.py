@@ -412,11 +412,10 @@ def _granite4_h_micro() -> ExperimentConfig:
     alike); every layer whole, every width published. Sequences of 8192.
     Every block is recomputed in the backward pass, one at a time
     (`remat_blocks`): ten blocks' intermediates at 8,192 tokens do not fit
-    beside 12.4 GB of weights, gradients and Adam's moments, and
-    `train.remat` would hold them all at once. The attention block keeps
-    its flash forward kernel's output and row statistics (34.6 MB) and runs
-    the kernel once. Recipe: gpt_small_lm's (the source's own is not in its
-    config)."""
+    beside 12.4 GB of weights, gradients and Adam's moments. The attention
+    block keeps its flash forward kernel's output and row statistics
+    (34.6 MB) and runs the kernel once. Recipe: gpt_small_lm's (the source's
+    own is not in its config)."""
     return ExperimentConfig(
         model=ModelConfig(
             name="gpt_granite4_h_micro",

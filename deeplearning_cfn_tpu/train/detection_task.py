@@ -83,7 +83,6 @@ class DetectionTask:
         self.anchors = generate_anchors(
             (s, s), strides=[STRIDES[l] for l in LEVELS],
             scales=[anchor_scale * STRIDES[l] for l in LEVELS])
-        self.remat = cfg.train.remat
 
     # -- init ---------------------------------------------------------------
 
@@ -367,9 +366,6 @@ class DetectionTask:
         variables = {"params": params}
         if batch_stats:
             variables["batch_stats"] = batch_stats
-        # Note: remat here would need nn.remat on the backbone (a bound
-        # Module isn't a jax type, so jax.checkpoint can't wrap `forward`);
-        # the backbone is the memory hog and XLA already dedups the rest.
         if train:
             (total, metrics), mutated = self.model.apply(
                 variables, batch, method=forward, mutable=["batch_stats"])

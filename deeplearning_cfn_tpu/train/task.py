@@ -102,7 +102,6 @@ class ClassificationTask:
 
         self.param_rules = getattr(
             sys.modules[type(self.model).__module__], "PARAM_RULES", ())
-        self.remat = cfg.train.remat
 
     def init(self, rng: jax.Array):
         shape = (1, self.cfg.data.image_size, self.cfg.data.image_size, 3)
@@ -129,14 +128,8 @@ class ClassificationTask:
                 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
         has_stats = bool(batch_stats)
         if train:
-            fwd = self._forward_train
-            if self.remat:
-                # Rematerialize the forward: trade FLOPs for HBM. Wraps the
-                # pure apply, not the Module (Modules aren't callables with
-                # init/apply after jax.checkpoint).
-                fwd = jax.checkpoint(fwd)
-            logits, new_stats = fwd(params, batch_stats, batch["image"],
-                                    rng)
+            logits, new_stats = self._forward_train(
+                params, batch_stats, batch["image"], rng)
         else:
             variables = {"params": params}
             if has_stats:
@@ -208,7 +201,6 @@ class MlmTask:
         self.param_rules = PARAM_RULES
         self.model = build_model(cfg.model.name, cfg.model.num_classes,
                                  dtype, **kwargs)
-        self.remat = cfg.train.remat
 
     def init(self, rng: jax.Array):
         s = self.cfg.data.seq_len
@@ -222,8 +214,6 @@ class MlmTask:
         apply = lambda p, b: self.model.apply(
             {"params": p}, b["input_ids"], b["input_mask"],
             b["segment_ids"], b["mlm_positions"], train=train, rngs=rngs)
-        if train and self.remat:
-            apply = jax.checkpoint(apply)
         out = apply(params, batch)
         mask = example_mask(batch, batch["input_ids"].shape[0])
         weights = batch["mlm_weights"] * mask[:, None]
@@ -283,7 +273,6 @@ class Seq2SeqTask:
         from ..models.transformer_nmt import PARAM_RULES
 
         self.param_rules = PARAM_RULES
-        self.remat = cfg.train.remat
 
     def init(self, rng: jax.Array):
         s = self.cfg.data.seq_len
@@ -351,8 +340,6 @@ class Seq2SeqTask:
         apply = lambda p, b: self.model.apply(
             {"params": p}, b["src_ids"], b["src_mask"], b["tgt_in_ids"],
             train=train, rngs=rngs)
-        if train and self.remat:
-            apply = jax.checkpoint(apply)
         logits = apply(params, batch)
         ex_mask = example_mask(batch, batch["src_ids"].shape[0])
         mask = batch["tgt_mask"] * ex_mask[:, None]
@@ -407,7 +394,6 @@ class CausalLmTask:
             kwargs.setdefault("mesh", mesh)
         self.param_rules = PARAM_RULES
         self.model = build_model(cfg.model.name, 0, dtype, **kwargs)
-        self.remat = cfg.train.remat
 
     def init(self, rng: jax.Array):
         ids = jnp.zeros((1, self.cfg.data.seq_len), jnp.int32)
@@ -422,8 +408,6 @@ class CausalLmTask:
         apply = lambda p, ids: self.model.apply(
             {"params": p}, ids, train=train,
             mutable=["nudges"] if train else False, **call)
-        if train and self.remat:
-            apply = jax.checkpoint(apply)
         out, sown = apply(params, inputs) if train \
             else (apply(params, inputs), {})
         logits, moe_aux = out if isinstance(out, tuple) else (out, None)

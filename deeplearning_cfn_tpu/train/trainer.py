@@ -215,7 +215,7 @@ class Trainer:
             # bit-equal to accum=1 on the same global batch (that would
             # weight microbatches by their mask sums); matching the DP
             # contract is the deliberate choice — accum exists to emulate
-            # a larger device count (ADVICE r3 #4).
+            # a larger device count.
             def split(v):
                 g = v.shape[0]
                 return v.reshape(g // accum, accum, *v.shape[1:]) \

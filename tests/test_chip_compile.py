@@ -1,39 +1,20 @@
-"""The flash-attention kernels and the rotary-position kernel, compiled by the
-TPU's own compiler for a chip that is described and not attached (a v5e 2x2),
-at the shapes the shipped presets produce. Interpret mode cannot see what this does: a slice that is
-not aligned to the tiling, or more fast memory than a kernel may use. A
-compile that passes is not a chip run — chip_smoke.py is."""
+"""The flash-attention kernels, compiled by the TPU's own compiler for a chip
+that is described and not attached (a v5e 2x2), at the shapes the shipped
+presets produce, and the whole train step of the cell that runs them under
+their third mask (SDAR's). Interpret mode cannot see what this does: a slice
+that is not aligned to the tiling, or more fast memory than a kernel may use.
+A compile that passes is not a chip run — chip_smoke.py is.
+``test_chip_compile_laguna_zaya1.py`` and
+``test_chip_compile_mellum2_granite4h.py`` hold the other kernels and cells;
+``chip_steps.py`` says what goes where."""
 
-import os
+import jax
+import jax.numpy as jnp
+import pytest
+from chip_steps import _bench, _row_scatters, v5e_chip  # noqa: F401
+from jax.sharding import SingleDeviceSharding
 
-os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import pytest  # noqa: E402
-from jax.sharding import SingleDeviceSharding  # noqa: E402
-
-from deeplearning_cfn_tpu.ops.attention import fused_attention  # noqa: E402
-
-
-@pytest.fixture(scope="module")
-def v5e_chip():
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # no TPU compiler in this installation
-        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e!r}")
-    # Such a compile is written to the persistent cache but cannot be read
-    # back without a chip, so the next one warns: keep the cache off here
-    # (the suite's conftest already does; this holds for a lone run too).
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield topo.devices[0]
-    jax.config.update("jax_enable_compilation_cache", was)
+from deeplearning_cfn_tpu.ops.attention import fused_attention
 
 
 @pytest.mark.parametrize("name,shape,sk,causal", [
@@ -64,371 +45,6 @@ def test_flash_kernel_compiles_for_v5e(v5e_chip, name, shape, sk, causal,
         argnums=(0, 1, 2))
     compiled = jax.jit(fn).lower(arg, kv, kv).compile()
     assert "tpu_custom_call" in compiled.as_text()
-
-
-@pytest.mark.parametrize("name,heads,window", [
-    ("laguna_full", 48, 0), ("laguna_sliding", 64, 512)])
-@pytest.mark.parametrize("what", ["forward", "grad"])
-def test_grouped_windowed_kernels_compile_for_v5e(v5e_chip, name, heads,
-                                                  window, what):
-    """The Laguna cell's two attention shapes: 8 K/V heads under 48 and 64
-    query heads at 4096 positions and head size 128, the sliding one under
-    its window of 512 (sub-tiles in all three kernels, on a grid of the
-    band: ``_tile_plan``'s plan for it, and index maps that clamp), the
-    full one on its whole grid with the dead steps' index maps clamped. No
-    dead step of either copies a block for nothing."""
-    from deeplearning_cfn_tpu.ops.attention import _grid_gauges
-
-    one_chip = SingleDeviceSharding(v5e_chip)
-    q = jax.ShapeDtypeStruct((2, heads, 4096, 128), jnp.bfloat16,
-                             sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((2, 8, 4096, 128), jnp.bfloat16,
-                              sharding=one_chip)
-
-    def attn(q, k, v):
-        return fused_attention(q, k, v, causal=True, window=window,
-                               implementation="pallas")
-
-    fn = attn if what == "forward" else jax.grad(
-        lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum(),
-        argnums=(0, 1, 2))
-    compiled = jax.jit(fn).lower(q, kv, kv).compile()
-    text = compiled.as_text()
-    assert text.count("tpu_custom_call") == (1 if what == "forward" else 3)
-    # K/V are not repeated to the query heads: every kernel takes them as
-    # they are, 8 heads.
-    for line in text.splitlines():
-        if "tpu_custom_call" in line and " custom-call(" in line:
-            assert "bf16[2,8,4096,128]" in line, line[:300]
-    for kernel in ("flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq")[
-            :1 if what == "forward" else 3]:
-        assert _grid_gauges(kernel, window) == (
-            (8, 1, 0) if window else (16, 6, 0)), kernel
-
-
-@pytest.mark.parametrize("name,heads,rope", [
-    ("q_sliding", 64, "sliding_rope"), ("q_full", 48, "full_rope"),
-    ("k_sliding", 8, "sliding_rope"), ("k_full", 8, "full_rope")])
-@pytest.mark.parametrize("what", ["forward", "grad"])
-def test_rope_kernel_compiles_for_v5e(v5e_chip, name, heads, rope, what):
-    """The Laguna cell's four rotary shapes: q ``[2,4096,64*128]`` turning
-    whole heads (one lane rotate), q ``[2,4096,48*128]`` turning 64 of 128
-    lanes (YaRN: two rotates and a select), k ``[2,4096,8*128]`` at both.
-    Forward reads the projection's layout and writes the flash kernels';
-    the gradient is the same kernel the other way round."""
-    from deeplearning_cfn_tpu.models.lm import _LAGUNA_XS2
-    from deeplearning_cfn_tpu.models.transformer import rope_to_heads
-
-    one_chip = SingleDeviceSharding(v5e_chip)
-    x = jax.ShapeDtypeStruct((2, 4096, heads, 128), jnp.bfloat16,
-                             sharding=one_chip)
-    g = jax.ShapeDtypeStruct((2, heads, 4096, 128), jnp.bfloat16,
-                             sharding=one_chip)
-
-    def turn(x):
-        return rope_to_heads(x, _LAGUNA_XS2[rope], "pallas")
-
-    # The turn is linear: its gradient alone depends on no x, and a jit
-    # without the described chip among its arguments compiles for the CPU.
-    compiled = jax.jit(turn).lower(x).compile() if what == "forward" else \
-        jax.jit(lambda x, g: jax.vjp(turn, x)[1](g)[0]).lower(x, g).compile()
-    text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 1
-    assert ("rope_fwd" if what == "forward" else "rope_bwd") in text
-
-
-@pytest.mark.parametrize("what", ["forward", "grad"])
-def test_convolution_kernels_compile_for_v5e(v5e_chip, what):
-    """The Granite cell's convolution alone: bfloat16 ``xBC [1, 8192,
-    4352]`` under 4 taps, cut at 4096 and 4224 into the scan's x, B and C.
-    One kernel a pass (the backward's residuals are the inputs, so its
-    gradient runs no forward), the token block the rule's 512."""
-    from deeplearning_cfn_tpu.ops.conv import (causal_conv_silu, conv_path,
-                                               token_block)
-
-    one_chip = SingleDeviceSharding(v5e_chip)
-    shape, cuts = (1, 8192, 4352), (4096, 4224)
-    assert conv_path("pallas", shape, 4, cuts) == ("kernel", False)
-    assert token_block(8192, 4352, 2) == 512
-    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
-    w = jax.ShapeDtypeStruct((4, 4352), jnp.float32, sharding=one_chip)
-    bias = jax.ShapeDtypeStruct((4352,), jnp.float32, sharding=one_chip)
-    conv = lambda x, w, bias: causal_conv_silu(x, w, bias, cuts)
-    fn = conv if what == "forward" else jax.grad(
-        lambda *a: sum(p.astype(jnp.float32).sum() for p in conv(*a)),
-        argnums=(0, 1, 2))
-    text = jax.jit(fn).lower(x, w, bias).compile().as_text()
-    assert text.count("tpu_custom_call") == 1
-    assert ("causal_conv_fwd" if what == "forward"
-            else "causal_conv_bwd") in text
-
-
-def _row_scatters(text, width=2048):
-    """The instructions of a compiled step that scatter rows of ``width``
-    under an expert layer's ``moe_dispatch`` or ``moe_combine``: the rows go
-    to the buffer and back by gathers (``models/moe.py:take_rows``,
-    ``sum_rows``), so there are none; what is still scattered there is
-    integers."""
-    import re
-
-    return [line.strip()[:200] for line in text.splitlines()
-            if re.search(rf"= \w+\[\d+,{width}\]\S* scatter\(", line)
-            and re.search(r"/moe_(dispatch|combine)/", line)]
-
-
-def _bench():
-    import os
-    import sys
-
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark")
-    sys.path.insert(0, bench)
-    try:
-        from harness import manifest
-        import rehearse_compile
-    finally:
-        sys.path.remove(bench)
-    return manifest, rehearse_compile
-
-
-def _rows_calls(since=None):
-    """``moe.rows.calls`` by path, less what it read at ``since``."""
-    from deeplearning_cfn_tpu.obs.trace import get_tracer
-
-    calls = get_tracer().registry.counter("moe.rows.calls")
-    return {path: calls.value(path=path) - (since[path] if since else 0)
-            for path in ("gather", "kernel", "scatter_add")}
-
-
-def _gmm_calls(since=None):
-    """``moe.gmm.calls`` as ``{(kernel, tile, divides)}``: the series that
-    moved since ``since`` (a call's own return), or every series' count."""
-    from deeplearning_cfn_tpu.obs.trace import get_tracer
-
-    series = get_tracer().registry.counter("moe.gmm.calls").series()
-    if since is None:
-        return series
-    return {tuple(dict(key)[label] for label in ("kernel", "tile", "divides"))
-            for key, n in series.items() if n > since.get(key, 0)}
-
-
-def test_laguna_step_compiles_and_fits_a_v5e(v5e_chip):
-    """The whole train step of ``laguna_xs2_train_4k`` at the cell's shapes
-    (``benchmark/rehearse_compile.py``, the builder's rehearsal): the chip's
-    compiler takes it, the flash kernels, the grouped matmuls and the rotary
-    kernels are in it, and arguments plus temporaries fit the chip's 16 GB.
-    PERF.md section 4 has the number."""
-    from deeplearning_cfn_tpu.obs.trace import get_tracer
-
-    manifest, rehearse_compile = _bench()
-
-    calls = get_tracer().registry.counter("attention.rope.calls")
-    before = {path: calls.value(path=path) for path in ("kernel", "xla")}
-    rows_before, gmm_before = _rows_calls(), _gmm_calls()
-    cell = manifest.Cell(manifest.load_manifest(), "laguna_xs2_train_4k")
-    _, compiled, _ = rehearse_compile.compile_step(cell)
-    # Each grouped matmul at the tile of its own shape and kernel
-    # (``models/moe.py:gmm_tile``), none padded: the usual buffer's 16,384
-    # rows in 256-row tiles, the contraction whole in ``gmm``; the second
-    # buffer's 65,536 the same but ``tgmm``'s rows, 512 (long groups).
-    assert _gmm_calls(gmm_before) == {
-        ("gmm", "256x2048x1024", "yes"), ("gmm", "256x512x2048", "yes"),
-        ("gmm_t", "256x1024x2048", "yes"), ("gmm_t", "256x2048x512", "yes"),
-        ("tgmm", "256x1024x1024", "yes"), ("tgmm", "256x512x2048", "yes"),
-        ("tgmm", "512x1024x1024", "yes"), ("tgmm", "512x512x2048", "yes")}
-    # Four expert layers, each traced twice, every one moving its rows by
-    # XLA's gathers under either buffer: 8,192 tokens of 2048 are a source
-    # of 33.5 MB, under the size from which the row kernel is the cheaper.
-    assert _rows_calls(rows_before) == {"gather": 8, "kernel": 0,
-                                        "scatter_add": 0}
-    # ``compile_step`` traces the model twice, once for the parameters'
-    # shapes and once in the step: each trace turns q and k of five layers.
-    assert {path: calls.value(path=path) - n
-            for path, n in before.items()} == {"kernel": 20, "xla": 0}
-    mem = compiled.memory_analysis()
-    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
-        + mem.output_size_in_bytes - mem.alias_size_in_bytes
-    assert 4e9 < total < 16e9, total
-    # 5 forward and 10 backward flash kernels, and the grouped matmuls.
-    text = compiled.as_text()
-    assert text.count("tpu_custom_call") > 15
-    assert "/moe_dispatch/" in text and "/moe_combine/" in text
-    assert _row_scatters(text) == [] and "live_rows" not in text
-    # q and k of five layers, turned forward and back by the kernel, which
-    # keeps the scope that ``blocks_ms`` counts it under.
-    kernels = [line for line in text.splitlines()
-               if 'custom_call_target="tpu_custom_call"' in line
-               and "/rope/" in line]
-    assert sum("rope_fwd" in line for line in kernels) == 10
-    assert sum("rope_bwd" in line for line in kernels) == 10
-    assert all("/self_attn/rope/" in line for line in kernels)
-
-
-def test_zaya1_step_compiles_and_fits_a_v5e(v5e_chip):
-    """The whole train step of ``zaya1_8b_train_4k`` at the cell's shapes:
-    Mosaic takes the flash and rotary kernels at 8 query heads over 2 K/V
-    heads of 128 (a group of 4), megablox its 8 groups of 2048 x 4096, the
-    latent mixing is in the step under its scope, and arguments plus
-    temporaries read under 15 GB of the chip's 16 (and over the quarter of
-    it a cell has to fill). PERF.md section 4 has the number."""
-    from deeplearning_cfn_tpu.obs.trace import get_tracer
-
-    manifest, rehearse_compile = _bench()
-
-    registry = get_tracer().registry
-    mixed = registry.counter("attention.cca.calls")
-    turned = registry.counter("attention.rope.calls")
-    before = (mixed.value(), turned.value(path="kernel"),
-              turned.value(path="xla"))
-    rows_before, gmm_before = _rows_calls(), _gmm_calls()
-    cell = manifest.Cell(manifest.load_manifest(), "zaya1_8b_train_4k")
-    _, compiled, _ = rehearse_compile.compile_step(cell)
-    # The one buffer of 8,192 rows: the contraction whole in ``gmm`` forward
-    # and transposed (4096 in the first product's backward), a result block
-    # of 1024 x 1024 in ``tgmm``; none padded.
-    assert _gmm_calls(gmm_before) == {
-        ("gmm", "256x2048x1024", "yes"), ("gmm_t", "256x4096x512", "yes"),
-        ("gmm_t", "256x2048x1024", "yes"), ("tgmm", "256x1024x1024", "yes")}
-    assert _rows_calls(rows_before) == {"gather": 10, "kernel": 0,
-                                        "scatter_add": 0}
-    # Traced twice (the parameters' shapes, the step), five layers each.
-    assert (mixed.value() - before[0], turned.value(path="kernel")
-            - before[1], turned.value(path="xla") - before[2]) == (10, 20, 0)
-    assert registry.gauge("moe.router.state_layers").value() == 4
-    mem = compiled.memory_analysis()
-    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
-        + mem.output_size_in_bytes - mem.alias_size_in_bytes
-    assert 4e9 < total < 15e9, total
-    text = compiled.as_text()
-    kernels = [line for line in text.splitlines()
-               if 'custom_call_target="tpu_custom_call"' in line]
-    flash = [line for line in kernels if "core_attention/flash_" in line]
-    assert len(flash) == 15
-    # K/V are not repeated to the query heads: 2 heads under 8.
-    assert all("bf16[2,2,4096,128]" in line and "bf16[2,8,4096,128]" in line
-               for line in flash)
-    rope = [line for line in kernels if "/self_attn/rope/" in line]
-    assert sum("rope_fwd" in line for line in rope) == 10
-    assert sum("rope_bwd" in line for line in rope) == 10
-    # Forward, the forward again (recomputed) and backward: eight grouped
-    # matmuls a layer, all under the scope the readers know.
-    assert sum("/moe_experts/jit(" in line and "/mlp/" in line
-               for line in kernels) == 40
-    assert "/self_attn/cca_mix/" in text and "/mlp/moe_router/" in text
-    assert "/moe_dispatch/" in text and "/moe_combine/" in text
-    assert _row_scatters(text) == [] and "live_rows" not in text
-
-
-def test_granite4h_step_compiles_and_fits_a_v5e(v5e_chip):
-    """The whole train step of ``granite4_h_micro_train_8k`` at the cell's
-    shapes: nine Mamba-2 mixers and one attention layer at 8,192 tokens,
-    every block recomputed in the backward pass. Mosaic takes the flash
-    kernels at 32 query heads over 8 K/V heads of 64 with no rotary kernel
-    beside them, and in every Mamba layer the scan's two kernels
-    (``ops/ssd.py``) and the convolution's two (``ops/conv.py``), forward,
-    recomputed and backward, with no decay matrix and no float32 ``[1, 8192,
-    4352]`` in HBM; the mixers' five scopes are in the text, forward,
-    recomputed and backward; no row is scattered inside a block; and 12.4 GB
-    of state with
-    one block's intermediates fit the chip, over the quarter of it a cell
-    has to fill. PERF.md section 4 has the number."""
-    import re
-
-    from deeplearning_cfn_tpu.obs.trace import get_tracer
-
-    manifest, rehearse_compile = _bench()
-    registry = get_tracer().registry
-    scans = registry.counter("ssm.scan.calls")
-    convs = registry.counter("ssm.conv.calls")
-    blocks = registry.counter("model.blocks.recomputed")
-    kept = registry.counter("model.blocks.kept_flash")
-    turned = registry.counter("attention.rope.calls")
-    scanned = lambda: tuple(scans.value(path=p, chunk="256")
-                            for p in ("kernel", "xla"))
-    convolved = lambda: tuple(convs.value(path=p) for p in ("kernel", "xla"))
-    before = (scanned(), blocks.value(),
-              turned.value(path="kernel") + turned.value(path="xla"),
-              kept.value(), convolved())
-    cell = manifest.Cell(manifest.load_manifest(),
-                         "granite4_h_micro_train_8k")
-    assert cell.chips == 1
-    _, compiled, _ = rehearse_compile.compile_step(cell)
-    # Traced twice (the parameters' shapes, the step): nine mixers, each
-    # through the scan's kernels and the convolution's, and ten recomputed
-    # blocks each; the backward pass traces nothing again, and nothing turns
-    # q or k.
-    assert (tuple(n - m for n, m in zip(scanned(), before[0])),
-            blocks.value() - before[1], turned.value(path="kernel")
-            + turned.value(path="xla") - before[2],
-            tuple(n - m for n, m in zip(convolved(), before[4]))) \
-        == ((18, 0), 20, 0, (18, 0))
-    # The attention block keeps its kernel's pair: [1, 32, 8192] rows of 64
-    # bfloat16 and a float32.
-    assert kept.value() - before[3] == 2
-    assert registry.gauge("model.blocks.kept_bytes").value() \
-        == 32 * 8192 * (64 * 2 + 4) == 34_603_008
-    assert registry.gauge("ssm.scan.chunks").value() == 32
-    assert registry.gauge("ssm.state_bytes").value() == 64 * 64 * 128 * 4
-    mem = compiled.memory_analysis()
-    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
-        + mem.output_size_in_bytes - mem.alias_size_in_bytes
-    assert 4 * 2 ** 30 < total < 15.75 * 2 ** 30, total
-    text = compiled.as_text()
-    kernels = [line for line in text.splitlines()
-               if 'custom_call_target="tpu_custom_call"' in line]
-    # The one attention layer: forward, dK/dV, dQ, the forward's output and
-    # row statistics kept across the recomputation (33.6 MB + 1 MB); K/V
-    # are not repeated to the query heads.
-    flash = [line for line in kernels if "/layer_5/" in line]
-    assert len(flash) == 3
-    assert all("core_attention/flash_" in line
-               and "rematted_computation" not in line
-               and "bf16[1,8,8192,64]" in line
-               and "bf16[1,32,8192,64]" in line for line in flash)
-    # Every Mamba layer's scan: the forward, the forward again that keeps
-    # the states (recomputed) and the backward, all under the scope the
-    # readers know; x is read as the projection left it, [B, S, H * P]. And
-    # its convolution the same way: the forward, the forward again and the
-    # backward under ``ssm_conv``, x, B and C written as the scan reads them.
-    mamba = [line for line in kernels if line not in flash]
-    assert len(mamba) == 54 and len(kernels) == 57
-    name_of = lambda line: re.search(r'op_name="([^"]*)"', line).group(1)
-    passes = lambda names, kernel: sorted(
-        ("rematted_computation" in name, "transpose(jvp" in name,
-         re.search(rf"/({kernel}_\w+)", name).group(1)) for name in names)
-    for layer in (0, 1, 2, 3, 4, 6, 7, 8, 9):
-        own = [name_of(line) for line in mamba if f"/layer_{layer}/" in line]
-        scan = [name for name in own if "/self_attn/ssm_scan/" in name]
-        conv = [name for name in own if "/self_attn/ssm_conv/" in name]
-        assert len(scan) == len(conv) == 3 and len(own) == 6, own
-        assert passes(scan, "ssd") == [
-            (False, False, "ssd_fwd"), (False, True, "ssd_bwd"),
-            (True, True, "ssd_fwd")], scan
-        assert passes(conv, "causal_conv") == [
-            (False, False, "causal_conv_fwd"),
-            (False, True, "causal_conv_bwd"),
-            (True, True, "causal_conv_fwd")], conv
-    assert all("bf16[1,8192,4096]" in line for line in mamba)
-    assert all("bf16[1,8192,4352]" in line for line in mamba
-               if "causal_conv_" in line)
-    # The convolution's float32 passes are gone with XLA's shifts.
-    assert "f32[1,8192,4352]" not in text
-    # The decay matrix of 64 heads never reaches HBM.
-    assert not re.search(r"f32\[[\d,]*,256,256\]", text)
-    for scope in ("ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm",
-                  "ssm_out_proj"):
-        for layer in (0, 4, 6, 9):
-            held = re.findall(rf'op_name="([^"]*/layer_{layer}/[^"]*'
-                              rf'/self_attn/{scope}/[^"]*)"', text)
-            assert any("transpose(jvp" not in name for name in held), scope
-            assert any("rematted_computation" in name for name in held), \
-                scope
-    assert "/layer_5/" in text and not re.search(
-        r"/layer_5/[^\"]*/self_attn/ssm_", text)
-    # What is scattered is the loss's one-hot and the embedding's gradient.
-    assert not [line.strip()[:200] for line in text.splitlines()
-                if re.search(r"= \S+ scatter\(", line)
-                and re.search(r"/layer_\d+/", line)]
 
 
 def test_sdar_step_compiles_and_fits_a_v5e(v5e_chip):
@@ -506,135 +122,3 @@ def test_sdar_step_compiles_and_fits_a_v5e(v5e_chip):
     for scope in ("bd_noise", "qk_norm", "moe_router", "lm_head", "lm_loss"):
         assert re.search(rf'op_name="[^"]*\b{scope}\b', text), scope
     assert not _row_scatters(text)
-
-
-def test_mellum2_step_compiles_for_four_v5e_chips(v5e_chip):
-    """The whole train step of ``mellum2_12b_train_8k_ep4`` at the cell's
-    shapes for the four chips of a described ``v5e:2x2`` on ``expert=4``:
-    Mosaic takes every Pallas call under its ``shard_map`` (flash, rotary,
-    megablox), the state and the temporaries fit a chip, the exchange's
-    collectives are in the text under their scopes and nothing else moves
-    rows of 2304 between chips, and no row is scattered. PERF.md section 4
-    has the number."""
-    import re
-
-    from deeplearning_cfn_tpu.obs.trace import get_tracer
-
-    manifest, rehearse_compile = _bench()
-    registry = get_tracer().registry
-    wrapped = registry.counter("parallel.shard_map.calls")
-    exchanged = registry.counter("moe.exchange.calls")
-    label = dict(path="all_gather", ranks="4")
-    before = {k: wrapped.value(kernel=k) for k in ("flash", "rope", "gmm")}
-    exchanges, rows_before = exchanged.value(**label), _rows_calls()
-    gmm_before = _gmm_calls()
-    cell = manifest.Cell(manifest.load_manifest(),
-                         "mellum2_12b_train_8k_ep4")
-    assert cell.chips == 4
-    _, compiled, _ = rehearse_compile.compile_step(cell)
-    # Four layers. The step is traced once on the mesh; the trace for the
-    # parameters' shapes initialises, which wraps nothing.
-    assert {k: wrapped.value(kernel=k) - n for k, n in before.items()} \
-        == {"flash": 4, "rope": 8, "gmm": 4}
-    assert exchanged.value(**label) - exchanges == 4
-    # A rank's 32,768 tokens of 2304 (its four ranks' after the exchange's
-    # gather) are a source of 151 MB: the step's four layers fetch their rows
-    # by the row kernel; the initialisation traces one row, by XLA's gather.
-    assert _rows_calls(rows_before) == {"gather": 4, "kernel": 4,
-                                        "scatter_add": 0}
-    # A rank's buffer of 131,072 rows in 16 groups at 2304, 1792 and 896:
-    # every kernel's tile divides what it is asked (``divides=yes``), each
-    # product's three kernels at a tile of their own.
-    assert _gmm_calls(gmm_before) == {
-        ("gmm", "256x2304x896", "yes"), ("gmm", "256x896x1152", "yes"),
-        ("gmm_t", "256x1792x1152", "yes"), ("gmm_t", "256x2304x896", "yes"),
-        ("tgmm", "512x1152x896", "yes"), ("tgmm", "512x896x1152", "yes")}
-    # A rank sends 3 x 8192 tokens of 2304 in bfloat16 and float32, twice.
-    assert registry.gauge("moe.exchange.bytes").value() \
-        == 2 * 3 * 8192 * 2304 * 6
-    mem = compiled.memory_analysis()
-    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
-        + mem.output_size_in_bytes - mem.alias_size_in_bytes
-    assert 4 * 2 ** 30 < total < 15.75 * 2 ** 30, total
-    # 595.1 M parameters a chip with Adam's two moments: the stacks are
-    # sharded, 16 experts a chip.
-    assert 7.1e9 < mem.argument_size_in_bytes < 7.2e9
-    text = compiled.as_text()
-    kernels = [line for line in text.splitlines()
-               if 'custom_call_target="tpu_custom_call"' in line]
-    flash = [line for line in kernels if "core_attention/flash_" in line]
-    assert len(flash) == 12 and all("/shard_map/" in line for line in flash)
-    # A sequence a chip, 4 K/V heads under 32, not repeated.
-    assert all("bf16[1,4,8192,128]" in line and "bf16[1,32,8192,128]" in line
-               for line in flash)
-    rope = [line for line in kernels if "/self_attn/rope/" in line]
-    assert sum("rope_fwd" in line for line in rope) == 8
-    assert sum("rope_bwd" in line for line in rope) == 8
-    assert any("/moe_experts/" in line and "/mlp/shard_map/" in line
-               for line in kernels)
-    # The row kernel, a layer, under the usual buffer (the second buffer's
-    # branch keeps XLA's gathers): the rows to the buffer, again where the
-    # backward pass recomputes them, and their cotangents back to the tokens
-    # under ``moe_dispatch``; the rows summed into their tokens and their
-    # cotangents under ``moe_combine`` (the recomputed sum is dead code).
-    # Inside the layer's own ``shard_map``: no new wrapper.
-    rows = [line for line in kernels
-            if re.search(r'op_name="[^"]*/live_rows/pallas_call"', line)]
-    assert all("/mlp/shard_map/" in line for line in rows)
-    assert sum("/moe_dispatch/" in line for line in rows) == 4 * 3
-    assert sum("/moe_combine/" in line for line in rows) == 4 * 2
-    assert len(rows) == 20 and len(kernels) == 92 + 20
-    assert _row_scatters(text, 2304) == []
-    moved = [line for line in text.splitlines() if re.search(
-        r"= \S*\[(1,)?(8192|32768),2304\]\S* (all-gather|reduce-scatter|"
-        r"all-reduce|all-to-all|collective-permute)(-start)?\(", line)]
-    assert moved and all(re.search(r"/moe_exchange_(in|out)/", line)
-                         for line in moved)
-    kinds = {(re.search(r"= (\w+)\[", line).group(1),
-              re.search(r"\]\S* (all-gather|reduce-scatter)", line).group(1),
-              re.search(r"moe_exchange_(in|out)", line).group(0),
-              "transpose(" in line) for line in moved}
-    # Forward: the tokens gathered in bfloat16, the parts summed in float32;
-    # backward, the transposes.
-    assert kinds == {("bf16", "all-gather", "moe_exchange_in", False),
-                     ("f32", "reduce-scatter", "moe_exchange_out", False),
-                     ("f32", "all-gather", "moe_exchange_out", True),
-                     ("bf16", "reduce-scatter", "moe_exchange_in", True)}
-    assert "all-to-all" not in text
-
-
-@pytest.mark.parametrize("name,rows,groups,k,n", [
-    ("mellum2_in", 131072, 16, 2304, 1792),
-    ("mellum2_out", 131072, 16, 896, 2304),
-    ("zaya1_in", 8192, 8, 2048, 4096), ("zaya1_out", 8192, 8, 2048, 2048),
-    ("laguna_in", 16384, 32, 2048, 1024),
-    ("laguna_out", 16384, 32, 512, 2048),
-    # Laguna's second buffer, of every pair: long groups, 512-row tiles.
-    ("laguna_every_pair_in", 65536, 32, 2048, 1024)])
-@pytest.mark.parametrize("what", ["forward", "grad"])
-def test_grouped_matmul_compiles_at_the_cells_widths(v5e_chip, name, rows,
-                                                     groups, k, n, what):
-    """megablox's ``gmm`` and, through ``models/moe.py:megablox_gmm``'s VJP,
-    the backward ``gmm`` and ``tgmm`` at a rank's shapes in the three expert
-    cells, each kernel with the tile ``gmm_tile`` chooses for it: Mellum2's
-    131,072 buffer rows in 16 groups at widths no power of two divides (2304
-    = 18 x 128, 1792 = 14 x 128, 896 = 7 x 128), ZAYA1's 8,192 in 8, Laguna's
-    16,384 in 32. Mosaic takes each (VMEM), and the VJP adds no kernel."""
-    from deeplearning_cfn_tpu.models.moe import grouped_matmul
-
-    sharding = SingleDeviceSharding(v5e_chip)
-    lhs = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=sharding)
-    rhs = jax.ShapeDtypeStruct((groups, k, n), jnp.bfloat16,
-                               sharding=sharding)
-    sizes = jax.ShapeDtypeStruct((groups,), jnp.int32, sharding=sharding)
-    f = lambda a, b, s: grouped_matmul(a, b, s, "megablox")
-    if what == "grad":
-        f = jax.grad(lambda a, b, s: jnp.sum(grouped_matmul(
-            a, b, s, "megablox").astype(jnp.float32)), argnums=(0, 1))
-    since = _gmm_calls()
-    text = jax.jit(f).lower(lhs, rhs, sizes).compile().as_text()
-    assert text.count("tpu_custom_call") == (1 if what == "forward" else 2)
-    calls = _gmm_calls(since)
-    assert {kernel for kernel, _, _ in calls} == (
-        {"gmm"} if what == "forward" else {"gmm", "gmm_t", "tgmm"})
-    assert all(divides == "yes" for _, _, divides in calls)

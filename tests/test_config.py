@@ -41,13 +41,13 @@ def test_overrides_scalar_types():
     apply_overrides(cfg, [
         "train.global_batch=256",
         "schedule.base_lr=0.5",
-        "train.remat=true",
+        "train.shard_opt_state=true",
         "model.name=resnet50",
         "mesh.model=2",
     ])
     assert cfg.train.global_batch == 256
     assert cfg.schedule.base_lr == 0.5
-    assert cfg.train.remat is True
+    assert cfg.train.shard_opt_state is True
     assert cfg.model.name == "resnet50"
     assert cfg.mesh.model == 2
 
@@ -64,6 +64,8 @@ def test_overrides_unknown_key_raises():
     cfg = ExperimentConfig()
     with pytest.raises(KeyError):
         apply_overrides(cfg, ["train.nonexistent=1"])
+    with pytest.raises(KeyError):  # gone at PR 46: BlockStyle.remat is the way
+        apply_overrides(cfg, ["train.remat=true"])
     with pytest.raises(KeyError):
         apply_overrides(cfg, ["nosection.x=1"])
     with pytest.raises(ValueError):

@@ -164,9 +164,13 @@ def test_multilevel_roi_align_matches_dense_reference():
             levels, jnp.int32)[:, None]).astype(stacked.dtype)
         return jnp.einsum("lnhwc,ln->nhwc", stacked, sel)
 
+    # Each side one compiled program: run operation by operation, the two
+    # are some 230 tiny programs to compile.
     for out_size in (7, 14):
-        got = multilevel_roi_align(feats, boxes, out_size, strides)
-        want = dense_reference(feats, boxes, out_size)
+        got = jax.jit(lambda f, b: multilevel_roi_align(
+            f, b, out_size, strides))(feats, boxes)
+        want = jax.jit(lambda f, b: dense_reference(f, b, out_size))(
+            feats, boxes)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=1e-5)
 
@@ -177,8 +181,8 @@ def test_multilevel_roi_align_matches_dense_reference():
     def loss_ref(f):
         return jnp.sum(dense_reference(f, boxes, 7) ** 2)
 
-    g_new = jax.grad(loss_new)(feats)
-    g_ref = jax.grad(loss_ref)(feats)
+    g_new = jax.jit(jax.grad(loss_new))(feats)
+    g_ref = jax.jit(jax.grad(loss_ref))(feats)
     for lvl in feats:
         np.testing.assert_allclose(np.asarray(g_new[lvl]),
                                    np.asarray(g_ref[lvl]),
@@ -282,8 +286,7 @@ def test_deconv_to_upsample_conversion():
     SAME ConvTranspose and Dense(converted weights) + MaskHead's
     depth-to-space must agree to f32 rounding. flax ConvTranspose puts
     kernel tap (a, b) at output offset (1-a, 1-b), so the conversion must
-    flip both spatial axes — the unflipped formula swaps every 2×2 block
-    (ADVICE r4)."""
+    flip both spatial axes — the unflipped formula swaps every 2×2 block."""
     import flax.linen as nn
 
     from deeplearning_cfn_tpu.models.maskrcnn import convert_deconv_to_upsample

@@ -153,8 +153,9 @@ def test_scan_kernels_run_a_device_each_under_the_wrapper(devices):
         loss(implementation="interpret", mesh=mesh),
         argnums=(0, 1, 2, 3, 4)))(x, dt, a, b, c)
     assert calls.value(kernel="ssd") == before + 1
-    want = jax.value_and_grad(loss(implementation="reference"),
-                              argnums=(0, 1, 2, 3, 4))(x, dt, a, b, c)
+    want = jax.jit(jax.value_and_grad(
+        loss(implementation="reference"),
+        argnums=(0, 1, 2, 3, 4)))(x, dt, a, b, c)
     for g, w in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         scale = float(jnp.max(jnp.abs(w)))
@@ -427,8 +428,8 @@ def test_step_on_four_ranks_is_the_step_on_one_device(devices, impl):
     four, records4 = _mellum_tiny_steps(devices, 4, impl)
     made = {k: wrapped.value(kernel=k) - n for k, n in before.items()}
     # Four layers, one trace of the step; a head of 16 is no lane tile, so
-    # the rotary kernel is not taken here (tests/test_chip_compile.py has it
-    # at the real size).
+    # the rotary kernel is not taken here (tests/test_chip_compile_laguna_zaya1.py
+    # has it at the real size).
     assert made == {"flash": 4 if impl == "interpret" else 0, "rope": 0,
                     "gmm": 4}
     one, records1 = _mellum_tiny_steps(devices, 1, impl)

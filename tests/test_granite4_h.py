@@ -397,7 +397,7 @@ def test_preset_is_the_chips_share_at_published_widths():
     cfg = get_preset("granite4_h_micro_lm")
     assert (cfg.data.seq_len, cfg.train.global_batch,
             cfg.data.vocab_size) == (8192, 1, 12_544)
-    assert cfg.model.kwargs["remat_blocks"] and not cfg.train.remat
+    assert cfg.model.kwargs["remat_blocks"]
     task = build_task(cfg)
     shapes = jax.eval_shape(task.init, jax.random.PRNGKey(0))["params"]
     count = lambda tree: sum(int(np.prod(s.shape))

@@ -23,6 +23,14 @@ from deeplearning_cfn_tpu.models import build_model
 from deeplearning_cfn_tpu.train.run import run_experiment
 
 
+def _init(model, ids):
+    """The model's variables from key 0, made by one compiled program: run
+    eagerly, every initializer and every operation of the forward pass is a
+    program of its own to compile."""
+    return jax.jit(lambda key, ids: model.init(key, ids, train=False))(
+        jax.random.PRNGKey(0), ids)
+
+
 def test_lm_source_invariants():
     src = make_lm_source(64, seq_len=16, vocab_size=32, seed=0)
     batch = src.gather(np.arange(64))
@@ -69,7 +77,7 @@ def test_lm_is_causal():
     model = build_model("gpt_tiny", 0, jnp.float32, vocab_size=32,
                         max_len=16, dropout_rate=0.0)
     ids = jnp.arange(12, dtype=jnp.int32)[None, :] % 32
-    variables = model.init(jax.random.PRNGKey(0), ids, train=False)
+    variables = _init(model, ids)
     base = model.apply(variables, ids, train=False)
     bumped = ids.at[0, 8].set((ids[0, 8] + 7) % 32)
     out = model.apply(variables, bumped, train=False)
@@ -93,7 +101,7 @@ def test_lm_kv_cache_decode_matches_full_forward(num_experts):
     T = 10
     ids = (jax.random.randint(jax.random.PRNGKey(1), (1, T), 0, 32)
            .astype(jnp.int32))
-    variables = model.init(jax.random.PRNGKey(0), ids, train=False)
+    variables = _init(model, ids)
     full = model.apply(variables, ids, train=False)
     if num_experts:
         full = full[0]  # (logits, moe_aux) when MoE layers exist
@@ -161,7 +169,7 @@ def test_lm_generate_greedy_matches_manual_rollout():
     model = build_model("gpt_tiny", 0, jnp.float32, vocab_size=32,
                         max_len=16, dropout_rate=0.0)
     prompt = jnp.array([[5, 9, 3], [1, 2, 7]], jnp.int32)
-    variables = model.init(jax.random.PRNGKey(0), prompt, train=False)
+    variables = _init(model, prompt)
 
     out = lm_generate(model, variables, prompt, max_new_tokens=6)
     assert out.shape == (2, 9)
@@ -169,8 +177,9 @@ def test_lm_generate_greedy_matches_manual_rollout():
                                   np.asarray(prompt))
 
     manual = prompt
+    apply = jax.jit(lambda v, ids: model.apply(v, ids, train=False))
     for _ in range(6):
-        logits = model.apply(variables, manual, train=False)
+        logits = apply(variables, manual)
         nxt = jnp.argmax(logits[:, -1, :], -1).astype(jnp.int32)
         manual = jnp.concatenate([manual, nxt[:, None]], axis=1)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(manual))
@@ -186,11 +195,12 @@ def test_lm_generate_recompute_fallback_for_gpt_long():
                         mlp_dim=64, max_len=16)
     assert not hasattr(type(model), "decode_step")
     prompt = jnp.array([[3, 7, 1]], jnp.int32)
-    variables = model.init(jax.random.PRNGKey(0), prompt, train=False)
+    variables = _init(model, prompt)
     out = lm_generate(model, variables, prompt, max_new_tokens=5)
     manual = prompt
+    apply = jax.jit(lambda v, ids: model.apply(v, ids, train=False))
     for _ in range(5):
-        logits = model.apply(variables, manual, train=False)
+        logits = apply(variables, manual)
         nxt = jnp.argmax(logits[:, -1, :], -1).astype(jnp.int32)
         manual = jnp.concatenate([manual, nxt[:, None]], axis=1)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(manual))
@@ -202,7 +212,7 @@ def test_lm_generate_sampling_is_seeded_and_in_vocab():
     model = build_model("gpt_tiny", 0, jnp.float32, vocab_size=32,
                         max_len=16, dropout_rate=0.0)
     prompt = jnp.array([[4, 8]], jnp.int32)
-    variables = model.init(jax.random.PRNGKey(0), prompt, train=False)
+    variables = _init(model, prompt)
     a = lm_generate(model, variables, prompt, 5, temperature=1.0,
                     top_k=8, rng=jax.random.PRNGKey(7))
     b = lm_generate(model, variables, prompt, 5, temperature=1.0,
@@ -355,7 +365,7 @@ def test_lm_tensor_parallel_shards_kernels(tmp_workdir, devices):
 def _zaya1_tiny(**kw):
     model = build_model("gpt_zaya1_tiny", 0, jnp.float32, **kw)
     ids = (jnp.arange(2 * 32, dtype=jnp.int32).reshape(2, 32) * 7) % 96
-    return model, ids, model.init(jax.random.PRNGKey(0), ids)
+    return model, ids, jax.jit(model.init)(jax.random.PRNGKey(0), ids)
 
 
 def test_zaya1_is_causal():
@@ -363,9 +373,10 @@ def test_zaya1_is_causal():
     the value shift look one position back and none forward, and a token's
     one expert is chosen from the token alone."""
     model, ids, variables = _zaya1_tiny()
-    base, _ = model.apply(variables, ids)
+    apply = jax.jit(model.apply)
+    base, _ = apply(variables, ids)
     bumped = ids.at[0, 20].set((ids[0, 20] + 11) % 96)
-    out, _ = model.apply(variables, bumped)
+    out, _ = apply(variables, bumped)
     np.testing.assert_array_equal(np.asarray(base[0, :20]),
                                   np.asarray(out[0, :20]))
     np.testing.assert_array_equal(np.asarray(base[1]), np.asarray(out[1]))
@@ -399,8 +410,8 @@ def test_zaya1_carries_the_router_state_from_block_to_block():
     layer = TransformerLayer(heads, width, dtype=jnp.float32, style=style)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 64))
     state = jax.random.normal(jax.random.PRNGKey(2), (2, 32, 16))
-    call = lambda r: layer.apply({"params": params["layer_1"]}, x,
-                                 causal=True, router_state=r)
+    call = jax.jit(lambda r: layer.apply({"params": params["layer_1"]}, x,
+                                         causal=True, router_state=r))
     given, aux = call(state)
     zeros, aux0 = call(jnp.zeros_like(state))
     assert aux["router_state"].shape == (2, 32, 16)
@@ -428,8 +439,8 @@ def test_zaya1_flash_path_matches_the_xla_path_at_published_heads():
             num_kv_heads=z["kv_heads"], head_dim=z["head_dim"],
             rope=z["rope"], latent_mix=z["latent_mix"]))
     x = jax.random.normal(jax.random.PRNGKey(3), (1, 512, 256))
-    params = attention("reference").init(jax.random.PRNGKey(4), x,
-                                         causal=True)
+    params = jax.jit(lambda key, x: attention("reference").init(
+        key, x, causal=True))(jax.random.PRNGKey(4), x)
     assert params["params"]["conv_heads"]["kernel"].shape == (256, 1280)
     assert params["params"]["value_prev"]["kernel"].shape == (256, 128)
 
@@ -437,10 +448,10 @@ def test_zaya1_flash_path_matches_the_xla_path_at_published_heads():
         out = attention(implementation).apply(params, x, causal=True)
         return jnp.sum(out.astype(jnp.float32) ** 2), out
 
-    (_, got), got_grad = jax.value_and_grad(loss, has_aux=True)(
-        params, "interpret")
-    (_, want), want_grad = jax.value_and_grad(loss, has_aux=True)(
-        params, "reference")
+    value_and_grad = jax.jit(jax.value_and_grad(loss, has_aux=True),
+                             static_argnums=1)
+    (_, got), got_grad = value_and_grad(params, "interpret")
+    (_, want), want_grad = value_and_grad(params, "reference")
     # bf16 outputs of size up to 4: an ulp there is 0.03.
     np.testing.assert_allclose(got.astype(jnp.float32),
                                want.astype(jnp.float32), atol=2e-2, rtol=2e-2)
@@ -540,7 +551,8 @@ def test_zaya1_train_step_moves_the_balancing_biases(devices, accum):
     batch = {"tokens": jnp.asarray(tokens),
              "loss_mask": jnp.ones((4, 32), jnp.float32)}
     start = jax.device_get(state.params)
-    _, aux = task.loss_fn(state.params, {}, batch, None, True)
+    _, aux = jax.jit(lambda params: task.loss_fn(
+        params, {}, batch, None, True))(state.params)
     trainer = Trainer(cfg, task.loss_fn, tx, mesh=mesh, donate=False)
     new, metrics = trainer.train_step(state, batch, jax.random.PRNGKey(1))
     assert "nudges" not in metrics
@@ -556,8 +568,8 @@ def test_zaya1_train_step_moves_the_balancing_biases(devices, accum):
                     aux["nudges"][f"layer_{i}"]["mlp"]["router"]["bias"]),
                 atol=1e-7)
     # An evaluation sows nothing.
-    assert "nudges" not in task.loss_fn(state.params, {}, batch, None,
-                                        False)[1]
+    assert "nudges" not in jax.eval_shape(lambda params: task.loss_fn(
+        params, {}, batch, None, False), state.params)[1]
 
 
 def test_nudges_for_no_parameter_are_an_error():

@@ -99,8 +99,9 @@ def test_seq_attention_actually_parallel(impl, collective, devices):
                         batch_axes="data")
     ids = jnp.zeros((8, 32), jnp.int32)
     pos = jnp.zeros((8, 4), jnp.int32)
-    variables = model.init(jax.random.PRNGKey(0), ids, jnp.ones_like(ids),
-                           ids, pos, train=False)
+    variables = jax.jit(lambda key: model.init(
+        key, ids, jnp.ones_like(ids), ids, pos, train=False))(
+            jax.random.PRNGKey(0))
 
     fwd = lambda v: model.apply(v, ids, jnp.ones_like(ids), ids, pos,
                                 train=False)

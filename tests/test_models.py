@@ -12,11 +12,18 @@ def _param_count(params):
     return sum(np.prod(p.shape) for p in jax.tree_util.tree_leaves(params))
 
 
+def _init(model, x):
+    """The model's variables from key 0, as a function to compile
+    (``jax.jit``) or only to trace (``jax.eval_shape``): called as it is,
+    every operation of it is a program of its own to compile."""
+    return lambda: model.init(jax.random.PRNGKey(0), x, train=False)
+
+
 def test_resnet20_shapes_and_params():
     model = build_model("resnet20", num_classes=10, dtype=jnp.float32)
     x = jnp.zeros((2, 32, 32, 3))
-    variables = model.init(jax.random.PRNGKey(0), x, train=False)
-    logits = model.apply(variables, x, train=False)
+    variables = jax.jit(_init(model, x))()
+    logits = jax.jit(lambda v: model.apply(v, x, train=False))(variables)
     assert logits.shape == (2, 10)
     assert logits.dtype == jnp.float32
     # He et al. ResNet-20 is ~0.27M params.
@@ -27,11 +34,13 @@ def test_resnet20_shapes_and_params():
 def test_resnet50_shapes_and_params():
     model = build_model("resnet50", num_classes=1000, dtype=jnp.bfloat16)
     x = jnp.zeros((1, 64, 64, 3))  # small spatial for test speed
-    variables = model.init(jax.random.PRNGKey(0), x, train=False)
+    # Shapes and dtypes are all this asks, so nothing is computed.
+    variables = jax.eval_shape(_init(model, x))
     n = _param_count(variables["params"])
     # Canonical ResNet-50 ≈ 25.6M params.
     assert 24e6 < n < 27e6, n
-    logits = model.apply(variables, x, train=False)
+    logits = jax.eval_shape(lambda v: model.apply(v, x, train=False),
+                            variables)
     assert logits.shape == (1, 1000)
     assert logits.dtype == jnp.float32  # head forced to f32
 
@@ -55,10 +64,11 @@ def test_resnet50_s2d_stem():
     # stem (downstream stages are identical) with a 4×4×12 stem kernel.
     model = build_model("resnet50_s2d", num_classes=1000, dtype=jnp.bfloat16)
     x = jnp.zeros((1, 64, 64, 3))
-    variables = model.init(jax.random.PRNGKey(0), x, train=False)
+    variables = jax.eval_shape(_init(model, x))
     stem_kernel = variables["params"]["conv_init_s2d"]["kernel"]
     assert stem_kernel.shape == (4, 4, 12, 64), stem_kernel.shape
-    logits = model.apply(variables, x, train=False)
+    logits = jax.eval_shape(lambda v: model.apply(v, x, train=False),
+                            variables)
     assert logits.shape == (1, 1000)
     n = _param_count(variables["params"])
     assert 24e6 < n < 27e6, n  # same ballpark as classic resnet50
@@ -67,9 +77,9 @@ def test_resnet50_s2d_stem():
 def test_batchnorm_stats_update():
     model = build_model("resnet20", num_classes=10, dtype=jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 32, 32, 3))
-    variables = model.init(jax.random.PRNGKey(0), x, train=False)
-    _, mutated = model.apply(variables, x, train=True,
-                             mutable=["batch_stats"])
+    variables = jax.jit(_init(model, x))()
+    _, mutated = jax.jit(lambda v: model.apply(
+        v, x, train=True, mutable=["batch_stats"]))(variables)
     before = jax.tree_util.tree_leaves(variables["batch_stats"])
     after = jax.tree_util.tree_leaves(mutated["batch_stats"])
     assert any(not np.allclose(np.asarray(b), np.asarray(a))
@@ -79,7 +89,7 @@ def test_batchnorm_stats_update():
 def test_bn_params_stay_f32_under_bf16():
     model = build_model("resnet50", num_classes=10, dtype=jnp.bfloat16)
     x = jnp.zeros((1, 32, 32, 3))
-    variables = model.init(jax.random.PRNGKey(0), x, train=False)
+    variables = jax.eval_shape(_init(model, x))
     flat = jax.tree_util.tree_leaves_with_path(variables["params"])
     for path, leaf in flat:
         assert leaf.dtype == jnp.float32, path

@@ -66,8 +66,8 @@ def test_moe_matches_dense_per_token(top_k):
     moe = MoeMlp(num_experts=e, mlp_dim=m, capacity_factor=float(e),
                  top_k=top_k, dtype=jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(0), (b, s, f), jnp.float32)
-    variables = moe.init(jax.random.PRNGKey(1), x)
-    y, aux = moe.apply(variables, x)
+    variables = jax.jit(moe.init)(jax.random.PRNGKey(1), x)
+    y, aux = jax.jit(moe.apply)(variables, x)
     p = variables["params"]
 
     logits = x @ np.asarray(p["router"]["kernel"])
@@ -263,21 +263,21 @@ def test_held_experts_layer_keeps_its_first_routers_names():
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 16, 32))
     first = HeldExpertsMlp(num_experts=8, mlp_dim=16, top_k=2, held=(0, 4),
                            routed_scale=2.5, shared_dim=8, dtype=jnp.float32)
-    params = first.init(jax.random.PRNGKey(1), x)["params"]
+    params = jax.jit(first.init)(jax.random.PRNGKey(1), x)["params"]
     assert set(params) == {"router", "experts_in", "experts_out", "shared"}
     assert params["router"]["kernel"].shape == (32, 8)
-    out, aux = first.apply({"params": params}, x)
+    out, aux = jax.jit(first.apply)({"params": params}, x)
     assert set(aux) == {"rows_held", "load_max_over_mean"}
 
     second = HeldExpertsMlp(num_experts=8, mlp_dim=16, held=(0, 4),
                             dtype=jnp.float32,
                             router=MlpStateRouter(8, 16))
     state = jnp.ones((2, 16, 16))
-    params = second.init(jax.random.PRNGKey(1), x, state)["params"]
+    params = jax.jit(second.init)(jax.random.PRNGKey(1), x, state)["params"]
     assert set(params) == {"router", "experts_in", "experts_out"}
     assert set(params["router"]) == {"down", "scale", "norm", "hidden_0",
                                      "hidden_1", "out", "bias"}
-    out, aux = second.apply({"params": params}, x, state)
+    out, aux = jax.jit(second.apply)({"params": params}, x, state)
     assert aux["router_state"].shape == (2, 16, 16)
     assert 0 <= float(aux["rows_held"]) <= 32
     # One choice a token and half of the experts held: twice a uniform

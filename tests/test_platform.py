@@ -46,11 +46,43 @@ def test_compile_cache_defaults_to_fixed_path_in_checkout(
         assert ".jax_cache/" in fh.read().split()
 
 
-def test_suite_runs_with_the_compile_cache_off():
-    """tests/conftest.py: tier-1 neither grows a cache in the checkout nor
-    changes its timing — in this process and in the children it starts."""
-    assert jax.config.jax_enable_compilation_cache is False
+def test_suite_shares_one_compile_cache_outside_the_checkout(cache_config):
+    """tests/conftest.py: the test process compiles into the session's one
+    directory, which is not in the checkout (``.jax_cache`` is the program's
+    own, and a test that calls ``main()`` in this process points jax's
+    option at it without moving the open cache); the children a test starts
+    run with the cache off."""
+    assert jax.config.jax_enable_compilation_cache is True
+    cache = os.environ["DLCFN_TEST_SESSION_COMPILE_CACHE"]
+    assert os.path.isdir(cache)
+    assert os.path.commonpath([cache, REPO_ROOT]) != REPO_ROOT
+    platform.configure_compile_cache()  # as an in-process ``main()`` does
+
+    def probe(x):
+        return x * float(os.getpid())
+
+    probe.__name__ = f"probe_of_worker_{os.getpid()}"  # jax names the entry
+    jax.jit(probe)(1.0)
+    assert any(name.startswith(f"jit_{probe.__name__}-")
+               for name in os.listdir(cache))
     assert os.environ["JAX_ENABLE_COMPILATION_CACHE"] == "false"
+
+
+def test_the_same_program_built_twice_is_compiled_once():
+    """What the session's cache is for: a second jit of the same tiny
+    function is a new Python object, so jax's in-memory cache misses, and
+    the persistent one answers."""
+    from deeplearning_cfn_tpu.runtime import jit_events
+
+    jit_events.install()
+    salt = float(int.from_bytes(os.urandom(3), "little"))
+    build = lambda: jax.jit(lambda x: x * salt + 1.0)
+    assert build()(2.0) == 2.0 * salt + 1.0
+    before = jit_events.totals("")
+    assert build()(2.0) == 2.0 * salt + 1.0
+    after = jit_events.totals("")
+    assert (after["cache_requests"] - before["cache_requests"],
+            after["cache_hits"] - before["cache_hits"]) == (1, 1)
 
 
 @pytest.mark.parametrize("accelerator,env,want", [

@@ -2,7 +2,7 @@
 is: ``out[i] = sum over j < count[i] of weight[i, j] * src[idx[i, j]]``, at
 one and eight slots a row, at the edges of its tiles and of its SMEM blocks,
 in bfloat16 (a row is half of a pair's words) and float32. What the chip's
-compiler makes of it is ``tests/test_chip_compile.py``'s."""
+compiler makes of it is ``tests/test_chip_compile*.py``'s."""
 
 import jax
 import jax.numpy as jnp

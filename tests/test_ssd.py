@@ -482,12 +482,12 @@ def test_mixer_is_the_same_function_through_the_kernels():
     u = jax.random.normal(jax.random.PRNGKey(0), (2, 256, 32))
     by = {impl: _mixer(**KERNEL_MIXER, scan_impl=impl)
           for impl in ("interpret", "reference")}
-    params = by["reference"].init(jax.random.PRNGKey(1), u)
+    params = jax.jit(by["reference"].init)(jax.random.PRNGKey(1), u)
     w = jax.random.normal(jax.random.PRNGKey(2), u.shape)
     loss = lambda impl: lambda p: jnp.sum(by[impl].apply(p, u) * w)
-    _close(by["interpret"].apply(params, u), by["reference"].apply(params, u),
-           "mixer", tol=2e-5)
-    got, want = (jax.grad(loss(impl))(params)
+    _close(jax.jit(by["interpret"].apply)(params, u),
+           jax.jit(by["reference"].apply)(params, u), "mixer", tol=2e-5)
+    got, want = (jax.jit(jax.grad(loss(impl)))(params)
                  for impl in ("interpret", "reference"))
     for (path, g), t in zip(jax.tree_util.tree_leaves_with_path(got),
                             jax.tree_util.tree_leaves(want)):

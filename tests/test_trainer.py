@@ -282,13 +282,6 @@ def test_profile_steps_captures_trace(tmp_workdir, devices):
     assert files, f"no trace files under {trace_root}"
 
 
-def test_remat_flag_trains(tmp_workdir, devices):
-    cfg = _tiny_cfg(tmp_workdir, steps=2)
-    apply_overrides(cfg, ["train.remat=true"])
-    final = run_experiment(cfg)
-    assert np.isfinite(final["loss"])
-
-
 def test_exact_eval_counts_every_example(tmp_workdir, devices):
     """The eval set does not divide the eval batch (70 % 32 != 0): with the
     padded-tail pipeline the trainer must still count ALL 70 examples, and

@@ -72,11 +72,11 @@ def test_bert_tiny_forward_shapes():
     model = build_model("bert_tiny", num_classes=2, dtype=jnp.float32)
     s, p = 32, 6
     ids = jnp.zeros((2, s), jnp.int32)
-    variables = model.init(jax.random.PRNGKey(0), ids,
-                           jnp.ones((2, s), jnp.int32), ids,
-                           jnp.zeros((2, p), jnp.int32), train=False)
-    out = model.apply(variables, ids, jnp.ones((2, s), jnp.int32), ids,
-                      jnp.zeros((2, p), jnp.int32), train=False)
+    args = (ids, jnp.ones((2, s), jnp.int32), ids,
+            jnp.zeros((2, p), jnp.int32))
+    variables = jax.jit(lambda key: model.init(key, *args, train=False))(
+        jax.random.PRNGKey(0))
+    out = jax.jit(lambda v: model.apply(v, *args, train=False))(variables)
     assert out["mlm_logits"].shape == (2, p, 512)
     assert out["nsp_logits"].shape == (2, 2)
 
@@ -86,10 +86,10 @@ def test_nmt_tiny_forward_shapes():
                         dtype=jnp.float32)
     s = 16
     ids = jnp.zeros((2, s), jnp.int32)
-    variables = model.init(jax.random.PRNGKey(0), ids,
-                           jnp.ones((2, s), jnp.int32), ids, train=False)
-    logits = model.apply(variables, ids, jnp.ones((2, s), jnp.int32), ids,
-                         train=False)
+    args = (ids, jnp.ones((2, s), jnp.int32), ids)
+    variables = jax.jit(lambda key: model.init(key, *args, train=False))(
+        jax.random.PRNGKey(0))
+    logits = jax.jit(lambda v: model.apply(v, *args, train=False))(variables)
     assert logits.shape == (2, s, 128)
 
 
@@ -129,11 +129,12 @@ def test_bert_dropout_trains():
         train=TrainConfig(dtype="float32"),
     )
     task = build_task(cfg)
-    variables = task.init(jax.random.PRNGKey(0))
+    variables = jax.jit(task.init)(jax.random.PRNGKey(0))
     src = make_mlm_source(8, 32, 64, seed=0)
     batch = {k: jnp.asarray(v) for k, v in src.arrays.items()}
-    loss, aux = task.loss_fn(variables["params"], {}, batch,
-                             jax.random.PRNGKey(1), True)
+    loss, aux = jax.jit(lambda params, key: task.loss_fn(
+        params, {}, batch, key, True))(variables["params"],
+                                       jax.random.PRNGKey(1))
     assert jnp.isfinite(loss)
 
 

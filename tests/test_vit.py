@@ -25,16 +25,20 @@ from deeplearning_cfn_tpu.train.run import run_experiment
 def test_vit_shapes_and_params():
     model = build_model("vit_s16", num_classes=1000, dtype=jnp.bfloat16)
     x = jnp.zeros((2, 224, 224, 3))
-    variables = model.init(jax.random.PRNGKey(0), x, train=False)
+    # Shapes and dtypes are all this asks, so nothing is computed.
+    variables = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), x, train=False))
     n = sum(int(np.prod(p.shape)) for p in
             jax.tree_util.tree_leaves(variables["params"]))
     assert 20e6 < n < 24e6, n  # ViT-S/16 ≈ 22M
-    logits = model.apply(variables, x, train=False)
+    logits = jax.eval_shape(lambda v: model.apply(v, x, train=False),
+                            variables)
     assert logits.shape == (2, 1000)
     assert logits.dtype == jnp.float32
 
     with pytest.raises(ValueError, match="divisible"):
-        model.apply(variables, jnp.zeros((1, 100, 100, 3)), train=False)
+        jax.eval_shape(lambda v: model.apply(
+            v, jnp.zeros((1, 100, 100, 3)), train=False), variables)
 
 
 def test_vit_dropout_active_in_train_mode():
@@ -50,7 +54,7 @@ def test_vit_dropout_active_in_train_mode():
         train=TrainConfig(dtype="float32"),
     )
     task = ClassificationTask(cfg)
-    variables = task.init(jax.random.PRNGKey(0))
+    variables = jax.jit(task.init)(jax.random.PRNGKey(0))
     # The head kernel is zero-init (logits would be constant and hide the
     # dropout noise) — randomize it for this test.
     params = jax.tree_util.tree_map(lambda x: x, variables["params"])
@@ -59,12 +63,14 @@ def test_vit_dropout_active_in_train_mode():
     variables = {"params": params}
     batch = {"image": jnp.ones((4, 32, 32, 3)),
              "label": jnp.zeros((4,), jnp.int32)}
-    l1, _ = task.loss_fn(variables["params"], {}, batch,
-                         jax.random.PRNGKey(1), True)
-    l2, _ = task.loss_fn(variables["params"], {}, batch,
-                         jax.random.PRNGKey(2), True)
-    l_eval1, _ = task.loss_fn(variables["params"], {}, batch, None, False)
-    l_eval2, _ = task.loss_fn(variables["params"], {}, batch, None, False)
+    loss_fn = jax.jit(lambda params, key: task.loss_fn(
+        params, {}, batch, key, True))
+    l1, _ = loss_fn(variables["params"], jax.random.PRNGKey(1))
+    l2, _ = loss_fn(variables["params"], jax.random.PRNGKey(2))
+    eval_fn = jax.jit(lambda params: task.loss_fn(
+        params, {}, batch, None, False))
+    l_eval1, _ = eval_fn(variables["params"])
+    l_eval2, _ = eval_fn(variables["params"])
     assert float(l1) != float(l2)  # dropout noise differs by rng
     assert float(l_eval1) == float(l_eval2)  # eval is deterministic
 
