@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from ..obs.trace import get_tracer
 from ..ops.attention import FLASH_LSE, FLASH_OUT, flash_kept_bytes
+from ..ops.sparse_index import INDEX_GRADS, INDEX_SELECTED, INDEX_STATS
 from . import register_model
 from .moe import MOE_PARAM_RULES
 from .transformer import (
@@ -122,11 +123,14 @@ class TransformerCausalLm(nn.Module):
             # Python: static under the recomputation.
             # What a recomputed block keeps beside its input: its flash
             # forward kernel's output and row statistics, so the backward
-            # pass computes q, k and v again and not the kernel.
+            # pass computes q, k and v again and not the kernel; of an
+            # indexer, its selection and what its loss's pass left for the
+            # backward pass (ops/sparse_index.py), for the same reason.
             recomputed = nn.remat(
                 TransformerLayer, static_argnums=(5,),
                 policy=jax.checkpoint_policies.save_only_these_names(
-                    FLASH_OUT, FLASH_LSE))
+                    FLASH_OUT, FLASH_LSE, INDEX_SELECTED, INDEX_STATS,
+                    INDEX_GRADS))
             if any(style.remat and style.mlp == "experts" and style.router
                    and dict(style.router).get("kind", "mlp_state")
                    == "mlp_state" for _, _, _, style in self.blocks):
@@ -192,7 +196,7 @@ class TransformerCausalLm(nn.Module):
             if style.remat:
                 # Positional: x, enc, self_bias, cross_bias, causal.
                 x = lyr(x, None, None, None, True, layout=layout)
-                if style.mlp == "experts":
+                if style.mlp == "experts" or style.indexer:
                     x, aux = x
                     counted.append(aux)
             elif style.mlp == "experts":
@@ -200,6 +204,9 @@ class TransformerCausalLm(nn.Module):
                 x, aux = lyr(x, causal=True, router_state=state,
                              layout=layout)
                 state = aux.pop("router_state", None)
+                counted.append(aux)
+            elif style.indexer:
+                x, aux = lyr(x, causal=True, layout=layout)
                 counted.append(aux)
             else:
                 x = lyr(x, causal=True, layout=layout)
@@ -241,11 +248,21 @@ class TransformerCausalLm(nn.Module):
         logits = self._logits(self.final_norm(x))
         if not counted:
             return logits
-        worst = lambda name: jnp.max(jnp.stack([a[name] for a in counted]))
-        aux = {"rows_held": sum(a["rows_held"] for a in counted),
-               "load_max_over_mean": worst("load_max_over_mean")}
-        if "rank_load_max_over_mean" in counted[0]:
+        has = lambda name: [a[name] for a in counted if name in a]
+        worst = lambda name: jnp.max(jnp.stack(has(name)))
+        mean = lambda name: sum(has(name)) / len(has(name))
+        aux = {}
+        if has("rows_held"):
+            aux = {"rows_held": sum(has("rows_held")),
+                   "load_max_over_mean": worst("load_max_over_mean")}
+        if has("rank_load_max_over_mean"):
             aux["rank_load_max_over_mean"] = worst("rank_load_max_over_mean")
+        if has("indexer_kl"):
+            # The indexers' loss is the mean over layers and rows; so is
+            # what they kept of the causal pairs; ties are counted.
+            aux.update(indexer_kl=mean("indexer_kl"),
+                       selected_kept_share=mean("selected_kept_share"),
+                       selected_ties=sum(has("selected_ties")))
         return logits, aux
 
     def __call__(self, tokens, train: bool = False, layout=None):
@@ -770,4 +787,74 @@ def gpt_sdar_tiny(num_classes: int = 0, dtype=jnp.float32, *,
                   remat_blocks: bool = False,
                   attention_impl: str = "auto", mesh=None):
     return _sdar(_SDAR_TINY, dtype, vocab_size, layers_held, experts_held,
+                 remat_blocks, attention_impl, mesh)
+
+
+# Keye-VL-2.0-30B-A3B's language model as Kwai-Keye published it (config.json,
+# `model_type: KeyeVL2`): Qwen3-MoE's layer 48 times, of hidden size 2048, 32
+# query heads over 4 K/V heads of 128 with an RMSNorm on each head's q and k,
+# every MLP 128 experts of width 768, 8 a token by softmax scores normalised
+# over the chosen, RMSNorm 1e-6, an untied head over 151,936 tokens; and on
+# every attention layer `sa_config`: an indexer of 16 heads of 64 over one
+# index key a token, each row keeping its 2048 best keys
+# (``BlockStyle.indexer``); rotary positions at theta 1e7 in three sections
+# of 16, 24 and 24 frequency pairs (``Rope.sections``). The vision tower is
+# not here: its widths are not in the repository. benchmark/configs/
+# keye_vl2_30b_a3b.json lists what the source leaves unsaid and how it was
+# read.
+_KEYE_VL2_30B_A3B = dict(
+    hidden_size=2048, num_layers=48, head_dim=128, heads=32, kv_heads=4,
+    experts=128, top_k=8, expert_width=768,
+    rope=Rope(theta=10_000_000.0, sections=(16, 24, 24)),
+    indexer=(("heads", 16), ("head_dim", 64), ("topk", 2048)))
+# The same block at sizes a CPU test holds: a row of 64 keeps 16.
+_KEYE_TINY = dict(
+    hidden_size=64, num_layers=2, head_dim=16, heads=4, kv_heads=2,
+    experts=8, top_k=2, expert_width=32,
+    rope=Rope(theta=10_000_000.0, sections=(2, 3, 3)),
+    indexer=(("heads", 2), ("head_dim", 8), ("topk", 16)))
+
+
+def _keye(sizes, dtype, vocab_size, layers_held, experts_held, remat_blocks,
+          attention_impl, mesh=None):
+    """The ``KeyeVL2`` language model at ``sizes``, or one chip's share of
+    it, told as :func:`_sdar` is."""
+    z = sizes
+    layers = range(z["num_layers"]) if layers_held is None \
+        else tuple(layers_held)
+    first, count = experts_held or (0, z["experts"])
+    experts = (("num_experts", z["experts"]),
+               ("held", (int(first), int(count))),
+               ("implementation", _grouped_matmul_for(attention_impl)))
+    router = (("kind", "softmax_top_k"), ("top_k", z["top_k"]))
+    style = BlockStyle(
+        num_kv_heads=z["kv_heads"], head_dim=z["head_dim"], rms_eps=1e-6,
+        rope=z["rope"], qk_norm=True, mlp="experts", experts=experts,
+        router=router, remat=bool(remat_blocks), indexer=z["indexer"])
+    return TransformerCausalLm(
+        vocab_size=vocab_size, hidden_size=z["hidden_size"], dtype=dtype,
+        attention_impl=attention_impl, tie_embeddings=False, mesh=mesh,
+        blocks=tuple((i, z["heads"], z["expert_width"], style)
+                     for i in layers))
+
+
+@register_model("gpt_keye_vl2_30b_a3b")
+def gpt_keye_vl2_30b_a3b(num_classes: int = 0, dtype=jnp.bfloat16, *,
+                         vocab_size: int = 151_936, max_len: int = 16_384,
+                         layers_held=None, experts_held=None,
+                         remat_blocks: bool = False,
+                         attention_impl: str = "auto", mesh=None):
+    # Every width is the published one; num_classes and max_len are not read,
+    # as in gpt_sdar_30b_a3b.
+    return _keye(_KEYE_VL2_30B_A3B, dtype, vocab_size, layers_held,
+                 experts_held, remat_blocks, attention_impl, mesh)
+
+
+@register_model("gpt_keye_tiny")
+def gpt_keye_tiny(num_classes: int = 0, dtype=jnp.float32, *,
+                  vocab_size: int = 96, max_len: int = 64,
+                  layers_held=None, experts_held=None,
+                  remat_blocks: bool = False,
+                  attention_impl: str = "auto", mesh=None):
+    return _keye(_KEYE_TINY, dtype, vocab_size, layers_held, experts_held,
                  remat_blocks, attention_impl, mesh)

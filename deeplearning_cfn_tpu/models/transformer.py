@@ -28,6 +28,7 @@ from jax.sharding import PartitionSpec as P
 from ..obs.trace import get_tracer
 from ..ops import fused_attention
 from ..ops.rope import kernel_engages, rotate_to_heads
+from ..ops.sparse_index import index_loss, select_top_k
 
 Dtype = Any
 
@@ -66,7 +67,12 @@ class Rope:
     takes YaRN's frequencies (Peng et al. 2023): the slow ones divided by the
     factor, the fast ones kept, a linear ramp between the dimensions that
     turn ``beta_fast`` and ``beta_slow`` times over ``original_len``; cos and
-    sin are multiplied by ``attention_factor``."""
+    sin are multiplied by ``attention_factor``. ``sections`` (Qwen2-VL's
+    multi-section positions, chunked) splits the frequency pairs among as
+    many position streams: the first ``sections[0]`` pairs turn by stream
+    0, the next ``sections[1]`` by stream 1, and so on; ``tables`` then
+    takes ``[len(sections), seq_len]`` position ids, and equal streams give
+    the tables of a ``Rope`` without sections, bit for bit."""
 
     theta: float = 10000.0
     rotary_dim: int = 0
@@ -75,6 +81,7 @@ class Rope:
     beta_fast: float = 32.0
     beta_slow: float = 1.0
     attention_factor: float = 1.0
+    sections: Tuple[int, ...] = ()
 
     def inv_freq(self, head_dim: int) -> np.ndarray:
         dim = self.rotary_dim or head_dim
@@ -97,11 +104,22 @@ class Rope:
     def tables(self, seq_len: int, head_dim: int, positions=None):
         """``(cos, sin)``, each float32 ``[seq_len, rotary_dim / 2]``, for
         the positions ``0 .. seq_len - 1`` or for the ``seq_len`` position
-        ids listed (a block-diffusion row's repeat)."""
+        ids listed (a block-diffusion row's repeat), or for a stream of
+        ``seq_len`` ids a section (``[len(sections), seq_len]``)."""
         positions = np.arange(seq_len) if positions is None \
             else np.asarray(positions)
-        angles = positions.astype(np.float64)[:, None] \
-            * self.inv_freq(head_dim)[None, :]
+        inv_freq = self.inv_freq(head_dim)
+        if positions.ndim == 2:
+            if len(positions) != len(self.sections) \
+                    or sum(self.sections) != inv_freq.size:
+                raise ValueError(
+                    f"{len(positions)} position streams for sections "
+                    f"{self.sections} of {inv_freq.size} frequency pairs")
+            stream = np.repeat(np.arange(len(self.sections)), self.sections)
+            positions = positions[stream].T  # [seq_len, pairs]
+        else:
+            positions = positions[:, None]
+        angles = positions.astype(np.float64) * inv_freq[None, :]
         return tuple((f(angles) * self.attention_factor).astype(np.float32)
                      for f in (np.cos, np.sin))
 
@@ -211,7 +229,25 @@ class BlockStyle:
 
     ``qk_norm``: an RMSNorm over each head's channels on q and on k, a
     learned scale of ``head_dim`` each (``query_norm``, ``key_norm``), before
-    the rotary turn (Qwen3's)."""
+    the rotary turn (Qwen3's).
+
+    ``indexer`` makes the attention learned sparse attention
+    (DeepSeek-Sparse-Attention's indexer over these grouped heads): keyword
+    pairs ``heads``, ``head_dim``, ``topk``. From the block's normed input
+    under ``stop_gradient`` come ``heads`` index queries of ``head_dim``
+    (``index_query``), one index key (``index_key``, a LayerNorm on it:
+    ``index_key_norm``) and ``heads`` float32 weights a token
+    (``index_weight``); queries and key turn by the rope's theta over their
+    whole head, by position stream 0. A row keeps the causal keys whose
+    index score is among its ``topk`` best (``ops/sparse_index.py``), one
+    selection for every head; the flash kernels take it as their mask; the
+    selection passes no gradient. The block then returns ``(x, aux)`` with
+    ``aux["indexer_kl"]``, the rows' mean ``KL(P || softmax I)`` over the
+    kept keys (``P`` the attention's own distribution, averaged over the
+    heads, a constant), which reaches the indexer's parameters alone, and
+    what the selection kept (``selected_kept_share``, ``selected_ties``).
+    With a rope of ``sections`` the block's rows are given one position
+    stream a section (a text row's streams are equal)."""
 
     num_kv_heads: int = 0          # 0: as many as query heads
     head_dim: int = 0              # 0: hidden size / heads
@@ -235,6 +271,7 @@ class BlockStyle:
     # bytes an attention block.
     remat: bool = False
     qk_norm: bool = False
+    indexer: Tuple[Tuple[str, Any], ...] = ()
 
 
 class Leaf(nn.Module):
@@ -396,12 +433,15 @@ class MultiHeadAttention(nn.Module):
     def _kernel_mesh(self):
         return None if self.is_initializing() else self.mesh
 
-    def core_attention(self, q, k, v, bias, causal, layout=None):
+    def core_attention(self, q, k, v, bias, causal, layout=None,
+                       selected=None):
         """The [B,H,S,D] attention op. Subclasses swap this for a
         distributed strategy (SeqParallelAttention) while inheriting the
         projections/KV-cache/dropout plumbing unchanged. A ``layout``
         (``ops/attention.py:BlockDiffusion``) is the call's whole mask, in
-        place of ``causal`` and the style's window."""
+        place of ``causal`` and the style's window. ``selected`` (an
+        indexer's packed selection) masks the causal call, which then
+        returns the rows' statistics beside the output."""
         st = self.style or BlockStyle()
         if layout is not None:
             causal, window = False, 0
@@ -411,7 +451,48 @@ class MultiHeadAttention(nn.Module):
                                sm_scale=st.attn_scale or None,
                                implementation=self.attention_impl,
                                window=window, mesh=self._kernel_mesh(),
-                               layout=layout)
+                               layout=layout, selected=selected)
+
+    def _indexer(self, x, q, k, v):
+        """Learned sparse attention over ``q``, ``k``, ``v`` ``[B, H, S,
+        D]`` from the block's normed input ``x``: ``(out, aux)``
+        (:class:`BlockStyle`'s ``indexer``)."""
+        st = self.style
+        ix = dict(st.indexer)
+        heads, dim, topk = ix["heads"], ix["head_dim"], ix["topk"]
+        b, s = x.shape[:2]
+        x = jax.lax.stop_gradient(x)
+        dense = lambda name, feats, dtype=self.dtype: nn.Dense(
+            feats, dtype=dtype, param_dtype=jnp.float32, name=name,
+            use_bias=False, kernel_init=nn.initializers.xavier_uniform())
+        with jax.named_scope("indexer_proj"):
+            qi = dense("index_query", heads * dim)(x).reshape(
+                b, s, heads, dim)
+            ki = nn.LayerNorm(epsilon=1e-6, dtype=self.dtype,
+                              param_dtype=jnp.float32,
+                              name="index_key_norm")(
+                                  dense("index_key", dim)(x))
+            # Float32 as a router's logits are: a flipped selection is as
+            # discrete as a flipped expert.
+            w = dense("index_weight", heads, jnp.float32)(
+                x.astype(jnp.float32))
+            turn = Rope(theta=st.rope.theta)
+            qi, ki = (rope_to_heads(t, turn, self.attention_impl,
+                                    self._kernel_mesh())
+                      for t in (qi, ki[:, :, None, :]))
+            ki = ki[:, 0]
+        words, lse_i, kept = select_top_k(qi, ki, w, topk,
+                                          self.attention_impl,
+                                          mesh=self._kernel_mesh())
+        out, lse = self.core_attention(q, k, v, None, True, selected=words)
+        with jax.named_scope("indexer_loss"):
+            kl = index_loss(qi, ki, w, q, k, lse, words, lse_i,
+                            st.attn_scale or q.shape[-1] ** -0.5,
+                            self.attention_impl, mesh=self._kernel_mesh())
+        return out, {
+            "indexer_kl": jnp.sum(kl) / (b * s),
+            "selected_kept_share": jnp.sum(kept) / (b * s * (s + 1) / 2),
+            "selected_ties": jnp.sum(jnp.maximum(kept - topk, 0.0))}
 
     @nn.compact
     def __call__(self, x, kv=None, bias=None, causal=False,
@@ -505,11 +586,23 @@ class MultiHeadAttention(nn.Module):
             with jax.named_scope("qk_norm"):
                 q = RMSNorm(st.rms_eps, self.dtype, name="query_norm")(q)
                 k = RMSNorm(st.rms_eps, self.dtype, name="key_norm")(k)
+        if st.indexer and (layout is not None or st.rope is None
+                           or st.window or bias is not None or not causal):
+            raise NotImplementedError(
+                "an indexer selects among the keys of causal self-attention "
+                "with rotary positions: no layout, window or bias")
         if st.rope is not None:
             # Both copies of a block-diffusion row stand at the row's own
             # positions: 0 .. L - 1 twice.
             positions = None if layout is None \
                 else np.tile(np.arange(layout.length), 2)
+            if st.rope.sections:
+                if layout is not None:
+                    raise NotImplementedError(
+                        "sectioned rotary positions under a layout")
+                # A text row: every stream counts its tokens.
+                positions = np.tile(np.arange(x.shape[1]),
+                                    (len(st.rope.sections), 1))
             with jax.named_scope("rope"):
                 q, k = (rope_to_heads(t, st.rope, self.attention_impl,
                                       self._kernel_mesh(), positions)
@@ -753,6 +846,8 @@ class MultiHeadAttention(nn.Module):
                                   causal=False, implementation="reference")
         elif layout is not None:
             out = self.core_attention(q, k, v, bias, causal, layout)
+        elif st.indexer:
+            out, index_aux = self._indexer(x, q, k, v)
         else:
             out = self.core_attention(q, k, v, bias, causal)
         b, h, s, d = out.shape
@@ -764,7 +859,7 @@ class MultiHeadAttention(nn.Module):
         if self.dropout_rate > 0:
             out = nn.Dropout(self.dropout_rate)(
                 out, deterministic=deterministic)
-        return out
+        return (out, index_aux) if st.indexer else out
 
 
 class Mlp(nn.Module):
@@ -824,7 +919,9 @@ class TransformerLayer(nn.Module):
     ``style.mlp == "experts"`` it returns ``(x, aux)`` too, where ``aux``
     holds what the expert layer counted and, where its router keeps a state,
     ``aux["router_state"]``: what the next block is to be called with as
-    ``router_state`` (the first block is called with none).
+    ``router_state`` (the first block is called with none). A style with an
+    ``indexer`` returns ``(x, aux)`` as well, the indexer's loss and what
+    its selection kept in ``aux``.
     """
 
     num_heads: int
@@ -880,6 +977,9 @@ class TransformerLayer(nn.Module):
                     norm("self_attn_norm")(x), causal=causal, layout=layout)
         else:
             raise ValueError(f"unknown BlockStyle.mixer {st.mixer!r}")
+        index_aux = {}
+        if st.mixer == "attention" and st.indexer:
+            mixed, index_aux = mixed
         x = join("self_attn", x, mixed, stream=not st.from_embedding)
         y = norm("mlp_norm")(x)
         if st.mlp == "experts":
@@ -898,11 +998,12 @@ class TransformerLayer(nn.Module):
             out, aux = HeldExpertsMlp(
                 mlp_dim=self.mlp_dim, dtype=self.dtype, name="mlp",
                 router=router, mesh=self.mesh, **experts)(y, router_state)
-            return join("mlp", x, out), aux
+            return join("mlp", x, out), {**aux, **index_aux}
         if st.mlp != "swiglu":
             raise ValueError(f"unknown BlockStyle.mlp {st.mlp!r}")
-        return join("mlp", x, GatedMlp(self.mlp_dim, self.dtype,
-                                       name="mlp")(y))
+        x = join("mlp", x, GatedMlp(self.mlp_dim, self.dtype,
+                                    name="mlp")(y))
+        return (x, index_aux) if index_aux else x
 
     @nn.compact
     def __call__(self, x, enc=None, self_bias=None, cross_bias=None,

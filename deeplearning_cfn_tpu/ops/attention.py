@@ -39,6 +39,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..obs.trace import get_tracer
 from ..parallel.kernels import batch_axes_of, rows_spec, shard_rows
+from .sparse_index import LANES, SUPER, packed_width, selected_bits, \
+    unpack_selection
 
 # The names of the flash forward kernel's output and row statistics
 # (logsumexp) among a differentiated call's residuals, for
@@ -125,11 +127,13 @@ class BlockDiffusion:
                          ~q_clean & (kb == qb))
 
 
-def _mask_name(window: int = 0, layout: Optional[BlockDiffusion] = None
-               ) -> str:
+def _mask_name(window: int = 0, layout: Optional[BlockDiffusion] = None,
+               selected: bool = False) -> str:
     """The ``mask`` label of a call's flash gauges and counter."""
     if layout is not None:
         return "block_diffusion"
+    if selected:
+        return "selected"
     return "window" if window else ""
 
 
@@ -528,12 +532,24 @@ class _BlockWalk(_Walk):
     mask = "block_diffusion"
 
 
+class _SelectedWalk(_Walk):
+    """A causal call's walk under a selection (``fused_attention``'s
+    ``selected``): the triangle's tiles as ever, each masked by the call's
+    bit mask; only the gauges' label differs."""
+    mask = "selected"
+
+
 def _walk(sq: int, sk: int, plan, causal: bool, window: int,
-          by_columns: bool, layout: Optional[BlockDiffusion] = None
-          ) -> _Walk:
+          by_columns: bool, layout: Optional[BlockDiffusion] = None,
+          selected: bool = False) -> _Walk:
     """The ``_Walk`` of one kernel of a call (``sq`` and ``sk`` of a
     ``layout``: its padded square's)."""
     block_q, block_k = plan[:2]
+    if selected:
+        n = -(-sq // block_q)
+        return _SelectedWalk(block_k if by_columns else block_q,
+                             block_q if by_columns else block_k, n, n, 0, 0,
+                             by_columns, n != 1)
     if layout is not None:
         half = sq // (2 * block_q)
         return _BlockWalk(block_q, block_q, 2 * half, 2 * half, 0, 0,
@@ -599,13 +615,13 @@ def _record_grid(kernel: str, walk: _Walk, cases, group: int) -> None:
 
 
 def _grid_gauges(kernel: str, window: int = 0,
-                 layout: Optional[BlockDiffusion] = None
-                 ) -> Tuple[float, ...]:
+                 layout: Optional[BlockDiffusion] = None,
+                 selected: bool = False) -> Tuple[float, ...]:
     """What ``_record_grid`` last set for a kernel: ``(grid_steps,
     dead_grid_steps, dead_step_copies)`` a query head."""
     registry = get_tracer().registry
     return tuple(registry.gauge(f"attention.flash.{name}").value(
-        **_labels(kernel, _mask_name(window, layout)))
+        **_labels(kernel, _mask_name(window, layout, selected)))
         for name in _GRID_GAUGES)
 
 
@@ -613,8 +629,8 @@ def _record_subtiles(kernel: str, counts: Tuple[int, int, int],
                      mask: str = "") -> None:
     """The mechanism's engagement is static, so it is two gauges set when a
     kernel is traced (docs/OBSERVABILITY.md); those of a call with a mask of
-    its own are labelled ``mask="window"`` or ``mask="block_diffusion"``
-    beside the kernel."""
+    its own are labelled ``mask="window"``, ``mask="block_diffusion"`` or
+    ``mask="selected"`` beside the kernel."""
     total, live, masked = counts
     labels = _labels(kernel, mask)
     registry = get_tracer().registry
@@ -685,6 +701,36 @@ def _by_blocks(shape, q0, k0, edge, block):
     return kb < qb if edge == _EARLIER_BLOCKS else kb <= qb
 
 
+def _kept(sel, r0, r1, c0, c1):
+    """The pairs a selection keeps of a piece, rows ``[r0, r1)`` by columns
+    ``[c0, c1)`` of a grid tile: ``sel`` is the tile's words (a ref ``[1,
+    block_q, 128]``) and the bit its first 128 columns stand at
+    (``ops/sparse_index.py`` has the layout)."""
+    sel_ref, first_bit = sel
+    return selected_bits(sel_ref[0, r0:r1, :], first_bit + c0 // LANES,
+                         (c1 - c0) // LANES) != 0
+
+
+def _selection_plan(selected, sq: int, plan):
+    """A selected call's words padded to its grid, the index of the 128
+    lanes that hold a K/V block's bits, and the bit its first columns stand
+    at: ``(words, lanes_of(block), first_bit_of(block))``."""
+    block_q, block_k, _, sub_k = plan
+    sq_p = -(-sq // block_q) * block_q
+    tiles = sq_p // block_k
+    if block_k % LANES or sub_k % LANES or sq_p % block_k or (
+            tiles > 1 and SUPER % block_k):
+        raise ValueError(
+            f"the flash kernels take a selection under grid tiles of whole "
+            f"runs of {LANES} columns that divide {SUPER}; got {plan}")
+    words = jnp.pad(selected, [
+        (0, 0), (0, sq_p - selected.shape[1]),
+        (0, packed_width(sq_p) - selected.shape[2])])
+    per_run = max(SUPER // block_k, 1)
+    return (words, lambda block: block // per_run,
+            lambda block: (block % per_run) * (block_k // LANES))
+
+
 # ---------------------------------------------------------------------------
 # Reference implementation (oracle + fallback + backward)
 # ---------------------------------------------------------------------------
@@ -699,12 +745,17 @@ def attention_reference(
     sm_scale: Optional[float] = None,
     window: int = 0,
     layout: Optional[BlockDiffusion] = None,
-) -> jnp.ndarray:
+    selected: Optional[jnp.ndarray] = None,
+    return_stats: bool = False,
+):
     """Plain jnp attention; computes in f32 regardless of input dtype (the
     softmax accumulator precision the kernel also uses). Fewer K/V heads
     than query heads are repeated to them here (the kernels index them
     instead); ``window`` keeps, of a causal row ``i``, columns ``j`` with
-    ``i - j < window``; a ``layout`` keeps the pairs of its definition."""
+    ``i - j < window``; a ``layout`` keeps the pairs of its definition;
+    ``selected`` (a packed bit mask a batch row, ``ops/sparse_index.py``)
+    keeps, of the causal pairs, those whose bit is set. ``return_stats``
+    adds each row's logsumexp over what it keeps, ``[B, H, Sq]`` float32."""
     *_, sq, d = q.shape
     sk = k.shape[-2]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
@@ -723,9 +774,15 @@ def attention_reference(
             logits = jnp.where(q_pos - k_pos < window, logits, _NEG_INF)
     if layout is not None:
         logits = jnp.where(layout.mask(), logits, _NEG_INF)
+    if selected is not None:
+        logits = jnp.where(unpack_selection(selected, sk)[:, None], logits,
+                           _NEG_INF)
     weights = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", weights.astype(v.dtype), v,
-                      preferred_element_type=jnp.float32).astype(q.dtype)
+    out = jnp.einsum("bhqk,bhkd->bhqd", weights.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32).astype(q.dtype)
+    if return_stats:
+        return out, jax.nn.logsumexp(logits, axis=-1)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -736,7 +793,8 @@ def attention_reference(
 def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                   m_scr, l_scr, acc_scr, *,
                   causal: bool, sm_scale: float, cases, one_tile: bool,
-                  walk: _Walk, window: int = 0, block: int = 0):
+                  walk: _Walk, window: int = 0, block: int = 0,
+                  sel_ref=None, sel_bit=None):
     """One (batch, head, q-block, kv-block) grid step of the online softmax.
 
     The kv-block axis is the innermost ("arbitrary") grid dimension: the
@@ -760,6 +818,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
     The walk's ``rel`` is by the TRUE (unpadded) lengths — the causal
     diagonal aligns their ends; the refs hold block-padded arrays. The
     [S,S] score matrix never exists in HBM.
+
+    ``sel_ref`` (a selected call: the words of this tile's rows for the 128
+    lanes that hold this K/V block's bits, ``sel_bit(block)`` the bit its
+    first columns stand at) is then the whole mask of every piece: the
+    selection holds causal pairs alone.
     """
     from jax.experimental import pallas as pl  # deferred: TPU-only path
 
@@ -786,6 +849,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
             )  # [r1 - r0, c1 - c0]
             if bias_ref is not None:  # the plan gives a bias one piece
                 s = s + bias_ref[0, 0, :, :].astype(jnp.float32)
+            if sel_ref is not None:
+                # As under a window, a row may keep nothing of a piece.
+                masked = 0
+                s = jnp.where(_kept((sel_ref, sel_bit(kb)), r0, r1, c0, c1),
+                              s, _NEG_INF)
             if masked & _CAUSAL:
                 s = jnp.where(_below_diagonal(s.shape, q0 + r0, k0 + c0),
                               s, _NEG_INF)
@@ -903,10 +971,15 @@ def _unpad_copies(x: jnp.ndarray, layout: Optional[BlockDiffusion]
 
 
 def _flash_forward(q, k, v, bias, causal, sm_scale, interpret=False,
-                   return_stats=False, plan=None, window=0, layout=None):
+                   return_stats=False, plan=None, window=0, layout=None,
+                   selected=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    rows = q.shape[2]
+    if selected is not None:
+        # Whole runs of 128 columns; the padding's bits are clear.
+        q, k, v = (_pad_to(t, 2, LANES) for t in (q, k, v))
     if layout is not None:
         plan = plan or _tile_plan(0, 0, q.shape[-1], causal, layout=layout)
         _check_layout_plan(layout, plan)
@@ -928,7 +1001,7 @@ def _flash_forward(q, k, v, bias, causal, sm_scale, interpret=False,
     block_q, block_k = plan[:2]
     cases = _schedule(sq, sk, plan, causal, window=window, layout=layout)
     walk = _walk(sq, sk, plan, causal, window, by_columns=False,
-                 layout=layout)
+                 layout=layout, selected=selected is not None)
     _record_subtiles("flash_fwd",
                      _subtile_counts(sq, sk, plan, cases, walk), walk.mask)
 
@@ -968,7 +1041,19 @@ def _flash_forward(q, k, v, bias, causal, sm_scale, interpret=False,
                      one_tile=(sq_p, sk_p) == (block_q, block_k))
     if layout is not None:
         kernel_kw["block"] = layout.block
-    if bias is not None:
+    if selected is not None:
+        words, lanes_of, kernel_kw["sel_bit"] = _selection_plan(
+            selected, sq, plan)
+        in_specs.append(pl.BlockSpec(
+            (1, block_q, LANES), lambda ib, ih, iq, ik: (
+                ib, iq, lanes_of(walk.named(iq, ik)))))
+        args.append(words)
+
+        def kernel(q_ref, k_ref, v_ref, sel_ref, o_ref, *rest):
+            lse = rest[0] if return_stats else None
+            _flash_kernel(q_ref, k_ref, v_ref, None, o_ref, lse,
+                          *rest[-3:], sel_ref=sel_ref, **kernel_kw)
+    elif bias is not None:
         # Keep broadcast dims at size 1 (indexed with block 0) instead of
         # materializing [B,H,Sq,Sk] in HBM.
         bb, bh, bq = bias.shape[0], bias.shape[1], bias.shape[2]
@@ -1023,6 +1108,7 @@ def _flash_forward(q, k, v, bias, causal, sm_scale, interpret=False,
         interpret=interpret,
         name="flash_fwd",
     )(*args)
+    sq = min(sq, rows) if selected is not None else sq
     if return_stats:
         out, lse = result
         return _unpad_copies(out[:, :, :sq, :], layout), \
@@ -1081,6 +1167,12 @@ def _piece_mask(kind, q0, k0, causal, cols, window, block=0):
     return mask
 
 
+def _selection_mask(sel, r0, r1, c0, c1):
+    """The mask of a backward kernel's piece under a selection: the whole
+    of it, on every piece (``_kept``)."""
+    return lambda s: jnp.where(_kept(sel, r0, r1, c0, c1), s, _NEG_INF)
+
+
 def _bwd_rows(q_ref, do_ref, lse_ref, delta_ref, r0, r1):
     """Rows ``[r0, r1)`` of the q-side refs as the backward kernels use
     them: ``(q, do, lse, delta)``, float32."""
@@ -1112,7 +1204,8 @@ def _bwd_piece(rows, k_blk, v_blk, *, sm_scale, mask):
 def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                            dk_ref, dv_ref, dk_scr, dv_scr, *, causal,
                            sm_scale, seq_k, cases, one_tile, walk,
-                           window=0, group=1, block=0):
+                           window=0, group=1, block=0, sel_ref=None,
+                           sel_bit=None):
     """One (batch, head, kv-block, q-block) grid step: accumulate this q
     block's contribution to dK/dV of one kv block in VMEM scratch; write on
     the last q step. Same block-mapped structure as the forward kernel, and
@@ -1149,6 +1242,9 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         for r0, r1, masked in pieces:
             mask = _piece_mask(masked, q0 + r0, k0 + c0, causal, cols,
                                window, block) if masked else None
+            if sel_ref is not None:
+                mask = _selection_mask((sel_ref, sel_bit(ik)), r0, r1, c0,
+                                       c1)
             rows = _bwd_rows(q_ref, do_ref, lse_ref, delta_ref, r0, r1)
             q_blk, do_blk = rows[:2]
             p, ds = _bwd_piece(rows, k_blk, v_blk, sm_scale=sm_scale,
@@ -1172,7 +1268,8 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, dq_scr, *, causal, sm_scale, seq_k, cases,
-                         one_tile, walk, window=0, block=0):
+                         one_tile, walk, window=0, block=0, sel_ref=None,
+                         sel_bit=None):
     """One (batch, head, q-block, kv-block) grid step: accumulate one kv
     block's contribution to dQ of one q block; write on the last kv step.
     Band of rows by band of rows, as the forward is."""
@@ -1198,6 +1295,9 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             v_blk = v_ref[0, 0, c0:c1, :].astype(jnp.float32)
             mask = _piece_mask(masked, q0 + r0, k0 + c0, causal, cols,
                                window, block) if masked else None
+            if sel_ref is not None:
+                mask = _selection_mask((sel_ref, sel_bit(kb)), r0, r1, c0,
+                                       c1)
             _, ds = _bwd_piece(rows, k_blk, v_blk, sm_scale=sm_scale,
                                mask=mask)
             dq = dq + sm_scale * jax.lax.dot_general(
@@ -1213,11 +1313,16 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, interpret,
-                    plan=None, window=0, layout=None):
+                    plan=None, window=0, layout=None, selected=None):
     """dq, dk, dv via the blocked kernels (bias-free path)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    rows = q.shape[2]
+    if selected is not None:
+        q, k, v, out, g = (_pad_to(t, 2, LANES) for t in (q, k, v, out, g))
+        lse = jnp.pad(lse, [(0, 0), (0, 0), (0, q.shape[2] - rows)],
+                      constant_values=-_NEG_INF)
     if layout is not None:
         plan = plan or _tile_plan(0, 0, q.shape[-1], causal, backward=True,
                                   layout=layout)
@@ -1239,7 +1344,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, interpret,
                                        mask_whole=True, window=window,
                                        layout=layout)}
     walks = {name: _walk(sq, sk, plan, causal, window,
-                         by_columns=name == "flash_bwd_dkdv", layout=layout)
+                         by_columns=name == "flash_bwd_dkdv", layout=layout,
+                         selected=selected is not None)
              for name in cases}
     for name, kernel_cases in cases.items():
         _record_subtiles(name, _subtile_counts(
@@ -1282,12 +1388,36 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, interpret,
     row_by_inner = pl.BlockSpec((1, 1, block_q, _STAT_LANES), q_rows)
     kv_by_outer = pl.BlockSpec((1, 1, block_k, d),
                                lambda ib, ih, ik, iq: (ib, ih, ik, 0))
+    dkdv_kernel = functools.partial(
+        _flash_bwd_dkdv_kernel, **common, group=group,
+        cases=cases["flash_bwd_dkdv"], walk=walk)
+    dq_kernel = functools.partial(
+        _flash_bwd_dq_kernel, **common, cases=cases["flash_bwd_dq"],
+        walk=walks["flash_bwd_dq"])
+    sel_by_inner, sel_by_outer, words = [], [], []
+    if selected is not None:
+        padded, lanes_of, sel_bit = _selection_plan(selected, sq, plan)
+        words, by_columns = [padded], walk
+        sel_by_inner = [pl.BlockSpec(
+            (1, block_q, LANES), lambda ib, ih, ik, step: (
+                ib, by_columns.named(ik, step % by_columns.steps),
+                lanes_of(ik)))]
+        sel_by_outer = [pl.BlockSpec(
+            (1, block_q, LANES), lambda ib, ih, iq, ik: (
+                ib, iq, lanes_of(walks["flash_bwd_dq"].named(iq, ik))))]
+
+        def with_selection(kernel):
+            # The words come after the six operands every call has.
+            return lambda *refs: kernel(*refs[:6], *refs[7:],
+                                        sel_ref=refs[6], sel_bit=sel_bit)
+
+        dkdv_kernel, dq_kernel = map(with_selection,
+                                     (dkdv_kernel, dq_kernel))
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkdv_kernel, **common, group=group,
-                          cases=cases["flash_bwd_dkdv"], walk=walk),
+        dkdv_kernel,
         grid=(b, hk, sk_p // block_k, group * walk.steps),
         in_specs=[q_by_inner, kv_by_outer, kv_by_outer, q_by_inner,
-                  row_by_inner, row_by_inner],
+                  row_by_inner, row_by_inner] + sel_by_inner,
         out_specs=[kv_by_outer, kv_by_outer],
         out_shape=[jax.ShapeDtypeStruct((b, hk, sk_p, d), k.dtype),
                    jax.ShapeDtypeStruct((b, hk, sk_p, d), v.dtype)],
@@ -1296,7 +1426,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, interpret,
         compiler_params=semantics,
         interpret=interpret,
         name="flash_bwd_dkdv",
-    )(qp, kp, vp, dop, lse_p, delta_p)
+    )(qp, kp, vp, dop, lse_p, delta_p, *words)
 
     # dQ: grid over q blocks, kv blocks innermost (accumulated).
     q_by_outer = pl.BlockSpec((1, 1, block_q, d),
@@ -1306,19 +1436,20 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, interpret,
     walk = walks["flash_bwd_dq"]
     kv_by_inner = pl.BlockSpec((1, 1, block_k, d), _kv_by_inner(walk, group))
     dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, **common,
-                          cases=cases["flash_bwd_dq"], walk=walk),
+        dq_kernel,
         grid=(b, h, sq_p // block_q, walk.steps),
         in_specs=[q_by_outer, kv_by_inner, kv_by_inner, q_by_outer,
-                  row_by_outer, row_by_outer],
+                  row_by_outer, row_by_outer] + sel_by_outer,
         out_specs=q_by_outer,
         out_shape=jax.ShapeDtypeStruct((b, h, sq_p, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=semantics,
         interpret=interpret,
         name="flash_bwd_dq",
-    )(qp, kp, vp, dop, lse_p, delta_p)
+    )(qp, kp, vp, dop, lse_p, delta_p, *words)
 
+    if selected is not None:
+        sq = sk = rows
     return tuple(_unpad_copies(t, layout) for t in (
         dq[:, :, :sq, :], dk[:, :, :sk, :], dv[:, :, :sk, :]))
 
@@ -1421,6 +1552,63 @@ def _bwd(causal, sm_scale, use_pallas, interpret, window, layout, res, g):
 _fused_attention.defvjp(_fwd, _bwd)
 
 
+# A selected call's kernels, one jitted function each as a layout's are. The
+# selection is the fourth mask and the first that is an operand: a call of
+# its own beside ``_fused_attention``, which returns the row statistics with
+# the output (the indexer's loss reads them, as constants) and whose rule
+# hands the words on to the backward kernels.
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+def _selected_forward(q, k, v, selected, *, sm_scale, interpret):
+    with jax.named_scope("core_attention"):
+        return _flash_forward(q, k, v, None, True, sm_scale,
+                              interpret=interpret, return_stats=True,
+                              selected=selected)
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+def _selected_backward(q, k, v, out, lse, g, selected, *, sm_scale,
+                       interpret):
+    with jax.named_scope("core_attention"):
+        return _flash_backward(q, k, v, out, lse, g, True, sm_scale,
+                               interpret, selected=selected)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _selected_attention(q, k, v, selected, sm_scale, use_pallas, interpret):
+    """``(out, lse)`` of a causal call under a selection."""
+    if use_pallas:
+        return _selected_forward(q, k, v, selected, sm_scale=sm_scale,
+                                 interpret=interpret)
+    return attention_reference(q, k, v, None, True, sm_scale,
+                               selected=selected, return_stats=True)
+
+
+def _selected_fwd(q, k, v, selected, sm_scale, use_pallas, interpret):
+    out, lse = _selected_attention(q, k, v, selected, sm_scale, use_pallas,
+                                   interpret)
+    if use_pallas:
+        out = checkpoint_name(out, FLASH_OUT)
+        lse = checkpoint_name(lse, FLASH_LSE)
+    return (out, lse), (q, k, v, selected, out, lse)
+
+
+def _selected_bwd(sm_scale, use_pallas, interpret, res, g):
+    q, k, v, selected, out, lse = res
+    g = g[0]  # the row statistics are read as constants
+    if use_pallas:
+        return _selected_backward(q, k, v, out, lse, g, selected,
+                                  sm_scale=sm_scale,
+                                  interpret=interpret) + (None,)
+    _, vjp = jax.vjp(lambda q, k, v: attention_reference(
+        q, k, v, None, True, sm_scale, selected=selected), q, k, v)
+    return vjp(g) + (None,)
+
+
+_selected_attention.defvjp(_selected_fwd, _selected_bwd)
+
+
 # Auto-dispatch crossover, measured on a v5e in r03 (that log is lost and
 # no cell measures it: PERF.md section 7): XLA's own fused attention beat the flash kernel at S=512
 # (9.0 ms vs 6.7 ms, 0.74×) while flash won 1.4× at S=2048 and 35× at
@@ -1476,7 +1664,8 @@ def fused_attention(
     window: int = 0,
     mesh=None,
     layout: Optional[BlockDiffusion] = None,
-) -> jnp.ndarray:
+    selected: Optional[jnp.ndarray] = None,
+):
     """Multi-head attention, fused on TPU.
 
     ``mesh``: the mesh the step is compiled for. Where its batch axes hold
@@ -1496,9 +1685,22 @@ def fused_attention(
     nor window, over its ``2 * length`` positions) keeps the pairs of a
     block-diffusion training row instead: a third static mask, whose dead
     quadrant, dead tiles and dead sub-tiles the kernels leave out as they do
-    a window's. Each mask of its own is counted when a call is traced:
-    ``attention.flash.calls`` labelled ``mask`` (``causal``, ``window``,
-    ``block_diffusion``, ``none``) and ``path`` (docs/OBSERVABILITY.md).
+    a window's. ``selected`` (``[B, Sq, W]`` int32, the packed bit mask of
+    ``ops/sparse_index.py``; causal self-attention calls with no bias,
+    window or layout) is the fourth mask and the one that is data: of the
+    causal pairs a row keeps those whose bit is set, one selection for all
+    the heads. The kernels walk the causal triangle's tiles and mask inside
+    them by the words; the call returns ``(out, lse)``, the rows' logsumexp
+    over what they keep beside the output (``[B, H, Sq]`` float32, a
+    constant to differentiation), and no gradient passes to the selection.
+
+    The four masks, and which exclude which: ``causal`` alone; ``window``
+    (needs ``causal``, excludes ``bias``); ``layout`` (excludes ``causal``,
+    ``window``, ``bias``); ``selected`` (needs ``causal``, excludes
+    ``window``, ``layout``, ``bias``). Each is counted when a call is
+    traced: ``attention.flash.calls`` labelled ``mask`` (``causal``,
+    ``window``, ``block_diffusion``, ``selected``, ``none``) and ``path``
+    (docs/OBSERVABILITY.md).
 
     implementation: 'auto' (on TPU: flash kernel, except the measured
     short-sequence window — Sk < 1024 with the quadratic backward
@@ -1525,6 +1727,18 @@ def fused_attention(
             f"2 x {layout.length} positions: no causal flag, window or "
             f"bias; got causal={causal}, window={window}, bias given: "
             f"{bias is not None}, {q.shape[-2]} rows, {k.shape[-2]} columns")
+    if selected is not None and (
+            not causal or window or layout is not None or bias is not None
+            or q.shape[-2] != k.shape[-2]
+            or selected.shape[:2] != (q.shape[0], q.shape[-2])
+            or selected.shape[2] != packed_width(q.shape[-2])):
+        raise ValueError(
+            f"a selection masks a causal self-attention call with no "
+            f"window, layout or bias, by [B, S, {packed_width(q.shape[-2])}]"
+            f" words; got causal={causal}, window={window}, layout given: "
+            f"{layout is not None}, bias given: {bias is not None}, "
+            f"{q.shape[-2]} rows, {k.shape[-2]} columns, words "
+            f"{selected.shape}")
     if causal and q.shape[-2] > k.shape[-2]:
         # Ill-defined: ends are aligned, so the leading queries would
         # precede every key (and the kernel/reference paths would disagree
@@ -1538,8 +1752,17 @@ def fused_attention(
     get_tracer().registry.counter(
         "attention.flash.calls",
         "attention calls traced, by their static mask and the path taken",
-    ).inc(mask=_mask_name(window, layout) or ("causal" if causal else "none"),
+    ).inc(mask=_mask_name(window, layout, selected is not None)
+          or ("causal" if causal else "none"),
           path="kernel" if use_pallas else "xla")
+    if selected is not None:
+        rows = [rows_spec(batch_axes_of(mesh), n) for n in (4, 3)]
+        return shard_rows(
+            lambda q, k, v, words: _selected_attention(
+                q, k, v, words, scale, use_pallas, interpret),
+            mesh if use_pallas else None, "flash",
+            (rows[0], rows[0], rows[0], rows[1]), tuple(rows),
+            scope="core_attention")(q, k, v, selected)
     if use_pallas and bias is None:
         rows = rows_spec(batch_axes_of(mesh), 4)
         return shard_rows(

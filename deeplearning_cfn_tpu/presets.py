@@ -466,6 +466,38 @@ def _sdar_30b_a3b() -> ExperimentConfig:
     )
 
 
+@register_preset("keye_vl2_30b_a3b_lm")
+def _keye_vl2_30b_a3b() -> ExperimentConfig:
+    """Keye-VL-2.0-30B-A3B's language model (Kwai-Keye: Qwen3-MoE's layer
+    with learned sparse attention, an indexer of 16 heads of 64 choosing
+    each row's 2048 keys, and rotary positions in three sections) trained on
+    one chip's share of a pod in which 8 chips share each layer: the chip
+    holds layers 0-5 of 48 as one pipeline stage of eight, experts 0-15 of
+    each layer's 128 and 19,072 of the 151,936 vocabulary rows (embedding
+    and untied head alike); attention and the indexer whole, every width
+    published. One packed text row of 16,384 tokens a step, next-token
+    prediction with the indexer's KL loss beside the cross-entropy (the
+    sparse stage of DSA's recipe). Every block is recomputed in the backward
+    pass (`remat_blocks`). Recipe: gpt_small_lm's (the source publishes
+    none), no auxiliary balance loss."""
+    return ExperimentConfig(
+        model=ModelConfig(
+            name="gpt_keye_vl2_30b_a3b",
+            kwargs=dict(layers_held=tuple(range(6)), experts_held=(0, 16),
+                        remat_blocks=True),
+        ),
+        data=DataConfig(name="lm_text", seq_len=16_384, vocab_size=19_072),
+        train=TrainConfig(global_batch=1, steps=100_000, dtype="bfloat16",
+                          shard_opt_state=False),
+        optimizer=OptimizerConfig(name="adamw", b1=0.9, b2=0.95,
+                                  weight_decay=0.1, grad_clip_norm=1.0),
+        schedule=ScheduleConfig(name="cosine", base_lr=6e-4,
+                                warmup_steps=2000),
+        mesh=MeshConfig(data=-1),
+        stack=StackConfig(slice_type="v5e-8"),
+    )
+
+
 @register_preset("transformer_nmt_wmt")
 def _nmt() -> ExperimentConfig:
     """Transformer NMT WMT En-De (reference: Sockeye + MXNet

@@ -359,6 +359,11 @@ class Seq2SeqTask:
         return loss, aux
 
 
+# The weight of the indexers' KL loss in the objective of a model of learned
+# sparse attention (``BlockStyle.indexer``).
+INDEXER_KL_WEIGHT = 1.0
+
+
 class CausalLmTask:
     """Decoder-only next-token pretraining (GPT family — beyond the
     reference's workload era; models/lm.py explains why it earns a slot).
@@ -433,6 +438,21 @@ class CausalLmTask:
             loss = ce_loss
             hits = (jnp.argmax(logits, -1) == targets).astype(jnp.float32)
             aux = {"token_accuracy": jnp.sum(hits * weights) / denom}
+            if moe_aux is not None and "indexer_kl" in moe_aux:
+                # A model of learned sparse attention: its indexers' loss
+                # joins the objective whole (DSA's sparse stage; the
+                # stop-gradients that keep it from the trunk, and the
+                # cross-entropy from the indexers, are the attention
+                # block's), and is reported beside the cross-entropy with
+                # what the selections kept.
+                moe_aux = dict(moe_aux)
+                aux["indexer_kl"] = moe_aux.pop("indexer_kl")
+                loss = loss + INDEXER_KL_WEIGHT * aux["indexer_kl"]
+                # The step's `loss` stays the cross-entropy (the trainer
+                # lets a task's own `loss` stand for the objective's).
+                aux["loss"] = ce_loss
+                aux["sel_kept_share"] = moe_aux.pop("selected_kept_share")
+                aux["sel_ties"] = moe_aux.pop("selected_ties")
             if moe_aux is not None:
                 # What the model's expert layers report goes to the step's
                 # metrics as moe_<name>; of it the capacity layer's two

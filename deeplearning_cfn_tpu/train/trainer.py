@@ -85,6 +85,10 @@ class Trainer:
         gradient moves, added after the optimizer's
         (``TrainState.apply_gradients``). The loss must be a
         global-batch mean — that is what makes the compiler's psum correct.
+        It is what is differentiated and, unless ``aux`` has a ``"loss"``
+        of its own, what a step reports as ``loss`` (a task whose objective
+        has a second term reports its first there: ``CausalLmTask`` under an
+        indexer's loss).
     """
 
     def __init__(
@@ -465,11 +469,15 @@ class Trainer:
                         # histogram every realized step
                         # (docs/OBSERVABILITY.md).
                         # Likewise what a block-diffusion step drew
-                        # (``bd_masked_share``).
+                        # (``bd_masked_share``), the indexers' loss
+                        # (``train.indexer_kl``) and what their selections
+                        # kept (``attention.selected.kept_share``, ``ties``).
                         registry = get_tracer().registry
                         for k, v in realized.items():
-                            for prefix, family in (("moe_", "moe."),
-                                                   ("bd_", "train.bd.")):
+                            for prefix, family in (
+                                    ("moe_", "moe."), ("bd_", "train.bd."),
+                                    ("indexer_", "train.indexer_"),
+                                    ("sel_", "attention.selected.")):
                                 if k.startswith(prefix):
                                     name = family + k[len(prefix):]
                                     registry.gauge(name).set(v)

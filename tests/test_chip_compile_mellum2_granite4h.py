@@ -279,3 +279,77 @@ def test_granite4h_step_compiles_and_fits_a_v5e(v5e_chip):
     assert not [line.strip()[:200] for line in text.splitlines()
                 if re.search(r"= \S+ scatter\(", line)
                 and re.search(r"/layer_\d+/", line)]
+
+
+def test_keye_step_compiles_and_fits_a_v5e(v5e_chip):
+    """The whole train step of ``keye_vl2_30b_a3b_train_16k`` at the cell's
+    shapes: six recomputed blocks of learned sparse attention over one row
+    of 16,384 tokens. Mosaic takes the selection's kernel (a tile of rows'
+    index scores in VMEM, the threshold by bisection), the flash kernels
+    under the selection (the packed words a fourth operand, 32 query heads
+    over 4 K/V heads of 128) and the loss's pass; each runs once a layer:
+    the recomputation reads the selection, the forward's output and row
+    statistics and the loss's gradients, kept; the indexer's scopes are in
+    the text; and 10.55 GB of state with one block's intermediates fit the
+    chip, over the quarter of it a cell has to fill."""
+    import re
+
+    from deeplearning_cfn_tpu.obs.trace import get_tracer
+    from deeplearning_cfn_tpu.ops.attention import _grid_gauges
+
+    manifest, rehearse_compile = _bench()
+    registry = get_tracer().registry
+    calls = registry.counter("attention.flash.calls")
+    selections = registry.counter("attention.selected.calls")
+    blocks = registry.counter("model.blocks.recomputed")
+    before = (calls.value(mask="selected", path="kernel"),
+              calls.value(mask="causal", path="kernel"),
+              selections.value(path="kernel"), blocks.value())
+    cell = manifest.Cell(manifest.load_manifest(),
+                         "keye_vl2_30b_a3b_train_16k")
+    assert cell.chips == 1
+    _, compiled, _ = rehearse_compile.compile_step(cell)
+    # Traced twice (the parameters' shapes, the step), six layers each: every
+    # attention call is a selected one.
+    assert (calls.value(mask="selected", path="kernel") - before[0],
+            calls.value(mask="causal", path="kernel") - before[1],
+            selections.value(path="kernel") - before[2],
+            blocks.value() - before[3]) == (12, 0, 12, 12)
+    assert registry.gauge("attention.selected.topk").value() == 2048
+    # The causal triangle's tiles, 16 x 16 of 1024: 136 live, and a dead
+    # step names the diagonal's block again.
+    assert _grid_gauges("flash_fwd", selected=True) == (256, 120, 0)
+    assert _grid_gauges("flash_bwd_dq", selected=True) == (256, 120, 0)
+    assert _grid_gauges("flash_bwd_dkdv", selected=True) == (256, 120, 0)
+    assert registry.gauge("attention.flash.live_subtile_share").value(
+        kernel="flash_fwd", mask="selected") == 136 / 256
+    mem = compiled.memory_analysis()
+    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    assert 4 * 2 ** 30 < total < 15.75 * 2 ** 30, total
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    # A layer: 3 flash, the selection, the loss's pass, 6 rotary, 16 grouped
+    # matmuls.
+    assert len(kernels) == 6 * (3 + 1 + 1 + 6 + 16) == 162
+    name = lambda line: re.search(r'op_name="([^"]*)"', line).group(1)
+    for layer in range(6):
+        mine = re.compile(r"/(flash_\w+|index_select|index_loss)\b")
+        own = sorted(
+            (("rematted_computation" in name(line)),
+             mine.search(name(line)).group(1))
+            for line in kernels if f"/layer_{layer}/" in name(line)
+            and mine.search(name(line)))
+        assert own == [(False, "flash_bwd_dkdv"), (False, "flash_bwd_dq"),
+                       (False, "flash_fwd"), (False, "index_loss"),
+                       (False, "index_select")], own
+    flash = [line for line in kernels if "core_attention/flash_" in line]
+    # The words go in beside q, k and v: [1, 16384, 512] int32.
+    assert len(flash) == 18 and all(
+        "s32[1,16384,512]" in line and "bf16[1,4,16384,128]" in line
+        for line in flash)
+    for scope in ("indexer_proj", "indexer_select", "indexer_loss"):
+        assert re.search(rf'op_name="[^"]*/self_attn/[^"]*{scope}', text), \
+            scope
+    assert not _row_scatters(text)
