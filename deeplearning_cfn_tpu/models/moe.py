@@ -377,13 +377,31 @@ def inverse_permutation(order: jnp.ndarray) -> jnp.ndarray:
 # The bytes of a gather's source from which XLA's gather pays three times as
 # much a slot (128 MiB, the chip's VMEM: tools/moe_rows_sweep.py, PR 36).
 _GATHER_CLIFF = 2 ** 27
+# Both sides of the rows' movement by XLA's gathers (:func:`rows_path`).
+_GATHERS = ("gather", "gather")
 
 
-def rows_path(implementation: str, tokens: int, width: int, dtype) -> str:
-    """How :func:`take_rows` and :func:`sum_rows` fetch a row where a rank's
-    layer has ``tokens`` rows of ``width`` in ``dtype`` (after the exchange's
-    gather): ``"kernel"`` (``ops/rows.py``: one DMA a live slot) or XLA's
+def _buffer_rows(pairs: int, count: int, num_experts: int) -> int:
+    """The rows of the usual buffer where ``count`` of ``num_experts``
+    experts are held over ``pairs`` (token, choice) pairs: twice a uniform
+    router's (``_BUFFER_SHARE``) in whole tiles, at most every pair."""
+    return min(pairs, _whole_tiles(
+        int(_BUFFER_SHARE * pairs * count / num_experts)))
+
+
+def rows_path(implementation: str, tokens: int, width: int, dtype,
+              top_k: int, count: int, num_experts: int) -> Tuple[str, str]:
+    """How :func:`take_rows` and :func:`sum_rows` fetch a row, **each side of
+    the movement from its own source**: ``(to the buffer, to the tokens)``,
+    each ``"kernel"`` (``ops/rows.py``: one DMA a live slot) or XLA's
     ``"gather"``; ``"interpret"`` is the kernel interpreted, for the tests.
+    *To the buffer* (``take_rows`` forward, ``sum_rows``' ``d y``) the source
+    is the rank's ``tokens`` rows of ``width`` in ``dtype`` (after the
+    exchange's gather) and the slots are the buffer's rows; *to the tokens*
+    (``sum_rows`` forward, ``take_rows``' transpose) the source is the usual
+    buffer (:func:`_buffer_rows` of the ``tokens * top_k`` pairs where
+    ``count`` of ``num_experts`` experts are held) and the slots are every
+    pair.
 
     Alone on the chip (``tools/moe_rows_sweep.py --quick``, my chip runs,
     PR 36, calls 1 and 2, ``chiprun_out/c1_rows_*.jsonl``,
@@ -402,19 +420,32 @@ def rows_path(implementation: str, tokens: int, width: int, dtype) -> str:
     0.91 / 0.94 / 0.86 by XLA, 0.36 / 0.70 / 0.70 / 0.58 by the kernel, 0.5
     ms a layer for twenty more kernels to lower at set-up; ZAYA1's one
     choice a token: 0.10 / 0.10 / 0.10 / 0.27 by XLA, 0.24 / 0.26 / 0.27 /
-    0.28 by the kernel. **So: the kernel where the rank's tokens are a source
-    of 2 ** 27 bytes or more** (their buffer is then larger still), on a TPU
-    or where the kernels are named (``implementation="megablox"``: a compile
-    for a chip that is not attached), for rows the kernel can move; XLA's
-    gather elsewhere, and off the TPU as :func:`grouped_matmul` falls back
-    to ``ragged_dot``."""
+    0.28 by the kernel. SDAR's and Keye's 16,384 positions of 2048 (2 ** 26
+    bytes) with 16 of 128 experts held, a buffer of 32,768 rows (2 ** 27
+    bytes) of which 14.8 to 21.2 k are live (PR 48, call 2,
+    ``tools/moe_rows_sweep_pr48.jsonl``): to the tokens, 131,072 slots out
+    of the buffer, 6.07-6.14 and 6.14-6.20 ms by XLA (46-47 ns a slot) for
+    1.55-1.74 and 1.58-1.76 by the kernel; to the buffer, 32,768 slots out
+    of the tokens, 0.62 by XLA (18.8 ns a slot) for 0.72-0.82 by the kernel
+    forward, and 2.35-2.54 for 1.23-1.31 in ``sum_rows``' transpose, where
+    what XLA loses is not a row's price but the rows' dots sent back by a
+    gather of 131,072 numbers (1.1 ms; the kernel's form sorts them, 0.2).
+    **So: the kernel on the side whose source is 2 ** 27 bytes or more**,
+    on a TPU or where the kernels are named (``implementation="megablox"``:
+    a compile for a chip that is not attached), for rows the kernel can
+    move; XLA's gather elsewhere, and off the TPU as :func:`grouped_matmul`
+    falls back to ``ragged_dot``."""
     if not rows_kernel_fits(width, dtype):
-        return "gather"
+        return _GATHERS
     if implementation == "interpret":
-        return "interpret"
-    large = tokens * width * jnp.dtype(dtype).itemsize >= _GATHER_CLIFF
-    return "kernel" if _named(implementation) == "megablox" and large \
-        else "gather"
+        return "interpret", "interpret"
+    if _named(implementation) != "megablox":
+        return _GATHERS
+    row = width * jnp.dtype(dtype).itemsize
+    return tuple(
+        "kernel" if source * row >= _GATHER_CLIFF else "gather"
+        for source in (tokens, _buffer_rows(tokens * top_k, count,
+                                            num_experts)))
 
 
 def _rows_of_tokens(y, weight, inv, n_live, top_k: int):
@@ -475,17 +506,18 @@ def _rows_to_tokens(y, weight, inv, n_live, top_k: int, out_dtype, path):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def take_rows(m, token, inv, n_live, top_k: int, path: str = "gather"):
+def take_rows(m, token, inv, n_live, top_k: int, path=_GATHERS):
     """``xs[r] = m[token[r]]`` for the live rows ``r < n_live`` of the
     buffer, 0 for the others. Its transpose is :func:`sum_rows` without the
     weights, and is written down as that: autodiff would make a scatter-add
-    of it. ``path`` (:func:`rows_path`) is how a row is fetched, here and in
-    the transpose."""
-    if path == "gather":
+    of it. ``path`` (:func:`rows_path`) is how a row is fetched: to the
+    buffer here, to the tokens in the transpose."""
+    to_buffer, _ = path
+    if to_buffer == "gather":
         live = jnp.arange(token.shape[0]) < n_live
         return jnp.where(live[:, None], m[token], 0)
     return sum_live_rows(m, token[:, None], n_live,
-                         interpret=path == "interpret")
+                         interpret=to_buffer == "interpret")
 
 
 def _take_rows_fwd(m, token, inv, n_live, top_k, path):
@@ -495,7 +527,7 @@ def _take_rows_fwd(m, token, inv, n_live, top_k, path):
 def _take_rows_bwd(top_k, path, kept, d_xs):
     inv, n_live = kept
     return _rows_to_tokens(d_xs, None, inv, n_live, top_k, d_xs.dtype,
-                           path), None, None, None
+                           path[1]), None, None, None
 
 
 take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
@@ -503,7 +535,7 @@ take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def sum_rows(y, weight, order, inv, n_live, top_k: int, out_dtype=None,
-             path: str = "gather"):
+             path=_GATHERS):
     """``out[t] = sum over j of weight[t k + j] * y[inv[t k + j]]`` over the
     pairs whose row is live, products and sum in float32, in ``y``'s dtype
     (or ``out_dtype``: float32 where the ranks' parts are still to be
@@ -511,9 +543,10 @@ def sum_rows(y, weight, order, inv, n_live, top_k: int, out_dtype=None,
     through the sort's inverse. Its transpose is :func:`take_rows` times the
     weights: ``d y[r] = weight[order[r]] * d out[token[r]]``, and
     ``d weight[p]`` is the dot of pair ``p``'s row with its token's
-    cotangent."""
+    cotangent. Of ``path`` (:func:`rows_path`) the side to the tokens here,
+    the side to the buffer in the transpose."""
     return _rows_to_tokens(y, weight, inv, n_live, top_k,
-                           out_dtype or y.dtype, path)
+                           out_dtype or y.dtype, path[1])
 
 
 def _sum_rows_fwd(y, weight, order, inv, n_live, top_k, out_dtype, path):
@@ -525,7 +558,8 @@ def _sum_rows_bwd(top_k, out_dtype, path, kept, d_out):
     y, weight, order, inv, n_live = kept
     rows = y.shape[0]
     pair = order[:rows]
-    if path == "gather":
+    to_buffer, _ = path
+    if to_buffer == "gather":
         live = (jnp.arange(rows) < n_live)[:, None]
         g = d_out[pair // top_k].astype(jnp.float32)
         d_y = jnp.where(live, g * weight[pair][:, None], 0).astype(y.dtype)
@@ -542,7 +576,7 @@ def _sum_rows_bwd(top_k, out_dtype, path, kept, d_out):
     d_y, dots = sum_live_rows(
         d_out, (pair // top_k)[:, None], n_live,
         sort_with(inv, weight)[1][:rows, None], dot_with=y,
-        out_dtype=y.dtype, interpret=path == "interpret")
+        out_dtype=y.dtype, interpret=to_buffer == "interpret")
     if order.shape[0] == inv.shape[0]:
         at_pair = sort_with(order, jnp.pad(
             dots[:, 0], (0, inv.shape[0] - rows)))[1]
@@ -557,7 +591,7 @@ sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
 
 def _held_rows(m, pair_weight, order, inv, sizes, n_held, w_in, w_out, *,
                rows: int, top_k: int, implementation: str,
-               path: str = "gather", out_dtype=None):
+               path=_GATHERS, out_dtype=None):
     """The held experts' part of the layer's result from a buffer of ``rows``
     rows: the first ``rows`` (token, choice) pairs in ``order`` (sorted by
     expert, those of held experts first, ``n_held`` of them); ``inv`` is
@@ -603,7 +637,7 @@ def _in_passes(part, rows: int, m, pair_weight, order, inv, sizes, n_held,
 
 
 def _rank_part(m, chosen, weight, w_in, w_out, *, num_experts: int,
-               first: int, ranks: int, implementation: str, path: str):
+               first: int, ranks: int, implementation: str, path):
     """What one rank adds to the layer's result: ``m [T, F]`` its own tokens
     with their ``chosen [T, k]`` experts and ``weight [T, k]``, ``w_in`` /
     ``w_out`` the stacks of the experts it holds, ``first`` the first of
@@ -641,22 +675,22 @@ def _rank_part(m, chosen, weight, w_in, w_out, *, num_experts: int,
     # Recomputed in the backward pass: little arithmetic, and the row
     # buffers (0.3 GB a layer at the usual size, four times that at the
     # other) are then never kept.
-    # The second buffer, which few steps take, keeps XLA's gathers whatever
-    # ``path`` is: the row kernel there too is twenty more kernels in
-    # Mellum2's step, 38 MB more of a 342 MB program to load at every start
-    # and 3.6 s more to trace and lower (PERF.md, PR 36; PR 33 kept that
-    # branch's one gather for the same reason).
+    # The second buffer, which few steps take, keeps XLA's gathers on both
+    # sides whatever ``path`` is: the row kernel there too is twenty more
+    # kernels in Mellum2's step, 38 MB more of a 342 MB program to load at
+    # every start and 3.6 s more to trace and lower (PERF.md, PR 36; PR 33
+    # kept that branch's one gather for the same reason).
     part = lambda rows, path=path: jax.checkpoint(functools.partial(
         _held_rows, rows=rows, top_k=k, implementation=implementation,
         path=path, out_dtype=jnp.float32 if ranks > 1 else None))
-    usual = _whole_tiles(int(_BUFFER_SHARE * pairs * count / e))
+    usual = _buffer_rows(pairs, count, e)
     operands = (m, weight.reshape(-1), order, inv, sizes, n_held, w_in,
                 w_out)
-    if usual >= pairs:
+    if usual == pairs:
         y = part(pairs)(*operands)
     else:
-        second = part(pairs, "gather") if ranks == 1 else functools.partial(
-            _in_passes, part(usual, "gather"), usual)
+        second = part(pairs, _GATHERS) if ranks == 1 else functools.partial(
+            _in_passes, part(usual, _GATHERS), usual)
         y = jax.lax.cond(n_held <= usual, part(usual), second, *operands)
     if ranks > 1:
         with jax.named_scope("moe_exchange_out"):
@@ -801,8 +835,9 @@ class HeldExpertsMlp(nn.Module):
     gather (:func:`sum_rows`: the sort is a permutation, so every token reads
     its ``k`` rows through the inverse and adds them in float32; each
     gather's backward pass is the other, so no row is scatter-added in
-    either direction; ``moe.rows.calls`` counts the calls, by whether XLA's
-    gather or the row kernel fetches a row: :func:`rows_path`;
+    either direction; ``moe.rows.calls`` counts the calls, by the side of
+    the movement and whether XLA's gather or the row kernel fetches a row
+    there: :func:`rows_path`;
     ``moe.gmm.calls`` the grouped-matmul kernels traced, by kernel, tile and
     whether the tile divides its product: :func:`gmm_tile`). The row buffer is
     static: twice what a uniform router would send (``_BUFFER_SHARE``), and
@@ -865,16 +900,23 @@ class HeldExpertsMlp(nn.Module):
                 m, None if router_state is None
                 else router_state.reshape(b * s, -1))
         # Rows go to the buffer and back through the sort's permutation and
-        # its inverse, fetched by the row kernel or by XLA's gathers (a rank
-        # sees its own tokens and, with an exchange, its ranks'); counted
-        # here, once a layer call, as the path is static.
-        path = rows_path(self.implementation, ranks * b * s // math.prod(
-            mesh.shape[a] for a in axes), f, self.dtype)
+        # its inverse, each side fetched by the row kernel or by XLA's
+        # gathers (a rank sees its own tokens and, with an exchange, its
+        # ranks'); counted here, once a layer call and side, as the path is
+        # static. An initialisation keeps only the parameters: no kernel is
+        # traced and lowered for rows that are never moved.
+        path = _GATHERS if self.is_initializing() else rows_path(
+            self.implementation, ranks * b * s // math.prod(
+                mesh.shape[a] for a in axes), f, self.dtype,
+            chosen.shape[-1], count // ranks, e)
         registry = get_tracer().registry
-        registry.counter(
+        calls = registry.counter(
             "moe.rows.calls",
-            "expert-layer calls traced, by the way their rows move",
-        ).inc(path="gather" if path == "gather" else "kernel")
+            "expert-layer calls traced, by the side of their rows' movement "
+            "and the way it is fetched")
+        for side, way in zip(("buffer", "tokens"), path):
+            calls.inc(side=side,
+                      path="gather" if way == "gather" else "kernel")
         if ranks > 1:
             registry.counter(
                 "moe.exchange.calls",

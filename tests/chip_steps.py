@@ -67,10 +67,16 @@ def _bench():
 
 
 def _rows_calls(since=None):
-    """``moe.rows.calls`` by path, less what it read at ``since``."""
-    calls = get_tracer().registry.counter("moe.rows.calls")
-    return {path: calls.value(path=path) - (since[path] if since else 0)
-            for path in ("gather", "kernel", "scatter_add")}
+    """``moe.rows.calls`` as ``{(side, path): calls}``: the series that
+    moved since ``since`` (a call's own return) by how far, or every
+    series' count."""
+    series = {tuple(dict(key)[label] for label in ("side", "path")): n
+              for key, n in get_tracer().registry.counter(
+                  "moe.rows.calls").series().items()}
+    if since is None:
+        return series
+    return {key: n - since.get(key, 0) for key, n in series.items()
+            if n > since.get(key, 0)}
 
 
 def _gmm_calls(since=None):

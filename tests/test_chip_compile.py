@@ -11,7 +11,8 @@ A compile that passes is not a chip run — chip_smoke.py is.
 import jax
 import jax.numpy as jnp
 import pytest
-from chip_steps import _bench, _row_scatters, v5e_chip  # noqa: F401
+from chip_steps import (_bench, _row_scatters, _rows_calls,  # noqa: F401
+                        v5e_chip)
 from jax.sharding import SingleDeviceSharding
 
 from deeplearning_cfn_tpu.ops.attention import fused_attention
@@ -71,6 +72,7 @@ def test_sdar_step_compiles_and_fits_a_v5e(v5e_chip):
     before = (calls.value(mask="block_diffusion", path="kernel"),
               calls.value(mask="causal", path="kernel"), blocks.value(),
               kept.value())
+    rows_before = _rows_calls()
     cell = manifest.Cell(manifest.load_manifest(),
                          "sdar_30b_a3b_train_bd_8k")
     assert cell.chips == 1
@@ -101,7 +103,7 @@ def test_sdar_step_compiles_and_fits_a_v5e(v5e_chip):
     text = compiled.as_text()
     kernels = [line for line in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(kernels) == 150
+    assert len(kernels) == 150 + 12
     name = lambda line: re.search(r'op_name="([^"]*)"', line).group(1)
     flash = [line for line in kernels if "core_attention/flash_" in line]
     # A layer: forward, dK/dV, dQ, and none again: the recomputation reads
@@ -118,7 +120,22 @@ def test_sdar_step_compiles_and_fits_a_v5e(v5e_chip):
     rope = [line for line in kernels if "/rope/" in name(line)]
     assert len(rope) == 36 and all("16384" in line for line in rope)
     assert len([line for line in kernels if "/moe_experts/" in name(line)
-                ]) == len(kernels) - 54
+                ]) == len(kernels) - 54 - 12
+    # 16,384 positions of 2048 are a source of 2 ** 26 bytes and the buffer
+    # of 32,768 rows one of 2 ** 27: the step's six layers fetch a row to the
+    # buffer by XLA's gather and to the tokens by the row kernel; the
+    # initialisation traces one row. A layer's two kernels: the rows'
+    # cotangents back to the tokens under ``moe_dispatch``, the rows summed
+    # into their tokens under ``moe_combine``, once (the recomputed sum is
+    # dead code).
+    assert _rows_calls(rows_before) == {
+        ("buffer", "gather"): 12, ("tokens", "gather"): 6,
+        ("tokens", "kernel"): 6}
+    rows = [name(line) for line in kernels
+            if name(line).endswith("/live_rows/pallas_call")]
+    assert len(rows) == 12
+    assert sum("/moe_dispatch/" in line for line in rows) == 6
+    assert sum("/moe_combine/" in line for line in rows) == 6
     for scope in ("bd_noise", "qk_norm", "moe_router", "lm_head", "lm_loss"):
         assert re.search(rf'op_name="[^"]*\b{scope}\b', text), scope
     assert not _row_scatters(text)

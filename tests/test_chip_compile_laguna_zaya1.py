@@ -109,8 +109,10 @@ def test_zaya1_step_compiles_and_fits_a_v5e(v5e_chip):
     assert _gmm_calls(gmm_before) == {
         ("gmm", "256x2048x1024", "yes"), ("gmm_t", "256x4096x512", "yes"),
         ("gmm_t", "256x2048x1024", "yes"), ("tgmm", "256x1024x1024", "yes")}
-    assert _rows_calls(rows_before) == {"gather": 10, "kernel": 0,
-                                        "scatter_add": 0}
+    # The tokens and the one buffer of every pair, 8,192 rows of 2048 each
+    # (33.5 MB): both sides of every layer by XLA's gathers.
+    assert _rows_calls(rows_before) == {("buffer", "gather"): 10,
+                                        ("tokens", "gather"): 10}
     # Traced twice (the parameters' shapes, the step), five layers each.
     assert (mixed.value() - before[0], turned.value(path="kernel")
             - before[1], turned.value(path="xla") - before[2]) == (10, 20, 0)
@@ -164,10 +166,11 @@ def test_laguna_step_compiles_and_fits_a_v5e(v5e_chip):
         ("tgmm", "256x1024x1024", "yes"), ("tgmm", "256x512x2048", "yes"),
         ("tgmm", "512x1024x1024", "yes"), ("tgmm", "512x512x2048", "yes")}
     # Four expert layers, each traced twice, every one moving its rows by
-    # XLA's gathers under either buffer: 8,192 tokens of 2048 are a source
-    # of 33.5 MB, under the size from which the row kernel is the cheaper.
-    assert _rows_calls(rows_before) == {"gather": 8, "kernel": 0,
-                                        "scatter_add": 0}
+    # XLA's gathers on both sides under either buffer: 8,192 tokens of 2048
+    # are a source of 33.5 MB and the usual buffer's 16,384 rows one of 67,
+    # under the size from which the row kernel is the cheaper.
+    assert _rows_calls(rows_before) == {("buffer", "gather"): 8,
+                                        ("tokens", "gather"): 8}
     # ``compile_step`` traces the model twice, once for the parameters'
     # shapes and once in the step: each trace turns q and k of five layers.
     assert {path: calls.value(path=path) - n

@@ -104,10 +104,12 @@ def test_mellum2_step_compiles_for_four_v5e_chips(v5e_chip):
         == {"flash": 4, "rope": 8, "gmm": 4}
     assert exchanged.value(**label) - exchanges == 4
     # A rank's 32,768 tokens of 2304 (its four ranks' after the exchange's
-    # gather) are a source of 151 MB: the step's four layers fetch their rows
-    # by the row kernel; the initialisation traces one row, by XLA's gather.
-    assert _rows_calls(rows_before) == {"gather": 4, "kernel": 4,
-                                        "scatter_add": 0}
+    # gather) are a source of 151 MB and its buffer of 131,072 rows one of
+    # 604: the step's four layers fetch their rows by the row kernel on both
+    # sides; the initialisation traces one row, by XLA's gather.
+    assert _rows_calls(rows_before) == {
+        ("buffer", "gather"): 4, ("tokens", "gather"): 4,
+        ("buffer", "kernel"): 4, ("tokens", "kernel"): 4}
     # A rank's buffer of 131,072 rows in 16 groups at 2304, 1792 and 896:
     # every kernel's tile divides what it is asked (``divides=yes``), each
     # product's three kernels at a tile of their own.
@@ -305,6 +307,7 @@ def test_keye_step_compiles_and_fits_a_v5e(v5e_chip):
     before = (calls.value(mask="selected", path="kernel"),
               calls.value(mask="causal", path="kernel"),
               selections.value(path="kernel"), blocks.value())
+    rows_before = _rows_calls()
     cell = manifest.Cell(manifest.load_manifest(),
                          "keye_vl2_30b_a3b_train_16k")
     assert cell.chips == 1
@@ -330,9 +333,17 @@ def test_keye_step_compiles_and_fits_a_v5e(v5e_chip):
     text = compiled.as_text()
     kernels = [line for line in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line]
+    # SDAR's expert layer at SDAR's shape: a row goes to the buffer by XLA's
+    # gather (the tokens are 2 ** 26 bytes) and to the tokens by the row
+    # kernel (the buffer is 2 ** 27); the initialisation traces one row.
+    assert _rows_calls(rows_before) == {
+        ("buffer", "gather"): 12, ("tokens", "gather"): 6,
+        ("tokens", "kernel"): 6}
     # A layer: 3 flash, the selection, the loss's pass, 6 rotary, 16 grouped
-    # matmuls.
-    assert len(kernels) == 6 * (3 + 1 + 1 + 6 + 16) == 162
+    # matmuls, 2 of the row kernel (the rows' cotangents to the tokens, the
+    # rows summed into their tokens).
+    assert len(kernels) == 6 * (3 + 1 + 1 + 6 + 16 + 2) == 174
+    assert sum("/live_rows/pallas_call" in line for line in kernels) == 12
     name = lambda line: re.search(r'op_name="([^"]*)"', line).group(1)
     for layer in range(6):
         mine = re.compile(r"/(flash_\w+|index_select|index_loss)\b")

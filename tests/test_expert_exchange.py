@@ -330,7 +330,8 @@ def test_exchange_gives_the_whole_layer_and_its_gradients(devices, routing,
         assert 1.0 <= float(aux4["rank_load_max_over_mean"]) < ranks
 
 
-@pytest.mark.parametrize("width,path", [(F, "gather"), (128, "interpret")])
+@pytest.mark.parametrize("width,path", [(F, ("gather", "gather")),
+                                        (128, ("interpret", "interpret"))])
 def test_windows_of_the_second_buffer_add_up(width, path):
     """``_in_passes`` alone: three windows of 40 rows over 100 sorted pairs
     (the last one padded) give what one buffer of every pair gives, by XLA's
@@ -364,8 +365,9 @@ def test_windows_of_the_second_buffer_add_up(width, path):
             fn(m, weight, *fixed, w_in, w_out)))
 
     moved = (m, weight, w_in, w_out)
-    want = jax.value_and_grad(loss(part(tokens * top_k, "gather")),
-                              argnums=(0, 1, 2, 3))(*moved)
+    want = jax.value_and_grad(
+        loss(part(tokens * top_k, ("gather", "gather"))),
+        argnums=(0, 1, 2, 3))(*moved)
     got = jax.value_and_grad(loss(functools.partial(
         _in_passes, part(40, path), 40)), argnums=(0, 1, 2, 3))(*moved)
     for a, b in zip(jax.tree_util.tree_leaves(got),

@@ -305,8 +305,9 @@ _BUFFERS = {
 # (choices a token, the rows' width, how a row is fetched): XLA's gathers at
 # any width, and the row kernel interpreted, which wants whole lane tiles: 256,
 # and 384 = 3 x 128, no power of two, as Mellum2's 2304 = 18 x 128 is none.
-_FETCHES = [(1, _WIDTH, "gather"), (4, _WIDTH, "gather"),
-            (1, 256, "interpret"), (8, 384, "interpret")]
+_GATHERS, _INTERPRETED = ("gather", "gather"), ("interpret", "interpret")
+_FETCHES = [(1, _WIDTH, _GATHERS), (4, _WIDTH, _GATHERS),
+            (1, 256, _INTERPRETED), (8, 384, _INTERPRETED)]
 
 
 def _sorted_routing(seed, top_k, held, experts=_EXPERTS):
@@ -390,6 +391,49 @@ def test_rows_move_as_the_plain_forms_move_them(buffer, top_k, width, path):
         assert not np.any(got) and not np.any(back_got(d_out)[0])
 
 
+@pytest.mark.parametrize("path", [("gather", "interpret"),
+                                  ("interpret", "gather")])
+@pytest.mark.parametrize("buffer", sorted(_BUFFERS))
+def test_each_side_of_the_movement_takes_its_own_path(buffer, path):
+    """``take_rows`` and ``sum_rows`` where one side of the movement goes by
+    XLA's gathers and the other by the row kernel (SDAR's and Keye's layers:
+    the buffer is a source of 2 ** 27 bytes and the tokens are not), and the
+    mirror, against both sides by XLA's gathers: values and every gradient
+    (``m``; ``y`` and the weights), over dead slots, tokens with no live
+    pair and a buffer that is not full."""
+    from deeplearning_cfn_tpu.models.moe import sum_rows, take_rows
+
+    top_k, width = 2, 128
+    held, spare = _BUFFERS[buffer]
+    _, group, order, inv, n_held = _sorted_routing(11, top_k, held)
+    pairs = _TOKENS * top_k
+    rows = pairs if spare is None else max(n_held + spare, 1)
+    n_live = jnp.minimum(n_held, rows)
+    token = order[:rows] // top_k
+    if buffer == "live_rows_under_the_buffer":
+        live = (np.asarray(inv) < int(n_live)).reshape(-1, top_k)
+        assert int(n_live) < rows < pairs           # not full, dead slots
+        assert (~live.any(axis=1)).any() and live.all(axis=1).any()
+    keys = jax.random.split(jax.random.PRNGKey(5), 5)
+    m = jax.random.normal(keys[0], (_TOKENS, width))
+    y = jax.random.normal(keys[1], (rows, width))
+    weight = jax.random.uniform(keys[2], (pairs,))
+    d_xs = jax.random.normal(keys[3], (rows, width))
+    d_out = jax.random.normal(keys[4], (_TOKENS, width))
+
+    def both(path):
+        xs, take_back = jax.vjp(
+            lambda m: take_rows(m, token, inv, n_live, top_k, path), m)
+        out, sum_back = jax.vjp(
+            lambda y, w: sum_rows(y, w, order, inv, n_live, top_k, None,
+                                  path), y, weight)
+        return xs, take_back(d_xs)[0], out, *sum_back(d_out)
+
+    for a, b in zip(both(path), both(_GATHERS)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(a, b, atol=1e-5)
+
+
 def test_a_tokens_live_pairs_come_first_in_the_choices_order():
     """``_live_first``: what the kernel walks on the tokens' side."""
     from deeplearning_cfn_tpu.models.moe import _live_first
@@ -424,9 +468,10 @@ def test_held_experts_layer_counts_a_call_once(top_k, held, branches, width,
                                                impl, path):
     """``moe.rows.calls`` counts a layer call when it is traced: once, not
     once a branch of the ``lax.cond`` over the two buffers nor again where
-    ``jax.checkpoint`` traces the rows' part for the backward pass; under
-    ``path=kernel`` where the row kernel fetches the rows (rows of whole
-    lane tiles, the kernels named or a TPU), else ``path=gather``."""
+    ``jax.checkpoint`` traces the rows' part for the backward pass; for
+    each side of the movement (``side=buffer|tokens``) under ``path=kernel``
+    where the row kernel fetches the rows (rows of whole lane tiles, the
+    kernels named or a TPU), else ``path=gather``."""
     from deeplearning_cfn_tpu.models.moe import HeldExpertsMlp
     from deeplearning_cfn_tpu.obs.trace import get_tracer
 
@@ -436,12 +481,14 @@ def test_held_experts_layer_counts_a_call_once(top_k, held, branches, width,
                            implementation=impl)
     params = layer.init(jax.random.PRNGKey(1), x)["params"]
     calls = get_tracer().registry.counter("moe.rows.calls")
-    paths = ("gather", "kernel", "scatter_add")
-    before = {p: calls.value(path=p) for p in paths}
+    before = calls.series()
     loss = lambda p: jnp.sum(layer.apply({"params": p}, x)[0] ** 2)
     text = str(jax.make_jaxpr(jax.grad(loss))(params))
-    assert {p: calls.value(path=p) - n for p, n in before.items()} \
-        == {p: int(p == path) for p in paths}
+    # Once a layer call and side of the movement.
+    assert {key: n - before.get(key, 0) for key, n in calls.series().items()
+            if n > before.get(key, 0)} \
+        == {(("path", path), ("side", side)): 1
+            for side in ("buffer", "tokens")}
     # (The kernel's own text has its ``pl.when``s.)
     assert path == "kernel" or (" cond[" in text) == bool(branches)
     assert ("live_rows" in text) == (path == "kernel")
@@ -451,26 +498,56 @@ def test_held_experts_layer_counts_a_call_once(top_k, held, branches, width,
     assert not re.findall(rf",{width}\] = scatter", text)
 
 
-@pytest.mark.parametrize("tokens,width,dtype,impl,path", [
-    # A rank of Mellum2's four: 32,768 tokens of 2304 in bfloat16, 151 MB.
-    (32768, 2304, jnp.bfloat16, "megablox", "kernel"),
-    (32768, 2048, jnp.bfloat16, "megablox", "kernel"),      # 2 ** 27 bytes
-    (16384, 2048, jnp.float32, "megablox", "kernel"),
-    (24, 2304, jnp.bfloat16, "interpret", "interpret"),
-    # Under 2 ** 27 bytes of tokens XLA's gather is the cheaper: Laguna's
-    # and ZAYA1's 8,192 tokens of 2048, and 24,576 of them.
-    (8192, 2048, jnp.bfloat16, "megablox", "gather"),
-    (24576, 2048, jnp.bfloat16, "megablox", "gather"),
+# (choices a token, experts held, experts) of a rank of Mellum2's four: the
+# usual buffer is four rows a token.
+_MELLUM2 = (8, 16, 64)
+
+
+@pytest.mark.parametrize("tokens,width,dtype,impl,routing,path", [
+    # A rank of Mellum2's four: 32,768 tokens of 2304 in bfloat16, 151 MB,
+    # and a buffer of 131,072 rows, 604 MB.
+    (32768, 2304, jnp.bfloat16, "megablox", _MELLUM2, ("kernel", "kernel")),
+    (32768, 2048, jnp.bfloat16, "megablox", _MELLUM2,       # 2 ** 27 bytes
+     ("kernel", "kernel")),
+    (16384, 2048, jnp.float32, "megablox", _MELLUM2, ("kernel", "kernel")),
+    (24, 2304, jnp.bfloat16, "interpret", _MELLUM2,
+     ("interpret", "interpret")),
+    # Under 2 ** 27 bytes of tokens XLA's gather is the cheaper to the
+    # buffer: 8,192 tokens of 2048, and 24,576 of them; their buffers of
+    # four rows a token are 2 ** 27 bytes and more.
+    (8192, 2048, jnp.bfloat16, "megablox", _MELLUM2, ("gather", "kernel")),
+    (24576, 2048, jnp.bfloat16, "megablox", _MELLUM2, ("gather", "kernel")),
     # Off the TPU, as ``grouped_matmul`` falls back to ``ragged_dot``.
-    (32768, 2304, jnp.bfloat16, "auto", "gather"),
-    (32768, 2304, jnp.bfloat16, "ragged_dot", "gather"),
+    (32768, 2304, jnp.bfloat16, "auto", _MELLUM2, ("gather", "gather")),
+    (32768, 2304, jnp.bfloat16, "ragged_dot", _MELLUM2,
+     ("gather", "gather")),
     # Rows the kernel cannot move: not whole lane tiles; 16-bit floats.
-    (32768, 2304 + 64, jnp.bfloat16, "megablox", "gather"),
-    (32768, 2304, jnp.float16, "interpret", "gather"),
+    (32768, 2304 + 64, jnp.bfloat16, "megablox", _MELLUM2,
+     ("gather", "gather")),
+    (32768, 2304, jnp.float16, "interpret", _MELLUM2, ("gather", "gather")),
+    # The five expert cells. SDAR's and Keye's: 16,384 positions of 2048
+    # (2 ** 26 bytes) and 16 of 128 experts held, a buffer of 32,768 rows
+    # (2 ** 27): to the buffer by XLA's gather, to the tokens by the kernel.
+    (16384, 2048, jnp.bfloat16, "megablox", (8, 16, 128),
+     ("gather", "kernel")),
+    # Laguna's: 8,192 tokens (2 ** 25), a buffer of 16,384 rows (2 ** 26).
+    (8192, 2048, jnp.bfloat16, "megablox", (8, 32, 256),
+     ("gather", "gather")),
+    # ZAYA1's: one choice a token, the one buffer of every pair (2 ** 25).
+    (8192, 2048, jnp.bfloat16, "megablox", (1, 8, 16), ("gather", "gather")),
+    # Off the TPU at SDAR's shape, and with the kernel interpreted.
+    (16384, 2048, jnp.bfloat16, "auto", (8, 16, 128), ("gather", "gather")),
+    (16384, 2048, jnp.bfloat16, "interpret", (8, 16, 128),
+     ("interpret", "interpret")),
+    # A buffer one tile under the size: 2 ** 27 bytes less 512 rows.
+    (16128, 2048, jnp.bfloat16, "megablox", (8, 16, 128),
+     ("gather", "gather")),
 ])
-def test_how_a_row_is_fetched_follows_what_the_layer_sees(tokens, width,
-                                                          dtype, impl, path):
+def test_how_a_row_is_fetched_follows_what_the_layer_sees(
+        tokens, width, dtype, impl, routing, path):
+    """Each side of the rows' movement takes its path from its own source:
+    to the buffer from the tokens, to the tokens from the usual buffer."""
     from deeplearning_cfn_tpu.models.moe import rows_path
 
     assert jax.default_backend() == "cpu"
-    assert rows_path(impl, tokens, width, dtype) == path
+    assert rows_path(impl, tokens, width, dtype, *routing) == path

@@ -6,7 +6,8 @@
 
 For each of the expert cells' buffer shapes (rows of 2048 in bfloat16, the
 (token, choice) pairs of 8,192 tokens sorted by expert as ``HeldExpertsMlp``
-sorts them; ``--case shape:tokens:width`` names another size, and
+sorts them; ``--case shape:tokens:width[:share]`` names another size and
+another share of the choices on held experts, and
 ``--separating`` the cases that tell a row's width from its source's size:
 a rank of Mellum2's routing at widths 2048 / 2304 / 2560 and 8,192 / 32,768
 tokens, Laguna's at width 2304) it times, forward and backward apart:
@@ -79,6 +80,12 @@ SHAPES = {
     # tokens (``mellum2_rank:32768:2304``): 262,144 pairs, 16 of 64 experts
     # held, twice a uniform router's 65,536 rows.
     "mellum2_rank": (8, 64, 16, False, 1 / 4),
+    # SDAR-30B-A3B's and Keye-VL-2.0-30B-A3B's layer (``sdar:16384:2048``):
+    # 131,072 pairs, 16 of 128 experts held, twice a uniform router's 16,384
+    # rows: a buffer of 2 ** 27 bytes over tokens of 2 ** 26. Their steps'
+    # layers hold 14.8 to 21.4 k live rows (``sdar:16384:2048:0.113`` and
+    # ``:0.163``).
+    "sdar": (8, 128, 16, False, 1 / 8),
 }
 # What tells a row's width from its source's size (PERF.md, PR 36).
 SEPARATING = [f"mellum2_rank:{t}:{w}" for t in (8192, 32768)
@@ -236,11 +243,11 @@ def viewed_sum(view, y, weight, inv, n_live, top_k):
     return total.astype(y.dtype)
 
 
-def measure(name, steps, say, quick=False, views=False):
-    top_k, experts, held, every_pair, share = SHAPES[name]
+def measure(name, steps, say, quick=False, views=False, share=None):
+    top_k, experts, held, every_pair, usual_share = SHAPES[name]
+    share = share or usual_share
     pairs = TOKENS * top_k
-    rows = pairs if every_pair else min(pairs, moe._whole_tiles(
-        int(moe._BUFFER_SHARE * pairs * held / experts)))
+    rows = pairs if every_pair else moe._buffer_rows(pairs, held, experts)
     keys = jax.random.split(jax.random.PRNGKey(0), 5)
     group = routing(keys[0], top_k, experts, held, share)
     order = order_of(group)
@@ -282,12 +289,13 @@ def measure(name, steps, say, quick=False, views=False):
         say(line)
         return got
 
-    take_back = lambda path: lambda d, t, i, n: jax.vjp(
-        lambda m: moe.take_rows(m, t, i, n, top_k, path), m)[1](d)[0]
-    sum_there = lambda path: lambda y, w, o, i, n: moe.sum_rows(
-        y, w, o, i, n, top_k, None, path)
-    sum_back = lambda path: lambda y, w, o, i, n, g: jax.vjp(
-        lambda y, w: moe.sum_rows(y, w, o, i, n, top_k, None, path),
+    # Both sides of the movement by the one way (``moe.rows_path``'s pair).
+    take_back = lambda way: lambda d, t, i, n: jax.vjp(
+        lambda m: moe.take_rows(m, t, i, n, top_k, (way, way)), m)[1](d)[0]
+    sum_there = lambda way: lambda y, w, o, i, n: moe.sum_rows(
+        y, w, o, i, n, top_k, None, (way, way))
+    sum_back = lambda way: lambda y, w, o, i, n, g: jax.vjp(
+        lambda y, w: moe.sum_rows(y, w, o, i, n, top_k, None, (way, way)),
         y, w)[1](g)
     d_xs = y
     there = 2 * live_rows * WIDTH * 2 / peak * 1e3
@@ -302,8 +310,8 @@ def measure(name, steps, say, quick=False, views=False):
         want = timed("dispatch_fwd", "gather", plain_take,
                      (m, token, n_live), floor=there)
         timed("dispatch_fwd", "kernel", lambda m, t, i, n: moe.take_rows(
-            m, t, i, n, top_k, "kernel"), (m, token, inv, n_live), want=want,
-            floor=there)
+            m, t, i, n, top_k, ("kernel", "kernel")),
+            (m, token, inv, n_live), want=want, floor=there)
         for form, view in views.items():
             timed("dispatch_fwd", form, functools.partial(viewed_take, view),
                   (m, token, n_live), want=want, floor=there)
@@ -369,8 +377,9 @@ def main():
     ap.add_argument("--tokens", type=int, default=TOKENS)
     ap.add_argument("--width", type=int, default=WIDTH)
     ap.add_argument("--case", action="append", default=[],
-                    metavar="SHAPE:TOKENS:WIDTH",
-                    help="a shape at its own size (repeatable)")
+                    metavar="SHAPE:TOKENS:WIDTH[:SHARE]",
+                    help="a shape at its own size, and the share of the "
+                    "choices that fall on held experts (repeatable)")
     ap.add_argument("--separating", action="store_true",
                     help="the cases that tell the width from the size")
     ap.add_argument("--quick", action="store_true",
@@ -397,9 +406,10 @@ def main():
 
         say({"device": jax.devices()[0].device_kind, "steps": args.steps})
         for case in cases:
-            name, tokens, width = case.split(":")
+            name, tokens, width, *share = case.split(":")
             globals().update(TOKENS=int(tokens), WIDTH=int(width))
-            measure(name, args.steps, say, args.quick, args.views)
+            measure(name, args.steps, say, args.quick, args.views,
+                    float(share[0]) if share else None)
 
 
 if __name__ == "__main__":
