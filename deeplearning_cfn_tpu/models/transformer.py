@@ -53,10 +53,15 @@ class RMSNorm(nn.Module):
     def __call__(self, x):
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
                            jnp.float32)
-        x32 = x.astype(jnp.float32)
-        var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-        return (x32 * jax.lax.rsqrt(var + self.epsilon) * scale) \
-            .astype(self.dtype)
+        return rms_norm(x, scale, self.epsilon, self.dtype)
+
+
+def rms_norm(x: jnp.ndarray, scale: jnp.ndarray, epsilon: float,
+             dtype: Dtype) -> jnp.ndarray:
+    """:class:`RMSNorm`'s arithmetic over the last dimension."""
+    x32 = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+    return (x32 * jax.lax.rsqrt(var + epsilon) * scale).astype(dtype)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -147,7 +152,7 @@ def apply_rope(x: jnp.ndarray, rope: Rope, positions=None) -> jnp.ndarray:
 
 def rope_to_heads(x: jnp.ndarray, rope: Rope,
                   implementation: str = "auto", mesh=None,
-                  positions=None) -> jnp.ndarray:
+                  positions=None, norm=None) -> jnp.ndarray:
     """``x [B, S, H, D]`` turned by its positions, as ``[B, H, S, D]``: the
     kernel where ``ops/rope.py:kernel_engages`` says so (a head of whole lane
     tiles on a TPU), else :func:`apply_rope` and the transpose. Which one is
@@ -155,7 +160,10 @@ def rope_to_heads(x: jnp.ndarray, rope: Rope,
     labelled ``path=kernel|xla`` (docs/OBSERVABILITY.md). ``mesh`` is the
     step's, for the kernel (``ops/rope.py:rotate_to_heads``). ``positions``
     lists the rows' position ids where they are not ``0 .. S - 1``: the
-    kernel takes its tables from the host, so only the tables change."""
+    kernel takes its tables from the host, so only the tables change.
+    ``norm`` (a block with ``qk_norm``): ``(scale, epsilon)`` of an
+    :class:`RMSNorm` over each head before it turns: inside the kernel where
+    that runs, else :func:`rms_norm` before :func:`apply_rope`."""
     b, seq_len, h, head_dim = x.shape
     use_kernel, interpret = kernel_engages(implementation, seq_len, head_dim)
     get_tracer().registry.counter(
@@ -167,7 +175,10 @@ def rope_to_heads(x: jnp.ndarray, rope: Rope,
         # the caller's split of the last dimension, and XLA drops both.
         return rotate_to_heads(x.reshape(b, seq_len, h * head_dim),
                                *rope.tables(seq_len, head_dim, positions),
-                               head_dim, interpret=interpret, mesh=mesh)
+                               head_dim, interpret=interpret, mesh=mesh,
+                               norm=norm)
+    if norm is not None:
+        x = rms_norm(x, *norm, x.dtype)
     return apply_rope(x, rope, positions).transpose(0, 2, 1, 3)
 
 
@@ -582,10 +593,22 @@ class MultiHeadAttention(nn.Module):
             raise NotImplementedError(
                 "a block-diffusion layout is for a styled block's "
                 "self-attention in training and evaluation")
+        norms = (None, None)
         if st.qk_norm:
-            with jax.named_scope("qk_norm"):
-                q = RMSNorm(st.rms_eps, self.dtype, name="query_norm")(q)
-                k = RMSNorm(st.rms_eps, self.dtype, name="key_norm")(k)
+            norms = tuple((Leaf("scale", (head_dim,), name=name)(),
+                           st.rms_eps) for name in ("query_norm", "key_norm"))
+            # With rotary positions the norm goes where the turn goes: into
+            # the rotary kernel where that runs (``rope_to_heads``).
+            fused = st.rope is not None and kernel_engages(
+                self.attention_impl, q.shape[1], head_dim)[0]
+            get_tracer().registry.counter(
+                "attention.qk_norm.calls",
+                "q/k norm pairs traced, by where the norm is computed",
+            ).inc(path="fused" if fused else "xla")
+            if st.rope is None:
+                with jax.named_scope("qk_norm"):
+                    q, k = (rms_norm(t, *norm, self.dtype)
+                            for t, norm in zip((q, k), norms))
         if st.indexer and (layout is not None or st.rope is None
                            or st.window or bias is not None or not causal):
             raise NotImplementedError(
@@ -603,10 +626,12 @@ class MultiHeadAttention(nn.Module):
                 # A text row: every stream counts its tokens.
                 positions = np.tile(np.arange(x.shape[1]),
                                     (len(st.rope.sections), 1))
-            with jax.named_scope("rope"):
+            # In a block with ``qk_norm`` the norm and the turn are one
+            # call, under both names: ``qk_norm/rope``.
+            with jax.named_scope("qk_norm/rope" if st.qk_norm else "rope"):
                 q, k = (rope_to_heads(t, st.rope, self.attention_impl,
-                                      self._kernel_mesh(), positions)
-                        for t in (q, k))
+                                      self._kernel_mesh(), positions, norm)
+                        for t, norm in zip((q, k), norms))
             v = v.transpose(0, 2, 1, 3)
         else:
             q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # [B,H,S,D]

@@ -54,6 +54,20 @@ def _row_scatters(text, width=2048):
             and re.search(r"/moe_(dispatch|combine)/", line)]
 
 
+def _norms_by_xla(text, rows=16384):
+    """The instructions of a compiled step under ``qk_norm`` (or an
+    ``RMSNorm`` called ``query_norm`` / ``key_norm``) that hold a result of
+    ``rows`` positions and are no Pallas kernel's: XLA's norm over q or k,
+    forward or backward. The norm is inside the rotary kernel
+    (``ops/rope.py``), so there are none; what XLA still does there is the sum
+    of the scale's gradient over the kernel's grid steps."""
+    return [line.strip()[:200] for line in text.splitlines()
+            if re.search(r'op_name="[^"]*\b(qk|query|key)_norm\b', line)
+            and re.search(rf"= \(?\w+\[[\d,]*\b{rows}\b[\d,]*\]", line)
+            and "tpu_custom_call" not in line
+            and " get-tuple-element(" not in line]  # a kernel's second result
+
+
 def _bench():
     bench = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmark")

@@ -11,8 +11,8 @@ A compile that passes is not a chip run — chip_smoke.py is.
 import jax
 import jax.numpy as jnp
 import pytest
-from chip_steps import (_bench, _row_scatters, _rows_calls,  # noqa: F401
-                        v5e_chip)
+from chip_steps import (_bench, _norms_by_xla, _row_scatters,  # noqa: F401
+                        _rows_calls, v5e_chip)
 from jax.sharding import SingleDeviceSharding
 
 from deeplearning_cfn_tpu.ops.attention import fused_attention
@@ -69,9 +69,11 @@ def test_sdar_step_compiles_and_fits_a_v5e(v5e_chip):
     calls = registry.counter("attention.flash.calls")
     blocks = registry.counter("model.blocks.recomputed")
     kept = registry.counter("model.blocks.kept_flash")
+    norms = registry.counter("attention.qk_norm.calls")
     before = (calls.value(mask="block_diffusion", path="kernel"),
               calls.value(mask="causal", path="kernel"), blocks.value(),
-              kept.value())
+              kept.value(), norms.value(path="fused"),
+              norms.value(path="xla"))
     rows_before = _rows_calls()
     cell = manifest.Cell(manifest.load_manifest(),
                          "sdar_30b_a3b_train_bd_8k")
@@ -83,6 +85,9 @@ def test_sdar_step_compiles_and_fits_a_v5e(v5e_chip):
             calls.value(mask="causal", path="kernel") - before[1],
             blocks.value() - before[2], kept.value() - before[3]) \
         == (6, 6, 12, 12)
+    # A norm pair a layer, inside the rotary kernel both times.
+    assert (norms.value(path="fused") - before[4],
+            norms.value(path="xla") - before[5]) == (12, 0)
     # Six blocks' pairs over both copies: [1, 32, 16384] rows of 128
     # bfloat16 and a float32, 0.82 GB.
     assert registry.gauge("model.blocks.kept_bytes").value() \
@@ -119,6 +124,13 @@ def test_sdar_step_compiles_and_fits_a_v5e(v5e_chip):
                        (False, "flash_fwd")], own
     rope = [line for line in kernels if "/rope/" in name(line)]
     assert len(rope) == 36 and all("16384" in line for line in rope)
+    # q's and k's norm is inside them, forward, recomputed and backward, and
+    # XLA norms nothing of 16,384 positions beside them.
+    assert sorted(re.search(r"/self_attn/(.*)/pallas_call", name(line))
+                  .group(1) for line in rope) \
+        == ["qk_norm/rope/norm_rope_bwd"] * 12 \
+        + ["qk_norm/rope/norm_rope_fwd"] * 24
+    assert not _norms_by_xla(text)
     assert len([line for line in kernels if "/moe_experts/" in name(line)
                 ]) == len(kernels) - 54 - 12
     # 16,384 positions of 2048 are a source of 2 ** 26 bytes and the buffer

@@ -6,8 +6,8 @@ Mellum2's cell on four chips and of Granite's."""
 import jax
 import jax.numpy as jnp
 import pytest
-from chip_steps import (_bench, _gmm_calls, _row_scatters, _rows_calls,
-                        v5e_chip)  # noqa: F401
+from chip_steps import (_bench, _gmm_calls, _norms_by_xla,  # noqa: F401
+                        _row_scatters, _rows_calls, v5e_chip)
 from jax.sharding import SingleDeviceSharding
 
 
@@ -345,6 +345,12 @@ def test_keye_step_compiles_and_fits_a_v5e(v5e_chip):
     assert len(kernels) == 6 * (3 + 1 + 1 + 6 + 16 + 2) == 174
     assert sum("/live_rows/pallas_call" in line for line in kernels) == 12
     name = lambda line: re.search(r'op_name="([^"]*)"', line).group(1)
+    # The rotary kernels hold q's and k's norm (the indexer's heads of 64
+    # turn by XLA), and XLA norms nothing of 16,384 positions beside them.
+    rope = [name(line) for line in kernels if "/rope/" in name(line)]
+    assert len(rope) == 36 and all(
+        "/self_attn/qk_norm/rope/norm_rope_" in line for line in rope)
+    assert not _norms_by_xla(text)
     for layer in range(6):
         mine = re.compile(r"/(flash_\w+|index_select|index_loss)\b")
         own = sorted(

@@ -193,6 +193,39 @@ def test_convolution_kernels_run_a_device_each_under_the_wrapper(devices):
         np.testing.assert_allclose(g, w, rtol=0, atol=2e-5 * scale)
 
 
+def test_rotary_kernel_with_the_norm_runs_a_device_each_under_the_wrapper(
+        devices):
+    """Four sequences over a data=2 x expert=2 mesh through the rotary kernel
+    with a block's q/k norm inside (interpret mode), forward and backward,
+    against the same call on no mesh: the scale is whole on every device and
+    its gradient is summed over them; the wrapper was entered once."""
+    from deeplearning_cfn_tpu.models.transformer import Rope, rope_to_heads
+    from deeplearning_cfn_tpu.obs.trace import get_tracer
+
+    mesh = _mesh(devices, data=2, expert=2)
+    rope = Rope(theta=1_000_000.0)
+    kx, ks, kg = jax.random.split(jax.random.PRNGKey(0), 3)
+    x = 3.0 * jax.random.normal(kx, (4, 512, 2, 128), jnp.bfloat16)
+    scale = 1.0 + 0.25 * jax.random.normal(ks, (128,), jnp.float32)
+    weight = jax.random.normal(kg, (4, 2, 512, 128), jnp.float32)
+    calls = get_tracer().registry.counter("parallel.shard_map.calls")
+    before = calls.value(kernel="rope")
+
+    def loss(mesh):
+        return lambda x, scale: jnp.sum(weight * rope_to_heads(
+            x, rope, "interpret", mesh, norm=(scale, 1e-6)))
+
+    got = jax.jit(jax.value_and_grad(loss(mesh), argnums=(0, 1)))(x, scale)
+    assert calls.value(kernel="rope") == before + 1
+    want = jax.jit(jax.value_and_grad(loss(None), argnums=(0, 1)))(x, scale)
+    assert calls.value(kernel="rope") == before + 1
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        np.testing.assert_allclose(g, w, rtol=0,
+                                   atol=2e-5 * float(np.max(np.abs(w))))
+
+
 # -- the softmax router --------------------------------------------------------
 
 
